@@ -55,6 +55,11 @@ def run_warmup_cold(menu: bool, sf: float = 0.05) -> dict:
     from ..utils import metric, settings
     from . import tpch
 
+    # "first execution" must be honestly cold: no executable may come back
+    # from the persistent cache an earlier job (or the other phase) filled
+    import jax
+
+    jax.config.update("jax_enable_compilation_cache", False)
     cat = tpch.gen_tpch_cached(sf=sf)
     boot = Session(catalog=cat)
     out: dict = {"menu": bool(menu)}

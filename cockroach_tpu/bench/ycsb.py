@@ -58,8 +58,8 @@ def run_ycsb_e(
 
     Scans issue through Engine.scan_batch in groups of `concurrency` — the
     vectorized analog of the reference's concurrent YCSB workers (pkg/
-    workload/ycsb runs many goroutines against one store; over a remote-
-    attached TPU, batching is the only way past the 1/RTT serial floor)."""
+    workload/ycsb runs many goroutines against one store; batching keeps
+    the scan rate off the one-op-per-device-round-trip floor)."""
     import sys
 
     rng = np.random.default_rng(seed)

@@ -1,10 +1,12 @@
 """Canonical sort-key encoding — bit-packed u64 operands for lax.sort.
 
 The round-2 engine handed lax.sort one operand per null band / NaN band /
-value column (a k-key sort cost 2k+1 operands). On the TPU backend each
-lax.sort instantiation costs ~17-20s of XLA compile time REGARDLESS of shape,
-scaling with operand count (measured on v5e: 2 operands 16s, 3 operands 43s at
-1M rows) — so operand count, not row count, is the compile budget.
+value column (a k-key sort cost 2k+1 operands). For a v5e each lax.sort
+instantiation above a few thousand rows costs tens of seconds to minutes of
+XLA compile time, growing with operand count and rows (PR 22, compiled for a
+described v5e: u64 key + i32 perm 0.9 s at 4,096 rows, 37 s at 65,536, 55 s
+at 1M; the 6-operand MVCC sort 5 s at 4,096 rows, 75 s at 16,384) — so
+sort instantiations and their operand count are the compile budget.
 
 This module packs an ordered key list into the *minimum* number of sort
 operands: every key contributes a bit-segment stream
@@ -22,9 +24,9 @@ Value encodings (order-preserving within the segment's bit width):
   bits from the dictionary size.
 - BOOL: 1 bit.
 - BYTES: big-endian packed 64-bit word lanes (coldata.pack_be_words).
-- FLOAT: passes through as a NATIVE float64 sort operand — the x64 rewriter
-  on this TPU backend miscompiles f64<->u32 bitcasts (verified: negative
-  doubles collapse to f32-NaN bit patterns), so floats ride lax.sort's
+- FLOAT: passes through as a NATIVE float64 sort operand — XLA:TPU's X64
+  rewriting does not implement f64<->u32 bitcast-convert (observed on an
+  attached v5e, PR 22: UNIMPLEMENTED at compile), so floats ride lax.sort's
   comparator directly, with their NaN band packed as a bit-segment.
 
 DESC inverts value bits within the segment (floats: negation); NULL ordering

@@ -19,7 +19,7 @@ from __future__ import annotations
 
 import jax
 import numpy as np
-from ._compat import shard_map
+from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..coldata.batch import Batch

@@ -23,7 +23,7 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
-from ._compat import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from ..coldata.batch import Batch, Column

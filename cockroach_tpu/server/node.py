@@ -93,6 +93,9 @@ class Node:
               http_port: int | None = None,
               kv_port: int | None = None) -> "Node":
         self._stop.clear()
+        from ..sql import plancache
+
+        plancache.maybe_enable_compile_cache()
         self.liveness.heartbeat()  # own record exists before anything reads
 
         # disk health: WAL-backed engines get a monitor fed by their own

@@ -411,7 +411,6 @@ def run_cached_ex(rel, text: str | None = None):
 
     if not _cacheable():
         return rel.run(), "bypass", ""
-    maybe_enable_compile_cache()
     cache = cache_for(rel.catalog)
     plan = rel.optimized_plan()
     if _is_virtual_plan(plan):
@@ -536,15 +535,16 @@ _compile_cache_on = False
 
 
 def maybe_enable_compile_cache() -> None:
-    """Idempotently turn on JAX's persistent compilation cache when
-    ``sql.compile_cache.enabled`` is set — process restarts then reload
-    executables from disk instead of recompiling the kernel fleet."""
+    """Idempotently turn on JAX's persistent compilation cache unless
+    ``sql.compile_cache.enabled`` is off — process restarts then reload
+    executables from disk instead of recompiling the kernel fleet. Every
+    Session and Node calls this at construction."""
     global _compile_cache_on
     if _compile_cache_on or not settings.get("sql.compile_cache.enabled"):
         return
     from ..utils.backend import enable_compile_cache
 
-    enable_compile_cache(settings.get("sql.compile_cache.dir") or None)
+    enable_compile_cache()
     _compile_cache_on = True
 
 

@@ -83,6 +83,9 @@ class Session:
         confined to the tenant's table-id range, and capability checks
         gate CREATE TABLE / BACKUP. None = the unscoped legacy session
         (system-tenant powers, no restrictions)."""
+        from . import plancache
+
+        plancache.maybe_enable_compile_cache()
         self.catalog = catalog if catalog is not None else Catalog()
         # key_width must fit the WIDEST key family the session can write:
         # secondary-index entries are 21 bytes (kv/index.ENTRY_BYTES),

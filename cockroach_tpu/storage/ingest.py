@@ -118,23 +118,10 @@ class RunBuilder:
     def _merge(self, blocks: tuple) -> mvcc.KVBlock:
         if len(blocks) == 1:
             return blocks[0]
-        # the compaction merge picker's discipline: bitonic pallas kernel
-        # when eligible, concat + lax.sort otherwise
-        from ..utils import settings
-        from . import pallas_merge as pm
-
-        eng = self.engine
-        use = eng.pallas_merge
-        if use is None:
-            mode = settings.get("storage.pallas_merge")
-            use = mode == "on" or (mode == "auto"
-                                   and jax.default_backend() == "tpu")
-        if use and eng.key_width == 16 and pm.eligible(blocks):
-            interpret = (eng._pallas_merge_interpret
-                         or jax.default_backend() == "cpu")
-            return pm.merge_runs(blocks, interpret=interpret)
-        total = sum(b.capacity for b in blocks)
-        return mvcc.merge_blocks(blocks, cap=_pad(total))
+        # the compaction merge picker: bitonic pallas kernel when
+        # eligible, concat + lax.sort otherwise
+        return self.engine._merge_for_compaction(
+            blocks, sum(b.capacity for b in blocks))
 
     def _flush(self) -> None:
         if not self._batches:

@@ -13,7 +13,7 @@ the newest recorded baseline:
   flagged — a bench refactor that silently stops emitting a series must
   not pass as "no regressions".
 
-Both runs must come from the same platform (a cpu-fallback run diffed
+Both runs must come from the same platform (an old CPU record diffed
 against a tpu baseline would flag everything); mismatches flag, they do
 not silently pass.
 

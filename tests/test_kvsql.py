@@ -172,7 +172,7 @@ def test_ycsb_e_microbench():
 
 def test_q1_over_kv_backed_lineitem():
     """TPC-H Q1 end-to-end over a lineitem that LIVES IN THE ENGINE —
-    strings included (VERDICT: the kv/table.py fixed-width restriction is
+    strings included (the kv/table.py fixed-width restriction is
     gone). Oracle: the same query over the host-resident catalog table."""
     from cockroach_tpu.bench import queries as Q
     from cockroach_tpu.bench import tpch

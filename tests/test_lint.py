@@ -894,7 +894,7 @@ def test_real_tree_new_passes_are_clean_individually():
     gate runs them all; this pins the per-rule contract)."""
     found = run_lint(
         ["cockroach_tpu", "scripts", "tests", "bench.py",
-         "__graft_entry__.py"],
+         "__graft_entry__.py", "chip_smoke.py"],
         rules=("untimed-wait", "recompile-hazard", "race-coverage"))
     assert not found, [f.render() for f in found]
 

@@ -101,11 +101,13 @@ def test_pallas_filter_edge_windows():
     np.testing.assert_array_equal(np.asarray(got[1]), np.asarray(want[1]))
 
 
-def test_scan_batch_through_pallas_filter():
+def test_scan_batch_through_pallas_filter(monkeypatch):
     """End-to-end batched scans with the Pallas filter forced on
     (interpret mode on CPU) must equal the jnp-filtered results."""
     from cockroach_tpu.storage.lsm import Engine
     from cockroach_tpu.utils import settings
+
+    monkeypatch.setattr(mvcc, "PALLAS_FILTER_INTERPRET", True)
 
     def build():
         eng = Engine(key_width=16, val_width=8, memtable_size=1 << 20)
@@ -128,3 +130,27 @@ def test_scan_batch_through_pallas_filter():
     finally:
         settings.reset("storage.pallas_filter")
     assert got == want
+
+
+def test_on_never_interprets_by_itself():
+    """`storage.pallas_filter = on` compiles the kernel for the backend in
+    use. On the CPU that compile is refused, and the refusal surfaces: the
+    program neither interprets nor falls back to jnp on its own."""
+    from cockroach_tpu.storage.lsm import Engine
+    from cockroach_tpu.utils import settings
+
+    eng = Engine(key_width=16, val_width=8, memtable_size=1 << 20)
+    for i in range(40):
+        eng.put(b"k%08d" % i, b"v%d" % i, ts=5)
+    eng.flush()
+    before = mvcc.KERNEL_CALLS["scan_filter.jnp"]
+    settings.set("storage.pallas_filter", "on")
+    try:
+        with pytest.raises(Exception, match="(?i)interpret|cpu"):
+            eng.scan_batch([b"k%08d" % 3], ts=11, max_keys=4)
+    finally:
+        settings.reset("storage.pallas_filter")
+    assert mvcc.KERNEL_CALLS["scan_filter.jnp"] == before
+    assert eng.scan_batch([b"k%08d" % 3], ts=11, max_keys=4)[0][0][0] \
+        == b"k%08d" % 3
+    assert mvcc.KERNEL_CALLS["scan_filter.jnp"] == before + 1

@@ -1,0 +1,150 @@
+"""Ask the v5e compiler, without a chip, whether the main path's kernels
+compile at real widths: the two storage Pallas kernels, the fused q1 tile
+step, the hash shuffle across four chips, and the packed-key sort.
+
+The TPU compiler is installed here and compiles for a chip that is described
+and not attached. Nothing runs, so these say nothing about results or times;
+they refuse what the chip would refuse (a relayout Mosaic cannot do, a tile
+that does not fit VMEM, a program that cannot be partitioned).
+
+Only one process may hold libtpu, so the topology is described inside a
+module-scoped fixture (never at import, in a skipif or in parametrize
+arguments), every compile happens in this process, and all of it lives in
+this one file. The persistent compile cache is switched off around the
+compiles: an entry written for a described chip cannot be read back here.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import NamedSharding, PartitionSpec as P, SingleDeviceSharding
+
+from cockroach_tpu.storage import mvcc
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _kvblock_shape(n, sharding, key_width=16, val_width=16):
+    def s(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=sharding)
+
+    return mvcc.KVBlock(
+        key=s((n, key_width), jnp.uint8), ts=s((n,), jnp.int64),
+        seq=s((n,), jnp.int64), txn=s((n,), jnp.int64),
+        tomb=s((n,), jnp.bool_), value=s((n, val_width), jnp.uint8),
+        vlen=s((n,), jnp.int32), mask=s((n,), jnp.bool_))
+
+
+@pytest.mark.parametrize("B,window", [(128, 128), (64, 1024)])
+def test_scan_filter_kernel_compiles(one_chip, B, window):
+    """storage/pallas_scan.py at the batched-scan window layout, 16-byte
+    keys — what `auto` selects on a chip for the YCSB engine."""
+    from cockroach_tpu.storage.pallas_scan import pallas_scan_filter
+
+    scalar = jax.ShapeDtypeStruct((), jnp.int64, sharding=one_chip)
+    compiled = pallas_scan_filter.lower(
+        _kvblock_shape(B * window, one_chip), scalar, scalar, window=window,
+    ).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("rows", [1024, 32768])
+def test_merge_kernel_compiles(one_chip, rows):
+    """storage/pallas_merge.py merging two sorted runs; 32768+32768 is the
+    largest eligible pair under MAX_MERGE_ROWS."""
+    from cockroach_tpu.storage import pallas_merge as pm
+
+    blk = _kvblock_shape(rows, one_chip)
+    assert pm.eligible((blk, blk))
+    # crlint: allow-raw-jit(AOT compile for a described chip: nothing is dispatched)
+    compiled = jax.jit(pm.merge_pair).lower(blk, blk).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_fused_q1_tile_step_compiles(one_chip):
+    """__graft_entry__.entry(): filter -> decimal projection -> dense-state
+    group-by, at the default 1 << 20-row scan tile."""
+    import __graft_entry__ as graft
+
+    fn, (batch,) = graft.entry()
+    tile = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct((1 << 20,) + x.shape[1:], x.dtype,
+                                       sharding=one_chip), batch)
+    # crlint: allow-raw-jit(AOT compile for a described chip: nothing is dispatched)
+    compiled = jax.jit(fn).lower(tile).compile()
+    print("q1 tile step memory:", compiled.memory_analysis())
+
+
+def test_hash_shuffle_compiles_on_four_chips(topo):
+    """parallel/shuffle.make_shuffle on a 4-device mesh of the described
+    chips: the repartitioning must lower to an all-to-all."""
+    from cockroach_tpu import coldata as cd
+    from cockroach_tpu.coldata.batch import Batch, Column
+    from cockroach_tpu.parallel import mesh as mesh_mod
+    from cockroach_tpu.parallel.shuffle import make_shuffle
+
+    assert len(topo.devices) == 4
+    mesh = mesh_mod.make_mesh(devices=list(topo.devices))
+    rows = NamedSharding(mesh, P(mesh_mod.AXIS))
+    schema = cd.Schema.of(k=cd.INT64, v=cd.DECIMAL(12, 2), d=cd.DATE)
+    local_cap = 1 << 18
+    n = 4 * local_cap
+
+    def col(dt):
+        return Column(data=jax.ShapeDtypeStruct((n,), dt, sharding=rows),
+                      valid=jax.ShapeDtypeStruct((n,), jnp.bool_,
+                                                 sharding=rows))
+
+    batch = Batch(cols=(col(jnp.int64), col(jnp.int64), col(jnp.int32)),
+                  mask=jax.ShapeDtypeStruct((n,), jnp.bool_, sharding=rows))
+    shuffle = make_shuffle(mesh, schema, (0,), local_cap)
+    compiled = shuffle._jitted.lower(batch).compile()
+    assert "all-to-all" in compiled.as_text()
+    print("shuffle memory per device:", compiled.memory_analysis())
+
+
+def test_packed_key_sort_compiles(one_chip):
+    """The engine's canonical sort: one packed u64 key word plus the
+    permutation operand (ops/keys.py). Kept small: the same compile took
+    37 s at 1 << 16 rows and 55 s at 1 << 20 in this sandbox (a scratch
+    script, PR 22), against under a second here."""
+    n = 1 << 12
+    keys = jax.ShapeDtypeStruct((n,), jnp.uint64, sharding=one_chip)
+    perm = jax.ShapeDtypeStruct((n,), jnp.int32, sharding=one_chip)
+    # crlint: allow-raw-jit(AOT compile for a described chip: nothing is dispatched)
+    compiled = jax.jit(
+        lambda k, p: jax.lax.sort([k, p], num_keys=1)).lower(
+            keys, perm).compile()
+    assert compiled.as_text()
+
+
+def test_described_devices_are_not_attached(topo):
+    """The process still runs on the CPU mesh: describing a chip must not
+    change what jax.devices() reports to the rest of the suite."""
+    assert jax.devices()[0].platform == "cpu"
+    assert np.all([d.platform == "tpu" for d in topo.devices])
