@@ -5,6 +5,9 @@ functions, so the control flow, the oracles and the four-device sharding
 rules are checked here, where a fault costs no chip time."""
 
 import json
+import pathlib
+import subprocess
+import sys
 
 import jax
 
@@ -23,6 +26,17 @@ def test_main_refuses_to_run_without_a_tpu(capsys):
     cap = capsys.readouterr()
     assert "no TPU" in cap.err and "no CPU mode" in cap.err
     assert '"ok"' not in cap.out and cap.out.strip() == ""
+
+
+def test_bench_refuses_to_run_without_a_tpu():
+    """No chip is an error for bench.py too: non-zero exit, no result line,
+    nothing run on the CPU (the first worker stops the ladder)."""
+    root = pathlib.Path(__file__).resolve().parent.parent
+    out = subprocess.run([sys.executable, str(root / "bench.py")], cwd=root,
+                         capture_output=True, text=True, timeout=300)
+    assert out.returncode != 0
+    assert "no TPU" in out.stderr and out.stderr.count("jax found only") == 2
+    assert out.stdout.strip() == ""
 
 
 def test_sql_phase_tiny(capsys):
