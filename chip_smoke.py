@@ -2,7 +2,7 @@
 
     python chip_smoke.py             # one chip: device, sql, kv
     python chip_smoke.py --full      # the same at bench.py's full sizes
-    python chip_smoke.py --chips 4   # four chips: shuffle only
+    python chip_smoke.py --chips 4   # four chips: shuffle only (SF0.05)
 
 The quickest proof that the system still starts on the chip. It drives the
 main path once through the entry points a user calls, checks every answer
@@ -20,7 +20,11 @@ keyspace (4,096 keys, where its sorts compile in seconds; its batched scans
 still go through the Pallas scan filter on the chip, but nothing that small
 is merged, so the merge gate is reached only by `--full`). `--full` restores
 q3 three times and YCSB-E at bench.py's 1M keys: 2,364 s on an empty cache
-when PR 22 ran it.
+when PR 22 ran it. The four-chip shuffle runs q3 at SF0.05 for the same
+reason, four times over (a four-chip call is charged fourfold): its one SPMD
+program holds 18 sorts and did not finish compiling for a described v5e 2x2
+at SF1 in 263 CPU-minutes, against 243 s on 8 cores at SF0.05. `--full
+--chips 4` asks for SF1.
 
 There is no CPU mode. Without a TPU the script prints why and exits
 non-zero before any phase runs; tests/test_chip_smoke.py rehearses the phase
@@ -443,8 +447,9 @@ def main(argv: list[str] | None = None) -> int:
                     help="4 runs the distributed shuffle phase only")
     ap.add_argument("--seed", type=int, default=19920101)
     ap.add_argument("--full", action="store_true",
-                    help="q3 three times and YCSB-E at 1M keys: bench.py's "
-                         "sizes, about 40 minutes on an empty compile cache")
+                    help="q3 three times and YCSB-E at 1M keys (bench.py's "
+                         "sizes, about 40 minutes on an empty compile "
+                         "cache); with --chips 4, the shuffle at SF1")
     args = ap.parse_args(argv)
 
     import cockroach_tpu  # noqa: F401  # crlint: allow-unused-import(side-effect import: package init enables jax x64, and a bare copy of this script must fail here)
@@ -461,7 +466,8 @@ def main(argv: list[str] | None = None) -> int:
         return 2
 
     if args.chips == 4:
-        phases = [("shuffle", lambda: phase_shuffle(seed=args.seed))]
+        phases = [("shuffle", lambda: phase_shuffle(
+            sf=1.0 if args.full else 0.05, seed=args.seed))]
     elif args.full:
         phases = [("sql", lambda: phase_sql(seed=args.seed)),
                   ("kv", phase_kv)]
