@@ -10,7 +10,12 @@ observable so the win is measurable and regressions are catchable:
 - ``jit`` wraps ``jax.jit`` so every *call* of the compiled function bumps
   one process-global counter (thread-safe: ParallelUnorderedSyncOp calls
   kernels from puller threads). All flow-layer kernels are jitted through
-  it.
+  it, each under a ``name`` (``<operator>_<role>``: ``hashjoin_build``,
+  ``groupagg_fold_step``, ``pipe_filter``): the XLA module is
+  ``jit_<name>`` and the body runs under ``jax.named_scope(name)``, so a
+  profiler trace and the persistent compile cache name the program by what
+  it does, not by the Python closure that built it. A name is static: it
+  never holds a per-query value (the caches key on the module).
 - ``flow/runtime.py`` snapshots ``total()`` around a query and attributes
   the delta to the root's ``ComponentStats.kernel_dispatches`` (surfaced
   by EXPLAIN ANALYZE).
@@ -39,6 +44,7 @@ see README "Cache hierarchy"):
 from __future__ import annotations
 
 import functools
+import re
 import threading
 import time
 
@@ -46,6 +52,7 @@ import jax
 
 from ..utils import metric, tracing
 
+_NAME = re.compile(r"[a-z][a-z0-9_]{0,47}")
 _lock = threading.Lock()
 _total = 0
 _compiles = 0
@@ -91,10 +98,6 @@ def kernel_cache_hits() -> int:
     return _cache_hits
 
 
-def kernel_cache_size() -> int:
-    return len(_kernel_cache)
-
-
 def clear_kernel_cache() -> None:
     """Drop all shared wrappers (tests; frees the underlying executables
     only once operator trees release their references)."""
@@ -114,13 +117,17 @@ def kernel_key(*parts):
     return parts
 
 
-def jit(fn=None, key=None, **jit_kwargs):
-    """``jax.jit`` with per-call dispatch accounting, per-trace compile
-    accounting, and optional process-global sharing under ``key``. Usable
-    like jax.jit, both directly and via ``functools.partial(jit, ...)`` as
-    a decorator."""
+def jit(fn=None, key=None, name=None, **jit_kwargs):
+    """``jax.jit`` with a stable program name, per-call dispatch
+    accounting, per-trace compile accounting, and optional process-global
+    sharing under ``key``. Usable like jax.jit, both directly and via
+    ``functools.partial(jit, name=..., ...)`` as a decorator."""
     if fn is None:
-        return functools.partial(jit, key=key, **jit_kwargs)
+        return functools.partial(jit, key=key, name=name, **jit_kwargs)
+    if not isinstance(name, str) or not _NAME.fullmatch(name):
+        raise ValueError(
+            f"dispatch.jit needs a static name=<operator>_<role> "
+            f"(lower case, digits, '_'), got {name!r}")
     if key is not None:
         global _cache_hits
         with _lock:
@@ -135,8 +142,11 @@ def jit(fn=None, key=None, **jit_kwargs):
     def traced(*args, **kwargs):
         # plain-Python body: runs once per jax trace == one new compile
         note_compile()
-        return fn(*args, **kwargs)
+        with jax.named_scope(name):
+            return fn(*args, **kwargs)
 
+    # jax names the XLA module jit_<__name__>
+    traced.__name__ = traced.__qualname__ = name
     jitted = jax.jit(traced, **jit_kwargs)
 
     @functools.wraps(fn)
@@ -147,11 +157,13 @@ def jit(fn=None, key=None, **jit_kwargs):
             return jitted(*args, **kwargs)
         # traced call: split wall time into compile (trace happened under
         # this call) vs execute, folded into the enclosing span's tags so
-        # EXPLAIN ANALYZE (DEBUG) shows where dispatch time went
+        # EXPLAIN ANALYZE (DEBUG) shows where dispatch time went, and
+        # tracing.totals() sums them over a window
         # crlint: allow-race-coverage(_compiles is a monotonic counter: every write holds _lock; these lockless GIL-atomic snapshot reads only split telemetry into compile-vs-dispatch buckets — taking _lock per dispatch on the serving hot path buys nothing a stale-by-one read can break)
         c0 = _compiles
         t0 = time.perf_counter()
-        out = jitted(*args, **kwargs)
+        with tracing.annotation("flow.dispatch", kernel=name):
+            out = jitted(*args, **kwargs)
         dt_ms = (time.perf_counter() - t0) * 1e3
         if _compiles > c0:
             sp.inc_tag("jit_compiles", _compiles - c0)

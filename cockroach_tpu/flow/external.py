@@ -281,7 +281,7 @@ def make_bucket_fn(schema: Schema, keys, tables, nparts: int,
         "grace_bucket", schema, tuple(keys), nparts, with_hash,
         tuple(sorted((i, _array_key(t)) for i, t in (tables or {}).items())),
     )
-    return dispatch.jit(fn, key=key)
+    return dispatch.jit(fn, key=key, name="grace_bucket")
 
 
 # ---------------------------------------------------------------------------
@@ -396,6 +396,7 @@ class GraceHashJoinOp(OneInputOperator):
             key=dispatch.kernel_key(
                 "grace_hashprobe", pschema, bschema, pkeys, bkeys, spec,
                 tkey),
+            name="gracejoin_hashprobe",
         )
 
         def hindex_raw(b):
@@ -405,6 +406,7 @@ class GraceHashJoinOp(OneInputOperator):
             hindex_raw,
             key=dispatch.kernel_key("grace_hashindex", bschema, bkeys,
                                     tkey),
+            name="gracejoin_hashindex",
         )
 
         # oversized partitions degrade to sorted-run merge probing: the
@@ -424,6 +426,7 @@ class GraceHashJoinOp(OneInputOperator):
             mindex_raw,
             key=dispatch.kernel_key("grace_mergeindex", bschema, bkeys,
                                     rkey),
+            name="gracejoin_mergeindex",
         )
 
         def mj_raw(p, b, index, out_cap, jt):
@@ -438,6 +441,7 @@ class GraceHashJoinOp(OneInputOperator):
             key=dispatch.kernel_key(
                 "grace_mergeprobe", pschema, bschema, pkeys, bkeys, spec,
                 rkey),
+            name="gracejoin_mergeprobe",
         )
 
     def _partition_all(self):
@@ -827,6 +831,7 @@ class ExternalSortOp(OneInputOperator):
             lambda b: _primary_u64(b, schema, key, rank_table),
             key=dispatch.kernel_key("extsort_u64", schema, key,
                                     _array_key(rank_table)),
+            name="extsort_u64",
         )
         rank_tables = {
             k.col: self.child.dictionaries[k.col].ranks
@@ -842,7 +847,7 @@ class ExternalSortOp(OneInputOperator):
             "extsort_sort", schema, keys,
             tuple(sorted((c, _array_key(t))
                          for c, t in rank_tables.items())),
-        ))
+        ), name="extsort_sort")
 
     def _stage_all(self):
         # pass 1: stage all rows + their primary u64 on the host

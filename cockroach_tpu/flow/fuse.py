@@ -127,6 +127,7 @@ class FusedPipeline(Operator):
     def __init__(self, top: Operator, members: list[Operator]):
         super().__init__()
         self.top = top
+        self.KERNEL = top.KERNEL  # its program is pipe_<top operator>
         self.child = top  # chain walks (fused_depth) see through the wrapper
         self.members = members
         self.output_schema = top.output_schema
@@ -175,7 +176,8 @@ class FusedPipeline(Operator):
             # a repeat query's fused chain reuses the first's executables
             pkey = getattr(self.top, "_parts_key", None)
             cached = (cfn, dispatch.jit(
-                cfn, key=None if pkey is None else ("pipe", pkey)))
+                cfn, key=None if pkey is None else ("pipe", pkey),
+                name=f"pipe_{self.KERNEL}"))
             self._pipe_fn = cached
         fn = cached[1]
         for t in src.stream_tiles():

@@ -65,6 +65,15 @@ class Operator:
     output_schema: Schema
     dictionaries: dict[int, Dictionary]
     col_stats: dict[int, tuple]
+    # the operator's part of its device programs' names (dispatch.jit
+    # name=<KERNEL>_<role>): the class name in lower case without "op"
+    # unless a class gives a shorter one. Static — never a per-query value.
+    KERNEL = "operator"
+
+    def __init_subclass__(cls, **kw):
+        super().__init_subclass__(**kw)
+        if "KERNEL" not in cls.__dict__:
+            cls.KERNEL = cls.__name__.strip("_").lower().removesuffix("op")
 
     def __init__(self):
         self.dictionaries = {}

@@ -88,12 +88,12 @@ class _FusedPull:
     chain fn), pulled from the chain's source. Cached on the consumer so the
     composition traces once per operator instance."""
 
-    def __init__(self, parts, tile_fn):
+    def __init__(self, parts, tile_fn, kernel: str):
         src, chain_fn, _ = parts
         self.src = src
         self.chain = chain_fn
         self._fn = dispatch.jit(
-            lambda t, *a: tile_fn(chain_fn(t, *a))
+            lambda t, *a: tile_fn(chain_fn(t, *a)), name=f"{kernel}_fused"
         )
 
     def pull(self, parts):
@@ -136,7 +136,7 @@ def _consume(op: OneInputOperator, tile_fn_name: str, tile_fn,
     attr = f"_fused_{tile_fn_name}"
     cached = getattr(op, attr, None)
     if cached is None or cached.chain is not parts[1]:
-        cached = _FusedPull(parts, tile_fn)
+        cached = _FusedPull(parts, tile_fn, f"{op.KERNEL}_{tile_fn_name}")
         setattr(op, attr, cached)
     yield from cached.pull(parts)
 
@@ -163,10 +163,11 @@ def _fold(op: OneInputOperator, tag: str, tile_raw, tile_jit, merge_raw,
     cached = getattr(op, attr, None)
     if cached is None or cached[0] is not cfn:
         nc = len(args)
-        seed = dispatch.jit(lambda t, *a: tile_raw(cfn(t, *a[:nc])))
+        seed = dispatch.jit(lambda t, *a: tile_raw(cfn(t, *a[:nc])),
+                            name=f"{op.KERNEL}_fold_seed")
         step = dispatch.jit(
             lambda acc, t, *a: merge_raw(acc, tile_raw(cfn(t, *a[:nc]))),
-            donate_argnums=0,
+            donate_argnums=0, name=f"{op.KERNEL}_fold_step",
         )
         cached = (cfn, seed, step)
         setattr(op, attr, cached)
@@ -312,7 +313,7 @@ class ScanOp(SourceOperator):
             # wrapper per tile size serves EVERY resident table
             self._slice = dispatch.jit(
                 functools.partial(_slice_tile, res_tile),
-                key=("slice_tile", res_tile))
+                key=("slice_tile", res_tile), name="scan_slice")
             self._slice_tile = res_tile
 
     # -- streaming mode -----------------------------------------------------
@@ -507,7 +508,7 @@ class HashBucketOp(OneInputOperator):
         self._key = dispatch.kernel_key(
             "hashbucket", schema, keys, n_parts, part)
         self._raw = raw
-        self._fn = dispatch.jit(raw, key=self._key)
+        self._fn = dispatch.jit(raw, key=self._key, name="hashbucket_tile")
 
     def stream_parts(self):
         return _compose_parts(self, self.child, self._raw, key=self._key)
@@ -568,7 +569,7 @@ class FilterOp(OneInputOperator):
         self._key = dispatch.kernel_key(
             "filter", schema, predicate, params is not None)
         self._raw = raw
-        self._fn = dispatch.jit(raw, key=self._key)
+        self._fn = dispatch.jit(raw, key=self._key, name="filter_tile")
 
     def stream_parts(self):
         extra = () if self._params is None else self._params.args()
@@ -657,7 +658,7 @@ class ProjectOp(OneInputOperator):
 
         self._key = dispatch.kernel_key("project", schema, exprs)
         self._raw = raw
-        self._fn = dispatch.jit(raw, key=self._key)
+        self._fn = dispatch.jit(raw, key=self._key, name="project_tile")
 
     def stream_parts(self):
         return _compose_parts(self, self.child, self._raw, key=self._key)
@@ -681,7 +682,8 @@ class LimitOp(OneInputOperator):
             return b.with_mask(keep), seen + jnp.sum(b.mask, dtype=jnp.int32)
 
         self._fn = dispatch.jit(
-            fn, key=dispatch.kernel_key("limit", offset, limit))
+            fn, key=dispatch.kernel_key("limit", offset, limit),
+            name="limit_tile")
 
     def init(self):
         super().init()
@@ -710,6 +712,8 @@ class AggregateOp(OneInputOperator):
     - partial:  input rows -> state columns (feeds an Exchange)
     - final:    state columns (partial layout) -> final results
     """
+
+    KERNEL = "hashagg"
 
     def __init__(
         self,
@@ -872,7 +876,8 @@ class AggregateOp(OneInputOperator):
             )
             return part
 
-        @functools.partial(dispatch.jit, static_argnames=("cap",))
+        @functools.partial(dispatch.jit, static_argnames=("cap",),
+                           name="hashagg_merge")
         def merge_fn(tiles, cap):
             both = concat(list(tiles), capacity=cap)
             # ordered partials stay in scan order per tile, so their
@@ -884,9 +889,10 @@ class AggregateOp(OneInputOperator):
                                         presorted=ordered, compact=True)
 
         self._partial_raw = partial_fn
-        self._partial_fn = dispatch.jit(partial_fn)
+        self._partial_fn = dispatch.jit(partial_fn, name="hashagg_partial")
         self._merge_fn = merge_fn
-        self._finalize_fn = dispatch.jit(self._finalize)
+        self._finalize_fn = dispatch.jit(self._finalize,
+                                         name="hashagg_finalize")
 
     def _finalize(self, state: Batch) -> Batch:
         return agg_ops.finalize_states(state, self.final_map, self.num_keys)
@@ -1089,6 +1095,8 @@ class ScalarAggregateOp(OneInputOperator):
     """Aggregation without GROUP BY — exactly one output row, even on empty
     input (SQL scalar aggregate semantics)."""
 
+    KERNEL = "scalaragg"
+
     def __init__(self, child: Operator, aggs: tuple[agg_ops.AggSpec, ...]):
         super().__init__(child)
         self.aggs = aggs
@@ -1105,11 +1113,12 @@ class ScalarAggregateOp(OneInputOperator):
         self.dictionaries = {}
         self.col_stats = {}
         self._tile_raw = lambda b: agg_ops.scalar_tile_states(b, aggs, base)
-        self._tile_fn = dispatch.jit(self._tile_raw)
+        self._tile_fn = dispatch.jit(self._tile_raw, name="scalaragg_tile")
         self._merge_raw = (
             lambda acc, new: agg_ops.scalar_merge_states(aggs, acc, new)
         )
-        self._merge_fn = dispatch.jit(self._merge_raw)
+        self._merge_fn = dispatch.jit(self._merge_raw,
+                                      name="scalaragg_merge")
         self._emitted = False
 
     def init(self):
@@ -1174,7 +1183,8 @@ class SortOp(OneInputOperator):
         keys = self.keys
         col_stats = dict(self.child.col_stats)
 
-        @functools.partial(dispatch.jit, static_argnames=("cap",))
+        @functools.partial(dispatch.jit, static_argnames=("cap",),
+                           name="sort_spool")
         def fn(batches, cap):
             big = concat(list(batches), capacity=cap)
             return sort_ops.sort_batch(big, schema, keys, rank_tables,
@@ -1281,9 +1291,9 @@ class TopKOp(OneInputOperator):
                                        rank_tables, col_stats)
 
         self._tile_raw = tile_raw
-        self._tile_fn = dispatch.jit(tile_raw)
+        self._tile_fn = dispatch.jit(tile_raw, name="topk_tile")
         self._merge_raw = merge_raw
-        self._merge_fn = dispatch.jit(merge_raw)
+        self._merge_fn = dispatch.jit(merge_raw, name="topk_merge")
 
     def _next(self):
         from .memory import Allocator, batch_bytes
@@ -1514,7 +1524,8 @@ class HashJoinOp(OneInputOperator):
         layout = self.exact_layout
         eremaps = self.build_code_remaps or None
 
-        @functools.partial(dispatch.jit, static_argnames=("cap",))
+        @functools.partial(dispatch.jit, static_argnames=("cap",),
+                           name="hashjoin_build")
         def build_fn(tiles, cap):
             big = concat(list(tiles), capacity=cap)
             index = join_ops.build_index(big, bschema, bkeys, bht,
@@ -1524,7 +1535,8 @@ class HashJoinOp(OneInputOperator):
 
         self._build_fn = build_fn
 
-        @functools.partial(dispatch.jit, static_argnames=("cap",))
+        @functools.partial(dispatch.jit, static_argnames=("cap",),
+                           name="hashjoin_lut")
         def lut_fn(tiles, cap):
             big = concat(list(tiles), capacity=cap)
             return big, join_ops.build_dense_lut(big, bkeys, layout, eremaps)
@@ -1546,7 +1558,8 @@ class HashJoinOp(OneInputOperator):
 
             self._probe_gen_raw = probe_gen_raw
             self._probe_gen_fn = functools.partial(
-                dispatch.jit, static_argnames=("out_cap",)
+                dispatch.jit, static_argnames=("out_cap",),
+                name="hashjoin_probe_gen",
             )(probe_gen_raw)
             self._out_cap = 0
 
@@ -1604,7 +1617,7 @@ class HashJoinOp(OneInputOperator):
                 return out
 
         self._probe_raw = probe_raw
-        self._probe_fn = dispatch.jit(probe_raw)
+        self._probe_fn = dispatch.jit(probe_raw, name="hashjoin_probe")
 
     def _ensure_built(self):
         from ..utils import settings
@@ -1791,7 +1804,7 @@ class HashJoinOp(OneInputOperator):
                     out = compact_batch(out, capacity=cap)
                 return out, cnt
 
-        self._emit_kern = dispatch.jit(kern)
+        self._emit_kern = dispatch.jit(kern, name="hashjoin_emit")
         self._emit_kern_key = key
         return self._emit_kern
 
@@ -1947,7 +1960,7 @@ def _consume_op(op: Operator, tag: str):
     attr = f"_fused_src_{tag}"
     cached = getattr(op, attr, None)
     if cached is None or cached[0] is not cfn:
-        cached = (cfn, dispatch.jit(cfn))
+        cached = (cfn, dispatch.jit(cfn, name=f"pipe_{op.KERNEL}_{tag}"))
         setattr(op, attr, cached)
     fn = cached[1]
     for t in src.stream_tiles():
@@ -2011,7 +2024,8 @@ class WindowOp(OneInputOperator):
         okeys = self.order_keys
         specs = self.specs
 
-        @functools.partial(dispatch.jit, static_argnames=("cap",))
+        @functools.partial(dispatch.jit, static_argnames=("cap",),
+                           name="window_spool")
         def fn(batches, cap):
             big = concat(list(batches), capacity=cap)
             return win_ops.compute_windows(
@@ -2398,7 +2412,8 @@ class MergeJoinOp(OneInputOperator):
         bkey = self.build_key
         brank = self.build_rank
 
-        @functools.partial(dispatch.jit, static_argnames=("cap",))
+        @functools.partial(dispatch.jit, static_argnames=("cap",),
+                           name="mergejoin_build")
         def build_fn(tiles, cap):
             big = concat(list(tiles), capacity=cap)
             return big, mj_ops.build_merge_index(big, bschema, bkey, brank)
@@ -2409,7 +2424,8 @@ class MergeJoinOp(OneInputOperator):
         prank = self.probe_rank
         spec = self.spec
 
-        @functools.partial(dispatch.jit, static_argnames=("out_cap",))
+        @functools.partial(dispatch.jit, static_argnames=("out_cap",),
+                           name="mergejoin_probe")
         def probe_fn(p, build, index, out_cap):
             return mj_ops.merge_join(
                 p, pschema, pkey, build, bschema, bkey, spec, out_cap,
@@ -2481,6 +2497,8 @@ class SmallGroupAggregateOp(OneInputOperator):
 
     States are positionally aligned [G] arrays, so cross-tile (and
     cross-device) merging is elementwise."""
+
+    KERNEL = "groupagg"
 
     def __init__(self, child: Operator, group_cols: tuple[int, ...],
                  aggs: tuple[agg_ops.AggSpec, ...], key_sizes: tuple[int, ...],
@@ -2571,10 +2589,12 @@ class SmallGroupAggregateOp(OneInputOperator):
             )
 
         self._tile_raw = tile_fn
-        self._tile_fn = dispatch.jit(tile_fn)
+        self._tile_fn = dispatch.jit(tile_fn, name="groupagg_tile")
         self._merge_raw = merge_fn
-        self._merge_fn = dispatch.jit(merge_fn, donate_argnums=0)
-        self._finalize_fn = dispatch.jit(finalize_fn)
+        self._merge_fn = dispatch.jit(merge_fn, donate_argnums=0,
+                                      name="groupagg_merge")
+        self._finalize_fn = dispatch.jit(finalize_fn,
+                                         name="groupagg_finalize")
 
     def _next(self):
         if self._emitted:

@@ -16,6 +16,7 @@ it.
 from __future__ import annotations
 
 import threading
+import time
 
 import numpy as np
 
@@ -23,6 +24,7 @@ from ..catalog import Catalog
 from ..coldata.batch import to_host
 from ..plan import builder as plan_builder
 from ..plan.spec import PlanNode
+from ..utils import tracing
 
 
 def _start_readback(b) -> None:
@@ -91,23 +93,17 @@ class _ReadbackShrink:
         self._checks = []
 
 
-def _xla_profile_ctx():
-    """jax.profiler trace annotation for the query, gated behind
-    sql.trace.xla_profile — TPU rounds then show up as named regions in an
-    XLA profile linkable from the trace. Degrades to a no-op context when
-    the profiler is unavailable."""
-    from contextlib import nullcontext
-
-    from ..utils import settings
-
-    if not settings.get("sql.trace.xla_profile"):
-        return nullcontext()
-    try:
-        import jax.profiler
-
-        return jax.profiler.TraceAnnotation("cockroach_tpu.query")
-    except Exception:  # crlint: allow-broad-except(profiler optional; query must run without it)
-        return nullcontext()
+def _readback(b, root, psp) -> dict[str, np.ndarray]:
+    """One tile to host columns: the wait for the device, the copy and the
+    dictionary decode, timed into the pull span's ``readback_ms`` and, on
+    the profiler's clock, a ``flow.readback`` region."""
+    r0 = time.perf_counter()
+    with tracing.annotation("flow.readback"):
+        out = to_host(b, root.output_schema, root.dictionaries)
+    if psp is not None:
+        psp.inc_tag("readback_ms",
+                    round((time.perf_counter() - r0) * 1e3, 3))
+    return out
 
 
 def _fold_operator_spans(parent_span, op) -> None:
@@ -117,8 +113,6 @@ def _fold_operator_spans(parent_span, op) -> None:
     where query latency went without per-tile span overhead in the pull
     loop. Exclusive times telescope: summing (self - children) over the
     whole subtree recovers the root operator's wall time."""
-    from ..utils import tracing
-
     st = getattr(op, "stats", None)
     if st is None:
         child = parent_span
@@ -144,9 +138,7 @@ def _post_run_updates(op) -> bool:
 
 
 def run_operator(root) -> dict[str, np.ndarray]:
-    import time
-
-    from ..utils import metric, settings, tracing
+    from ..utils import metric, settings
     from ..utils.errors import QueryError, _PASSTHROUGH
     from . import dispatch
 
@@ -169,7 +161,9 @@ def run_operator(root) -> dict[str, np.ndarray]:
         # shapes and validate their deferred counters after the pull; an
         # overflow (rare: first run after a data change) re-runs the query
         # with corrected capacities rather than paying a sync per tile
-        with _xla_profile_ctx():
+        # on the profiler's clock while sql.trace.xla_profile is on (the
+        # benchmark's trace reduction counts statements by this name)
+        with tracing.annotation("cockroach_tpu.query"):
             for attempt in range(4):
                 outs: list[dict[str, np.ndarray]] = []
                 shrink = _ReadbackShrink()
@@ -186,12 +180,7 @@ def run_operator(root) -> dict[str, np.ndarray]:
                                 b = shrink.shrink(b)
                                 _start_readback(b)
                             if prev is not None:
-                                r0 = time.perf_counter()
-                                outs.append(to_host(prev, root.output_schema,
-                                                    root.dictionaries))
-                                if psp is not None:
-                                    psp.inc_tag("readback_ms", round(
-                                        (time.perf_counter() - r0) * 1e3, 3))
+                                outs.append(_readback(prev, root, psp))
                             prev = b
                             if b is None:
                                 break
@@ -201,12 +190,7 @@ def run_operator(root) -> dict[str, np.ndarray]:
                             if b is None:
                                 break
                             b = shrink.shrink(b)
-                            r0 = time.perf_counter()
-                            outs.append(to_host(b, root.output_schema,
-                                                root.dictionaries))
-                            if psp is not None:
-                                psp.inc_tag("readback_ms", round(
-                                    (time.perf_counter() - r0) * 1e3, 3))
+                            outs.append(_readback(b, root, psp))
                     if psp is not None:
                         psp.add_tag("tiles", len(outs))
                 if not _post_run_updates(root):
@@ -252,8 +236,6 @@ def run_operator(root) -> dict[str, np.ndarray]:
 def run_plan_with_stats(plan: PlanNode, catalog: Catalog):
     """Run with ComponentStats collection; returns (results, root operator).
     The stats land on the active tracing span."""
-    from ..utils import tracing
-
     root = plan_builder.build(plan, catalog)
     root.collect_stats(True)
     with tracing.span("query") as sp:

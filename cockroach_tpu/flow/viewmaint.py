@@ -194,9 +194,12 @@ class ShapeClass:
         self._params_np: list[np.ndarray] | None = None
         self._charged = 0
         self._recharge()
-        self._delta_kernel = dispatch.jit(self._make_delta_kernel())
-        self._scan_kernel = dispatch.jit(self._make_scan_kernel())
-        self._finalize_kernel = dispatch.jit(self._make_finalize_kernel())
+        self._delta_kernel = dispatch.jit(self._make_delta_kernel(),
+                                          name="matview_delta")
+        self._scan_kernel = dispatch.jit(self._make_scan_kernel(),
+                                         name="matview_scan")
+        self._finalize_kernel = dispatch.jit(self._make_finalize_kernel(),
+                                             name="matview_finalize")
 
     def _recharge(self) -> None:
         """Standing ``[V, G]`` state is resident memory for the life of

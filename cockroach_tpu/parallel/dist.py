@@ -94,7 +94,7 @@ def make_distributed_groupby(
         out_specs=(P(AXIS), P(AXIS)),
         check_vma=False,
     )
-    return dispatch.jit(fn), final_schema
+    return dispatch.jit(fn, name="dist_groupby"), final_schema
 
 
 def make_distributed_join(
@@ -141,5 +141,5 @@ def make_distributed_join(
         out_specs=(P(AXIS), P(AXIS)),
         check_vma=False,
     )
-    return dispatch.jit(fn), join_ops.join_output_schema(
+    return dispatch.jit(fn, name="dist_join"), join_ops.join_output_schema(
         probe_schema, build_schema, spec)

@@ -610,7 +610,7 @@ class DistributedQuery:
         self._fn = dispatch.jit(shard_map(
             local_fn, mesh=self.mesh, in_specs=in_specs,
             out_specs=out_specs, check_vma=False,
-        ))
+        ), name="dist_query")
         # global sharded scan inputs (partitioned-scan placement), cached:
         # scan shapes don't depend on `factor`, so overflow retries reuse
         # the already-uploaded shards instead of re-sharding every table

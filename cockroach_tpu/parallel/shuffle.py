@@ -173,4 +173,4 @@ def make_shuffle(
     )
     # dispatch.jit, not jax.jit: an SPMD shuffle is one XLA dispatch like
     # any flow kernel — it must count into sql_kernel_dispatches
-    return dispatch.jit(sharded)
+    return dispatch.jit(sharded, name="shuffle_spmd")

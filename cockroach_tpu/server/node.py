@@ -32,7 +32,7 @@ from ..kv.jobs import Registry, register_builtin_jobs
 from ..kv.liveness import LeaseManager, NodeLiveness
 from ..kv.tsdb import TimeSeriesDB
 from ..storage.lsm import Engine
-from ..utils import admission, log, metric, settings
+from ..utils import admission, log, metric, settings, tracing
 
 
 class Node:
@@ -314,7 +314,8 @@ class Node:
 
         while not self._stop.wait(self._hb_interval):
             try:
-                self.liveness.heartbeat()
+                with tracing.timed("node.heartbeat"):
+                    self.liveness.heartbeat()
             except EpochFencedError:
                 # declared dead by a peer: the WHOLE node must stop taking
                 # work (a fenced node that keeps adopting jobs runs them in
@@ -360,38 +361,42 @@ class Node:
                     f"retry") from e
 
     def _lease_loop(self) -> None:
+        while not self._stop.wait(self._hb_interval):
+            with tracing.timed("node.lease"):
+                self._lease_pass()
+
+    def _lease_pass(self) -> None:
         from ..kv.liveness import NotLeaseHolderError, StillLiveError
         from ..kv.txn import TransactionRetryError
         from ..storage.lsm import WriteIntentError
 
-        while not self._stop.wait(self._hb_interval):
-            for rid in self._lease_ranges:
-                try:
-                    prev = self.leases.holder(rid)
-                    rec = self.leases.acquire(rid)
-                except NotLeaseHolderError:
-                    continue  # a live peer holds it; that's healthy
-                except (StillLiveError, TransactionRetryError):
-                    continue  # lost a failover race; next tick re-reads
-                except WriteIntentError:
-                    continue  # a peer's lease write mid-commit; next tick
-                except (ConnectionError, OSError):
-                    continue  # injected epoch_bump/transport fault
-                except Exception as e:  # noqa: BLE001 - loop must survive  # crlint: allow-broad-except(lease loop must survive; logged)
-                    log.warning(log.OPS, "lease acquire failed",
-                                range=rid, error=str(e))
-                    continue
-                if (prev is not None and prev.node_id != self.node_id
-                        and self.gossip is not None):
-                    # we just fenced the old holder: its gossiped state
-                    # is stale under the bumped epoch — expire it
-                    self.gossip.note_epoch(prev.node_id, prev.epoch + 1)
-                ad = (rec.node_id, rec.epoch)
-                if (self._advertised_leases.get(rid) != ad
-                        and self.gossip is not None):
-                    self.gossip.add_info(f"lease/{rid}",
-                                         f"{rec.node_id}:{rec.epoch}")
-                    self._advertised_leases[rid] = ad
+        for rid in self._lease_ranges:
+            try:
+                prev = self.leases.holder(rid)
+                rec = self.leases.acquire(rid)
+            except NotLeaseHolderError:
+                continue  # a live peer holds it; that's healthy
+            except (StillLiveError, TransactionRetryError):
+                continue  # lost a failover race; next tick re-reads
+            except WriteIntentError:
+                continue  # a peer's lease write mid-commit; next tick
+            except (ConnectionError, OSError):
+                continue  # injected epoch_bump/transport fault
+            except Exception as e:  # noqa: BLE001 - loop must survive  # crlint: allow-broad-except(lease loop must survive; logged)
+                log.warning(log.OPS, "lease acquire failed",
+                            range=rid, error=str(e))
+                continue
+            if (prev is not None and prev.node_id != self.node_id
+                    and self.gossip is not None):
+                # we just fenced the old holder: its gossiped state
+                # is stale under the bumped epoch — expire it
+                self.gossip.note_epoch(prev.node_id, prev.epoch + 1)
+            ad = (rec.node_id, rec.epoch)
+            if (self._advertised_leases.get(rid) != ad
+                    and self.gossip is not None):
+                self.gossip.add_info(f"lease/{rid}",
+                                     f"{rec.node_id}:{rec.epoch}")
+                self._advertised_leases[rid] = ad
 
     def _metrics_loop(self) -> None:
         import time as _time
@@ -414,17 +419,19 @@ class Node:
                 from ..kv import fanout
                 from ..storage import blockcache
 
-                flowmem.refresh_gauges()
-                admission.refresh_gauges()
-                blockcache.refresh_gauges()
-                fanout.refresh_gauges()
-                self.tsdb.record(metric.DEFAULT)
+                with tracing.timed("node.tsdb_scrape"):
+                    flowmem.refresh_gauges()
+                    admission.refresh_gauges()
+                    blockcache.refresh_gauges()
+                    fanout.refresh_gauges()
+                    self.tsdb.record(metric.DEFAULT)
                 retention = settings.get("ts.retention_seconds")
                 # prune at ~1/10 the scrape cadence: a retention trim scans
                 # the whole ts keyspace, too heavy for per-tick work
                 if retention and _time.monotonic() - last_prune >= iv * 10:
-                    wall, _ = hlc.unpack(self.db.clock.now())
-                    self.tsdb.prune_all(wall - int(retention * 1e3))
+                    with tracing.timed("node.tsdb_prune"):
+                        wall, _ = hlc.unpack(self.db.clock.now())
+                        self.tsdb.prune_all(wall - int(retention * 1e3))
                     last_prune = _time.monotonic()
             except Exception as e:  # metric write must never kill the node  # crlint: allow-broad-except(metric write must never kill the node; logged)
                 log.warning(log.OPS, "tsdb poll failed", error=str(e))
@@ -432,7 +439,8 @@ class Node:
     def _adopt_loop(self) -> None:
         while not self._stop.wait(self._adopt_interval):
             try:
-                adopted = self.jobs.adopt_orphans()
+                with tracing.timed("node.adopt"):
+                    adopted = self.jobs.adopt_orphans()
                 for j in adopted:
                     log.info(log.OPS, "re-adopted orphaned job",
                              job=j.job_id, state=j.state)
@@ -453,23 +461,27 @@ class Node:
         while not self._stop.wait(0.1):
             if self.gossip is None:
                 return
-            for key in self.gossip.keys():
-                if not key.startswith(self._SETTING_PREFIX):
-                    continue
-                name = key[len(self._SETTING_PREFIX):]
-                info = self.gossip.get_info(key)
-                if info is None or applied.get(name) == info:
-                    continue
-                try:
-                    self._applying_remote = True
-                    settings.set(name, info)
-                    applied[name] = info
-                except Exception as e:  # crlint: allow-broad-except(bad gossiped value is logged and pinned to avoid a retry storm)
-                    log.warning(log.OPS, "gossiped setting rejected",
-                                setting=name, error=str(e))
-                    applied[name] = info  # don't retry a bad value forever
-                finally:
-                    self._applying_remote = False
+            with tracing.timed("node.settings_apply"):
+                self._apply_gossiped_settings(applied)
+
+    def _apply_gossiped_settings(self, applied: dict[str, object]) -> None:
+        for key in self.gossip.keys():
+            if not key.startswith(self._SETTING_PREFIX):
+                continue
+            name = key[len(self._SETTING_PREFIX):]
+            info = self.gossip.get_info(key)
+            if info is None or applied.get(name) == info:
+                continue
+            try:
+                self._applying_remote = True
+                settings.set(name, info)
+                applied[name] = info
+            except Exception as e:  # crlint: allow-broad-except(bad gossiped value is logged and pinned to avoid a retry storm)
+                log.warning(log.OPS, "gossiped setting rejected",
+                            setting=name, error=str(e))
+                applied[name] = info  # don't retry a bad value forever
+            finally:
+                self._applying_remote = False
 
     def gossip_addr(self):
         return getattr(self, "_gossip_addr", None)

@@ -19,6 +19,11 @@ text format (the universally-supported encoding); the extended protocol
 
 Each connection gets its OWN Session over the shared catalog/DB — the
 reference's conn-executor-per-session model.
+
+The wire's own time goes into two timed sections (utils/tracing.timed: no
+span tree, so ``sql.execute`` stays the statement's root): ``pgwire.read``,
+a message's first byte to its body decoded, and ``pgwire.encode``,
+``session.execute`` returned to the result and ReadyForQuery sent.
 """
 
 from __future__ import annotations
@@ -31,6 +36,7 @@ import threading
 import numpy as np
 
 from ..sql import Session
+from ..utils import tracing
 
 _SSL_REQUEST = 80877103
 _CANCEL_REQUEST = 80877102
@@ -150,8 +156,8 @@ class _Conn:
                 out.append(struct.pack("!i", len(r)) + r)
         self._send(b"D", b"".join(out))
 
-    def _run_query(self, sql_text: str, send_row_desc: bool = True) -> None:
-        res = self.session.execute(sql_text)
+    def _send_result(self, res, sql_text: str,
+                     send_row_desc: bool = True) -> None:
         if isinstance(res, dict) and res and all(
             isinstance(v, np.ndarray) for v in res.values()
         ):
@@ -192,13 +198,39 @@ class _Conn:
             tag = b"OK"
         self._send(b"C", tag + b"\x00")
 
+    def _simple_query(self, sql_text: str) -> None:
+        empty = not sql_text.strip()
+        err = res = None
+        try:
+            if not empty:
+                res = self.session.execute(sql_text)
+        except Exception as e:  # crlint: allow-broad-except(query error becomes an ErrorResponse to the client)
+            err = e
+        with tracing.timed("pgwire.encode"):
+            if err is None:
+                try:
+                    if empty:
+                        self._send(b"I", b"")  # EmptyQueryResponse
+                    else:
+                        self._send_result(res, sql_text)
+                except Exception as e:  # crlint: allow-broad-except(encoding error becomes an ErrorResponse to the client)
+                    err = e
+            if err is not None:
+                self._error(f"{type(err).__name__}: {err}",
+                            code=_sqlstate_for(err))
+            self._ready()
+
     def serve(self) -> None:
         if not self.startup():
             return
         while True:
-            tag = self._recv_exact(1)
-            n = struct.unpack("!I", self._recv_exact(4))[0]
-            body = self._recv_exact(n - 4)
+            tag = self._recv_exact(1)  # the wait for the client: untimed
+            with tracing.timed("pgwire.read"):
+                n = struct.unpack("!I", self._recv_exact(4))[0]
+                body = self._recv_exact(n - 4)
+                if tag == b"Q":
+                    sql_text = body.rstrip(b"\x00").decode("utf-8",
+                                                           "replace")
             if tag == b"X":  # Terminate
                 return
             if self._ext_failed and tag != b"S":
@@ -207,16 +239,7 @@ class _Conn:
                 # until Sync — any extra response would desync the client
                 continue
             if tag == b"Q":
-                sql_text = body.rstrip(b"\x00").decode("utf-8", "replace")
-                try:
-                    if sql_text.strip():
-                        self._run_query(sql_text)
-                    else:
-                        self._send(b"I", b"")  # EmptyQueryResponse
-                except Exception as e:  # crlint: allow-broad-except(query error becomes an ErrorResponse to the client)
-                    self._error(f"{type(e).__name__}: {e}",
-                                code=_sqlstate_for(e))
-                self._ready()
+                self._simple_query(sql_text)
             elif tag in (b"P", b"B", b"D", b"E", b"C"):
                 # extended protocol (Parse/Bind/Describe/Execute/Close):
                 # on ANY failure send ONE ErrorResponse then discard until
@@ -237,7 +260,8 @@ class _Conn:
                 pass
             elif tag == b"S":  # Sync ends the extended batch
                 self._ext_failed = False
-                self._ready()
+                with tracing.timed("pgwire.encode"):
+                    self._ready()
             else:
                 self._error(f"unknown message {tag!r}")
                 self._ready()
@@ -249,6 +273,47 @@ class _Conn:
         end = body.index(b"\x00", off)
         return body[off:end].decode("utf-8", "replace"), end + 1
 
+    def _bind(self, body: bytes) -> None:
+        """Decode a Bind message into a portal (parameters inlined)."""
+        portal, off = self._cstr(body, 0)
+        stmt, off = self._cstr(body, off)
+        nfmt = struct.unpack_from("!H", body, off)[0]
+        fmts = struct.unpack_from("!%dH" % nfmt, body, off + 2)
+        off += 2 + 2 * nfmt
+        nparams = struct.unpack_from("!H", body, off)[0]
+        off += 2
+        params: list[str | None] = []
+        for i in range(nparams):
+            plen = struct.unpack_from("!i", body, off)[0]
+            off += 4
+            if plen < 0:
+                params.append(None)
+                continue
+            fmt = fmts[i] if i < len(fmts) else (
+                fmts[0] if len(fmts) == 1 else 0)
+            if fmt != 0:
+                raise ValueError(
+                    "binary parameter format is not supported "
+                    "(send text format)"
+                )
+            params.append(body[off:off + plen].decode("utf-8"))
+            off += plen
+        # trailing result-format codes: binary results are not
+        # implemented — reject loudly rather than sending text bytes
+        # a binary-mode client would decode as garbage
+        if off + 2 <= len(body):
+            nrf = struct.unpack_from("!H", body, off)[0]
+            rfmts = struct.unpack_from("!%dH" % nrf, body, off + 2)
+            if any(f != 0 for f in rfmts):
+                raise ValueError(
+                    "binary result format is not supported "
+                    "(request text format)"
+                )
+        sql = self._stmts.get(stmt.encode())
+        if sql is None:
+            raise ValueError(f"unknown prepared statement {stmt!r}")
+        self._portals[portal.encode()] = _inline_params(sql, params)
+
     def _extended(self, tag: bytes, body: bytes) -> None:
         if tag == b"P":  # Parse: name, query, param-type oids
             name, off = self._cstr(body, 0)
@@ -256,44 +321,8 @@ class _Conn:
             self._stmts[name.encode()] = query
             self._send(b"1", b"")  # ParseComplete
         elif tag == b"B":  # Bind: portal, stmt, formats, params
-            portal, off = self._cstr(body, 0)
-            stmt, off = self._cstr(body, off)
-            nfmt = struct.unpack_from("!H", body, off)[0]
-            fmts = struct.unpack_from("!%dH" % nfmt, body, off + 2)
-            off += 2 + 2 * nfmt
-            nparams = struct.unpack_from("!H", body, off)[0]
-            off += 2
-            params: list[str | None] = []
-            for i in range(nparams):
-                plen = struct.unpack_from("!i", body, off)[0]
-                off += 4
-                if plen < 0:
-                    params.append(None)
-                    continue
-                fmt = fmts[i] if i < len(fmts) else (
-                    fmts[0] if len(fmts) == 1 else 0)
-                if fmt != 0:
-                    raise ValueError(
-                        "binary parameter format is not supported "
-                        "(send text format)"
-                    )
-                params.append(body[off:off + plen].decode("utf-8"))
-                off += plen
-            # trailing result-format codes: binary results are not
-            # implemented — reject loudly rather than sending text bytes
-            # a binary-mode client would decode as garbage
-            if off + 2 <= len(body):
-                nrf = struct.unpack_from("!H", body, off)[0]
-                rfmts = struct.unpack_from("!%dH" % nrf, body, off + 2)
-                if any(f != 0 for f in rfmts):
-                    raise ValueError(
-                        "binary result format is not supported "
-                        "(request text format)"
-                    )
-            sql = self._stmts.get(stmt.encode())
-            if sql is None:
-                raise ValueError(f"unknown prepared statement {stmt!r}")
-            self._portals[portal.encode()] = _inline_params(sql, params)
+            with tracing.timed("pgwire.read"):  # the body's decode
+                self._bind(body)
             self._send(b"2", b"")  # BindComplete
         elif tag == b"D":  # Describe 'S'|'P' + name
             kind, name = body[:1], body[1:].rstrip(b"\x00")
@@ -326,7 +355,9 @@ class _Conn:
             # re-parameterizes it — so Parse-once/Bind-many clients hit
             # the prepared-plan cache on every rebind: no re-plan, no new
             # XLA compiles (the inlined literals rebind as jit arguments).
-            self._run_query(sql, send_row_desc=False)
+            res = self.session.execute(sql)
+            with tracing.timed("pgwire.encode"):
+                self._send_result(res, sql, send_row_desc=False)
         elif tag == b"C":  # Close 'S'|'P' + name
             kind, name = body[:1], body[1:].rstrip(b"\x00")
             (self._stmts if kind == b"S" else self._portals).pop(name, None)

@@ -433,16 +433,10 @@ def run_cached_ex(rel, text: str | None = None):
         # run BEFORE publishing: a plan whose first execution fails never
         # enters the cache (concurrent first executions may both build;
         # insert keeps whichever published first)
-        with entry.lock:
-            entry.store.set_values(values)
-            with tracing.leaf_span("query", cache="miss"):
-                res = runtime.run_operator(entry.root)
+        res = _run_entry(entry, values, "miss")
         entry = cache.insert(key, entry)
     else:
-        with entry.lock:
-            entry.store.set_values(values)
-            with tracing.leaf_span("query", cache="hit"):
-                res = runtime.run_operator(entry.root)
+        res = _run_entry(entry, values, "hit")
         if entry.fingerprint:
             # warm-menu hit accounting: a serving-path hit on a statement
             # the AOT menu compiled means the cold wall was paid at start
@@ -460,6 +454,19 @@ def run_cached_ex(rel, text: str | None = None):
     return res, status, entry.fingerprint
 
 
+def _run_entry(entry, values, status: str):
+    """Bind ``values`` and run a prepared plan. The one site that opens a
+    cached statement's ``query`` span (an instrumented, uncached run opens
+    it in flow/runtime.run_plan_with_stats; a statement has one or the
+    other)."""
+    from ..flow import runtime
+
+    with entry.lock:
+        entry.store.set_values(values)
+        with tracing.leaf_span("query", cache=status):
+            return runtime.run_operator(entry.root)
+
+
 def run_memoized(catalog, text: str):
     """Exact-text fast path; see :func:`run_memoized_ex` (this keeps the
     original results-or-None shape)."""
@@ -472,8 +479,6 @@ def run_memoized_ex(catalog, text: str):
     its entry is still live (same catalog version + settings), execute it
     without parsing or binding. Returns (results, entry fingerprint) or
     None (fall through to the normal path)."""
-    from ..flow import runtime
-
     if not _cacheable():
         return None
     cache = cache_for(catalog)
@@ -497,10 +502,7 @@ def run_memoized_ex(catalog, text: str):
         from . import warmmenu
 
         warmmenu.note_serving_hit(entry.fingerprint)
-    with entry.lock:
-        entry.store.set_values(values)
-        with tracing.leaf_span("query", cache="memo"):
-            return runtime.run_operator(entry.root), entry.fingerprint
+    return _run_entry(entry, values, "memo"), entry.fingerprint
 
 
 def probe(rel) -> str:

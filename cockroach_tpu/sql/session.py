@@ -212,10 +212,11 @@ class Session:
                     admission.classify_statement(text),
                     tenant_id=(None if self.tenant is None
                                else self.tenant.tenant_id),
-                    deadline=self._statement_deadline()), \
+                    deadline=self._statement_deadline()) as waited_s, \
                     flowmem.query_scope(self._mem_mon) as qmon, \
-                    tracing.span("sql.execute",
-                                 stmt=text.strip()[:120]) as sp:
+                    tracing.span("sql.execute", stmt=text.strip()[:120],
+                                 admission_wait_ms=round(waited_s * 1e3, 3)
+                                 ) as sp:
                 out = self._dispatch(text)
         except BaseException:
             # ANY failure inside an explicit block aborts it (postgres /

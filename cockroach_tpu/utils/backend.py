@@ -38,6 +38,11 @@ def enable_compile_cache() -> str:
         jax.config.update("jax_compilation_cache_dir", cache_dir)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    # every entry point comes through here once, so this is also where the
+    # tracer starts counting backend compiles by owner
+    from . import tracing
+
+    tracing.install_compile_listener()
     return cache_dir
 
 

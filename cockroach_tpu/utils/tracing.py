@@ -24,6 +24,21 @@ are only born through ``Tracer.span``/``remote_span``/``synthetic_span`` —
 no direct Span() construction or current-context mutation outside this
 module, so every span is guaranteed to close, unregister from the inflight
 table, and land in exactly one tree.
+
+Whole-window accounting: every span that closes adds its count, seconds,
+self seconds and numeric tags to a per-name record (``totals()``); a reader
+snapshots before and after a window and takes the difference, so nothing
+depends on which roots the ring still holds. ``timed(name)`` is the entry
+for work that must NOT grow a tree — the node's background loops, the wire
+— it feeds the same totals and names an *owner* for the compiles that
+happen inside it (``compiles_by_owner()``), but is never ``current()``, in
+the in-flight table or in the ring.
+
+The profiler's clock: while ``sql.trace.xla_profile`` is on, every span
+and timed section also enters a ``jax.profiler.TraceAnnotation`` of its
+name on its own thread (``annotation()`` is the same for sites too hot
+for a span), so a profiler trace places the device's idle time against
+the layer the host was in.
 """
 
 from __future__ import annotations
@@ -31,10 +46,12 @@ from __future__ import annotations
 import itertools
 import threading
 import time
-from contextlib import contextmanager
+from contextlib import contextmanager, nullcontext
 from contextvars import ContextVar
 from dataclasses import dataclass, field, is_dataclass
 from typing import Any
+
+from . import settings
 
 _ids = itertools.count(1)
 _id_lock = threading.Lock()
@@ -79,6 +96,7 @@ class Span:
     children: list["Span"] = field(default_factory=list)
     remote: bool = False     # grafted from another node's recording
     error: str | None = None
+    child_s: float = 0.0     # seconds its closed children covered (self time)
 
     def record(self, payload: Any) -> None:
         """Attach a structured payload (ComponentStats etc.)."""
@@ -144,8 +162,81 @@ class Span:
         return s
 
 
-MAX_FINISHED = 64   # ring of recent root spans (the span registry's cap)
+MAX_FINISHED = 1024  # ring of recent root spans (the span registry's cap)
 MAX_CHILDREN = 128  # per-span child cap (hot leaf sites: WAL appends)
+
+# JAX's own event for one backend compile (the benchmark's
+# kernels.xla_compiles_in_window counts the same event)
+COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+OWNER_STATEMENT = "statement"  # a span was current on the compiling thread
+OWNER_OTHER = "other"          # neither a timed section nor a span
+
+_NULL = nullcontext()
+# sql.trace.xla_profile, kept current by _on_setting so that no span looks
+# the setting up
+_mirror = False
+
+
+def _on_setting(name: str, _value) -> None:
+    global _mirror
+    if name == "sql.trace.xla_profile":
+        _mirror = bool(settings.get("sql.trace.xla_profile"))
+
+
+settings.on_change(_on_setting)
+
+
+def _profiler_annotation(name: str, **args):
+    """A ``jax.profiler.TraceAnnotation`` (not yet entered); a null
+    context where the profiler cannot be had — a query must run without
+    it."""
+    try:
+        from jax.profiler import TraceAnnotation
+    except Exception:  # crlint: allow-broad-except(profiler optional; query must run without it)
+        return _NULL
+    return TraceAnnotation(name, **args)
+
+
+def annotation(name: str, **args):
+    """Profiler-only region for sites too hot for an always-on span (each
+    kernel dispatch, each readback): on the profiler's clock while
+    ``sql.trace.xla_profile`` is on, nothing otherwise — no span, no
+    totals."""
+    if not _mirror:
+        return _NULL
+    return _profiler_annotation(name, **args)
+
+
+class _Timed:
+    """One timed section (``Tracer.timed``). Besides its wall seconds it
+    records the thread's own CPU seconds (``time.thread_time``): a loop
+    body's wall time is mostly waiting — for the interpreter lock, the
+    engine's mutex, the device's queue behind a statement's kernels — and
+    only the CPU seconds say what the section itself took from the host."""
+
+    __slots__ = ("_tracer", "_name", "_t0", "_c0", "_token", "_ann")
+
+    def __init__(self, tracer: "Tracer", name: str):
+        self._tracer, self._name = tracer, name
+
+    def __enter__(self) -> "_Timed":
+        self._token = self._tracer._owner.set(self._name)
+        self._ann = None
+        if _mirror:
+            self._ann = _profiler_annotation(self._name)
+            self._ann.__enter__()
+        self._c0 = time.thread_time()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        dt = time.perf_counter() - self._t0
+        cpu = time.thread_time() - self._c0
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+        self._tracer._owner.reset(self._token)
+        self._tracer._account(self._name, dt, None, None, cpu)
+        return False
 
 
 class Tracer:
@@ -161,6 +252,14 @@ class Tracer:
         self._fin_lock = threading.Lock()
         self._inflight: dict[int, Span] = {}
         self._if_lock = threading.Lock()
+        # the innermost open timed section's name on this thread/context
+        self._owner: ContextVar[str | None] = ContextVar(
+            f"crdb_tpu_trace_owner_{id(self)}", default=None)
+        # name -> [count, total_s, self_s, {numeric tag: sum}, cpu_s], and
+        # backend compiles by owner; one lock for both, taken once per close
+        self._totals: dict[str, list] = {}
+        self._compiles: dict[str, int] = {}
+        self._tot_lock = threading.Lock()
 
     # -- span lifecycle ----------------------------------------------------
 
@@ -193,6 +292,39 @@ class Tracer:
             return
         yield from self._run_span(Span(name=name, tags=dict(tags)), None)
 
+    def timed(self, name: str) -> _Timed:
+        """A timed section: feeds ``totals()`` and the profiler mirror and
+        owns the compiles inside it, but grows no tree — it is never
+        ``current()``, never in ``inflight()`` or ``finished``, and a
+        ``leaf_span`` inside it still yields None. For work that runs
+        many times a second outside any statement (the node's loops, the
+        wire): root spans there would evict the ring's statement sample
+        and switch on every leaf site under them."""
+        return _Timed(self, name)
+
+    def _account(self, name: str, total_s: float, span: Span | None,
+                 parent: Span | None, cpu_s: float = 0.0) -> None:
+        """One close: the per-name totals, and the parent's running
+        ``child_s`` (so children dropped past MAX_CHILDREN still count
+        against its self time)."""
+        with self._tot_lock:
+            rec = self._totals.get(name)
+            if rec is None:
+                rec = self._totals[name] = [0, 0.0, 0.0, {}, 0.0]
+            rec[0] += 1
+            rec[1] += total_s
+            if span is None:
+                rec[2] += total_s
+                rec[4] += cpu_s
+                return
+            rec[2] += max(0.0, total_s - span.child_s)
+            if parent is not None:
+                parent.child_s += total_s
+            sums = rec[3]
+            for k, v in span.tags.items():
+                if isinstance(v, (int, float)) and not isinstance(v, bool):
+                    sums[k] = sums.get(k, 0) + v
+
     def _run_span(self, s: Span, remote_parent: tuple[int, int] | None):
         parent = self._current.get()
         s.span_id = _next_id()
@@ -212,6 +344,10 @@ class Tracer:
         with self._if_lock:
             self._inflight[s.span_id] = s
         token = self._current.set(s)
+        ann = None
+        if _mirror:
+            ann = _profiler_annotation(s.name)
+            ann.__enter__()
         try:
             yield s
         except BaseException as e:
@@ -220,7 +356,11 @@ class Tracer:
             raise
         finally:
             s.duration = time.perf_counter() - s.start
+            if ann is not None:
+                ann.__exit__(None, None, None)
             self._current.reset(token)
+            self._account(s.name, s.duration, s,
+                          parent if remote_parent is None else None)
             with self._if_lock:
                 self._inflight.pop(s.span_id, None)
             if parent is None:
@@ -279,6 +419,40 @@ class Tracer:
         with self._if_lock:
             return sorted(self._inflight.values(), key=lambda s: s.start)
 
+    # -- whole-window accounting -------------------------------------------
+
+    def totals(self) -> dict[str, dict]:
+        """Snapshot of every closed span and timed section since the
+        process started, by name: ``count``, ``total_s``, ``self_s`` (a
+        span's seconds minus what its closed children covered; a timed
+        section's are its own), ``tags`` (sums of numeric tags) and
+        ``cpu_s`` (timed sections only: the thread's own CPU seconds
+        inside them, waits left out; 0 for a span). A reader takes the
+        difference of two snapshots over its window."""
+        with self._tot_lock:
+            return {n: {"count": r[0], "total_s": r[1], "self_s": r[2],
+                        "tags": dict(r[3]), "cpu_s": r[4]}
+                    for n, r in self._totals.items()}
+
+    def compiles_by_owner(self) -> dict[str, int]:
+        """Backend compiles JAX reported since the listener went in, by
+        who asked: the innermost timed section open on the compiling
+        thread, else ``statement`` where a span was current, else
+        ``other``."""
+        with self._tot_lock:
+            return dict(self._compiles)
+
+    def _on_duration(self, event: str, _seconds: float, **_kw) -> None:
+        # runs on the compiling thread, so both contextvars are its own
+        if event != COMPILE_EVENT:
+            return
+        owner = self._owner.get()
+        if owner is None:
+            owner = (OWNER_STATEMENT if self._current.get() is not None
+                     else OWNER_OTHER)
+        with self._tot_lock:
+            self._compiles[owner] = self._compiles.get(owner, 0) + 1
+
 
 # process-global default tracer (the reference hangs one off every Server)
 DEFAULT = Tracer()
@@ -310,6 +484,38 @@ def graft(payload: dict | None, into: Span | None = None) -> Span | None:
 
 def inflight() -> list[Span]:
     return DEFAULT.inflight()
+
+
+def timed(name: str) -> _Timed:
+    return DEFAULT.timed(name)
+
+
+def totals() -> dict[str, dict]:
+    return DEFAULT.totals()
+
+
+_listener_lock = threading.Lock()
+_listening = False
+
+
+def install_compile_listener() -> None:
+    """Register the default tracer's one ``jax.monitoring`` duration
+    listener (idempotent). ``utils/backend.enable_compile_cache`` — which
+    every entry point calls — and ``compiles_by_owner`` both come here."""
+    global _listening
+    with _listener_lock:
+        if _listening:
+            return
+        import jax.monitoring
+
+        jax.monitoring.register_event_duration_secs_listener(
+            DEFAULT._on_duration)
+        _listening = True
+
+
+def compiles_by_owner() -> dict[str, int]:
+    install_compile_listener()
+    return DEFAULT.compiles_by_owner()
 
 
 def synthetic_span(parent: Span, name: str, duration_s: float,
