@@ -20,6 +20,10 @@ from .coldata.types import Family, Schema
 
 
 
+# column families stored as host integers (what dense_key_info walks)
+_INT_KEY_FAMILIES = (Family.INT, Family.DECIMAL, Family.DATE,
+                     Family.TIMESTAMP, Family.INTERVAL)
+
 TILE_ALIGN = 1024  # pad device tables to a multiple of this (8x128 lanes)
 
 # canonical tile-shape menu (L0 of the cache hierarchy — see README):
@@ -142,8 +146,7 @@ class Table:
         info: dict[str, tuple[int, int]] = {}
         n = self.num_rows
         for name, t in zip(self.schema.names, self.schema.types):
-            if t.family not in (Family.INT, Family.DECIMAL, Family.DATE,
-                                Family.TIMESTAMP, Family.INTERVAL):
+            if t.family not in _INT_KEY_FAMILIES:
                 continue
             if name in self.valids or n == 0:
                 continue  # NULLs break the bijection
@@ -163,6 +166,64 @@ class Table:
                 info[name] = (lo, fanout)
         self._dense_keys = info
         return info
+
+    def unique_key(self, cols: tuple[str, ...]) -> bool:
+        """Is the tuple of columns a key of this table: NULL-free, and no
+        two rows equal on all of it? A PROOF over the host rows, never an
+        estimate: the binder plans a join's build side as unique on it
+        (sql/binder.py _build_unique), and a wrong True drops matches.
+
+        dense_key_info answers where a column is a surrogate key (fanout
+        1); ANALYZE statistics only ever say no early (fewer distinct
+        values than rows); otherwise one np.unique over the key, packed
+        into one word where its ranges fit. Host-verified once per column
+        set, cached; the cache dies with _dense_keys wherever the table's
+        columns are swapped."""
+        cols = tuple(sorted(set(cols)))
+        cache = getattr(self, "_unique_keys", None)
+        if cache is None:
+            cache = self._unique_keys = {}
+        got = cache.get(cols)
+        if got is None:
+            got = cache[cols] = self._verify_unique(cols)
+        return got
+
+    def _verify_unique(self, cols: tuple[str, ...]) -> bool:
+        n = self.num_rows
+        arrs = []
+        for name in cols:
+            a = np.asarray(self.columns[name])
+            if (self.schema.type_of(name).family not in _INT_KEY_FAMILIES
+                    or a.ndim != 1 or a.dtype.kind not in ("i", "u")
+                    or a.dtype == np.uint64):
+                return False  # no exact int64 representation
+            if name in self.valids and not np.asarray(
+                    self.valids[name]).all():
+                return False
+            arrs.append(a)
+        if not arrs or n <= 1:
+            return bool(arrs)
+        dense = self.dense_key_info()
+        if any(dense.get(name, (0, 0))[1] == 1 for name in cols):
+            return True
+        st = getattr(self, "table_stats", None)
+        if st is not None and st.row_count == n:
+            room = 1
+            for name in cols:
+                cs = st.cols.get(name)
+                room *= cs.ndv if cs is not None else n
+            if room < n:
+                return False  # pigeonhole: some key repeats
+        # mixed-radix pack (x - lo) over each column's range into one int64
+        # while the product of the ranges fits; rows otherwise
+        key, span = np.zeros(n, np.int64), 1
+        for a in arrs:
+            lo, hi = int(a.min()), int(a.max())
+            span *= hi - lo + 1
+            if span >= 1 << 63:
+                return len(np.unique(np.stack(arrs, axis=1), axis=0)) == n
+            key = key * (hi - lo + 1) + (a.astype(np.int64) - lo)
+        return len(np.unique(key)) == n
 
     def device_batch(self, names: tuple[str, ...] | None = None) -> Batch:
         """Device-resident batch of the requested columns. Cached per column,

@@ -40,6 +40,7 @@ from ..ops.aggregation import partial_layout
 from ..ops import expr as ex
 from ..ops import join as join_ops
 from ..ops import sort as sort_ops
+from ..utils import tracing
 from . import dispatch
 from .operator import OneInputOperator, Operator, SourceOperator
 
@@ -1769,7 +1770,23 @@ class HashJoinOp(OneInputOperator):
             self._chain_fn = chain
             self._chain_base = cfn
             self._chain_raw = raw
-        return src, self._chain_fn, cargs + (self._build_batch, self._index)
+        counted = getattr(self, "_counted_src", None)
+        if counted is None or counted.src is not src:
+            counted = self._counted_src = _CountedProbeTiles(src, self)
+        return (counted, self._chain_fn,
+                cargs + (self._build_batch, self._index))
+
+    def _note_probe_tile(self) -> None:
+        """One probe tile into the pull span's ``join_unique_tiles`` (served
+        by a unique-build strategy: analytic, LUT, sorted-unique) or
+        ``join_general_tiles`` (by hash_join_general), beside the dispatch
+        tags; tracing.totals() sums them over a window."""
+        sp = tracing.current()
+        if sp is None:
+            return
+        unique = self._probe_raw is not None and (
+            self.spec.build_unique or self._probe_kind != "sorted")
+        sp.inc_tag("join_unique_tiles" if unique else "join_general_tiles", 1)
 
     def _emit_kernel(self, cfn, nc):
         """(chain o probe o count [o compact]) jit for source-mode emission,
@@ -1834,6 +1851,7 @@ class HashJoinOp(OneInputOperator):
                     settings.get("sql.distsql.tile_size")))
             kern = self._emit_kernel(cfn, len(cargs))
             for t in src.stream_tiles():
+                self._note_probe_tile()
                 out, cnt = kern(t, *args)
                 self._emit_counts.append(cnt)
                 if self._emit_cap is None:
@@ -1849,6 +1867,7 @@ class HashJoinOp(OneInputOperator):
                 if self._emit_mode == "general" and self._emit_cap is None:
                     self._emit_cap = max(4096, _canonical_cap(b.capacity))
                 kern = self._emit_kernel(None, 0)
+            self._note_probe_tile()
             out, cnt = kern(b, self._build_batch, self._index)
             self._emit_counts.append(cnt)
             if self._emit_cap is None:
@@ -1913,6 +1932,7 @@ class HashJoinOp(OneInputOperator):
         p = self.child.next_batch()
         if p is None:
             return None
+        self._note_probe_tile()
         if self._probe_raw is not None:
             if self._emit_mode != "transparent":
                 out, cnt = self._emit_kernel(None, 0)(
@@ -1942,6 +1962,20 @@ class HashJoinOp(OneInputOperator):
         if getattr(self, "_build_alloc", None) is not None:
             self._build_alloc.close()
             self._build_alloc = None
+
+
+class _CountedProbeTiles:
+    """The tile source a transparent join hands its consumer: the probe is
+    fused into the consumer's kernel, so the join sees a tile only here."""
+
+    def __init__(self, src, join: HashJoinOp):
+        self.src = src
+        self.join = join
+
+    def stream_tiles(self):
+        for t in self.src.stream_tiles():
+            self.join._note_probe_tile()
+            yield t
 
 
 def _consume_op(op: Operator, tag: str):

@@ -1016,8 +1016,9 @@ class Binder:
                 )
         if not keys:
             raise BindError("LEFT JOIN requires at least one equi key")
-        rel = left.rel.join(right.rel, on=keys, how="left",
-                            build_unique=False)
+        rel = left.rel.join(
+            right.rel, on=keys, how="left",
+            build_unique=self._build_unique(right.rel, keys, right.table))
         return Source(
             alias=f"{left.alias}*{right.alias}", rel=rel,
             cols=rel.schema.names, base_rows=left.base_rows,
@@ -1025,6 +1026,44 @@ class Binder:
         )
 
     # -- join planning ------------------------------------------------------
+
+    def _build_unique(self, build: Rel, on, table: str | None) -> bool:
+        """JoinSpec.build_unique for `build` joined in on the (probe,
+        build) column pairs `on` (names or positions, as Rel.join takes
+        them): True only when the build key is PROVEN unique over the rows
+        that can reach the join. A wrong True drops matches, so anything
+        unproven stays False and keeps the duplicate-key probe.
+
+        Filters and column-reference projections only remove rows or
+        rename columns: the walk sees through them down to either a
+        grouping joined on (at least) all its group keys, or the scan of
+        `table` (the source's own base table: derived tables and CTEs pass
+        None) whose host rows prove it (catalog Table.unique_key). KV
+        tables have no such proof (nothing ties a cached plan to a
+        snapshot); join outputs, set operations and computed keys stop the
+        walk."""
+        from ..plan import spec as S
+
+        cols = [b if isinstance(b, int) else build.idx(b) for _, b in on]
+        node = build.plan
+        while isinstance(node, (S.Filter, S.Project)):
+            if isinstance(node, S.Project):
+                exprs = [node.exprs[c] for c in cols]
+                if not all(isinstance(e, ex.ColRef) for e in exprs):
+                    return False
+                cols = [e.idx for e in exprs]
+            node = node.input
+        if isinstance(node, S.Aggregate):
+            return (node.mode == "complete"
+                    and set(range(len(node.group_cols))) <= set(cols))
+        if not isinstance(node, S.TableScan) or node.table != table:
+            return False
+        tbl = self.catalog.tables.get(table)
+        prove = getattr(tbl, "unique_key", None)
+        if not callable(prove):
+            return False
+        names = node.columns or tbl.schema.names
+        return prove(tuple(names[c] for c in cols))
 
     # -- cardinality estimation (statistics_builder.go reduction) -----------
 
@@ -1146,7 +1185,9 @@ class Binder:
             off = len(rel.schema)
             nb = len(sources[nxt].rel.schema)
             rel = rel.join(
-                sources[nxt].rel, on=on, how="inner", build_unique=False
+                sources[nxt].rel, on=on, how="inner",
+                build_unique=self._build_unique(
+                    sources[nxt].rel, on, sources[nxt].table),
             )
             for p in range(nb):
                 colmap[(nxt, p)] = off + p
@@ -1214,8 +1255,10 @@ class Binder:
             off = len(rel.schema)
             nb = len(sources[nxt].rel.schema)
             if on:
-                rel = rel.join(sources[nxt].rel, on=on, how="inner",
-                               build_unique=False)
+                rel = rel.join(
+                    sources[nxt].rel, on=on, how="inner",
+                    build_unique=self._build_unique(
+                        sources[nxt].rel, on, sources[nxt].table))
             else:
                 rel = rel.cross_join(sources[nxt].rel)
             for p in range(nb):
@@ -1273,15 +1316,17 @@ class Binder:
                         )
                     # empty subquery: plain anti join keeps every row
                     # (including NULL keys) — exactly NOT IN () = TRUE
+            on = [(outer_pos, inner_col)]
             joined.rel = joined.rel.join(
-                sub, on=[(outer_pos, inner_col)], how=how, build_unique=False
+                sub, on=on, how=how,
+                build_unique=self._build_unique(sub, on, None),
             )
             return joined
         how = "anti" if negate else "semi"
         if isinstance(node, P.Exists):
             # correlated equality conjuncts reference outer columns
             sub_sel = node.select
-            inner_rel, corr, ne_pairs = self._bind_correlated(
+            inner_rel, corr, ne_pairs, inner_table = self._bind_correlated(
                 sub_sel, joined)
             resolver = self._make_resolver(scope, joined)
 
@@ -1292,7 +1337,9 @@ class Binder:
             on_pos = [(opos(oid), iname) for oid, iname in corr]
             if not ne_pairs:
                 joined.rel = joined.rel.join(
-                    inner_rel, on=on_pos, how=how, build_unique=False
+                    inner_rel, on=on_pos, how=how,
+                    build_unique=self._build_unique(inner_rel, on_pos,
+                                                    inner_table),
                 )
                 return joined
             # EXISTS with an extra `inner.s <> outer.s` correlation (TPC-H
@@ -1579,7 +1626,9 @@ class Binder:
 
     def _bind_correlated(self, sel: P.Select, joined: "BoundQuery"):
         """Bind an EXISTS subquery: conjuncts of its WHERE that are
-        equality with an outer column become the semi-join keys."""
+        equality with an outer column become the semi-join keys. Returns
+        the filtered inner Rel, the key pairs, the <> pairs and the inner
+        source's base table (None for a derived one)."""
         inner_sources, jf = self._bind_from(sel.from_)
         if len(inner_sources) != 1:
             raise BindError("correlated EXISTS supports one inner table")
@@ -1638,7 +1687,7 @@ class Binder:
             rel = rel.filter(ExprLowerer(rel).lower(p))
         if not corr:
             raise BindError("uncorrelated EXISTS not supported")
-        return rel, corr, ne_pairs
+        return rel, corr, ne_pairs, inner.table
 
     def _lower_with_subqueries(self, lower: ExprLowerer, c: P.Node) -> ex.Expr:
         """Lower a predicate, executing uncorrelated scalar subqueries into

@@ -302,8 +302,9 @@ class Registry:
             tbl.valids = new_valids
             tbl._device = None
             tbl._stats = None
-            if hasattr(tbl, "_dense_keys"):
-                del tbl._dense_keys
+            for proof in ("_dense_keys", "_unique_keys"):
+                if hasattr(tbl, proof):
+                    delattr(tbl, proof)
             if hasattr(tbl, "table_stats"):
                 del tbl.table_stats
             view._mat_gen = gen

@@ -368,9 +368,18 @@ def test_vtable_and_status_endpoint_snapshot():
         hi = db.clock.now()
         _events, resolved, err = _drain(sock, frames, hi)
         assert err is None and resolved >= hi
-        tab = crdb_internal.build(
-            object(), "crdb_internal.node_changefeed_subscribers")
-        rows = {name: tab.columns[name] for name in tab.schema.names}
+        # the sender writes a resolved frame to the wire BEFORE it records
+        # the frontier (Subscriber._maybe_checkpoint), so the frame this
+        # test just drained can be ahead of the registry for an instant
+        deadline = time.time() + 10
+        while True:
+            tab = crdb_internal.build(
+                object(), "crdb_internal.node_changefeed_subscribers")
+            rows = {name: tab.columns[name] for name in tab.schema.names}
+            if (len(rows["frontier"]) and int(rows["frontier"][0]) >= hi
+                    or time.time() > deadline):
+                break
+            time.sleep(0.01)
 
         def col_str(name):  # STRING columns are dictionary-encoded
             return str(tab.dictionaries[name].values[int(rows[name][0])])
