@@ -124,13 +124,15 @@ class Operator:
         None means this operator is a pipeline barrier."""
         return None
 
-    def post_run_update(self) -> bool:
+    def post_run_update(self, truncated: bool = False) -> bool:
         """End-of-query hook: adaptive operators fetch their deferred device
         counters here (ONE sync at query end, never per tile — a host sync
         stalls the pull loop for a device round trip) and update sticky
         execution choices. Returns True when this run's OUTPUT was invalid
         (e.g. a speculative emission capacity overflowed) and the runtime
-        must re-run the query with the corrected choices."""
+        must re-run the query with the corrected choices. ``truncated``:
+        an operator below overflowed, so this one's inputs were cut short
+        and what it counted says nothing."""
         return False
 
     def close(self) -> None:

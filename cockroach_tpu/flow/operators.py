@@ -71,6 +71,18 @@ def _canonical_cap(n: int) -> int:
     return _next_pow2(n)
 
 
+def _emission_cap(n: int) -> int:
+    """Learned compact-emission cap for ``n`` rows (2 x the live rows a
+    join's fullest tile kept): the canonical rung up to 65,536, plain pow2
+    past it. The next rung is 8x away, and a cap 8x the live rows makes
+    every join downstream carry seven dead rows for each live one (q9 at
+    SF1: 55.7k live rows a tile, rung 524,288, pow2 131,072). Caps only
+    grow once learned (post_run_update), so the finer steps mint at most
+    one specialization a doubling."""
+    cap = _canonical_cap(n)
+    return cap if cap <= (1 << 16) else _next_pow2(n)
+
+
 def _live_total(tiles: list[Batch]) -> int:
     """Total live rows across spooled tiles — ONE host sync for the spool."""
     if not tiles:
@@ -1363,9 +1375,25 @@ class HashJoinOp(OneInputOperator):
     ):
         super().__init__(probe)
         self.build = build
+        self.spec = spec
+        # existence probes (semi/anti) and unique-build probes have static
+        # probe-aligned output shapes: fusable, and eligible for the dense
+        # direct-addressing strategies picked in _ensure_built
+        self._fusable = (
+            spec.build_unique or spec.join_type in ("semi", "anti")
+        )
+        if self._fusable and len(build_keys) > 1:
+            # the analytic route addresses the build by its FIRST key: the
+            # order the SQL text wrote the equalities in decides nothing
+            # (q9: ps_suppkey = l_suppkey and ps_partkey = l_partkey, the
+            # clustered ps_partkey second)
+            lead = next((j for j, k in enumerate(build_keys)
+                         if self._dense_key(k) is not None), 0)
+            order = (lead, *(j for j in range(len(build_keys)) if j != lead))
+            probe_keys = tuple(probe_keys[j] for j in order)
+            build_keys = tuple(build_keys[j] for j in order)
         self.probe_keys = probe_keys
         self.build_keys = build_keys
-        self.spec = spec
         self.output_schema = join_ops.join_output_schema(
             probe.output_schema, build.output_schema, spec
         )
@@ -1411,12 +1439,6 @@ class HashJoinOp(OneInputOperator):
             have_remaps=True,
         )
         self._built = False
-        # existence probes (semi/anti) and unique-build probes have static
-        # probe-aligned output shapes: fusable, and eligible for the dense
-        # direct-addressing strategies picked in _ensure_built
-        self._fusable = (
-            spec.build_unique or spec.join_type in ("semi", "anti")
-        )
         self._analytic = None
         # Adaptive compact emission. A selective probe (e.g. TPC-H Q18's
         # lineitem against 14 surviving orders) emits probe-aligned tiles
@@ -1424,7 +1446,9 @@ class HashJoinOp(OneInputOperator):
         # O(tile x ncols) for a handful of rows. Sticky modes:
         #   learn       first run: probe output materializes with a live
         #               count per tile (device futures, fetched ONCE at
-        #               query end in post_run_update)
+        #               query end in post_run_update); also where a join
+        #               that has compacted goes while its literals are not
+        #               selective, to compact again when one is
         #   compact     output compacts in-kernel to _emit_cap; counts keep
         #               recording so an overflow (count > cap: results
         #               truncated) is detected at query end and the runtime
@@ -1450,6 +1474,8 @@ class HashJoinOp(OneInputOperator):
             else ("general" if self._gen_fusable else "transparent")
         )
         self._emit_cap = None
+        self._emit_cap_seen = 0  # the largest compact cap learned so far
+        self._emit_kerns: dict = {}  # (chain fn, probe fn, cap) -> jit
         self._emit_counts: list = []
         self._emit_tilecap = 0
 
@@ -1463,28 +1489,10 @@ class HashJoinOp(OneInputOperator):
         """
         if not self._fusable:
             return None
-        key = self.build_keys[0]
-        op = self.build
-        while not isinstance(op, ScanOp):
-            if isinstance(op, ProjectOp):
-                e = op.exprs[key]
-                if not isinstance(e, ex.ColRef):
-                    return None
-                key = e.idx
-                op = op.child
-            elif isinstance(op, FilterOp):
-                op = op.child
-            else:
-                return None
-        table = op.table
-        dense_fn = getattr(table, "dense_key_info", None)
-        if not callable(dense_fn):
-            return None
-        name = table.schema.names[op.col_idxs[key]]
-        got = dense_fn().get(name)
+        got = self._dense_key(self.build_keys[0])
         if got is None:
             return None
-        lo, fanout = got
+        table, (lo, fanout) = got
         if (self.spec.build_unique and fanout > 1
                 and len(self.build_keys) < 2):
             return None  # fanout rows share the first key: not unique by it
@@ -1506,6 +1514,30 @@ class HashJoinOp(OneInputOperator):
         return join_ops.DenseAnalytic(
             key_lo=lo, fanout=fanout, build_rows=table.num_rows
         )
+
+    def _dense_key(self, key: int):
+        """(table, (lo, fanout)) when build column ``key`` is, through a
+        position-preserving chain (Scan + Filter/Project only), a column of
+        the scanned table that is an affine function of the row index
+        (catalog Table.dense_key_info); else None."""
+        op = self.build
+        while not isinstance(op, ScanOp):
+            if isinstance(op, ProjectOp):
+                e = op.exprs[key]
+                if not isinstance(e, ex.ColRef):
+                    return None
+                key = e.idx
+                op = op.child
+            elif isinstance(op, FilterOp):
+                op = op.child
+            else:
+                return None
+        table = op.table
+        dense_fn = getattr(table, "dense_key_info", None)
+        if not callable(dense_fn):
+            return None
+        got = dense_fn().get(table.schema.names[op.col_idxs[key]])
+        return None if got is None else (table, got)
 
     def init(self):
         self.build.init()
@@ -1776,17 +1808,27 @@ class HashJoinOp(OneInputOperator):
         return (counted, self._chain_fn,
                 cargs + (self._build_batch, self._index))
 
-    def _note_probe_tile(self) -> None:
+    def _note_probe_tile(self, t, src=None) -> None:
         """One probe tile into the pull span's ``join_unique_tiles`` (served
         by a unique-build strategy: analytic, LUT, sorted-unique) or
-        ``join_general_tiles`` (by hash_join_general), beside the dispatch
-        tags; tracing.totals() sums them over a window."""
+        ``join_general_tiles`` (by hash_join_general), and its capacity
+        into ``join_probe_tile_rows`` (rows the probe pays for, live or
+        dead: known on the host, no sync), beside the dispatch tags;
+        tracing.totals() sums them over a window. ``t`` is a Batch, or a
+        resident scan's (table batch, offset) token whose tile size
+        ``src`` knows."""
         sp = tracing.current()
         if sp is None:
             return
         unique = self._probe_raw is not None and (
             self.spec.build_unique or self._probe_kind != "sorted")
         sp.inc_tag("join_unique_tiles" if unique else "join_general_tiles", 1)
+        rows = getattr(t, "capacity", None)
+        if rows is None:
+            while isinstance(src, _CountedProbeTiles):
+                src = src.src
+            rows = src._res_tile
+        sp.inc_tag("join_probe_tile_rows", int(rows))
 
     def _emit_kernel(self, cfn, nc):
         """(chain o probe o count [o compact]) jit for source-mode emission,
@@ -1800,8 +1842,8 @@ class HashJoinOp(OneInputOperator):
         if self._emit_mode == "general":
             graw = self._probe_gen_raw
             key = (cfn, graw, cap)
-            if getattr(self, "_emit_kern_key", None) == key:
-                return self._emit_kern
+            if key in self._emit_kerns:
+                return self._emit_kerns[key]
 
             def kern(t, *a):
                 p = cfn(t, *a[:nc]) if cfn is not None else t
@@ -1810,8 +1852,8 @@ class HashJoinOp(OneInputOperator):
         else:
             raw = self._probe_raw
             key = (cfn, raw, cap)
-            if getattr(self, "_emit_kern_key", None) == key:
-                return self._emit_kern
+            if key in self._emit_kerns:
+                return self._emit_kerns[key]
 
             def kern(t, *a):
                 out = raw(cfn(t, *a[:nc]) if cfn is not None else t,
@@ -1821,9 +1863,10 @@ class HashJoinOp(OneInputOperator):
                     out = compact_batch(out, capacity=cap)
                 return out, cnt
 
-        self._emit_kern = dispatch.jit(kern, name="hashjoin_emit")
-        self._emit_kern_key = key
-        return self._emit_kern
+        if len(self._emit_kerns) >= 8:  # the last few modes and caps
+            self._emit_kerns.pop(next(iter(self._emit_kerns)))
+        self._emit_kerns[key] = dispatch.jit(kern, name="hashjoin_emit")
+        return self._emit_kerns[key]
 
     def stream_tiles(self):
         """Source-mode drive loop (learn/compact emission)."""
@@ -1851,7 +1894,7 @@ class HashJoinOp(OneInputOperator):
                     settings.get("sql.distsql.tile_size")))
             kern = self._emit_kernel(cfn, len(cargs))
             for t in src.stream_tiles():
-                self._note_probe_tile()
+                self._note_probe_tile(t, src)
                 out, cnt = kern(t, *args)
                 self._emit_counts.append(cnt)
                 if self._emit_cap is None:
@@ -1867,15 +1910,23 @@ class HashJoinOp(OneInputOperator):
                 if self._emit_mode == "general" and self._emit_cap is None:
                     self._emit_cap = max(4096, _canonical_cap(b.capacity))
                 kern = self._emit_kernel(None, 0)
-            self._note_probe_tile()
+            self._note_probe_tile(b)
             out, cnt = kern(b, self._build_batch, self._index)
             self._emit_counts.append(cnt)
             if self._emit_cap is None:
                 self._emit_tilecap = max(self._emit_tilecap, out.capacity)
             yield out
 
-    def post_run_update(self) -> bool:
+    def post_run_update(self, truncated: bool = False) -> bool:
         if not self._emit_counts:
+            return False
+        if truncated and self._emit_mode != "general":
+            # a join below overflowed: these counts are of tiles it cut
+            # short. Count again at full tiles in the re-run, beside it,
+            # instead of finding the overflow one join an attempt
+            self._emit_counts = []
+            if self._emit_mode == "compact":
+                self._emit_mode, self._emit_cap = "learn", None
             return False
         # crlint: allow-host-sync(post_run_update: ONE stacked sync per query)
         counts = np.asarray(jax.block_until_ready(
@@ -1905,15 +1956,26 @@ class HashJoinOp(OneInputOperator):
             and mx > self._emit_cap
         )
         tile = self._emit_tilecap
-        cap = max(1024, _canonical_cap(2 * mx))
+        # a learned cap only grows: the plan serves every literal of its
+        # shape, and a run that kept few rows (a pattern that matches
+        # nothing) must not shrink the cap under the next statement (an
+        # overflow there: a re-run and new programs)
+        cap = max(1024, _emission_cap(2 * mx), self._emit_cap_seen)
         if tile and mx * 4 <= tile and cap < tile:
             # compacting only pays when the learned cap actually SHRINKS the
             # tile — at small tile sizes the cap floor equals the tile and
             # "compact" degenerates to one extra kernel per tile for nothing
             # (every join in a chain then self-drives: q9's five-join run
             # used to pay 5 kernels/tile instead of composing into 2)
-            self._emit_cap = cap
+            self._emit_cap = self._emit_cap_seen = cap
             self._emit_mode = "compact"
+        elif self._emit_cap_seen:
+            # selective for some literal of this plan, not for this one
+            # ('%a%' after '%green%'): keep counting at full tiles, so the
+            # next selective literal compacts again. Transparent joins
+            # record nothing and would never come back.
+            self._emit_mode = "learn"
+            self._emit_cap = None
         else:
             self._emit_mode = "transparent"
             self._emit_cap = None
@@ -1932,7 +1994,7 @@ class HashJoinOp(OneInputOperator):
         p = self.child.next_batch()
         if p is None:
             return None
-        self._note_probe_tile()
+        self._note_probe_tile(p)
         if self._probe_raw is not None:
             if self._emit_mode != "transparent":
                 out, cnt = self._emit_kernel(None, 0)(
@@ -1974,7 +2036,7 @@ class _CountedProbeTiles:
 
     def stream_tiles(self):
         for t in self.src.stream_tiles():
-            self.join._note_probe_tile()
+            self.join._note_probe_tile(t, self.src)
             yield t
 
 

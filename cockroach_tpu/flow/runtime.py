@@ -131,10 +131,11 @@ def _post_run_updates(op) -> bool:
     device-counter fetch — the ONE host sync speculative execution pays per
     query). Returns True when any operator invalidated this run's output
     (speculative emission capacity overflowed) and the query must re-run."""
-    rerun = op.post_run_update()
+    below = False
     for c in op.children():
-        rerun = _post_run_updates(c) or rerun
-    return rerun
+        below = _post_run_updates(c) or below
+    # children first: an overflow below cut this operator's inputs short
+    return op.post_run_update(truncated=below) or below
 
 
 def run_operator(root) -> dict[str, np.ndarray]:

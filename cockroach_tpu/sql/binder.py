@@ -262,6 +262,10 @@ class Source:
     # (statistics_builder.go selectivity role); None = no estimate, join
     # ordering falls back to base_rows
     est_rows: int | None = None
+    # estimated share of its rows the pushed-down filters keep; None = no
+    # filter (a join to it as the build side keeps every probe row that
+    # finds its key). The default join order places reducing builds first
+    keep_frac: float | None = None
     # base-table provenance (None for subquery sources); lets bind-time
     # checks prove column non-nullability from the catalog's valid bitmaps
     table: str | None = None
@@ -808,9 +812,13 @@ class Binder:
         for i, preds in per_source.items():
             s = sources[i]
             lower = ExprLowerer(s.rel)
+            st = self._source_stats(s)
+            s.keep_frac = 1.0
             for p in preds:
-                s.rel = s.rel.filter(self._lower_with_subqueries(lower, p))
+                e = self._lower_with_subqueries(lower, p)
+                s.rel = s.rel.filter(e)
                 lower = ExprLowerer(s.rel)
+                s.keep_frac *= self._kept_fraction(e, st, p, s)
             s.est_rows = self._estimate_source_rows(s, preds)
 
         # greedy join order: start at the largest source
@@ -1111,6 +1119,18 @@ class Binder:
                 return 1.0 - f if p.negated else f
         return self._DEFAULT_PRED_FRAC
 
+    def _kept_fraction(self, e: ex.Expr, st, p: P.Node, s: "Source") -> float:
+        """Share of a source's rows one pushed-down conjunct keeps. A string
+        predicate arrives as its per-dictionary-entry truth table, whose
+        share of true entries is exact enough and costs nothing; otherwise
+        the histogram estimate, or the unknown-selectivity constant."""
+        if (isinstance(e, ex.CodeLookup) and e.out_type.family is Family.BOOL
+                and np.size(e.table)):
+            return float(np.mean(e.table))
+        if st is not None:
+            return self._pred_fraction(st, p, s)
+        return self._DEFAULT_PRED_FRAC
+
     def _literal_for_stats(self, e: P.Node, col: str, s: "Source"):
         """Literal -> the RAW statistics domain (scaled DECIMALs, day
         counts) for column `col`, or None if not a literal."""
@@ -1179,8 +1199,12 @@ class Binder:
                     colmap[(nxt, p)] = off + p
                 placed.add(nxt)
                 continue
-            # smallest build side first
-            nxt = min(cand, key=lambda i: sizes[i])
+            # a build side that carries a filter drops probe rows, so it
+            # goes before whole tables reached by a foreign key (which only
+            # widen every probe row): strongest estimated reduction first,
+            # then the smallest build side
+            nxt = min(cand, key=lambda i: (
+                self._build_rank(sources[i]), sizes[i]))
             on = cand[nxt]  # (probe joined POSITION, build local POSITION)
             off = len(rel.schema)
             nb = len(sources[nxt].rel.schema)
@@ -1193,6 +1217,12 @@ class Binder:
                 colmap[(nxt, p)] = off + p
             placed.add(nxt)
         return BoundQuery(rel, {i: sources[i] for i in placed}, colmap)
+
+    @staticmethod
+    def _build_rank(s: "Source") -> tuple:
+        """Default-order rank of a candidate build side: reducing builds
+        (0, share kept) before whole tables (1, 0.0)."""
+        return (1, 0.0) if s.keep_frac is None else (0, s.keep_frac)
 
     def _dp_join_order(self, sources, equi_edges, sizes):
         """Selinger-style left-deep DP over the equi-join graph

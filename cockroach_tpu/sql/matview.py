@@ -176,7 +176,7 @@ class Registry:
         try:
             # the class key covers the WHOLE core (rename project
             # included) so a statement's bound plan keys identically
-            pcore, values, types = plancache.parameterize(core)
+            pcore, values, types = plancache.parameterize(core, tables=False)
             key = plancache.plan_key(pcore)
         except Exception as e:
             raise MatviewError(
@@ -458,7 +458,7 @@ def _match_view(reg: Registry, plan):
     if agg is None:
         return None
     try:
-        pplan, values, types = plancache.parameterize(plan)
+        pplan, values, types = plancache.parameterize(plan, tables=False)
         key = plancache.plan_key(pplan)
     except Exception:
         return None

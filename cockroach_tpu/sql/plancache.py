@@ -8,9 +8,11 @@ the expensive phase is not optimization but the build->fuse->XLA-compile
 pipeline, so the cache holds the BUILT operator tree:
 
 - ``parameterize`` rewrites numeric literals in Filter predicates into
-  ``ex.Param`` slots, so a repeat statement with different literals maps
-  to the same structural plan; the values are rebound per execution as
-  jit ARGUMENTS (ops/expr.param_scope), never retraced.
+  ``ex.Param`` slots, and the host-built tables of string predicates
+  (LIKE, IN, =: ``ex.CodeLookup``) into ``ex.ParamLookup`` slots, so a
+  repeat statement with different literals or another pattern maps to the
+  same structural plan; the values are rebound per execution as jit
+  ARGUMENTS (ops/expr.param_scope), never retraced.
 - ``plan_key`` derives a stable structural key from the parameterized
   plan (frozen dataclasses all the way down). Anything it cannot key
   byte-stably (runtime-filled dictionaries, unknown objects) raises
@@ -38,6 +40,7 @@ import enum
 import threading
 from collections import OrderedDict
 
+import jax.numpy as jnp
 import numpy as np
 
 from ..coldata.batch import Dictionary
@@ -48,10 +51,11 @@ from ..plan import spec as S
 from ..utils import metric, settings, tracing
 
 # literal families rewritten into Param slots: everything whose device
-# representation is a plain numeric scalar. STRING stays literal (string
-# predicates lower to host-built CodeLookup tables — content-keyed), BOOL
-# stays literal (structural TRUE/FALSE branches), NULL stays literal (its
-# valid-mask shape differs from any bound value)
+# representation is a plain numeric scalar. A STRING literal never reaches
+# here as a Const: string predicates lower to host-built CodeLookup tables,
+# which ride as table slots (_TableSlot). BOOL stays literal (structural
+# TRUE/FALSE branches), NULL stays literal (its valid-mask shape differs
+# from any bound value)
 _PARAM_FAMILIES = (Family.INT, Family.FLOAT, Family.DECIMAL, Family.DATE,
                    Family.TIMESTAMP, Family.INTERVAL)
 
@@ -59,6 +63,15 @@ _PARAM_FAMILIES = (Family.INT, Family.FLOAT, Family.DECIMAL, Family.DATE,
 class _Unkeyable(Exception):
     """The plan holds an object with no stable structural key; the
     statement runs uncached (conservative — a miss is always correct)."""
+
+
+@dataclasses.dataclass(frozen=True)
+class _TableSlot:
+    """The slot type of a string predicate's lookup table: length (the
+    column's dictionary) and dtype are pinned, the bytes are the value."""
+
+    size: int
+    dtype: str
 
 
 class ParamStore:
@@ -73,6 +86,9 @@ class ParamStore:
     def __init__(self, types):
         self._types = tuple(types)
         self._values: tuple | None = None
+        # lookup tables among the slots (the `query` span's
+        # lookup_tables_bound tag)
+        self.tables = sum(isinstance(t, _TableSlot) for t in self._types)
 
     def set_values(self, values) -> None:
         if len(values) != len(self._types):
@@ -81,6 +97,15 @@ class ParamStore:
                 f"got {len(values)}")
         out = []
         for v, t in zip(values, self._types):
+            if isinstance(t, _TableSlot):
+                a = np.asarray(v)
+                if a.shape != (t.size,) or str(a.dtype) != t.dtype:
+                    raise ValueError(
+                        f"lookup table {a.dtype}{a.shape} bound to a slot "
+                        f"of {t.dtype}({t.size},)")
+                # on the device once a run, not once a kernel call
+                out.append(jnp.asarray(a))
+                continue
             if t.family is Family.DECIMAL:
                 # the same host-side fixed-point scaling Const evaluation
                 # applies (ops/expr.py) — device kernels see scaled ints
@@ -94,14 +119,17 @@ class ParamStore:
         return self._values
 
 
-def parameterize(plan):
-    """Rewrite numeric Filter-predicate literals into Param slots.
+def parameterize(plan, tables: bool = True):
+    """Rewrite numeric Filter-predicate literals into Param slots and
+    string-predicate lookup tables into ParamLookup slots.
 
     Returns ``(pplan, values, types)``: the parameterized plan (shared
     across every statement with the same shape), the extracted literal
     values in slot order, and their SQL types. Runs AFTER index
     selection (plan/indexopt.py), so IndexScan lo/hi bounds stay
-    literal — different index bounds are different plans by design."""
+    literal — different index bounds are different plans by design.
+    ``tables=False`` leaves lookup tables baked and content-keyed (standing
+    views vmap their slots over views: scalars only)."""
     values: list = []
     types: list = []
 
@@ -115,7 +143,17 @@ def parameterize(plan):
                 types.append(e.type)
                 return p
             return e
-        if isinstance(e, ex.CodeLookup) or not isinstance(e, ex.Expr):
+        if isinstance(e, ex.CodeLookup):
+            t = np.asarray(e.table)
+            if not tables or t.ndim != 1:
+                return e
+            slot = _TableSlot(int(t.shape[0]), str(t.dtype))
+            p = ex.ParamLookup(e.col, len(values), slot.size, slot.dtype,
+                               e.out_type)
+            values.append(t)
+            types.append(slot)
+            return p
+        if not isinstance(e, ex.Expr):
             return e
         if isinstance(e, ex.Func2) and e.func == "round2":
             # round2's digit count is read with .value at trace time
@@ -179,9 +217,10 @@ def _key_of(x):
     if isinstance(x, np.ndarray):
         return ("nd", str(x.dtype), x.shape, x.tobytes())
     if isinstance(x, ex.CodeLookup):
-        # eq=False dataclass (identity semantics for jit keys); the plan
-        # key compares the host table's CONTENT so two binds of the same
-        # string predicate share an entry
+        # outside Filter predicates (projections, CASE arms) the table
+        # stays a baked constant: eq=False dataclass (identity semantics
+        # for jit keys); the plan key compares the host table's CONTENT so
+        # two binds of the same string expression share an entry
         t = np.asarray(x.table)
         return ("codelookup", x.col, _key_of(x.out_type), str(t.dtype),
                 t.shape, t.tobytes())
@@ -463,7 +502,8 @@ def _run_entry(entry, values, status: str):
 
     with entry.lock:
         entry.store.set_values(values)
-        with tracing.leaf_span("query", cache=status):
+        with tracing.leaf_span("query", cache=status,
+                               lookup_tables_bound=entry.store.tables):
             return runtime.run_operator(entry.root)
 
 

@@ -62,9 +62,9 @@ _REBIND = {
     "q1": {"delta_days": 60},
     "q3": {"date": "1995-03-01"},
     "q6": {"date": "1995-01-01", "discount": 0.05},
-    "q9": {},             # color is a string (host-prepared table): the
-    "q18": {"quantity": 250},  # q9 serving run is a same-structure rerun
-}
+    "q9": {"color": "red"},  # a string pattern: its host-prepared lookup
+    "q18": {"quantity": 250},  # table rides as a plan argument (PR 28), so a
+}                              # new colour compiles nothing
 
 
 def check(all_queries: bool = False) -> list[str]:

@@ -85,6 +85,31 @@ def test_extract_year():
     # day 10956 = 1999-12-31, day 10957 = 2000-01-01 (7 leap days in 1970-1999)
 
 
+def test_year_in_32_bits_agrees_with_the_calendar_at_its_edges():
+    """_year_from_days computes in int32 (PR 28: the chip's compiler takes
+    24 s for its nine divisions on int64): every year boundary a DATE can
+    hold, a TIMESTAMP's furthest days, and the dtype it hands on."""
+    import datetime
+
+    epoch = datetime.date(1970, 1, 1)
+    days, want = [], []
+    for y in list(range(1, 9999, 37)) + [1, 1900, 1970, 2000, 2100, 9999]:
+        for m, d in ((1, 1), (2, 28), (3, 1), (12, 31)):
+            days.append((datetime.date(y, m, d) - epoch).days)
+            want.append(y)
+    got = ex._year_from_days(np.asarray(days, dtype=np.int32))
+    assert got.dtype == np.int64
+    np.testing.assert_array_equal(np.asarray(got), want)
+    # int64 days of a TIMESTAMP's range (+-2**63 us): 400-year eras repeat
+    far = np.asarray([-106751991, 106751991, -719468, -719469], np.int64)
+    got = np.asarray(ex._year_from_days(far))
+    for d, y in zip(far, got):
+        era_days = 146097
+        k = (int(d) - 0) // era_days
+        base = int(d) - k * era_days  # same calendar position, years +400k
+        assert y == (epoch + datetime.timedelta(days=base)).year + 400 * k
+
+
 def test_division_by_zero_is_null():
     schema = cd.Schema.of(x=cd.INT64, y=cd.INT64)
     b = cd.from_host(
