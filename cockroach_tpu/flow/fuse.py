@@ -81,8 +81,9 @@ class _BarrierSource(Operator):
     composes into one kernel. Pure delegation otherwise."""
 
     # a segment boundary: joins below it never share the jit composed above
-    # it, so chain walks (HashJoinOp.fused_depth) stop counting here
+    # it, so chain walks (HashJoinOp._composes) stop counting here
     _chain_split = True
+    _passes_tiles = True
 
     def __init__(self, inner: Operator):
         super().__init__()
@@ -124,11 +125,13 @@ class FusedPipeline(Operator):
     (the role _consume plays for buffering consumers, for parents that
     pull per-operator)."""
 
+    _passes_tiles = True
+
     def __init__(self, top: Operator, members: list[Operator]):
         super().__init__()
         self.top = top
         self.KERNEL = top.KERNEL  # its program is pipe_<top operator>
-        self.child = top  # chain walks (fused_depth) see through the wrapper
+        self.child = top  # chain walks (_composes) see through the wrapper
         self.members = members
         self.output_schema = top.output_schema
         # shared refs, not copies: runtime-filled dictionaries (string_agg)

@@ -69,6 +69,10 @@ class Operator:
     # name=<KERNEL>_<role>): the class name in lower case without "op"
     # unless a class gives a shorter one. Static — never a per-query value.
     KERNEL = "operator"
+    # a tile comes out at the capacity it went in at, row for row in place
+    # (the operator masks or computes columns, never moves rows): a join
+    # above reads the capacity it will be handed through such links
+    _passes_tiles = False
 
     def __init_subclass__(cls, **kw):
         super().__init_subclass__(**kw)

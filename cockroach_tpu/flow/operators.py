@@ -104,7 +104,6 @@ class _FusedPull:
     def __init__(self, parts, tile_fn, kernel: str):
         src, chain_fn, _ = parts
         self.src = src
-        self.chain = chain_fn
         self._fn = dispatch.jit(
             lambda t, *a: tile_fn(chain_fn(t, *a)), name=f"{kernel}_fused"
         )
@@ -113,6 +112,21 @@ class _FusedPull:
         _, _, args = parts
         for t in self.src.stream_tiles():
             yield self._fn(t, *args)
+
+
+def _per_chain(op, attr: str, cfn, make):
+    """``op``'s cached composition over the chain function ``cfn``, made
+    once a chain. A few are kept, not one: a join below alternates between
+    driving its own kernel and composing its probe into this one as its
+    literals change (HashJoinOp._composes), and every chain it has
+    run must find its traced programs again."""
+    cache = op.__dict__.setdefault(attr, {})
+    got = cache.get(cfn)
+    if got is None:
+        if len(cache) >= 4:
+            cache.pop(next(iter(cache)))
+        got = cache[cfn] = make()
+    return got
 
 
 def _fusion_enabled() -> bool:
@@ -146,11 +160,9 @@ def _consume(op: OneInputOperator, tile_fn_name: str, tile_fn,
                 return
             yield fn(b)
         return
-    attr = f"_fused_{tile_fn_name}"
-    cached = getattr(op, attr, None)
-    if cached is None or cached.chain is not parts[1]:
-        cached = _FusedPull(parts, tile_fn, f"{op.KERNEL}_{tile_fn_name}")
-        setattr(op, attr, cached)
+    cached = _per_chain(
+        op, f"_fused_{tile_fn_name}", parts[1],
+        lambda: _FusedPull(parts, tile_fn, f"{op.KERNEL}_{tile_fn_name}"))
     yield from cached.pull(parts)
 
 
@@ -172,19 +184,14 @@ def _fold(op: OneInputOperator, tag: str, tile_raw, tile_jit, merge_raw,
             st = tile_jit(b)
             acc = st if acc is None else merge_jit(acc, st)
     src, cfn, args = parts
-    attr = f"_fold_{tag}"
-    cached = getattr(op, attr, None)
-    if cached is None or cached[0] is not cfn:
-        nc = len(args)
-        seed = dispatch.jit(lambda t, *a: tile_raw(cfn(t, *a[:nc])),
-                            name=f"{op.KERNEL}_fold_seed")
-        step = dispatch.jit(
+    nc = len(args)
+    seed, step = _per_chain(op, f"_fold_{tag}", cfn, lambda: (
+        dispatch.jit(lambda t, *a: tile_raw(cfn(t, *a[:nc])),
+                     name=f"{op.KERNEL}_fold_seed"),
+        dispatch.jit(
             lambda acc, t, *a: merge_raw(acc, tile_raw(cfn(t, *a[:nc]))),
             donate_argnums=0, name=f"{op.KERNEL}_fold_step",
-        )
-        cached = (cfn, seed, step)
-        setattr(op, attr, cached)
-    _, seed, step = cached
+        )))
     acc = None
     for t in src.stream_tiles():
         acc = seed(t, *args) if acc is None else step(acc, t, *args)
@@ -565,6 +572,8 @@ class FilterOp(OneInputOperator):
     instead of baked constants, so a cached plan rebinds literals with
     zero new traces (the prepared-plan fast path)."""
 
+    _passes_tiles = True
+
     def __init__(self, child: Operator, predicate: ex.Expr, params=None):
         super().__init__(child)
         self.output_schema = child.output_schema
@@ -619,8 +628,7 @@ def _compose_parts(op, child, raw_fn, key=None, extra=()):
     ckey = getattr(child, "_parts_key", None)
     chain_key = (("chain", ckey, key, len(cargs))
                  if ckey is not None and key is not None else None)
-    chain = getattr(op, "_chain_fn", None)
-    if chain is None or getattr(op, "_chain_base", None) is not cfn:
+    def make():
         chain = (_chain_cache.get(chain_key)
                  if chain_key is not None else None)
         if chain is None:
@@ -631,13 +639,16 @@ def _compose_parts(op, child, raw_fn, key=None, extra=()):
 
             if chain_key is not None:
                 chain = _chain_cache.setdefault(chain_key, chain)
-        op._chain_fn = chain
-        op._chain_base = cfn
+        return chain
+
+    chain = _per_chain(op, "_chain_fns", cfn, make)
     op._parts_key = chain_key
-    return src, op._chain_fn, tuple(cargs) + tuple(extra)
+    return src, chain, tuple(cargs) + tuple(extra)
 
 
 class ProjectOp(OneInputOperator):
+    _passes_tiles = True
+
     def __init__(self, child: Operator, exprs: tuple[ex.Expr, ...],
                  names: tuple[str, ...], dict_overrides: tuple = ()):
         super().__init__(child)
@@ -1452,7 +1463,11 @@ class HashJoinOp(OneInputOperator):
         #   compact     output compacts in-kernel to _emit_cap; counts keep
         #               recording so an overflow (count > cap: results
         #               truncated) is detected at query end and the runtime
-        #               re-runs with a corrected cap
+        #               re-runs with a corrected cap. Handed tiles a compact
+        #               join below already cut to no more than _emit_cap,
+        #               there is nothing to compact: for that run the probe
+        #               composes into the consumer as a transparent one
+        #               does (_composes), and the mode and cap stay
         #   transparent dense probes: fully fused into the consumer (no
         #               materialization, no counts)
         from ..utils import settings as _settings
@@ -1742,84 +1757,119 @@ class HashJoinOp(OneInputOperator):
     def children(self):
         return [self.child, self.build]
 
-    def fused_depth(self) -> int:
-        """Join probes sharing ONE composed jit below (and including) this
-        join. The count stops where composition actually splits: at a
-        fusion-pass segment boundary (_chain_split barrier source) and at
-        source-mode joins (learn/compact/general emission), which drive
-        their own kernel — joins below those never enter this jit."""
-        d = 1
-        op = self.child
-        while op is not None:
-            if getattr(op, "_chain_split", False):
-                break
-            if isinstance(op, (HashJoinOp, MergeJoinOp)):
-                if getattr(op, "_emit_mode", "transparent") != "transparent":
-                    break
-                d += 1
-            op = getattr(op, "child", None)
-        return d
+    def _composes(self) -> bool:
+        """Whether this join's probe rides in its consumer's kernel for the
+        run at hand, read from what the chain below is set to do in it.
+        Modes and caps change only between runs (post_run_update), so this
+        is known before the first tile.
 
-    def stream_parts(self):
+        A transparent join composes. So does a compact-mode join whose
+        tiles will already come at a capacity no larger than its own cap,
+        because a compact join below emits them and only position-
+        preserving links (_passes_tiles: they mask, never move rows) or
+        composed probes lie between. Compacting them again shrinks nothing
+        and costs a kernel and a copy of every carried column a tile (q9:
+        four joins re-compacting 131,072 rows into 131,072). A unique-build
+        probe emits at most one row a probe row: nothing can overflow here,
+        and the compact join below keeps counting.
+
+        Either way only while the composed jit stays within
+        sql.distsql.max_fused_joins probes: the compile-size safety valve,
+        so one fused segment never accretes unbounded XLA program size.
+        The count stops where composition actually splits: at a fusion-pass
+        segment boundary (_chain_split barrier source) and at joins that
+        drive their own kernel (learn, general, and compact emission that
+        compacts); joins below those never enter this jit."""
         from ..utils import settings
 
+        def composes(j, fed):
+            return j._emit_mode == "transparent" or (
+                j._emit_mode == "compact" and fed is not None
+                and fed <= j._emit_cap and below < valve)
+
+        valve = settings.get("sql.distsql.max_fused_joins")
+        chain = []
+        op = self.child
+        while op is not None:
+            chain.append(op)
+            op = getattr(op, "child", None)
+        fed = None  # tile capacity a compact join below hands up
+        below = 0  # probes already in the jit this join's would enter
+        for op in reversed(chain):
+            if getattr(op, "_chain_split", False):
+                below = 0
+            elif isinstance(op, HashJoinOp):
+                if composes(op, fed):
+                    below += 1
+                    if not op._fusable:  # a duplicate-key probe moves rows
+                        fed = None
+                else:
+                    below = 0
+                    fed = (op._emit_cap if op._emit_mode == "compact"
+                           else None)
+            elif isinstance(op, MergeJoinOp):
+                below += 1
+                fed = None
+            elif not op._passes_tiles:
+                fed = None
+        return below < valve and composes(self, fed)
+
+    def stream_parts(self):
         if not (self._fusable or self._gen_fusable):
             return None
         if getattr(self, "_grace", None) is not None:
             return None  # spilled: the Grace join drives the probe itself
         if not self._initialized:
             self.init()
-        if self._emit_mode != "transparent":
-            # learn/compact: this join is a tile SOURCE — it drives the
-            # child chain through its own (chain o probe [o compact])
-            # kernel, records a live count per tile (device future, fetched
-            # once per query in post_run_update) and hands downstream
-            # consumers small compacted tiles to compose their kernels on.
-            # Costs one extra async dispatch per tile; saves O(tile x
-            # ncols) per downstream operator when the probe is selective.
-            return self, _identity_fn, ()
-        if self.fused_depth() > settings.get("sql.distsql.max_fused_joins"):
-            # compile-size safety valve: very deep probe pipelines split at
-            # this join (it runs as its own per-operator jit) so one fused
-            # segment never accretes unbounded XLA program size
-            return None
+        # what this join is when its probe does not compose. Transparent:
+        # a barrier (it runs as its own per-operator jit). Learn / compact /
+        # general: a tile SOURCE — it drives the child chain through its
+        # own (chain o probe [o compact]) kernel, records a live count per
+        # tile (device future, fetched once per query in post_run_update)
+        # and hands downstream consumers small compacted tiles to compose
+        # their kernels on. Costs one extra async dispatch per tile; saves
+        # O(tile x ncols) per downstream operator when the probe is
+        # selective.
+        alone = (None if self._emit_mode == "transparent"
+                 else (self, _identity_fn, ()))
+        if not self._composes():
+            return alone
         parts = self.child.stream_parts()
         if parts is None:
-            return None
+            return alone
         self._ensure_built()
         if getattr(self, "_grace", None) is not None:
-            return None  # the build spilled while spooling
+            return alone  # the build spilled while spooling
         src, cfn, cargs = parts
-        chain = getattr(self, "_chain_fn", None)
-        if (chain is None or getattr(self, "_chain_base", None) is not cfn
-                or getattr(self, "_chain_raw", None) is not self._probe_raw):
-            nc = len(cargs)
-            raw = self._probe_raw
-
-            def chain(t, *a):
-                return raw(cfn(t, *a[:nc]), a[nc], a[nc + 1])
-
-            self._chain_fn = chain
-            self._chain_base = cfn
-            self._chain_raw = raw
+        raw = self._probe_raw
+        nc = len(cargs)
+        # one function object a (chain below, probe): the consumer's
+        # composed kernel is cached on its identity
+        chain = _per_chain(
+            self, "_chain_fns", (cfn, raw),
+            lambda: lambda t, *a: raw(cfn(t, *a[:nc]), a[nc], a[nc + 1]))
         counted = getattr(self, "_counted_src", None)
         if counted is None or counted.src is not src:
             counted = self._counted_src = _CountedProbeTiles(src, self)
-        return (counted, self._chain_fn,
-                cargs + (self._build_batch, self._index))
+        return counted, chain, cargs + (self._build_batch, self._index)
 
-    def _note_probe_tile(self, t, src=None) -> None:
+    def _note_probe_tile(self, t, src=None, composed=False) -> None:
         """One probe tile into the pull span's ``join_unique_tiles`` (served
         by a unique-build strategy: analytic, LUT, sorted-unique) or
         ``join_general_tiles`` (by hash_join_general), and its capacity
         into ``join_probe_tile_rows`` (rows the probe pays for, live or
         dead: known on the host, no sync), beside the dispatch tags;
-        tracing.totals() sums them over a window. ``t`` is a Batch, or a
+        tracing.totals() sums them over a window. ``composed``: the probe
+        ran inside the consumer's kernel; for a compact-mode join that is
+        a tile it did not emit and compact itself, counted into
+        ``join_passthrough_tiles`` as well. ``t`` is a Batch, or a
         resident scan's (table batch, offset) token whose tile size
         ``src`` knows."""
         sp = tracing.current()
         if sp is None:
             return
+        if composed and self._emit_mode == "compact":
+            sp.inc_tag("join_passthrough_tiles", 1)
         unique = self._probe_raw is not None and (
             self.spec.build_unique or self._probe_kind != "sorted")
         sp.inc_tag("join_unique_tiles" if unique else "join_general_tiles", 1)
@@ -1918,15 +1968,16 @@ class HashJoinOp(OneInputOperator):
             yield out
 
     def post_run_update(self, truncated: bool = False) -> bool:
-        if not self._emit_counts:
-            return False
-        if truncated and self._emit_mode != "general":
+        if truncated and self._emit_mode in ("learn", "compact"):
             # a join below overflowed: these counts are of tiles it cut
-            # short. Count again at full tiles in the re-run, beside it,
-            # instead of finding the overflow one join an attempt
+            # short (a join that passed its tiles through has none). Count
+            # again at full tiles in the re-run, beside it, instead of
+            # finding the overflow one join an attempt
             self._emit_counts = []
             if self._emit_mode == "compact":
                 self._emit_mode, self._emit_cap = "learn", None
+            return False
+        if not self._emit_counts:
             return False
         # crlint: allow-host-sync(post_run_update: ONE stacked sync per query)
         counts = np.asarray(jax.block_until_ready(
@@ -2027,8 +2078,9 @@ class HashJoinOp(OneInputOperator):
 
 
 class _CountedProbeTiles:
-    """The tile source a transparent join hands its consumer: the probe is
-    fused into the consumer's kernel, so the join sees a tile only here."""
+    """The tile source a transparent or passing-through join hands its
+    consumer: the probe is fused into the consumer's kernel, so the join
+    sees a tile only here."""
 
     def __init__(self, src, join: HashJoinOp):
         self.src = src
@@ -2036,7 +2088,7 @@ class _CountedProbeTiles:
 
     def stream_tiles(self):
         for t in self.src.stream_tiles():
-            self.join._note_probe_tile(t, self.src)
+            self.join._note_probe_tile(t, self.src, composed=True)
             yield t
 
 
@@ -2053,12 +2105,9 @@ def _consume_op(op: Operator, tag: str):
             yield b
         return
     src, cfn, args = parts
-    attr = f"_fused_src_{tag}"
-    cached = getattr(op, attr, None)
-    if cached is None or cached[0] is not cfn:
-        cached = (cfn, dispatch.jit(cfn, name=f"pipe_{op.KERNEL}_{tag}"))
-        setattr(op, attr, cached)
-    fn = cached[1]
+    fn = _per_chain(
+        op, f"_fused_src_{tag}", cfn,
+        lambda: dispatch.jit(cfn, name=f"pipe_{op.KERNEL}_{tag}"))
     for t in src.stream_tiles():
         yield fn(t, *args)
 

@@ -37,15 +37,23 @@ BUDGET_STEADY = 10
 BUDGET_STEADY_Q9 = 24
 BUDGET_STEADY_Q18 = 27
 # q9 as the served SQL text (bench/tpch_sql.py, what the cell tpch_sf1.q9
-# sends). Re-read in PR 28 under the reducing-first default join order
-# (lineitem x part(filtered) x supplier x nation x partsupp x orders):
-# 22 warm at sf 0.001 / 6 lineitem tiles, the same 22 as the parent's
-# order (supplier and nation first) gave — at 1,024-row tiles every join
-# stays transparent (the compaction cap floor equals the tile), so the
-# order moves rows a tile, not dispatches. Two more than the Rel-built q9
-# above (20, unchanged: its order is written by hand, the binder never
-# sees it): its part join is inner with a build spool, not a semi probe.
-BUDGET_STEADY_Q9_SQL = 26
+# sends), under the reducing-first default join order (lineitem x
+# part(filtered) x supplier x nation x partsupp x orders). Re-read in
+# PR 29: 21 warm at sf 0.001 / 6 lineitem tiles (the trees above read 20
+# and 23 as before) — at 1,024-row tiles every join stays transparent
+# (the compaction cap floor equals the tile), so neither the order nor
+# the pass-through of compact joins moves this count. One more than the
+# Rel-built q9 above (its order is written by hand, the binder never sees
+# it): its part join is inner with a build spool, not a semi probe.
+BUDGET_STEADY_Q9_SQL = 25
+# the same text where the joins DO compact (sf 0.01, one lineitem tile at
+# the default tile size): the part join cuts the tile to its cap and
+# emits; the four joins above are handed tiles at their own cap and
+# compose into the aggregate's fold (HashJoinOp._composes). Measured
+# 10 (5 build spools, 1 emit, 1 fold seed, finalize, 2 sort); each upper
+# join that goes back to emitting and compacting for itself adds one a
+# tile (the parent of PR 29 read 14), so the budget has no slack.
+BUDGET_STEADY_Q9_SQL_COMPACT = 10
 # ONE fused pre-aggregation kernel per extra input tile (acceptance
 # criterion of the fusion work; measured exactly 1.0) — the accumulator
 # merge rides inside the fold step kernel. The unfused engine pays 5.
@@ -59,6 +67,8 @@ BUDGET_SPMD = 2
 
 _SF = 0.001
 _TILE = 1024
+_SF_COMPACT = 0.01
+_TILE_COMPACT = 1 << 20  # the setting's default
 
 
 def _steady_dispatches(cat, tile: int, qname: str = "q1",
@@ -163,6 +173,15 @@ def check() -> list[str]:
                 f"exceed the recorded budget {BUDGET_STEADY_Q9_SQL} — a "
                 "join of the served six-table plan stopped fusing into "
                 "the per-tile step")
+        got = _steady_dispatches(gen_tpch(sf=_SF_COMPACT, seed=3),
+                                 _TILE_COMPACT, text=TPCH_SQL["q9"])
+        if got > BUDGET_STEADY_Q9_SQL_COMPACT:
+            problems.append(
+                f"q9 (SQL text, compacting joins) steady-state kernel "
+                f"dispatches {got} exceed the recorded budget "
+                f"{BUDGET_STEADY_Q9_SQL_COMPACT} — a join handed tiles "
+                "already cut to its own cap drives an emit of its own "
+                "again instead of composing into its consumer")
         spmd = _spmd_dispatches()
         if spmd < 1:
             problems.append(
@@ -188,7 +207,8 @@ def main() -> int:
         print("dispatch budget clean: fused pipeline within "
               f"{BUDGET_STEADY} steady / {BUDGET_PER_TILE}-per-tile, "
               f"q9 within {BUDGET_STEADY_Q9} (SQL text "
-              f"{BUDGET_STEADY_Q9_SQL}), q18 within "
+              f"{BUDGET_STEADY_Q9_SQL}, compacting "
+              f"{BUDGET_STEADY_Q9_SQL_COMPACT}), q18 within "
               f"{BUDGET_STEADY_Q18}, distributed plan within "
               f"{BUDGET_SPMD}")
     return 1 if problems else 0

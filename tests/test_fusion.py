@@ -225,3 +225,94 @@ def test_spill_and_skew_forced_tpch_equivalence(hcat, qname):
         settings.reset("sql.distsql.grace_skew_frac")
     assert metric.GRACE_JOIN_SPILLS.value > spills0, "never spilled"
     _assert_identical(got, want)
+
+
+def _emit_state(join, mode, cap):
+    join._emit_mode, join._emit_cap = mode, cap
+    join._emit_cap_seen = cap or join._emit_cap_seen
+
+
+@pytest.mark.parametrize("feeder, passes", [
+    (("compact", 1024), True),   # tiles arrive at the upper join's own cap
+    (("compact", 512), True),    # and under it
+    (("compact", 2048), False),  # over it: compacting shrinks them
+    (("learn", None), False),    # full tiles
+])
+def test_a_join_fed_tiles_at_its_own_cap_composes_into_its_consumer(
+        hcat, feeder, passes):
+    """A unique-build join in compact mode (cap 1,024) above another: fed
+    tiles a compact join already cut to no more than 1,024 rows it drives
+    no `hashjoin_emit` of its own (its probe rides in the aggregate's
+    kernel) and counts `join_passthrough_tiles`; fed wider tiles it
+    compacts as it always did. Same answer either way, and its learned
+    mode and cap stay."""
+    from cockroach_tpu.flow import dispatch, runtime
+    from cockroach_tpu.flow.operators import HashJoinOp
+    from cockroach_tpu.ops import expr as ex
+    from cockroach_tpu.plan import builder as plan_builder
+    from cockroach_tpu.sql.rel import Rel
+    from cockroach_tpu.utils import tracing
+
+    li = Rel.scan(hcat, "lineitem", ("l_orderkey", "l_quantity"))
+    li = li.filter(ex.Cmp("lt", li.c("l_quantity"),
+                          ex.Const(3.0, li.type_of("l_quantity"))))
+    rel = (li.join(Rel.scan(hcat, "orders", ("o_orderkey", "o_custkey")),
+                   on=[("l_orderkey", "o_orderkey")], how="inner")
+           .join(Rel.scan(hcat, "customer", ("c_custkey", "c_nationkey")),
+                 on=[("o_custkey", "c_custkey")], how="inner")
+           .groupby(["c_nationkey"], [("n", "count_rows", None)]))
+    want = _run(rel, fusion=False)
+    settings.set("sql.distsql.fusion.enabled", True)
+    settings.set("sql.distsql.tile_size", 8192)
+    try:
+        root = plan_builder.build(rel.optimized_plan(), hcat)
+        runtime.run_operator(root)
+        upper = root
+        while not isinstance(upper, HashJoinOp):
+            upper = upper.child
+        lower = upper.child
+        while not isinstance(lower, HashJoinOp):
+            lower = lower.child
+        assert upper.spec.build_unique and lower.spec.build_unique
+
+        drove = []
+        real = HashJoinOp.stream_tiles
+
+        def spy(self):
+            drove.append(self)
+            return real(self)
+
+        def run(feeder):
+            """(answer, programs issued, probe tiles a join, those the
+            upper join passed through)"""
+            _emit_state(lower, *feeder)
+            _emit_state(upper, "compact", 1024)
+            del drove[:]
+            with tracing.span("query"):
+                p0 = tracing.totals().get("flow/pull", {"tags": {}})["tags"]
+                d0 = dispatch.total()
+                got = runtime.run_operator(root)
+                d = dispatch.total() - d0
+                p1 = tracing.totals()["flow/pull"]["tags"]
+            return (got, d, *(
+                p1.get(k, 0) - p0.get(k, 0)
+                for k in ("join_unique_tiles", "join_passthrough_tiles")))
+
+        HashJoinOp.stream_tiles = spy
+        try:
+            got, issued, probed, passed = run(feeder)
+            drove_upper = upper in drove
+            # against the same tree with the upper join emitting
+            _got, compacting, _probed, _passed = run(("compact", 2048))
+        finally:
+            HashJoinOp.stream_tiles = real
+    finally:
+        settings.reset("sql.distsql.fusion.enabled")
+        settings.reset("sql.distsql.tile_size")
+    _assert_identical(got, want)
+    tiles = probed // 2  # two joins
+    assert tiles > 1 and lower in drove
+    assert drove_upper is not passes
+    assert passed == (tiles if passes else 0)
+    assert issued == compacting - (tiles if passes else 0)
+    assert upper._emit_mode == "compact" and upper._emit_cap_seen >= 1024

@@ -3,8 +3,9 @@ its COLOR parameter through Session -> parser -> binder -> plan cache ->
 flow, held to the benchmark's float64 pandas reference
 (benchmarks/oracles/tpch_q9.py); a new colour is a plan-cache hit that
 rebinds the pattern's lookup table and compiles nothing; the default join
-order places the filtered `part` join first; the two tags the cell's
-per-layer metrics read."""
+order places the filtered `part` join first; the tags the cell's
+per-layer metrics read; the four joins above `part`, handed tiles already
+cut to their own cap, compose into the aggregate (PR 29)."""
 
 import json
 import os
@@ -108,6 +109,24 @@ def test_a_new_colour_compiles_nothing(sess, host, settled, color):
         assert len(got["nation"]) == 0
 
 
+# a settled q9 at SF0.01, one lineitem tile: the parent (a57b729) issued 14
+# programs a statement, five of them `hashjoin_emit`; the four joins above
+# `part` now ride in the aggregate's fold kernel
+PARENT_SETTLED_DISPATCHES = 14
+
+
+@pytest.mark.parametrize("color", ["orchid", "navy", "linen"])
+def test_settled_q9_composes_the_upper_joins_into_the_aggregate(
+        sess, host, settled, color):
+    t0, d0 = _tags(), dispatch.total()
+    got = sess.execute(Q9.format(color=color))
+    t1, d1 = _tags(), dispatch.total()
+    _assert_answer(got, _reference(host, color))
+    assert t1["passed"] - t0["passed"] == 4  # four joins, one tile each
+    assert t1["unique"] - t0["unique"] == 5
+    assert d1 - d0 == PARENT_SETTLED_DISPATCHES - 4
+
+
 def test_another_columns_dictionary_keys_a_new_plan(cat):
     def key(text):
         pplan, values, types = plancache.parameterize(
@@ -178,6 +197,7 @@ def _tags():
     return {"rows": pull.get("join_probe_tile_rows", 0),
             "unique": pull.get("join_unique_tiles", 0),
             "general": pull.get("join_general_tiles", 0),
+            "passed": pull.get("join_passthrough_tiles", 0),
             "tables": query.get("lookup_tables_bound", 0)}
 
 
@@ -185,22 +205,28 @@ def test_the_tags_the_cells_metrics_read(sess, settled, monkeypatch):
     handed = []
     real = operators.HashJoinOp._note_probe_tile
 
-    def spy(self, t, src=None):
+    def spy(self, t, src=None, composed=False):
         cap = getattr(t, "capacity", None)
         if cap is None:  # a resident scan's (table batch, offset) token
             while not hasattr(src, "_res_tile"):
                 src = src.src
             cap = src._res_tile
-        handed.append(int(cap))
-        return real(self, t, src)
+        handed.append((int(cap), composed))
+        return real(self, t, src, composed)
 
     monkeypatch.setattr(operators.HashJoinOp, "_note_probe_tile", spy)
     t0 = _tags()
     sess.execute(Q9.format(color="plum"))
     t1 = _tags()
     assert len(handed) == 5  # one tile a join at SF0.01
-    assert t1["rows"] - t0["rows"] == sum(handed)
-    assert max(handed) == handed[0] and min(handed) < handed[0]
+    caps = [cap for cap, _composed in handed]
+    assert t1["rows"] - t0["rows"] == sum(caps)
+    # the `part` join emits (full tile in, its cap out); the four above
+    # are handed that cap, which is theirs too, and compose
+    assert [composed for _cap, composed in handed] == [False] + [True] * 4
+    assert caps[0] == max(caps) and set(caps[1:]) == {min(caps)}
+    assert min(caps) < max(caps)
+    assert t1["passed"] - t0["passed"] == 4
     assert t1["unique"] - t0["unique"] == 5
     assert t1["general"] - t0["general"] == 0
     assert t1["tables"] - t0["tables"] == 1
@@ -208,18 +234,24 @@ def test_the_tags_the_cells_metrics_read(sess, settled, monkeypatch):
     t2 = _tags()
     assert t2["tables"] - t1["tables"] == 0
     assert t2["rows"] - t1["rows"] == 0
+    assert t2["passed"] - t1["passed"] == 0
 
 
 def _pull_tags():
     pull = tracing.totals()["flow/pull"]
-    return pull["count"], pull["tags"].get("join_probe_tile_rows", 0)
+    return (pull["count"], pull["tags"].get("join_probe_tile_rows", 0),
+            pull["tags"].get("join_passthrough_tiles", 0))
 
 
-def _run(sess, color):
-    """(answer, attempts, probe-tile rows, programs compiled) of one q9."""
-    (p0, r0), c0 = _pull_tags(), dispatch.compiles()
+def _run(sess, color, passed=None):
+    """(answer, attempts, probe-tile rows, programs compiled) of one q9;
+    ``passed``: the probe tiles its compact-mode joins composed into their
+    consumer must number this."""
+    (p0, r0, t0), c0 = _pull_tags(), dispatch.compiles()
     got = sess.execute(Q9.format(color=color))
-    p1, r1 = _pull_tags()
+    p1, r1, t1 = _pull_tags()
+    if passed is not None:
+        assert t1 - t0 == passed
     return got, p1 - p0, r1 - r0, dispatch.compiles() - c0
 
 
@@ -229,20 +261,25 @@ def test_a_wide_pattern_overflows_to_the_right_answer_and_the_caps_come_back(
     on a colour that keeps a twentieth, so the run overflows and re-runs
     once, every join counting at full tiles, and answers exactly. The plan
     is every colour's: the next narrow colour runs once at full tiles,
-    the one after it on the caps learned before, and none of it compiles."""
+    the one after it on the caps learned before, the four upper joins
+    composed into the aggregate again, and none of it compiles: the
+    aggregate's kernel over the composed chain and its kernel over the
+    joins' own tiles are both kept."""
     cache = plancache.cache_for(sess.catalog)
     entries = len(cache)
-    _got, pulls, steady, compiled = _run(sess, "green")
+    _got, pulls, steady, compiled = _run(sess, "green", passed=4)
     assert (pulls, compiled) == (1, 0)
-    got, pulls, _rows, _compiled = _run(sess, "a")
+    # the first attempt still composes; the re-run counts at every join
+    got, pulls, _rows, compiled = _run(sess, "a", passed=4)
     want = _reference(host, "a")
     assert len(want) > 150
     _assert_answer(got, want)
     assert pulls == 2  # it overflowed, and one re-run was enough
-    got, pulls, full, compiled = _run(sess, "green")
+    assert compiled == 0  # the full-tile programs are the first run's
+    got, pulls, full, compiled = _run(sess, "green", passed=0)
     _assert_answer(got, _reference(host, "green"))
     assert (pulls, compiled) == (1, 0) and full > steady
-    got, pulls, rows, compiled = _run(sess, "red")
+    got, pulls, rows, compiled = _run(sess, "red", passed=4)
     _assert_answer(got, _reference(host, "red"))
     assert (pulls, rows, compiled) == (1, steady, 0)
     assert len(cache) == entries
@@ -261,14 +298,16 @@ def test_an_overflow_below_recounts_every_join_above_in_one_rerun():
     try:
         for _ in range(2):
             s.execute(Q9.format(color="green"))
-        _got, pulls, steady, _c = _run(s, "green")
+        # the four joins above the one that will overflow pass its tiles
+        # through: they hold no count of their own to notice it by
+        _got, pulls, steady, _c = _run(s, "green", passed=2 * 4)
         assert pulls == 1 and steady == 2 * (262144 + 4 * 65536)  # 2 tiles
-        got, pulls, _rows, _c = _run(s, "a")
+        got, pulls, _rows, _c = _run(s, "a", passed=2 * 4)
         assert pulls == 2
         _assert_answer(got, _reference(_Host(cat), "a"))
-        _got, pulls, rows, _c = _run(s, "green")
+        _got, pulls, rows, _c = _run(s, "green", passed=0)
         assert (pulls, rows) == (1, 2 * 5 * 262144)
-        _got, pulls, rows, _c = _run(s, "green")
+        _got, pulls, rows, _c = _run(s, "green", passed=2 * 4)
         assert (pulls, rows) == (1, steady)
     finally:
         s.close()
