@@ -1,30 +1,31 @@
-"""Chip smoke: the served SQL path and the storage plane on one attached TPU.
+"""Chip smoke: what no benchmark cell covers yet, on an attached TPU.
 
-    python chip_smoke.py             # one chip: device, sql, kv
-    python chip_smoke.py --full      # the same at bench.py's full sizes
+    python chip_smoke.py             # one chip: device, kv
+    python chip_smoke.py --full      # the same with YCSB-E at 1M keys
     python chip_smoke.py --chips 4   # four chips: shuffle only (SF0.05)
 
-The quickest proof that the system still starts on the chip. It drives the
-main path once through the entry points a user calls, checks every answer
-against an independent reference, and exits non-zero on the first phase that
-fails. Data is generated from --seed inside the run. One process holds the
-chip: everything runs here, and no child that needs JAX is started.
+The storage plane (YCSB-E and acknowledged SQL writes read back) and the
+four-chip shuffle, each driven once through the entry points a user calls,
+checked against an independent reference; the script exits non-zero on the
+first phase that fails. The served SQL path is the benchmark's: `python3
+benchmarks/run.py --workload tpch_sf1.q1 --seed 1 --seconds 5` is the quick
+proof that it starts on the chip. Data is generated from --seed inside the
+run. One process holds the chip: everything runs here, and no child that
+needs JAX is started.
 
 Sizes. A run must finish inside 1200 s on an empty compile cache, and on
 this engine a cold run is almost all compile (PERF.md, PR 22: one MVCC
-`lax.sort` instantiation takes minutes above 4,096 rows; q3's first two
-runs at SF1 take 810 s). So the default run keeps TPC-H at SF1 — q1 three
-times, q3 once, q1 again over pgwire — and the 1,000 acknowledged SQL
-writes, and cuts two things: q3's second and third run, and YCSB-E's
-keyspace (4,096 keys, where its sorts compile in seconds; its batched scans
-still go through the Pallas scan filter on the chip, but nothing that small
-is merged, so the merge gate is reached only by `--full`). `--full` restores
-q3 three times and YCSB-E at bench.py's 1M keys: 2,364 s on an empty cache
-when PR 22 ran it. The four-chip shuffle runs q3 at SF0.05 for the same
-reason, four times over (a four-chip call is charged fourfold): its one SPMD
-program holds 18 sorts and did not finish compiling for a described v5e 2x2
-at SF1 in 263 CPU-minutes, against 243 s on 8 cores at SF0.05. `--full
---chips 4` asks for SF1.
+`lax.sort` instantiation takes minutes above 4,096 rows). So the default
+run keeps the 1,000 acknowledged SQL writes and cuts YCSB-E's keyspace
+(4,096 keys, where its sorts compile in seconds; its batched scans still go
+through the Pallas scan filter on the chip, but nothing that small is
+merged, so the merge gate is reached only by `--full`, at 1M keys: 1,351 s
+on an empty cache when PR 22 ran it, 1,278 s of it compile). The
+four-chip shuffle runs q3 at SF0.05 for the same reason, four times over (a
+four-chip call is charged fourfold): its one SPMD program holds 18 sorts
+and did not finish compiling for a described v5e 2x2 at SF1 in 263
+CPU-minutes, against 243 s on 8 cores at SF0.05. `--full --chips 4` asks
+for SF1.
 
 There is no CPU mode. Without a TPU the script prints why and exits
 non-zero before any phase runs; tests/test_chip_smoke.py rehearses the phase
@@ -40,8 +41,6 @@ from __future__ import annotations
 import argparse
 import json
 import shutil
-import socket
-import struct
 import sys
 import tempfile
 import time
@@ -99,187 +98,13 @@ def phase_device() -> dict:
 
 
 # ---------------------------------------------------------------------------
-# sql
-
-
-class _SimpleQueryClient:
-    """Just enough of pgwire v3 for one simple query with text results."""
-
-    def __init__(self, addr):
-        self.sock = socket.create_connection(addr, timeout=600)
-        body = struct.pack("!I", 196608) + b"user\x00smoke\x00\x00"
-        self.sock.sendall(struct.pack("!I", len(body) + 4) + body)
-        self._until_ready()
-
-    def _recv(self, n: int) -> bytes:
-        buf = bytearray()
-        while len(buf) < n:
-            c = self.sock.recv(n - len(buf))
-            if not c:
-                raise ConnectionError("pgwire server closed the connection")
-            buf.extend(c)
-        return bytes(buf)
-
-    def _until_ready(self) -> list[tuple[bytes, bytes]]:
-        msgs = []
-        while True:
-            tag = self._recv(1)
-            body = self._recv(struct.unpack("!I", self._recv(4))[0] - 4)
-            msgs.append((tag, body))
-            if tag == b"Z":
-                return msgs
-
-    def query(self, sql: str) -> tuple[list[str], list[list[str | None]]]:
-        body = sql.encode() + b"\x00"
-        self.sock.sendall(b"Q" + struct.pack("!I", len(body) + 4) + body)
-        names: list[str] = []
-        rows: list[list[str | None]] = []
-        for tag, body in self._until_ready():
-            if tag == b"E":
-                raise RuntimeError(
-                    f"pgwire error: {body.decode(errors='replace')}")
-            if tag not in (b"T", b"D"):
-                continue
-            off = 2
-            row: list[str | None] = []
-            for _ in range(struct.unpack("!H", body[:2])[0]):
-                if tag == b"T":
-                    end = body.index(b"\x00", off)
-                    names.append(body[off:end].decode())
-                    off = end + 1 + 18
-                    continue
-                ln = struct.unpack("!i", body[off:off + 4])[0]
-                off += 4
-                row.append(None if ln == -1
-                           else body[off:off + ln].decode())
-                off += max(ln, 0)
-            if tag == b"D":
-                rows.append(row)
-        return names, rows
-
-    def close(self) -> None:
-        self.sock.sendall(b"X" + struct.pack("!I", 4))
-        self.sock.close()
-
-
-def _operators(root) -> list[str]:
-    """Class names of the operator tree, fusion wrappers looked through."""
-    from cockroach_tpu.flow.fuse import unwrap
-
-    names, stack = [], [root]
-    while stack:
-        op = unwrap(stack.pop())
-        names.append(type(op).__name__)
-        stack.extend(op.children())
-    return sorted(set(names))
-
-
-# second runs of a join query may re-specialize once: join emission caps are
-# learned from the first run (scripts/check_recompiles.py holds that to the
-# same budget). From the run after, a statement compiles nothing.
-_ADAPT_BUDGET = 16
-
-
-def phase_sql(sf: float = 1.0, seed: int = 19920101,
-              queries: tuple[str, ...] = ("q1", "q3"),
-              once: tuple[str, ...] = ()) -> dict:
-    """TPC-H at `sf` served as SQL text by an in-process Node: Session
-    (parse, bind, plan cache, admission, flow, device), then q1 again over
-    the node's pgwire listener. Every answer is held to bench.py's pandas
-    oracle. A query runs three times unless it is named in `once`."""
-    import bench  # the repo-root driver holds the pandas oracles
-    from cockroach_tpu.bench import tpch
-    from cockroach_tpu.bench.tpch_sql import TPCH_SQL
-    from cockroach_tpu.flow import dispatch
-    from cockroach_tpu.ops import segscan
-    from cockroach_tpu.plan import builder as plan_builder
-    from cockroach_tpu.server.node import Node
-    from cockroach_tpu.sql import Session, sql
-
-    t0 = time.time()
-    cat = tpch.gen_tpch(sf=sf, seed=seed)
-    node = Node().start(pg_port=0)
-    try:
-        # the way cli.py's --demo-tpch loads them: generated tables adopted
-        # by the serving catalog; scans cache them on the device
-        sess = Session(catalog=node._sql_catalog, db=node.db,
-                       bootstrap=False)
-        for name, table in cat.tables.items():
-            sess.catalog.tables[name] = table
-        nrows = sess.catalog.get("lineitem").num_rows
-        emit(phase="sql", step="load", sf=sf, seed=seed,
-             lineitem_rows=nrows, seconds=round(time.time() - t0, 2))
-
-        out: dict = {"lineitem_rows": nrows, "queries": {}}
-        results = {}
-        for q in queries:
-            runs = []
-            for run in (("first",) if q in once
-                        else ("first", "second", "third")):
-                c0, d0 = dispatch.compiles(), dispatch.total()
-                t0 = time.time()
-                res = sess.execute(TPCH_SQL[q])
-                runs.append({
-                    "run": run, "seconds": round(time.time() - t0, 3),
-                    "compiles": dispatch.compiles() - c0,
-                    "dispatches": dispatch.total() - d0})
-                emit(phase="sql", query=q, **runs[-1])
-            pandas_s = bench._pandas_baseline(q, sess.catalog, res)
-            if q not in once:
-                check(runs[1]["compiles"] <= _ADAPT_BUDGET, (q, runs))
-                check(runs[2]["compiles"] == 0, (
-                    f"{q}: a repeated statement compiled again", runs))
-            root = plan_builder.build(
-                sql(sess.catalog, TPCH_SQL[q]).optimized_plan(),
-                sess.catalog)
-            ops = _operators(root)
-            emit(phase="sql", query=q, oracle="pandas", equal=True,
-                 pandas_seconds=round(pandas_s, 3), operators=ops,
-                 segment_strategy=("scan" if segscan.use_scans()
-                                   else "scatter"))
-            results[q] = res
-            out["queries"][q] = {"runs": runs, "operators": ops}
-
-        # q1 once more, through the wire
-        q = queries[0]
-        client = _SimpleQueryClient(node.pg.addr)
-        try:
-            c0, t0 = dispatch.compiles(), time.time()
-            names, rows = client.query(TPCH_SQL[q])
-            wire_s, wire_c = time.time() - t0, dispatch.compiles() - c0
-        finally:
-            client.close()
-        want = results[q]
-        check(names == list(want), (names, list(want)))
-        check(len(rows) == len(want[names[0]]))
-        for j, name in enumerate(names):
-            col = np.asarray(want[name])
-            got = [r[j] for r in rows]
-            if col.dtype.kind in "fiu":
-                np.testing.assert_allclose(
-                    np.array(got, dtype=np.float64),
-                    col.astype(np.float64), rtol=1e-12, err_msg=name)
-            else:
-                check(got == [str(v) for v in col], name)
-        emit(phase="sql", query=q, via="pgwire", rows=len(rows),
-             equal_to_session=True, seconds=round(wire_s, 3),
-             compiles=wire_c)
-        out["pgwire"] = {"rows": len(rows), "compiles": wire_c}
-        sess.close()
-    finally:
-        node.stop()
-    return out
-
-
-# ---------------------------------------------------------------------------
 # kv
 
 
 def phase_kv(n_keys: int = 1 << 20, ops: int = 512, concurrency: int = 128,
              n_rows: int = 1000) -> dict:
-    """(a) YCSB-E over a WAL-backed 16-byte-key engine (the defaults are
-    the sizes bench.py uses); (b) acknowledged SQL writes read back through
-    a Session."""
+    """(a) YCSB-E over a WAL-backed 16-byte-key engine; (b) acknowledged
+    SQL writes read back through a Session."""
     from cockroach_tpu.bench.ycsb import run_ycsb_e
     from cockroach_tpu.kv import DB, Clock
     from cockroach_tpu.sql import Session
@@ -368,13 +193,32 @@ def phase_kv(n_keys: int = 1 << 20, ops: int = 512, concurrency: int = 128,
 # shuffle (four chips)
 
 
+def _pandas_q3(cat):
+    """The repo's one pandas Q3 (benchmarks/oracles/tpch_q3.py, which is no
+    package: loaded by path) over the generated catalog."""
+    import importlib.util
+    import pathlib
+    import types
+
+    from cockroach_tpu.bench import tpch
+
+    spec = importlib.util.spec_from_file_location(
+        "tpch_q3_oracle", pathlib.Path(__file__).resolve().parent
+        / "benchmarks" / "oracles" / "tpch_q3.py")
+    oracle = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(oracle)
+    loaded = types.SimpleNamespace(
+        frame=lambda table, columns: tpch.to_pandas(cat, table)[columns])
+    return oracle.answer(loaded, {"date": "1995-03-15"})
+
+
 def phase_shuffle(sf: float = 1.0, seed: int = 19920101, n_devices: int = 4
                   ) -> dict:
     """q3 through Rel.run_distributed on a mesh over `n_devices` devices,
     against the same plan on one device and the pandas oracle."""
     import jax
 
-    import bench
+    # the hand-built plan: no Session reaches the mesh yet (ROADMAP D5)
     from cockroach_tpu.bench import queries as Q
     from cockroach_tpu.bench import tpch
     from cockroach_tpu.parallel import mesh as mesh_mod
@@ -417,7 +261,9 @@ def phase_shuffle(sf: float = 1.0, seed: int = 19920101, n_devices: int = 4
     t0 = time.time()
     got = rel.run_distributed(mesh)
     dist_s = time.time() - t0
-    bench._pandas_baseline("q3", cat, got)
+    np.testing.assert_allclose(
+        np.asarray(got["revenue"], dtype=np.float64),
+        _pandas_q3(cat).revenue.to_numpy(), rtol=1e-9)
     emit(phase="shuffle", step="distributed", rows=len(got["revenue"]),
          equals_pandas=True, seconds=round(dist_s, 2))
     # the same plan on one device, last: on an empty cache it compiles
@@ -447,9 +293,9 @@ def main(argv: list[str] | None = None) -> int:
                     help="4 runs the distributed shuffle phase only")
     ap.add_argument("--seed", type=int, default=19920101)
     ap.add_argument("--full", action="store_true",
-                    help="q3 three times and YCSB-E at 1M keys (bench.py's "
-                         "sizes, about 40 minutes on an empty compile "
-                         "cache); with --chips 4, the shuffle at SF1")
+                    help="YCSB-E at 1M keys (over 20 minutes of sort compiles "
+                         "on an empty compile cache); with --chips 4, the "
+                         "shuffle at SF1")
     args = ap.parse_args(argv)
 
     import cockroach_tpu  # noqa: F401  # crlint: allow-unused-import(side-effect import: package init enables jax x64, and a bare copy of this script must fail here)
@@ -469,11 +315,9 @@ def main(argv: list[str] | None = None) -> int:
         phases = [("shuffle", lambda: phase_shuffle(
             sf=1.0 if args.full else 0.05, seed=args.seed))]
     elif args.full:
-        phases = [("sql", lambda: phase_sql(seed=args.seed)),
-                  ("kv", phase_kv)]
+        phases = [("kv", phase_kv)]
     else:
-        phases = [("sql", lambda: phase_sql(seed=args.seed, once=("q3",))),
-                  ("kv", lambda: phase_kv(n_keys=4096, ops=64,
+        phases = [("kv", lambda: phase_kv(n_keys=4096, ops=64,
                                           concurrency=8))]
     for name, fn in [("device", phase_device)] + phases:
         t0 = time.time()

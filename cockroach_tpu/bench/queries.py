@@ -1,6 +1,11 @@
-"""TPC-H queries as relational plans (reference: pkg/workload/tpch/queries.go
-holds the SQL text; here each query is built against sql.rel.Rel). Each
-builder returns a Rel; oracles live in tests (pandas over the same catalog).
+"""The 22 TPC-H queries as hand-built relational plans (sql.rel.Rel), kept
+as a test oracle: tests/test_sql.py::test_tpch_sql_matches_handbuilt holds
+the binder's plan for every served text (tpch_sql.py) to these answers, and
+tests/test_tpch_queries.py runs them against pandas. No user sends these
+plans (Rel.join's defaults are not the binder's), so nothing that counts
+or times runs them: the gates and the benchmark send the SQL text through
+a Session. Until a Session reaches the mesh (ROADMAP D5), chip_smoke.py's
+shuffle phase and __graft_entry__.py's dry run also take q3 from here.
 """
 
 from __future__ import annotations

@@ -1,8 +1,10 @@
 """The 22 TPC-H queries as SQL text, in the dialect sql/parser.py accepts.
 
-One copy, next to the generator (tpch.py): tests/test_sql.py checks every
-query against the hand-built plans of queries.py, and chip_smoke.py serves
-q1 and q3 from here through a Session and pgwire.
+One copy, next to the generator (tpch.py): the benchmark's cells send
+these texts (benchmarks/traffic/), scripts/check_dispatch_budget.py and
+scripts/check_recompiles.py count what a Session does with them, and
+tests/test_sql.py checks every query against the hand-built plans of
+queries.py.
 """
 
 TPCH_SQL = {

@@ -1484,8 +1484,7 @@ class HashJoinOp(OneInputOperator):
             and _settings.get("sql.distsql.fusion.general_probe")
         )
         self._emit_mode = (
-            "learn" if (self._fusable and _settings.get(
-                "sql.distsql.join_compact_emit"))
+            "learn" if self._fusable
             else ("general" if self._gen_fusable else "transparent")
         )
         self._emit_cap = None

@@ -28,7 +28,7 @@ def compile_cache_dir() -> tuple[str, bool]:
 def enable_compile_cache() -> str:
     """Turn on JAX's persistent compilation cache at ``compile_cache_dir()``
     and return the directory. The one place the program decides this:
-    Session, Node, cli.py, bench.py workers and chip_smoke.py all call it.
+    Session, Node, cli.py and chip_smoke.py all call it.
     With the variable set nothing is written to ``jax_compilation_cache_dir``
     in code — JAX already holds the environment's value."""
     import jax

@@ -372,12 +372,6 @@ DENSE_AGG = register_bool(
     "(falls back to the general sort-groupby path when off)",
     metamorphic=True,
 )
-JOIN_COMPACT_EMIT = register_bool(
-    "sql.distsql.join_compact_emit", True,
-    "adaptively compact selective join probe output in-kernel (learned "
-    "sticky capacity, overflow-checked once per query)",
-    metamorphic=True,
-)
 FUSION_GENERAL_PROBE = register_bool(
     "sql.distsql.fusion.general_probe", True,
     "fuse duplicate-key inner/left join probes as speculative streaming "

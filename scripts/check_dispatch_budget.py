@@ -1,15 +1,18 @@
 """Kernel-dispatch budget regression guard (tier-1, same spirit as
 check_settings_registered.py).
 
-Runs ONE representative fused query — TPC-H q1, a scan -> filter ->
-project -> group-by chain — at two tile sizes and checks two budgets
-against flow/dispatch.py's per-call accounting:
+Every case takes the path a user's statement takes: one ``Session`` over a
+generated TPC-H catalog executes the served SQL text (bench/tpch_sql.py,
+what the benchmark's cells send) until it has settled, and the case reads
+the ``flow/dispatch.total()`` delta around the next ``execute`` — the
+counter the benchmark's ``flow.dispatches_per_stmt`` divides.
 
-- **steady total**: warm (post-adaptive-learning) dispatches for the whole
-  query must stay at or under BUDGET_STEADY. A fusion regression (a chain
-  member silently falling back to its own per-operator jit) roughly
-  doubles this.
-- **per tile**: halving the tile size doubles the input tile count; the
+- **steady total**: warm (post-adaptive-learning) dispatches of one
+  statement must stay at or under its recorded budget. A fusion regression
+  (a chain member silently falling back to its own per-operator jit)
+  roughly doubles q1's; a join that stops fusing into the per-tile step
+  adds one a tile.
+- **per tile**: halving the tile size doubles q1's input tile count; the
   dispatch increase per extra tile must stay at or under BUDGET_PER_TILE
   (the fused pipeline pays exactly ONE pre-aggregation dispatch per tile).
 
@@ -24,40 +27,52 @@ from __future__ import annotations
 import os
 import sys
 
-# measured 8 with the fusion pass on (6 input tiles): 6 fused
-# slice+filter+project+group+merge dispatches + finalize + sort. The
-# unfused engine measures 31.
-BUDGET_STEADY = 10
-# the join-plane queries, same harness: q9's part|supplier|orders chain
-# probes its build tables inside the fused per-tile step kernel (measured
-# 20 warm at sf 0.001 / 6 lineitem tiles; an unfused chain pays one
-# dispatch + readback per join per tile and blows well past this), and
-# q18's ORDER BY ... LIMIT runs as a folded device top-k instead of a
-# full sort spool (measured 23).
-BUDGET_STEADY_Q9 = 24
-BUDGET_STEADY_Q18 = 27
-# q9 as the served SQL text (bench/tpch_sql.py, what the cell tpch_sf1.q9
-# sends), under the reducing-first default join order (lineitem x
-# part(filtered) x supplier x nation x partsupp x orders). Re-read in
-# PR 29: 21 warm at sf 0.001 / 6 lineitem tiles (the trees above read 20
-# and 23 as before) — at 1,024-row tiles every join stays transparent
-# (the compaction cap floor equals the tile), so neither the order nor
-# the pass-through of compact joins moves this count. One more than the
-# Rel-built q9 above (its order is written by hand, the binder never sees
-# it): its part join is inner with a build spool, not a semi probe.
-BUDGET_STEADY_Q9_SQL = 25
-# the same text where the joins DO compact (sf 0.01, one lineitem tile at
-# the default tile size): the part join cuts the tile to its cap and
-# emits; the four joins above are handed tiles at their own cap and
-# compose into the aggregate's fold (HashJoinOp._composes). Measured
-# 10 (5 build spools, 1 emit, 1 fold seed, finalize, 2 sort); each upper
-# join that goes back to emitting and compacting for itself adds one a
-# tile (the parent of PR 29 read 14), so the budget has no slack.
-BUDGET_STEADY_Q9_SQL_COMPACT = 10
+_SF = 0.001
+_TILE = 1024
+_SF_COMPACT = 0.01
+_TILE_COMPACT = 1 << 20  # the setting's default
+# learn, settle: the second execution re-specializes the kernels whose
+# capacities the first one learned, the third runs the settled plan (the
+# warm-up the cells do before their window opens)
+_SETTLE = 3
+
+# one budget a served text, warm at sf 0.001 / 1,024-row tiles (6 lineitem
+# tiles; every join stays transparent there: the compaction cap's floor
+# equals the tile). Each budget IS the CPU's reading, taken in PR 30
+# through Session.execute: the counts do not vary, so none has slack.
+BUDGETS = {
+    # 6 fused slice+filter+project+group+merge dispatches (fold seed + 5
+    # fold steps) + finalize + 2 sort (the ORDER BY's spool with the
+    # select list's projection fused in, then the sort). The unfused
+    # engine reads 31.
+    "q1": 9,
+    # 3 pipe_filter_build_spool, 6 groupagg fold (seed + 5 steps, both
+    # join probes inside), finalize, topk_fold_seed + limit_tile
+    "q3": 12,
+    # 6 build spools under the binder's reducing-first order (lineitem x
+    # part(filtered) x supplier x nation x partsupp x orders), 6
+    # pipe_hashjoin (all probes in one fused step a tile), 6 groupagg
+    # fold, finalize, 2 sort; an unfused chain pays one dispatch +
+    # readback per join per tile and blows well past this
+    "q9": 21,
+    # 4 build spools + hashjoin_lut, the IN (SELECT ... HAVING) grouping's
+    # 6 fold + finalize, the outer aggregate's 6 hashagg_partial_fused +
+    # merge + finalize, and ORDER BY ... LIMIT as a folded device top-k
+    # (topk_fold_seed + limit_tile) instead of a full sort spool
+    "q18": 22,
+}
+# q9 where the joins DO compact (sf 0.01, one lineitem tile at the default
+# tile size): the part join cuts the tile to its cap and emits; the four
+# joins above are handed tiles at their own cap and compose into the
+# aggregate's fold (HashJoinOp._composes). Read 10 (5 build spools, 1
+# emit, 1 fold seed, finalize, 2 sort); each upper join that goes back to
+# emitting and compacting for itself adds one a tile (the parent of PR 29
+# read 14), so the budget has no slack.
+BUDGET_Q9_COMPACT = 10
 # ONE fused pre-aggregation kernel per extra input tile (acceptance
-# criterion of the fusion work; measured exactly 1.0) — the accumulator
-# merge rides inside the fold step kernel. The unfused engine pays 5.
-BUDGET_PER_TILE = 1.25
+# criterion of the fusion work; read exactly 1.0) — the accumulator merge
+# rides inside the fold step kernel. The unfused engine pays 5.
+BUDGET_PER_TILE = 1.0
 # a distributed plan (partial agg -> all_to_all shuffle -> merge agg ->
 # finalize over the 8-way mesh) is ONE SPMD program = ONE dispatch; the
 # lower bound of 1 proves parallel/* kernels route through dispatch.jit
@@ -65,38 +80,47 @@ BUDGET_PER_TILE = 1.25
 # to this accounting).
 BUDGET_SPMD = 2
 
-_SF = 0.001
-_TILE = 1024
-_SF_COMPACT = 0.01
-_TILE_COMPACT = 1 << 20  # the setting's default
+CASES = (*BUDGETS, "q1_per_tile", "q9_compact", "spmd")
 
 
-def _steady_dispatches(cat, tile: int, qname: str = "q1",
-                       text: str | None = None) -> int:
-    from cockroach_tpu.bench import queries as Q
+def catalog(sf: float = _SF):
+    """The generated catalog every case of one scale factor shares."""
+    os.environ.setdefault("JAX_PLATFORMS", "cpu")
+    from cockroach_tpu.bench.tpch import gen_tpch
+
+    return gen_tpch(sf=sf, seed=3)
+
+
+def _steady_dispatches(cat, tile: int, qname: str) -> int:
+    """Dispatches of one settled execution of TPCH_SQL[qname] through a
+    Session (parser -> binder -> plan cache -> admission -> flow)."""
+    from cockroach_tpu.bench.tpch_sql import TPCH_SQL
     from cockroach_tpu.flow import dispatch
-    from cockroach_tpu.flow.runtime import run_operator
-    from cockroach_tpu.plan import builder as plan_builder
+    from cockroach_tpu.sql import Session
     from cockroach_tpu.utils import settings
 
     settings.set("sql.distsql.tile_size", tile)
-    if text is None:
-        rel = Q.QUERIES[qname](cat)
-    else:
-        from cockroach_tpu.sql import sql
-
-        rel = sql(cat, text)
-    root = plan_builder.build(rel.optimized_plan(), cat)
-    run_operator(root)  # warm: compile + adaptive capacity learning
-    d0 = dispatch.total()
-    run_operator(root)
-    return dispatch.total() - d0
+    sess = Session(cat)
+    try:
+        for _ in range(_SETTLE):
+            sess.execute(TPCH_SQL[qname])
+        d0 = dispatch.total()
+        sess.execute(TPCH_SQL[qname])
+        return dispatch.total() - d0
+    finally:
+        sess.close()
+        settings.reset("sql.distsql.tile_size")
 
 
 def _spmd_dispatches() -> int:
     """Warm dispatches for one distributed groupby over an 8-way mesh."""
+    import jax
     import numpy as np
 
+    if len(jax.devices()) < 8:  # standalone run: conftest hasn't forced
+        from cockroach_tpu.utils.backend import force_cpu_backend
+
+        force_cpu_backend(8)
     from cockroach_tpu import coldata as cd
     from cockroach_tpu.flow import dispatch
     from cockroach_tpu.ops import aggregation as agg
@@ -123,80 +147,56 @@ def _spmd_dispatches() -> int:
     return dispatch.total() - d0
 
 
-def check() -> list[str]:
-    """Returns a list of human-readable violations (empty = clean)."""
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
-    from cockroach_tpu.bench.tpch import gen_tpch
-    from cockroach_tpu.utils import settings
-
-    import jax
-
-    if len(jax.devices()) < 8:  # standalone run: conftest hasn't forced
-        from cockroach_tpu.utils.backend import force_cpu_backend
-
-        force_cpu_backend(8)  # the SPMD case needs the full virtual mesh
-    problems = []
-    try:
-        settings.set("sql.distsql.fusion.enabled", True)
-        cat = gen_tpch(sf=_SF, seed=3)
-        tiles = -(-cat.get("lineitem").num_rows // _TILE)
-        steady = _steady_dispatches(cat, _TILE)
-        if steady > BUDGET_STEADY:
-            problems.append(
-                f"q1 steady-state kernel dispatches {steady} exceed the "
-                f"recorded budget {BUDGET_STEADY} ({tiles} input tiles) — "
-                "a pipeline member stopped fusing or a new per-tile "
-                "dispatch crept into the pull loop")
-        halved = _steady_dispatches(cat, _TILE // 2)
-        per_tile = (halved - steady) / tiles
-        if per_tile > BUDGET_PER_TILE:
-            problems.append(
-                f"marginal dispatches per extra input tile {per_tile:.2f} "
-                f"({steady} -> {halved} when tiles double from {tiles}) "
-                f"exceed the budget {BUDGET_PER_TILE} — the per-tile "
-                "chain is no longer one fused kernel")
-        for qname, budget in (("q9", BUDGET_STEADY_Q9),
-                              ("q18", BUDGET_STEADY_Q18)):
-            got = _steady_dispatches(cat, _TILE, qname)
-            if got > budget:
-                problems.append(
-                    f"{qname} steady-state kernel dispatches {got} exceed "
-                    f"the recorded budget {budget} — the multiway fused "
-                    "probe (q9) or device top-k fold (q18) stopped "
-                    "covering the join plane's per-tile work")
-        from cockroach_tpu.bench.tpch_sql import TPCH_SQL
-
-        got = _steady_dispatches(cat, _TILE, text=TPCH_SQL["q9"])
-        if got > BUDGET_STEADY_Q9_SQL:
-            problems.append(
-                f"q9 (SQL text) steady-state kernel dispatches {got} "
-                f"exceed the recorded budget {BUDGET_STEADY_Q9_SQL} — a "
-                "join of the served six-table plan stopped fusing into "
-                "the per-tile step")
-        got = _steady_dispatches(gen_tpch(sf=_SF_COMPACT, seed=3),
-                                 _TILE_COMPACT, text=TPCH_SQL["q9"])
-        if got > BUDGET_STEADY_Q9_SQL_COMPACT:
-            problems.append(
-                f"q9 (SQL text, compacting joins) steady-state kernel "
-                f"dispatches {got} exceed the recorded budget "
-                f"{BUDGET_STEADY_Q9_SQL_COMPACT} — a join handed tiles "
-                "already cut to its own cap drives an emit of its own "
-                "again instead of composing into its consumer")
+def case(name: str, cat=None) -> list[str]:
+    """One entry of CASES; returns its violations (empty = clean). ``cat``
+    is the sf-0.001 catalog when the caller already holds one."""
+    if name == "spmd":
         spmd = _spmd_dispatches()
         if spmd < 1:
-            problems.append(
-                "distributed groupby registered 0 kernel dispatches — the "
-                "SPMD plan no longer routes through flow/dispatch.jit and "
-                "is invisible to dispatch accounting")
-        elif spmd > BUDGET_SPMD:
-            problems.append(
-                f"distributed groupby dispatches {spmd} exceed the budget "
-                f"{BUDGET_SPMD} — the partial-agg/shuffle/merge pipeline "
-                "is no longer one SPMD program")
-    finally:
-        settings.reset("sql.distsql.tile_size")
-        settings.reset("sql.distsql.fusion.enabled")
-    return problems
+            return ["distributed groupby registered 0 kernel dispatches — "
+                    "the SPMD plan no longer routes through "
+                    "flow/dispatch.jit and is invisible to dispatch "
+                    "accounting"]
+        if spmd > BUDGET_SPMD:
+            return [f"distributed groupby dispatches {spmd} exceed the "
+                    f"budget {BUDGET_SPMD} — the partial-agg/shuffle/merge "
+                    "pipeline is no longer one SPMD program"]
+        return []
+    if name == "q9_compact":
+        got = _steady_dispatches(catalog(_SF_COMPACT), _TILE_COMPACT, "q9")
+        if got > BUDGET_Q9_COMPACT:
+            return [f"q9 (compacting joins) steady-state kernel dispatches "
+                    f"{got} exceed the recorded budget {BUDGET_Q9_COMPACT} "
+                    "— a join handed tiles already cut to its own cap "
+                    "drives an emit of its own again instead of composing "
+                    "into its consumer"]
+        return []
+    cat = cat if cat is not None else catalog()
+    if name == "q1_per_tile":
+        tiles = -(-cat.get("lineitem").num_rows // _TILE)
+        steady = _steady_dispatches(cat, _TILE, "q1")
+        halved = _steady_dispatches(cat, _TILE // 2, "q1")
+        per_tile = (halved - steady) / tiles
+        if per_tile > BUDGET_PER_TILE:
+            return [f"marginal dispatches per extra input tile "
+                    f"{per_tile:.2f} ({steady} -> {halved} when tiles "
+                    f"double from {tiles}) exceed the budget "
+                    f"{BUDGET_PER_TILE} — the per-tile chain is no longer "
+                    "one fused kernel"]
+        return []
+    got = _steady_dispatches(cat, _TILE, name)
+    if got > BUDGETS[name]:
+        return [f"{name} steady-state kernel dispatches {got} exceed the "
+                f"recorded budget {BUDGETS[name]} — a pipeline member or a "
+                "join probe stopped fusing into the per-tile step, or a "
+                "new per-tile dispatch crept into the pull loop"]
+    return []
+
+
+def check() -> list[str]:
+    """Every case; returns the human-readable violations (empty = clean)."""
+    cat = catalog()
+    return [p for name in CASES for p in case(name, cat)]
 
 
 def main() -> int:
@@ -204,12 +204,10 @@ def main() -> int:
     for p in problems:
         print(f"FAIL: {p}", file=sys.stderr)
     if not problems:
-        print("dispatch budget clean: fused pipeline within "
-              f"{BUDGET_STEADY} steady / {BUDGET_PER_TILE}-per-tile, "
-              f"q9 within {BUDGET_STEADY_Q9} (SQL text "
-              f"{BUDGET_STEADY_Q9_SQL}, compacting "
-              f"{BUDGET_STEADY_Q9_SQL_COMPACT}), q18 within "
-              f"{BUDGET_STEADY_Q18}, distributed plan within "
+        print("dispatch budget clean: "
+              + ", ".join(f"{q} within {b}" for q, b in BUDGETS.items())
+              + f", q9 compacting within {BUDGET_Q9_COMPACT}, "
+              f"{BUDGET_PER_TILE} a tile, distributed plan within "
               f"{BUDGET_SPMD}")
     return 1 if problems else 0
 

@@ -5,9 +5,8 @@ broad-except, unused-import, tracing-api, lock-order, shared-state,
 mem-accounting, fault-coverage, untimed-wait, recompile-hazard,
 race-coverage, unknown-pragma) over the package, the
 scripts/ directory, the tests/ tree, and the repo-root entry points
-(bench.py, __graft_entry__.py, chip_smoke.py) and fails on any unsuppressed
-finding. This is the
-nogo/roachvet analog: the lint rules are only worth having if the tree
+(__graft_entry__.py, chip_smoke.py) and fails on any unsuppressed finding.
+This is the nogo/roachvet analog: the lint rules are only worth having if the tree
 is kept at zero findings, so the gate rides in tier-1 next to the
 settings and dispatch-budget audits. Pure AST pass — nothing is
 imported, so it runs without pulling in jax.
@@ -42,7 +41,7 @@ def check(repo_root: str | pathlib.Path | None = None,
     paths = [root / "cockroach_tpu", root / "scripts", root / "tests"]
     # repo-root entry points ride along when present (fixture trees in
     # the lint tests call check() on trimmed copies without them)
-    for entry in ("bench.py", "__graft_entry__.py", "chip_smoke.py"):
+    for entry in ("__graft_entry__.py", "chip_smoke.py"):
         if (root / entry).is_file():
             paths.append(root / entry)
     return [f.render() for f in run_lint(paths, timings=timings)]

@@ -1,30 +1,29 @@
 """Zero-recompile serving-path guard (tier-1, sibling of
 check_dispatch_budget.py).
 
-Drives representative TPC-H queries through the prepared-plan cache
-(sql/plancache.py) against flow/dispatch.py's compile accounting and
-checks three properties:
+Sends the served TPC-H SQL text (bench/tpch_sql.py, what the benchmark's
+cells send) through one ``Session`` — parser, binder, plan cache,
+admission, flow — against flow/dispatch.py's compile accounting and checks
+three properties a query:
 
-- **cold budget**: the FIRST execution of each query compiles at most a
-  recorded number of distinct kernels. Canonical tile shapes
-  (catalog.SHAPE_BUCKETS) and the keyed kernel cache keep this small; a
-  regression here is a shape or key leak (e.g. a per-table capacity
-  sneaking back into kernel shapes).
-- **bounded adaptation**: the SECOND execution (plan-cache hit, same
-  literals) may re-specialize a handful of kernels once — join emission
-  caps learn from run 1 (operators.post_run_update) — but within a small
-  recorded budget. The background warmup thread runs each statement
-  twice for exactly this reason.
-- **zero-recompile serving**: the THIRD execution — same statement
-  shape, DIFFERENT literals — must trigger 0 new XLA traces (the
-  plan-cache hit rebinds literals as jit arguments, and learned
-  capacities snap to the canonical shape ladder) and report a plan-cache
-  hit. Its wall time is printed (the <100ms warm-serving target on real
-  accelerators); only the compile count is asserted — CI machine speed
-  varies.
+- **cold budget**: the FIRST execution compiles at most a recorded number
+  of distinct kernels. Canonical tile shapes (catalog.SHAPE_BUCKETS) and
+  the keyed kernel cache keep this small; a regression here is a shape or
+  key leak (e.g. a per-table capacity sneaking back into kernel shapes).
+- **bounded adaptation**: the SECOND execution (the same text) may
+  re-specialize a handful of kernels once — join emission caps learn from
+  run 1 (operators.post_run_update) — but within a small recorded budget.
+- **zero-recompile serving**: the THIRD execution — the text with another
+  value of one of the query's substitution parameters (TPC-H clause 2.4),
+  changed in the text the way a cell's traffic changes it — must hit the
+  plan cache and trigger 0 new XLA traces (the hit rebinds literals and
+  host-prepared lookup tables as jit arguments, and learned capacities
+  snap to the canonical shape ladder). Its wall time is printed; only the
+  compile count is asserted — CI machine speed varies.
 
-Tier-1 runs the representative subset; ``--all`` sweeps every TPC-H
-query. Runnable directly:
+Tier-1 runs all 22 texts, one pytest case a query; the CLI runs the
+representative subset (BUDGETS) and ``--all`` sweeps the 22. Runnable
+directly:
 
     python -m scripts.check_recompiles [--all]
 """
@@ -36,97 +35,167 @@ import sys
 import time
 
 _SF = 0.001
+_TILE = 1024
 
-# cold-compile budgets per query (distinct kernel specializations on a
-# fresh process, fusion on, tile 1024, measured then padded ~50%): the
-# fused pipeline + spool/consumer kernels + finalize/sort. Queries run in
-# this order, so later queries already share earlier kernels (the
-# process-global kernel cache) — budgets encode that sharing too.
+# cold-compile budgets per query: distinct kernel specializations of the
+# first execution with no kernel shared from another query (each case
+# starts from an empty kernel cache, so the count does not depend on what
+# ran before), tile 1024; the CPU's reading in PR 30 beside each
 BUDGETS = {
-    "q1": 8,    # measured 4
-    "q3": 18,   # measured 12
-    "q6": 4,    # measured 2
-    "q9": 21,   # measured 14
-    "q18": 24,  # measured 16
+    "q1": 5,
+    "q3": 9,
+    "q6": 3,
+    "q9": 15,
+    "q18": 15,
 }
 # every query not listed above (the --all sweep) gets this generic cap
 BUDGET_DEFAULT = 45
 # run-2 adaptation: post_run_update switches join emission to compact
-# mode at a learned cap, re-specializing once (measured ≤5 on the tier-1
-# subset, ≤11 across the full sweep — q7's join tree)
-BUDGET_ADAPT = 16
+# mode at a learned cap, re-specializing once (read at most 4 across the
+# 22 texts — q20's join tree)
+BUDGET_ADAPT = 4
 
-# literal overrides for the serving run: same statement shape, different
-# values — the case the zero-recompile path exists for
+# the serving run's text: (old, new) substitutions of one or two of the
+# query's substitution parameters, by the clause that defines them
 _REBIND = {
-    "q1": {"delta_days": 60},
-    "q3": {"date": "1995-03-01"},
-    "q6": {"date": "1995-01-01", "discount": 0.05},
-    "q9": {"color": "red"},  # a string pattern: its host-prepared lookup
-    "q18": {"quantity": 250},  # table rides as a plan argument (PR 28), so a
-}                              # new colour compiles nothing
+    "q1": (("- 90", "- 60"),),                                   # DELTA
+    "q2": (("p_size = 15", "p_size = 23"),),                     # SIZE
+    "q3": (("1995-03-15", "1995-03-01"),),                       # DATE
+    "q4": (("1993-07-01", "1994-01-01"),),                       # DATE
+    "q5": (("1994-01-01", "1995-01-01"),),                       # DATE
+    "q6": (("1994-01-01", "1995-01-01"),                         # DATE
+           ("between 0.05 and 0.07", "between 0.04 and 0.06")),  # DISCOUNT
+    "q7": (("FRANCE", "CANADA"),),                               # NATION1
+    "q8": (("BRAZIL", "PERU"),),                                 # NATION
+    "q9": (("green", "red"),),                                   # COLOR
+    "q10": (("1993-10-01", "1994-01-01"),),                      # DATE
+    "q11": (("0.0001", "0.0002"),),                              # FRACTION
+    "q12": (("1994-01-01", "1995-01-01"),),                      # DATE
+    "q13": (("special", "pending"),),                            # WORD1
+    "q14": (("1995-09-01", "1996-03-01"),                        # DATE
+            ("1995-10-01", "1996-04-01")),
+    "q15": (("1996-01-01", "1996-04-01"),),                      # DATE
+    "q16": (("(49, 14, 23, 45, 19, 3, 36, 9)",                   # SIZE1-8
+             "(48, 15, 22, 44, 18, 4, 35, 8)"),),
+    "q17": (("MED BOX", "LG CASE"),),                            # CONTAINER
+    "q18": (("> 300", "> 250"),),                                # QUANTITY
+    "q19": (("l_quantity >= 1 and l_quantity <= 11",             # QUANTITY1
+             "l_quantity >= 2 and l_quantity <= 12"),),
+    "q20": (("forest", "azure"),),                               # COLOR
+    "q21": (("SAUDI ARABIA", "JAPAN"),),                         # NATION
+    "q22": (("'13', '31', '23', '29', '30', '18', '17'",         # I1-7
+             "'14', '32', '24', '28', '31', '19', '16'"),),
+}
+
+# texts that miss the serving guarantee on this route, with PR 30's
+# reading (ROADMAP Queue 1 has each): the case is EXPECTED to fail — a
+# strict xfail in tests/test_recompiles.py, "known" in the CLI — and
+# fails the gate on the day it passes, so the entry goes with the fix.
+# The gate before PR 30 reran these with the literal unchanged.
+KNOWN = {
+    "q8": "NATION is a string in a CASE arm: plan-cache miss, all 19 "
+          "kernels compile again for every nation",
+    "q11": "hit, but a new FRACTION compiles 6 kernels once (a learned "
+           "capacity moves with the HAVING threshold)",
+    "q15": "hit, but a new DATE compiles 5 kernels once (the revenue "
+           "view's cardinality moves a learned capacity)",
+    "q22": "hit, but new country codes compile 2 kernels once",
+}
 
 
-def check(all_queries: bool = False) -> list[str]:
-    """Returns a list of human-readable violations (empty = clean)."""
+def rebound(name: str) -> str:
+    """TPCH_SQL[name] with the query's parameter changed in the text."""
+    from cockroach_tpu.bench.tpch_sql import TPCH_SQL
+
+    text = TPCH_SQL[name]
+    for old, new in _REBIND[name]:
+        if old not in text:
+            raise KeyError(f"{name}: {old!r} is not in the served text")
+        text = text.replace(old, new)
+    return text
+
+
+def open_session():
+    """A Session over the generated catalog all cases share."""
     os.environ.setdefault("JAX_PLATFORMS", "cpu")
-    from cockroach_tpu.bench import queries as Q
     from cockroach_tpu.bench.tpch import gen_tpch
+    from cockroach_tpu.sql import Session
+
+    return Session(gen_tpch(sf=_SF, seed=3))
+
+
+def case(sess, name: str) -> list[str]:
+    """Cold, adaptation and serving run of one query through ``sess``;
+    returns its violations (empty = clean)."""
+    from cockroach_tpu.bench.tpch_sql import TPCH_SQL
     from cockroach_tpu.flow import dispatch
     from cockroach_tpu.sql import plancache
     from cockroach_tpu.utils import settings
 
     problems: list[str] = []
-    names = list(Q.QUERIES) if all_queries else list(BUDGETS)
+    cache = plancache.cache_for(sess.catalog)
+    settings.set("sql.distsql.tile_size", _TILE)
     try:
-        settings.set("sql.distsql.fusion.enabled", True)
-        settings.set("sql.distsql.shape_buckets.enabled", True)
-        settings.set("sql.distsql.tile_size", 1024)
-        settings.set("sql.plan_cache.enabled", True)
-        cat = gen_tpch(sf=_SF, seed=3)
-        for name in names:
-            c0 = dispatch.compiles()
-            _, status = plancache.run_cached(Q.QUERIES[name](cat))
-            cold = dispatch.compiles() - c0
-            budget = BUDGETS.get(name, BUDGET_DEFAULT)
-            if cold > budget:
-                problems.append(
-                    f"{name}: cold run compiled {cold} kernels, budget "
-                    f"{budget} — a kernel-cache key or canonical-shape "
-                    "regression is minting per-query specializations")
-            c1 = dispatch.compiles()
-            plancache.run_cached(Q.QUERIES[name](cat))
-            adapt = dispatch.compiles() - c1
-            if adapt > BUDGET_ADAPT:
-                problems.append(
-                    f"{name}: adaptation run re-specialized {adapt} "
-                    f"kernels, budget {BUDGET_ADAPT} — learned capacities "
-                    "are not converging in one run")
-            kwargs = _REBIND.get(name, {})
-            c2 = dispatch.compiles()
-            t0 = time.perf_counter()
-            _, status2 = plancache.run_cached(Q.QUERIES[name](cat, **kwargs))
-            warm_ms = (time.perf_counter() - t0) * 1e3
-            recompiles = dispatch.compiles() - c2
-            if status2 != "hit":
-                problems.append(
-                    f"{name}: serving run reported plan-cache status "
-                    f"{status2!r}, expected 'hit' — the statement no "
-                    "longer parameterizes to a stable plan key")
-            if recompiles:
-                problems.append(
-                    f"{name}: serving run with rebound literals "
-                    f"{kwargs or '(none)'} triggered {recompiles} new XLA "
-                    "compiles, expected 0 — the zero-recompile serving "
-                    "path is broken")
-            print(f"  {name}: cold {cold}/{budget} compiles, adapt "
-                  f"{adapt}/{BUDGET_ADAPT}, serve {recompiles} compiles "
-                  f"{warm_ms:.1f}ms [{status}->{status2}]")
+        dispatch.clear_kernel_cache()
+        c0 = dispatch.compiles()
+        sess.execute(TPCH_SQL[name])
+        cold = dispatch.compiles() - c0
+        budget = BUDGETS.get(name, BUDGET_DEFAULT)
+        if cold > budget:
+            problems.append(
+                f"{name}: cold run compiled {cold} kernels, budget "
+                f"{budget} — a kernel-cache key or canonical-shape "
+                "regression is minting per-query specializations")
+        c1 = dispatch.compiles()
+        sess.execute(TPCH_SQL[name])
+        adapt = dispatch.compiles() - c1
+        if adapt > BUDGET_ADAPT:
+            problems.append(
+                f"{name}: adaptation run re-specialized {adapt} "
+                f"kernels, budget {BUDGET_ADAPT} — learned capacities "
+                "are not converging in one run")
+        text = rebound(name)
+        c2, h0 = dispatch.compiles(), cache.hits
+        t0 = time.perf_counter()
+        sess.execute(text)
+        warm_ms = (time.perf_counter() - t0) * 1e3
+        recompiles = dispatch.compiles() - c2
+        hit = cache.hits - h0
+        if hit != 1:
+            problems.append(
+                f"{name}: serving run with {_REBIND[name]} hit the plan "
+                f"cache {hit} times, expected 1 — the statement no "
+                "longer parameterizes to a stable plan key")
+        if recompiles:
+            problems.append(
+                f"{name}: serving run with {_REBIND[name]} triggered "
+                f"{recompiles} new XLA compiles, expected 0 — the "
+                "zero-recompile serving path is broken")
+        print(f"  {name}: cold {cold}/{budget} compiles, adapt "
+              f"{adapt}/{BUDGET_ADAPT}, serve {recompiles} compiles "
+              f"{warm_ms:.1f}ms [{'hit' if hit == 1 else 'miss'}]")
     finally:
-        settings.reset("sql.distsql.fusion.enabled")
-        settings.reset("sql.distsql.shape_buckets.enabled")
         settings.reset("sql.distsql.tile_size")
-        settings.reset("sql.plan_cache.enabled")
+    return problems
+
+
+def check(all_queries: bool = False) -> list[str]:
+    """Returns a list of human-readable violations (empty = clean)."""
+    problems: list[str] = []
+    sess = open_session()
+    try:
+        for name in (_REBIND if all_queries else BUDGETS):
+            found = case(sess, name)
+            if name not in KNOWN:
+                problems += found
+            elif found:
+                print(f"  {name}: known ({KNOWN[name]})")
+            else:
+                problems.append(
+                    f"{name}: keeps the serving guarantee now — take it "
+                    "out of KNOWN")
+    finally:
+        sess.close()
     return problems
 
 
@@ -136,9 +205,10 @@ def main() -> int:
     for p in problems:
         print(f"FAIL: {p}", file=sys.stderr)
     if not problems:
-        n = len(BUDGETS) if not all_queries else "all TPC-H"
-        print(f"recompile guard clean ({n} queries): warmed repeats run "
-              "with zero new XLA compiles within per-query cold budgets")
+        n = len(_REBIND if all_queries else BUDGETS)
+        print(f"recompile guard clean ({n} queries): a served text with "
+              "another parameter runs with zero new XLA compiles, within "
+              "per-query cold budgets")
     return 1 if problems else 0
 
 

@@ -3,8 +3,9 @@
 Tests run on a virtual 8-device CPU mesh (the reference's analog is `fakedist`
 — pkg/sql/physicalplan/fake_span_resolver.go — which fakes multi-node
 distribution inside one process). The chip is reached only through
-chip_smoke.py and bench.py; tests/test_tpu_compile.py asks the chip's compiler
-about a described (not attached) v5e from inside its own fixture.
+benchmarks/run.py and chip_smoke.py; tests/test_tpu_compile.py asks the
+chip's compiler about a described (not attached) v5e from inside its own
+fixture.
 """
 
 from cockroach_tpu.utils.backend import force_cpu_backend

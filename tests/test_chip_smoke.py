@@ -5,9 +5,6 @@ functions, so the control flow, the oracles and the four-device sharding
 rules are checked here, where a fault costs no chip time."""
 
 import json
-import pathlib
-import subprocess
-import sys
 
 import jax
 
@@ -26,33 +23,6 @@ def test_main_refuses_to_run_without_a_tpu(capsys):
     cap = capsys.readouterr()
     assert "no TPU" in cap.err and "no CPU mode" in cap.err
     assert '"ok"' not in cap.out and cap.out.strip() == ""
-
-
-def test_bench_refuses_to_run_without_a_tpu():
-    """No chip is an error for bench.py too: non-zero exit, no result line,
-    nothing run on the CPU (the first worker stops the ladder)."""
-    root = pathlib.Path(__file__).resolve().parent.parent
-    out = subprocess.run([sys.executable, str(root / "bench.py")], cwd=root,
-                         capture_output=True, text=True, timeout=300)
-    assert out.returncode != 0
-    assert "no TPU" in out.stderr and out.stderr.count("jax found only") == 2
-    assert out.stdout.strip() == ""
-
-
-def test_sql_phase_tiny(capsys):
-    out = chip_smoke.phase_sql(sf=0.005, queries=("q1", "q3", "q6"),
-                               once=("q6",))
-    for q in ("q1", "q3"):
-        runs = out["queries"][q]["runs"]
-        assert [r["run"] for r in runs] == ["first", "second", "third"]
-        assert runs[0]["dispatches"] > 0 and runs[2]["compiles"] == 0
-    assert out["queries"]["q1"]["runs"][1]["compiles"] == 0
-    assert [r["run"] for r in out["queries"]["q6"]["runs"]] == ["first"]
-    assert out["pgwire"] == {"rows": 4, "compiles": 0}
-    lines = _lines(capsys)
-    assert any(ln.get("via") == "pgwire" and ln["equal_to_session"]
-               for ln in lines)
-    assert sum(ln.get("oracle") == "pandas" for ln in lines) == 3
 
 
 def test_kv_phase_tiny(capsys):

@@ -68,9 +68,8 @@ def test_one_function_decides(monkeypatch, env_dir):
 
 
 def test_every_entry_point_goes_through_it(monkeypatch, capsys):
-    """Session, Node, a bench.py worker and chip_smoke.py all call the one
-    function, and none of them names a directory of its own."""
-    import bench
+    """Session, Node and chip_smoke.py all call the one function, and none
+    of them names a directory of its own."""
     import chip_smoke
     from cockroach_tpu.server.node import Node
     from cockroach_tpu.sql import Session, plancache
@@ -89,13 +88,10 @@ def test_every_entry_point_goes_through_it(monkeypatch, capsys):
     node = Node().start(gossip_port=None)
     node.stop()
     assert len(calls) == 2
-    with pytest.raises(SystemExit) as exc:  # no TPU here: the worker refuses
-        bench._worker("q1")
-    assert exc.value.code == bench._NO_CHIP_RC and len(calls) == 3
     chip_smoke.phase_device()
-    assert len(calls) == 4
+    assert len(calls) == 3
     capsys.readouterr()
-    for path in ("bench.py", "chip_smoke.py", "cockroach_tpu/cli.py",
+    for path in ("chip_smoke.py", "cockroach_tpu/cli.py",
                  "cockroach_tpu/sql/plancache.py",
                  "cockroach_tpu/sql/session.py",
                  "cockroach_tpu/server/node.py"):

@@ -360,6 +360,12 @@ def test_vtable_and_status_endpoint_snapshot():
     from cockroach_tpu.server.http import AdminServer
     from cockroach_tpu.sql import crdb_internal
 
+    # the vtable spans every live hub of the process, and hubs sit in a
+    # WeakSet until collected: one that an earlier test of this worker
+    # left unclosed (seen under six workers in PR 30: a second hub with a
+    # subscriber 1 of its own) would be counted below
+    for leaked in fanout.hubs():
+        leaked.close()
     db = _db()
     db.txn(lambda t: t.put(b"s1", b"v1"))
     srv = RangefeedServer(db, poll_interval_s=0.02)

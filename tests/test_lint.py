@@ -79,7 +79,7 @@ def test_host_sync_scoped_to_hot_modules(tmp_path):
     # is not a finding
     src = "import jax.numpy as jnp\ndef f(x):\n    return int(jnp.sum(x))\n"
     root = _tree(tmp_path, {
-        "cockroach_tpu/bench/baseline.py": src,
+        "cockroach_tpu/bench/tpcds.py": src,
         "cockroach_tpu/flow/wire.py": src,
     })
     assert not run_lint([root], rules=("host-sync",))
@@ -893,8 +893,8 @@ def test_real_tree_new_passes_are_clean_individually():
     """Each PR-20 pass holds zero findings at HEAD on its own (the tree
     gate runs them all; this pins the per-rule contract)."""
     found = run_lint(
-        ["cockroach_tpu", "scripts", "tests", "bench.py",
-         "__graft_entry__.py", "chip_smoke.py"],
+        ["cockroach_tpu", "scripts", "tests", "__graft_entry__.py",
+         "chip_smoke.py"],
         rules=("untimed-wait", "recompile-hazard", "race-coverage"))
     assert not found, [f.render() for f in found]
 

@@ -274,19 +274,3 @@ def test_sql_slot_is_reentrant_per_thread():
         assert admission._SQL_QUEUE.in_use == 0
     finally:
         admission._SQL_QUEUE = saved
-
-
-# ------------------------------------------------------- mixed-load harness
-
-@pytest.mark.slow
-def test_mixed_load_harness_smoke():
-    """bench/load.py end-to-end at toy scale: the BENCH JSON fields exist,
-    ops completed, and the run leaves the memory plane drained."""
-    from cockroach_tpu.bench.load import run_mixed_load
-
-    r = run_mixed_load(sessions=2, duration_s=1.5, sf=0.005, n_keys=64)
-    assert r["ops"] > 0 and r["ops_per_sec"] > 0
-    assert r["errors"] == 0, r["last_error"]
-    assert r["peak_hbm_bytes"] > 0
-    assert r["p99_queue_wait_ms"] >= 0.0
-    assert r["admission_waits"] >= r["ops"]  # every admit observes the wait

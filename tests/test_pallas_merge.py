@@ -1,5 +1,6 @@
 """Pallas bitonic-merge parity vs the concat+sort path (interpret mode on
-CPU; the real-chip win is the compaction phase of bench.py's YCSB run)."""
+CPU; on the chip it is reached by the compactions of chip_smoke.py --full's
+YCSB-E run)."""
 
 import numpy as np
 import pytest

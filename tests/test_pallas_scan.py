@@ -1,5 +1,5 @@
 """Pallas MVCC scan-filter parity vs the jnp filter (interpret mode on
-CPU; the real-chip run happens in bench.py's YCSB phase on TPU)."""
+CPU; the real-chip run happens in chip_smoke.py's kv phase)."""
 
 import numpy as np
 import pytest

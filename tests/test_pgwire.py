@@ -167,6 +167,44 @@ def test_pgwire_through_node_lifecycle():
         node.stop()
 
 
+def test_pgwire_tpch_q1_equals_session():
+    """The served TPC-H q1 text over the node's pgwire listener returns the
+    Session's answer, column for column, and compiles nothing the Session's
+    run had not (the wire is the plan cache's second reader)."""
+    import numpy as np
+
+    from cockroach_tpu.bench.tpch import gen_tpch
+    from cockroach_tpu.bench.tpch_sql import TPCH_SQL
+    from cockroach_tpu.flow import dispatch
+    from cockroach_tpu.server.node import Node
+
+    node = Node().start(gossip_port=None, pg_port=0)
+    try:
+        # the way cli.py's --demo-tpch loads them: generated tables adopted
+        # by the serving catalog
+        sess = Session(catalog=node._sql_catalog, db=node.db,
+                       bootstrap=False)
+        sess.catalog.tables.update(gen_tpch(sf=0.005, seed=3).tables)
+        want = sess.execute(TPCH_SQL["q1"])
+        c = MiniPg(node.pg.addr)
+        c0 = dispatch.compiles()
+        rows, names, _, err = c.query(TPCH_SQL["q1"])
+        assert err is None and dispatch.compiles() == c0
+        c.close()
+        sess.close()
+    finally:
+        node.stop()
+    assert names == list(want) and len(rows) == len(want[names[0]]) == 4
+    for j, name in enumerate(names):
+        col, got = np.asarray(want[name]), [r[j] for r in rows]
+        if col.dtype.kind in "fiu":
+            np.testing.assert_allclose(np.array(got, dtype=np.float64),
+                                       col.astype(np.float64), rtol=1e-12,
+                                       err_msg=name)
+        else:
+            assert got == [str(v) for v in col], name
+
+
 class MiniPgExt(MiniPg):
     """Extended-protocol messages (Parse/Bind/Describe/Execute/Sync)."""
 
