@@ -246,39 +246,52 @@ def to_host(
     return out
 
 
+def live_index(mask: jax.Array, capacity: int):
+    """(idx, n): the positions of ``mask``'s set rows, in order, as the
+    first ``n`` of min(len(mask), capacity) slots (the rest hold len(mask),
+    which `take_rows` fills), and the TRUE number set, which may exceed the
+    slots. The one index every compaction gathers through."""
+    cap_in = mask.shape[0]
+    (idx,) = jnp.nonzero(mask, size=min(cap_in, capacity), fill_value=cap_in)
+    return idx, jnp.sum(mask, dtype=jnp.int32)
+
+
+def pad_rows(x: jax.Array, capacity: int) -> jax.Array:
+    """``x`` with zero rows appended up to ``capacity`` leading rows."""
+    pad = capacity - x.shape[0]
+    if pad <= 0:
+        return x
+    return jnp.concatenate([x, jnp.zeros((pad,) + x.shape[1:], x.dtype)])
+
+
+def take_rows(col: Column, idx: jax.Array, capacity: int) -> Column:
+    """``col``'s rows at ``idx`` (a `live_index`), then zero / invalid rows
+    up to ``capacity``; a slot past the live count is zero and invalid."""
+    data = jnp.take(col.data, idx, axis=0, mode="fill", fill_value=0)
+    valid = jnp.take(col.valid, idx, mode="fill", fill_value=False)
+    return Column(data=pad_rows(data, capacity),
+                  valid=pad_rows(valid, capacity))
+
+
 @functools.partial(jax.jit, static_argnames=("capacity",))  # crlint: allow-raw-jit(shared helper: call sites count via dispatch.note)
 def compact(batch: Batch, capacity: int | None = None) -> Batch:
     """Pack live rows to the front of a (possibly smaller) tile.
 
     The reference compacts via selection vectors; here each column GATHERS
-    its live rows through one shared nonzero index — O(cap_in) once for the
-    index plus O(cap_out) per column, so compacting a sparse 1M-row tile to
-    1k costs index-scan + a few tiny gathers, not a full-width scatter per
-    column (the prior design, measured as the dominant cost of selective
-    spool merges)."""
+    its live rows through one shared nonzero index (`live_index`, moved by
+    `take_rows`) — O(cap_in) once for the index plus O(cap_out) per column,
+    so compacting a sparse 1M-row tile to 1k costs index-scan + a few tiny
+    gathers, not a full-width scatter per column (the prior design, measured
+    as the dominant cost of selective spool merges). Every column of
+    ``batch`` is already materialised at cap_in when this runs: a join's
+    emission that knows its output mask before it has gathered the build
+    side compacts FIRST, through the same two helpers
+    (ops/join.py `emit_unique_compact`)."""
     cap_out = capacity or batch.capacity
-    cap_in = batch.capacity
-    mask = batch.mask
-    n = jnp.sum(mask, dtype=jnp.int32)
-    size = min(cap_in, cap_out)
-    (idx,) = jnp.nonzero(mask, size=size, fill_value=cap_in)
-
-    def move(col: Column) -> Column:
-        data = jnp.take(col.data, idx, axis=0, mode="fill", fill_value=0)
-        valid = jnp.take(col.valid, idx, mode="fill", fill_value=False)
-        if cap_out > size:
-            pad = cap_out - size
-            if data.ndim == 2:
-                data = jnp.concatenate(
-                    [data, jnp.zeros((pad, data.shape[1]), data.dtype)]
-                )
-            else:
-                data = jnp.concatenate([data, jnp.zeros((pad,), data.dtype)])
-            valid = jnp.concatenate([valid, jnp.zeros((pad,), jnp.bool_)])
-        return Column(data=data, valid=valid)
-
+    idx, n = live_index(batch.mask, cap_out)
     new_mask = jnp.arange(cap_out, dtype=jnp.int32) < n
-    return Batch(cols=tuple(move(c) for c in batch.cols), mask=new_mask)
+    return Batch(cols=tuple(take_rows(c, idx, cap_out) for c in batch.cols),
+                 mask=new_mask)
 
 
 def concat(batches: list[Batch], capacity: int) -> Batch:

@@ -1589,7 +1589,7 @@ class HashJoinOp(OneInputOperator):
             return big, join_ops.build_dense_lut(big, bkeys, layout, eremaps)
 
         self._lut_fn = lut_fn
-        self._probe_raw = None
+        self._probe_raw = self._probe_pair = None
         if not self._fusable:
             pschema = self.child.output_schema
             pkeys = self.probe_keys
@@ -1631,26 +1631,34 @@ class HashJoinOp(OneInputOperator):
         layout = self.exact_layout
         spec = self.spec
 
+        # the probe alone, (found_idx, found): what an emission needs to
+        # cut the tile before it gathers a build column
         if kind == "analytic":
             info = self._analytic
 
-            def probe_raw(p, build, index):
-                fi, fo = join_ops.dense_analytic_probe(
+            def probe_pair(p, build, index):
+                return join_ops.dense_analytic_probe(
                     p, pkeys, build, bkeys, info, remaps
                 )
-                return join_ops.emit_unique(p, build, spec, fi, fo)
         elif kind == "lut":
 
-            def probe_raw(p, build, index):
-                fi, fo = join_ops.dense_lut_probe(p, pkeys, layout, index)
-                return join_ops.emit_unique(p, build, spec, fi, fo)
+            def probe_pair(p, build, index):
+                return join_ops.dense_lut_probe(p, pkeys, layout, index)
         elif spec.build_unique:
 
-            def probe_raw(p, build, index):
-                return join_ops.hash_join_unique(
-                    p, pschema, pkeys, build, bschema, bkeys, spec,
+            def probe_pair(p, build, index):
+                return join_ops.probe_unique(
+                    p, pschema, pkeys, build, bschema, bkeys,
                     pht, bht, remaps, index=index, exact_layout=layout,
                 )
+        else:
+            probe_pair = None
+
+        if probe_pair is not None:
+
+            def probe_raw(p, build, index):
+                return join_ops.emit_unique(
+                    p, build, spec, *probe_pair(p, build, index))
         else:  # sorted-index existence probe over duplicate build keys
 
             def probe_raw(p, build, index):
@@ -1664,6 +1672,9 @@ class HashJoinOp(OneInputOperator):
                 return out
 
         self._probe_raw = probe_raw
+        # semi/anti carry no build column: nothing to materialise late
+        self._probe_pair = (probe_pair if spec.join_type in ("inner", "left")
+                            else None)
         self._probe_fn = dispatch.jit(probe_raw, name="hashjoin_probe")
 
     def _ensure_built(self):
@@ -1861,7 +1872,9 @@ class HashJoinOp(OneInputOperator):
         tracing.totals() sums them over a window. ``composed``: the probe
         ran inside the consumer's kernel; for a compact-mode join that is
         a tile it did not emit and compact itself, counted into
-        ``join_passthrough_tiles`` as well. ``t`` is a Batch, or a
+        ``join_passthrough_tiles`` as well; a tile it did emit, and cut
+        to its cap before it gathered a build column (`_emits_late`),
+        counts into ``join_late_emit_tiles``. ``t`` is a Batch, or a
         resident scan's (table batch, offset) token whose tile size
         ``src`` knows."""
         sp = tracing.current()
@@ -1878,13 +1891,33 @@ class HashJoinOp(OneInputOperator):
                 src = src.src
             rows = src._res_tile
         sp.inc_tag("join_probe_tile_rows", int(rows))
+        if not composed and self._emits_late(int(rows)):
+            sp.inc_tag("join_late_emit_tiles", 1)
+
+    def _emits_late(self, tile_rows: int) -> bool:
+        """Whether this join's own emit of a ``tile_rows`` probe tile cuts
+        the tile to the learned cap BEFORE it materialises the build side
+        (ops/join.py `emit_unique_compact`): compact mode, a unique-build
+        strategy of an inner or left join (`_probe_pair`), and a cap that
+        shrinks the tile. All host-known before the launch (the capacity
+        is static in the trace); `_emit_kernel` decides by the same three.
+        Everything else (learn, general, semi/anti, a cap no smaller than
+        the tile) emits probe-aligned as it always did."""
+        return (self._emit_mode == "compact"
+                and self._probe_pair is not None
+                and self._emit_cap < tile_rows)
 
     def _emit_kernel(self, cfn, nc):
-        """(chain o probe o count [o compact]) jit for source-mode emission,
-        cached on (chain fn, probe fn, emission cap). General duplicate-key
-        probes emit speculatively at the learned static capacity — the
-        kernel's second output is the TRUE total, so a truncating overflow
-        is detectable at query end without a per-tile host sync."""
+        """The jit for source-mode emission, one program a tile, cached on
+        (chain fn, probe fn, emission cap). Learn mode (no cap): chain o
+        probe o probe-aligned emit o count. Compact mode: chain o probe,
+        then the compaction's index from the probe's own output mask, and
+        only then the build columns, gathered once at the cap (`_emits_late`;
+        a cap that does not shrink the tile, or semi/anti, emits aligned
+        and compacts after). General duplicate-key probes emit
+        speculatively at the learned static capacity. The kernel's second
+        output is always the TRUE total of the whole tile, so a truncating
+        overflow is detectable at query end without a per-tile host sync."""
         from ..coldata.batch import compact as compact_batch
 
         cap = self._emit_cap
@@ -1899,14 +1932,20 @@ class HashJoinOp(OneInputOperator):
                 return graw(p, a[nc], a[nc + 1], cap)
 
         else:
-            raw = self._probe_raw
+            raw = self._probe_raw  # installed with its _probe_pair
             key = (cfn, raw, cap)
             if key in self._emit_kerns:
                 return self._emit_kerns[key]
+            # learn mode has no cap to cut to
+            pair = self._probe_pair if cap is not None else None
+            spec = self.spec
 
             def kern(t, *a):
-                out = raw(cfn(t, *a[:nc]) if cfn is not None else t,
-                          a[nc], a[nc + 1])
+                p = cfn(t, *a[:nc]) if cfn is not None else t
+                if pair is not None and cap < p.capacity:
+                    return join_ops.emit_unique_compact(
+                        p, a[nc], spec, *pair(p, a[nc], a[nc + 1]), cap)
+                out = raw(p, a[nc], a[nc + 1])
                 cnt = jnp.sum(out.mask, dtype=jnp.int64)
                 if cap is not None:
                     out = compact_batch(out, capacity=cap)

@@ -28,7 +28,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..coldata.batch import Batch, Column
+from ..coldata.batch import Batch, Column, live_index, pad_rows, take_rows
 from ..coldata.types import Family, Schema
 from .hashing import hash_columns
 
@@ -238,8 +238,12 @@ def dense_lut_probe(
 
 def emit_unique(probe: Batch, build: Batch, spec: JoinSpec,
                 found_idx, found) -> Batch:
-    """Probe-aligned emission shared by every unique-build probe strategy
-    (dense analytic / dense LUT / sorted bsearch)."""
+    """Probe-ALIGNED emission shared by every unique-build probe strategy
+    (dense analytic / dense LUT / sorted bsearch): every build column is
+    gathered for every probe row, matched or not. Right where the output
+    stays at the probe tile's capacity (a composed or transparent probe, a
+    learn run, semi/anti, which carry no build column); a join that cuts
+    its output to a learned cap emits through `emit_unique_compact`."""
     if spec.join_type == "semi":
         return probe.with_mask(probe.mask & found)
     if spec.join_type == "anti":
@@ -248,14 +252,42 @@ def emit_unique(probe: Batch, build: Batch, spec: JoinSpec,
         Column(data=c.data[found_idx], valid=c.valid[found_idx] & found)
         for c in build.cols
     )
-    cols = probe.cols + bcols
+    return Batch(cols=probe.cols + bcols,
+                 mask=_joined_mask(probe, spec, found))
+
+
+def _joined_mask(probe: Batch, spec: JoinSpec, found) -> jax.Array:
     if spec.join_type == "inner":
-        mask = probe.mask & found
-    elif spec.join_type == "left":
-        mask = probe.mask
-    else:
-        raise ValueError(f"unsupported join type {spec.join_type}")
-    return Batch(cols=cols, mask=mask)
+        return probe.mask & found
+    if spec.join_type == "left":
+        return probe.mask
+    raise ValueError(f"unsupported join type {spec.join_type}")
+
+
+def emit_unique_compact(probe: Batch, build: Batch, spec: JoinSpec,
+                        found_idx, found, capacity: int):
+    """(compact(emit_unique(...), capacity), true output rows) of an inner
+    or left join, materialising late: the output mask is known from the
+    probe alone, so the compaction's index (coldata `live_index`) is taken
+    FIRST, the probe columns move through it as `compact` moves them, and
+    each build column is gathered ONCE, at ``capacity`` rows, through
+    found_idx[index] — not at every probe row and then again at the cut
+    (q3's first join: nine columns and their valid bitmaps at 1,048,576
+    rows for the 2.6% that survive). Same columns, row order, mask and
+    valid bits as the eager order; a slot past the live count is invalid
+    (its build data is build row 0's, where `compact` leaves zeros). The
+    count is of the whole tile, so a cap below it shows as an overflow."""
+    idx, n = live_index(_joined_mask(probe, spec, found), capacity)
+    pcols = tuple(take_rows(c, idx, capacity) for c in probe.cols)
+    bi = jnp.take(found_idx, idx, mode="fill", fill_value=0)
+    f = jnp.take(found, idx, mode="fill", fill_value=False)
+    bcols = tuple(
+        Column(data=pad_rows(c.data[bi], capacity),
+               valid=pad_rows(c.valid[bi] & f, capacity))
+        for c in build.cols
+    )
+    mask = jnp.arange(capacity, dtype=jnp.int32) < n
+    return Batch(cols=pcols + bcols, mask=mask), n.astype(jnp.int64)
 
 
 def bsearch(sorted_u64: jax.Array, queries: jax.Array,
@@ -338,23 +370,23 @@ def _probe_positions(sh, ph):
     return bsearch(sh, ph, side="left")
 
 
-def hash_join_unique(
+def probe_unique(
     probe: Batch,
     probe_schema: Schema,
     probe_keys: tuple[int, ...],
     build: Batch,
     build_schema: Schema,
     build_keys: tuple[int, ...],
-    spec: JoinSpec,
     probe_hash_tables=None,
     build_hash_tables=None,
     build_code_remaps=None,
     index=None,
     exact_layout: ExactKeyLayout | None = None,
     exact_remaps=None,
-) -> Batch:
-    """Join with unique build keys. Output tile is probe-capacity:
-    probe columns followed by build columns (semi/anti: probe columns only).
+):
+    """(found_idx, found) of a sorted-index probe over unique build keys:
+    the probe half of `hash_join_unique`, in the shape the dense strategies
+    return, for an emission of the caller's choosing.
     `index` is an optional precomputed build_index() result so the build-side
     sort runs once per build batch, not once per probe tile.
 
@@ -410,6 +442,32 @@ def hash_join_unique(
         # guard against sentinel-hash self-matches
         found = found & p_active & build.mask[found_idx]
 
+    return found_idx, found
+
+
+def hash_join_unique(
+    probe: Batch,
+    probe_schema: Schema,
+    probe_keys: tuple[int, ...],
+    build: Batch,
+    build_schema: Schema,
+    build_keys: tuple[int, ...],
+    spec: JoinSpec,
+    probe_hash_tables=None,
+    build_hash_tables=None,
+    build_code_remaps=None,
+    index=None,
+    exact_layout: ExactKeyLayout | None = None,
+    exact_remaps=None,
+) -> Batch:
+    """Join with unique build keys (`probe_unique`, then `emit_unique`).
+    Output tile is probe-capacity: probe columns followed by build columns
+    (semi/anti: probe columns only)."""
+    found_idx, found = probe_unique(
+        probe, probe_schema, probe_keys, build, build_schema, build_keys,
+        probe_hash_tables, build_hash_tables, build_code_remaps,
+        index=index, exact_layout=exact_layout, exact_remaps=exact_remaps,
+    )
     return emit_unique(probe, build, spec, found_idx, found)
 
 

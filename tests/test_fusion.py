@@ -245,7 +245,10 @@ def test_a_join_fed_tiles_at_its_own_cap_composes_into_its_consumer(
     no `hashjoin_emit` of its own (its probe rides in the aggregate's
     kernel) and counts `join_passthrough_tiles`; fed wider tiles it
     compacts as it always did. Same answer either way, and its learned
-    mode and cap stay."""
+    mode and cap stay. Every tile a compact join does emit, at a cap under
+    the tile's capacity, is cut to the cap before the build side is
+    gathered and counts into `join_late_emit_tiles` (PR 31); a learn run
+    has no cap and counts none."""
     from cockroach_tpu.flow import dispatch, runtime
     from cockroach_tpu.flow.operators import HashJoinOp
     from cockroach_tpu.ops import expr as ex
@@ -284,7 +287,8 @@ def test_a_join_fed_tiles_at_its_own_cap_composes_into_its_consumer(
 
         def run(feeder):
             """(answer, programs issued, probe tiles a join, those the
-            upper join passed through)"""
+            upper join passed through, those a join cut before it gathered
+            its build side)"""
             _emit_state(lower, *feeder)
             _emit_state(upper, "compact", 1024)
             del drove[:]
@@ -296,14 +300,15 @@ def test_a_join_fed_tiles_at_its_own_cap_composes_into_its_consumer(
                 p1 = tracing.totals()["flow/pull"]["tags"]
             return (got, d, *(
                 p1.get(k, 0) - p0.get(k, 0)
-                for k in ("join_unique_tiles", "join_passthrough_tiles")))
+                for k in ("join_unique_tiles", "join_passthrough_tiles",
+                          "join_late_emit_tiles")))
 
         HashJoinOp.stream_tiles = spy
         try:
-            got, issued, probed, passed = run(feeder)
+            got, issued, probed, passed, late = run(feeder)
             drove_upper = upper in drove
             # against the same tree with the upper join emitting
-            _got, compacting, _probed, _passed = run(("compact", 2048))
+            _got, compacting, _probed, _passed, _late = run(("compact", 2048))
         finally:
             HashJoinOp.stream_tiles = real
     finally:
@@ -315,4 +320,6 @@ def test_a_join_fed_tiles_at_its_own_cap_composes_into_its_consumer(
     assert drove_upper is not passes
     assert passed == (tiles if passes else 0)
     assert issued == compacting - (tiles if passes else 0)
+    # the lower join's emit when it has a cap, the upper's when it emits
+    assert late == tiles * ((feeder[0] == "compact") + (not passes))
     assert upper._emit_mode == "compact" and upper._emit_cap_seen >= 1024

@@ -144,7 +144,10 @@ def test_spill_bit_identity_and_query_attribution():
         "select fingerprint, spills, max_mem_mb, mem_p50_mb, mem_p99_mb "
         "from crdb_internal.node_statement_statistics")
     rows = {str(f): i for i, f in enumerate(res["fingerprint"])}
-    key = next(f for f in rows if "group by l_orderkey" in f)
+    # this statement's row, not q3's ("group by l_orderkey, o_orderdate,
+    # ..."), which a test file earlier on the same worker may have left
+    key = next(f for f in rows
+               if "sum(l_quantity)" in f and "group by l_orderkey" in f)
     i = rows[key]
     assert int(res["spills"][i]) >= 1
     assert float(res["max_mem_mb"][i]) > 0

@@ -5,7 +5,9 @@ flow, held to the benchmark's float64 pandas reference
 rebinds the pattern's lookup table and compiles nothing; the default join
 order places the filtered `part` join first; the tags the cell's
 per-layer metrics read; the four joins above `part`, handed tiles already
-cut to their own cap, compose into the aggregate (PR 29)."""
+cut to their own cap, compose into the aggregate (PR 29); the `part` join's
+own emit, and q3's `orders` join's, cut the tile to the learned cap before
+they gather a build column (PR 31: `join_late_emit_tiles`)."""
 
 import json
 import os
@@ -109,9 +111,12 @@ def test_a_new_colour_compiles_nothing(sess, host, settled, color):
         assert len(got["nation"]) == 0
 
 
-# a settled q9 at SF0.01, one lineitem tile: the parent (a57b729) issued 14
-# programs a statement, five of them `hashjoin_emit`; the four joins above
-# `part` now ride in the aggregate's fold kernel
+# a settled q9 at SF0.01, one lineitem tile: PR 28 (a57b729) issued 14
+# programs a statement, five of them `hashjoin_emit`; since PR 29 the four
+# joins above `part` ride in the aggregate's fold kernel, and since PR 31
+# the one `hashjoin_emit` left (the `part` join's) takes the compaction's
+# index from the probe and gathers `part`'s columns once, at its cap: the
+# same 10 programs, one tile counted into `join_late_emit_tiles`
 PARENT_SETTLED_DISPATCHES = 14
 
 
@@ -123,6 +128,7 @@ def test_settled_q9_composes_the_upper_joins_into_the_aggregate(
     t1, d1 = _tags(), dispatch.total()
     _assert_answer(got, _reference(host, color))
     assert t1["passed"] - t0["passed"] == 4  # four joins, one tile each
+    assert t1["late"] - t0["late"] == 1  # the `part` join's one tile
     assert t1["unique"] - t0["unique"] == 5
     assert d1 - d0 == PARENT_SETTLED_DISPATCHES - 4
 
@@ -198,6 +204,7 @@ def _tags():
             "unique": pull.get("join_unique_tiles", 0),
             "general": pull.get("join_general_tiles", 0),
             "passed": pull.get("join_passthrough_tiles", 0),
+            "late": pull.get("join_late_emit_tiles", 0),
             "tables": query.get("lookup_tables_bound", 0)}
 
 
@@ -227,6 +234,9 @@ def test_the_tags_the_cells_metrics_read(sess, settled, monkeypatch):
     assert caps[0] == max(caps) and set(caps[1:]) == {min(caps)}
     assert min(caps) < max(caps)
     assert t1["passed"] - t0["passed"] == 4
+    # the one tile that was emitted was cut to the cap before the build
+    # side was gathered: the first join's tile count
+    assert t1["late"] - t0["late"] == 1
     assert t1["unique"] - t0["unique"] == 5
     assert t1["general"] - t0["general"] == 0
     assert t1["tables"] - t0["tables"] == 1
@@ -235,23 +245,28 @@ def test_the_tags_the_cells_metrics_read(sess, settled, monkeypatch):
     assert t2["tables"] - t1["tables"] == 0
     assert t2["rows"] - t1["rows"] == 0
     assert t2["passed"] - t1["passed"] == 0
+    assert t2["late"] - t1["late"] == 0
 
 
 def _pull_tags():
     pull = tracing.totals()["flow/pull"]
     return (pull["count"], pull["tags"].get("join_probe_tile_rows", 0),
-            pull["tags"].get("join_passthrough_tiles", 0))
+            pull["tags"].get("join_passthrough_tiles", 0),
+            pull["tags"].get("join_late_emit_tiles", 0))
 
 
-def _run(sess, color, passed=None):
+def _run(sess, color, passed=None, late=None):
     """(answer, attempts, probe-tile rows, programs compiled) of one q9;
     ``passed``: the probe tiles its compact-mode joins composed into their
-    consumer must number this."""
-    (p0, r0, t0), c0 = _pull_tags(), dispatch.compiles()
+    consumer must number this; ``late``: the tiles a join cut to its cap
+    before it gathered its build side, this."""
+    (p0, r0, t0, l0), c0 = _pull_tags(), dispatch.compiles()
     got = sess.execute(Q9.format(color=color))
-    p1, r1, t1 = _pull_tags()
+    p1, r1, t1, l1 = _pull_tags()
     if passed is not None:
         assert t1 - t0 == passed
+    if late is not None:
+        assert l1 - l0 == late
     return got, p1 - p0, r1 - r0, dispatch.compiles() - c0
 
 
@@ -267,19 +282,21 @@ def test_a_wide_pattern_overflows_to_the_right_answer_and_the_caps_come_back(
     joins' own tiles are both kept."""
     cache = plancache.cache_for(sess.catalog)
     entries = len(cache)
-    _got, pulls, steady, compiled = _run(sess, "green", passed=4)
+    _got, pulls, steady, compiled = _run(sess, "green", passed=4, late=1)
     assert (pulls, compiled) == (1, 0)
-    # the first attempt still composes; the re-run counts at every join
-    got, pulls, _rows, compiled = _run(sess, "a", passed=4)
+    # the first attempt still composes, above the `part` join's late emit;
+    # the re-run counts at every join, at full tiles (learn: no cap to cut
+    # to, so the aligned emission)
+    got, pulls, _rows, compiled = _run(sess, "a", passed=4, late=1)
     want = _reference(host, "a")
     assert len(want) > 150
     _assert_answer(got, want)
     assert pulls == 2  # it overflowed, and one re-run was enough
     assert compiled == 0  # the full-tile programs are the first run's
-    got, pulls, full, compiled = _run(sess, "green", passed=0)
+    got, pulls, full, compiled = _run(sess, "green", passed=0, late=0)
     _assert_answer(got, _reference(host, "green"))
     assert (pulls, compiled) == (1, 0) and full > steady
-    got, pulls, rows, compiled = _run(sess, "red", passed=4)
+    got, pulls, rows, compiled = _run(sess, "red", passed=4, late=1)
     _assert_answer(got, _reference(host, "red"))
     assert (pulls, rows, compiled) == (1, steady, 0)
     assert len(cache) == entries
@@ -323,3 +340,50 @@ def test_the_cells_loader_holds_this_program_to_its_plans_guarantee():
     from loaders import tpch_rebind
 
     assert tpch_rebind.compiles_for_a_new_pattern(2**31 + 30) == 0
+
+
+Q3 = " ".join(TPCH_SQL["q3"].split()).replace("1995-03-15", "{date}")
+
+
+def test_q3s_first_join_cuts_its_tile_before_it_gathers_orders(cat, host):
+    """The other cell's text (clause 2.4.3) at the same scale: the learn
+    run emits probe-aligned and counts nothing late; every statement after
+    it cuts the `orders` join's one lineitem tile to the learned cap
+    first. At this scale the `customer` join above really shrinks what it
+    is handed (8,192 rows into 1,024), so it still drives its own emit
+    (PR 29) and that emit is late as well; at SF1 it is handed its own cap
+    and composes, and only the first join's six tiles count. Each answer
+    is the float64 pandas oracle's (benchmarks/oracles/tpch_q3.py)."""
+    import datetime
+
+    from oracles import tpch_q3
+
+    epoch = datetime.date(1970, 1, 1)
+    s = Session(cat)
+    try:
+        for i, date in enumerate(["1995-03-15", "1995-03-04", "1995-03-28"]):
+            t0, d0 = _tags(), dispatch.total()
+            got = s.execute(Q3.format(date=date))
+            t1, issued = _tags(), dispatch.total() - d0
+            want = tpch_q3.answer(host, {"date": date})
+            assert len(want) == 10
+            assert list(got) == ["l_orderkey", "revenue", "o_orderdate",
+                                 "o_shippriority"]
+            np.testing.assert_array_equal(np.asarray(got["l_orderkey"]),
+                                          want.l_orderkey.to_numpy())
+            np.testing.assert_allclose(
+                np.asarray(got["revenue"], np.float64),
+                want.revenue.to_numpy(), rtol=1e-9, atol=0)
+            days = [(d - epoch).days if isinstance(d, datetime.date) else
+                    int(d) for d in np.asarray(got["o_orderdate"]).tolist()]
+            assert days == want.o_orderdate.tolist()
+            # one lineitem tile at SF0.01: two joins, two probe tiles
+            assert t1["unique"] - t0["unique"] == 2
+            assert t1["passed"] - t0["passed"] == 0
+            assert t1["late"] - t0["late"] == (0 if i == 0 else 2)
+            if i == 1:
+                settled = issued
+            elif i == 2:
+                assert issued == settled
+    finally:
+        s.close()
