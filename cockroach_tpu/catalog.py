@@ -36,6 +36,18 @@ TILE_ALIGN = 1024  # pad device tables to a multiple of this (8x128 lanes)
 SHAPE_BUCKETS = (1 << 10, 1 << 13, 1 << 16, 1 << 19, 1 << 21)
 
 
+def _stable_bounds(lo: int, hi: int) -> tuple[int, int]:
+    """(lo, hi) widened to powers of two: [0, 2**k - 1], or [-2**j, 2**k - 1]
+    below zero. A DECIMAL column holds measured amounts whose extremes move
+    with every load of the same schema at the same scale (o_totalprice's
+    largest order), and a bound that reaches a sort key's packing is a
+    constant of the program: the exact pair keys the compile cache by the
+    data. The widened pair costs a key at most one bit and is the same
+    from load to load."""
+    return (0 if lo >= 0 else -(1 << (-lo).bit_length()),
+            (1 << max(hi, 0).bit_length()) - 1)
+
+
 def _bucket_cap(n: int) -> int:
     for b in SHAPE_BUCKETS:
         if n <= b:
@@ -125,7 +137,10 @@ class Table:
                     a = a[np.asarray(self.valids[name])]
                 if len(a) == 0:
                     continue
-                stats[name] = (int(a.min()), int(a.max()))
+                lo, hi = int(a.min()), int(a.max())
+                if t.family is Family.DECIMAL:
+                    lo, hi = _stable_bounds(lo, hi)
+                stats[name] = (lo, hi)
             self._stats = stats
         return self._stats
 

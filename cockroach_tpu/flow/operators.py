@@ -32,7 +32,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..catalog import Table
+from ..catalog import SHAPE_BUCKETS, Table
 from ..coldata.batch import Batch, Column, Dictionary, concat
 from ..coldata.types import FLOAT64, Family, Schema
 from ..ops import aggregation as agg_ops
@@ -61,7 +61,6 @@ def _canonical_cap(n: int) -> int:
     every doubling boundary. Stays pow2 (spool consumers assume it): rungs
     are pow2, and above the top rung pow2 growth IS the coarse ladder.
     Falls back to plain pow2 with bucketing off."""
-    from ..catalog import SHAPE_BUCKETS
     from ..utils import settings
 
     if settings.get("sql.distsql.shape_buckets.enabled"):
@@ -69,6 +68,9 @@ def _canonical_cap(n: int) -> int:
             if n <= b:
                 return b
     return _next_pow2(n)
+
+
+_LOWEST_RUNG = SHAPE_BUCKETS[0]  # 1,024: the floor of a learned cap
 
 
 def _emission_cap(n: int) -> int:
@@ -952,7 +954,16 @@ class AggregateOp(OneInputOperator):
         else:
             source = _consume(self, "partial", tile_raw, tile_jit)
         source_it = iter(source)
+        # the pull span's tags (host-known, no sync; tracing.totals() sums
+        # them over a window): `agg_ordered_tiles`, input tiles grouped
+        # without a key sort; `agg_merge_rows` (_merge_down), the static
+        # cap of every hashagg_merge launch; `agg_spills`, a spool handed
+        # to the Grace aggregator
+        sp = tracing.current()
+        presorted = self.ordered and self.mode != "final"
         for part in source_it:
+            if sp is not None and presorted:
+                sp.inc_tag("agg_ordered_tiles", 1)
             self._tiles.append(part)
             spooled += part.capacity
             nb = batch_bytes(part)
@@ -980,6 +991,8 @@ class AggregateOp(OneInputOperator):
                     from .external import ChainOp, GraceAggregateOp
 
                     note_spill("agg")
+                    if sp is not None:
+                        sp.inc_tag("agg_spills", 1)
                     self.stats.spilled = True
                     alloc.close()
                     self._spool_alloc = None
@@ -1075,13 +1088,15 @@ class AggregateOp(OneInputOperator):
         return Batch(cols=tuple(cols), mask=final.mask)
 
     def _merge_down(self) -> Batch:
+        sp = tracing.current()
         cap = _spool_cap(self._tiles)
-        merged, ng = self._merge_fn(tuple(self._tiles), cap=cap)
-        # one bounded retry loop per merge-down, not per tile
-        while int(ng) > cap:
-            cap = _canonical_cap(int(ng))
+        while True:  # one bounded retry loop per merge-down, not per tile
+            if sp is not None:
+                sp.inc_tag("agg_merge_rows", cap)
             merged, ng = self._merge_fn(tuple(self._tiles), cap=cap)
-        return merged
+            if int(ng) <= cap:
+                return merged
+            cap = _canonical_cap(int(ng))
 
     def _next(self):
         if self._external is not None:
@@ -1762,6 +1777,19 @@ class HashJoinOp(OneInputOperator):
                 )
                 if self._fusable:
                     self._set_probe("sorted")
+            if (cap <= _LOWEST_RUNG and self._emit_mode == "learn"
+                    and not self._emit_cap_seen
+                    and _LOWEST_RUNG < settings.get("sql.distsql.tile_size")):
+                # a build side that fits the ladder's lowest rung (its live
+                # rows were just counted for the spool) seldom keeps more
+                # than a rung of a probe tile: start compact at that rung,
+                # where a learn run would emit, and compile its consumers
+                # for, full tiles. The emit still counts the whole tile, so
+                # a tile that keeps more overflows and re-runs on the cap
+                # post_run_update learns from the count, as any compact
+                # join whose literal widened
+                self._emit_mode = "compact"
+                self._emit_cap = _LOWEST_RUNG
         self._built = True
 
     def children(self):
@@ -1868,7 +1896,8 @@ class HashJoinOp(OneInputOperator):
         by a unique-build strategy: analytic, LUT, sorted-unique) or
         ``join_general_tiles`` (by hash_join_general), and its capacity
         into ``join_probe_tile_rows`` (rows the probe pays for, live or
-        dead: known on the host, no sync), beside the dispatch tags;
+        dead: known on the host, no sync; a semi or anti join's also into
+        ``semijoin_probe_tile_rows``), beside the dispatch tags;
         tracing.totals() sums them over a window. ``composed``: the probe
         ran inside the consumer's kernel; for a compact-mode join that is
         a tile it did not emit and compact itself, counted into
@@ -1885,13 +1914,11 @@ class HashJoinOp(OneInputOperator):
         unique = self._probe_raw is not None and (
             self.spec.build_unique or self._probe_kind != "sorted")
         sp.inc_tag("join_unique_tiles" if unique else "join_general_tiles", 1)
-        rows = getattr(t, "capacity", None)
-        if rows is None:
-            while isinstance(src, _CountedProbeTiles):
-                src = src.src
-            rows = src._res_tile
-        sp.inc_tag("join_probe_tile_rows", int(rows))
-        if not composed and self._emits_late(int(rows)):
+        rows = _tile_rows(t, src)
+        sp.inc_tag("join_probe_tile_rows", rows)
+        if self.spec.join_type in ("semi", "anti"):
+            sp.inc_tag("semijoin_probe_tile_rows", rows)
+        if not composed and self._emits_late(rows):
             sp.inc_tag("join_late_emit_tiles", 1)
 
     def _emits_late(self, tile_rows: int) -> bool:
@@ -1985,8 +2012,7 @@ class HashJoinOp(OneInputOperator):
                 self._note_probe_tile(t, src)
                 out, cnt = kern(t, *args)
                 self._emit_counts.append(cnt)
-                if self._emit_cap is None:
-                    self._emit_tilecap = max(self._emit_tilecap, out.capacity)
+                self._note_tilecap(out, t, src)
                 yield out
             return
         kern = None
@@ -2001,9 +2027,18 @@ class HashJoinOp(OneInputOperator):
             self._note_probe_tile(b)
             out, cnt = kern(b, self._build_batch, self._index)
             self._emit_counts.append(cnt)
-            if self._emit_cap is None:
-                self._emit_tilecap = max(self._emit_tilecap, out.capacity)
+            self._note_tilecap(out, b)
             yield out
+
+    def _note_tilecap(self, out: Batch, t, src=None) -> None:
+        """The widest probe tile seen, for post_run_update to judge a cap
+        by: a learn run emits probe-aligned, so its output's capacity; a
+        join that started compact on a small build never ran one, so its
+        input's."""
+        if self._emit_cap is None:
+            self._emit_tilecap = max(self._emit_tilecap, out.capacity)
+        elif not self._emit_tilecap:
+            self._emit_tilecap = _tile_rows(t, src)
 
     def post_run_update(self, truncated: bool = False) -> bool:
         if truncated and self._emit_mode in ("learn", "compact"):
@@ -2049,7 +2084,7 @@ class HashJoinOp(OneInputOperator):
         # shape, and a run that kept few rows (a pattern that matches
         # nothing) must not shrink the cap under the next statement (an
         # overflow there: a re-run and new programs)
-        cap = max(1024, _emission_cap(2 * mx), self._emit_cap_seen)
+        cap = max(_LOWEST_RUNG, _emission_cap(2 * mx), self._emit_cap_seen)
         if tile and mx * 4 <= tile and cap < tile:
             # compacting only pays when the learned cap actually SHRINKS the
             # tile — at small tile sizes the cap floor equals the tile and
@@ -2090,8 +2125,7 @@ class HashJoinOp(OneInputOperator):
                     p, self._build_batch, self._index
                 )
                 self._emit_counts.append(cnt)
-                if self._emit_cap is None:
-                    self._emit_tilecap = max(self._emit_tilecap, out.capacity)
+                self._note_tilecap(out, p)
                 return out
             return self._probe_fn(p, self._build_batch, self._index)
         if self._out_cap <= 0:
@@ -2113,6 +2147,17 @@ class HashJoinOp(OneInputOperator):
         if getattr(self, "_build_alloc", None) is not None:
             self._build_alloc.close()
             self._build_alloc = None
+
+
+def _tile_rows(t, src=None) -> int:
+    """Capacity in rows of a probe tile: a Batch, or a resident scan's
+    (table batch, offset) token whose tile size ``src`` knows."""
+    rows = getattr(t, "capacity", None)
+    if rows is None:
+        while isinstance(src, _CountedProbeTiles):
+            src = src.src
+        rows = src._res_tile
+    return int(rows)
 
 
 class _CountedProbeTiles:

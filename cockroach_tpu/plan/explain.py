@@ -12,7 +12,7 @@ from __future__ import annotations
 from . import spec as S
 
 
-def _node_label(n: S.PlanNode) -> str:
+def _node_label(n: S.PlanNode, op=None) -> str:
     if isinstance(n, S.TableScan):
         cols = f" columns={list(n.columns)}" if n.columns else ""
         return f"scan {n.table}{cols}"
@@ -29,7 +29,11 @@ def _node_label(n: S.PlanNode) -> str:
                 for a in n.aggs]
         mode = f" mode={n.mode}" if n.mode != "complete" else ""
         dense = " dense" if n.key_sizes else ""
-        return f"group-by keys={list(n.group_cols)} aggs={aggs}{mode}{dense}"
+        # the route the operator takes: its input arrives clustered on the
+        # group keys, so a tile is grouped without a key sort
+        ordered = " (ordered)" if getattr(op, "ordered", False) else ""
+        return (f"group-by keys={list(n.group_cols)} aggs={aggs}{mode}{dense}"
+                f"{ordered}")
     if isinstance(n, S.ScalarAggregate):
         aggs = [f"{a.func}({a.col if a.col is not None else '*'})"
                 for a in n.aggs]
@@ -95,14 +99,44 @@ def _group_tag(groups: dict[int, int], n: S.PlanNode) -> str:
     return f"  [pipeline {g}]" if g is not None else ""
 
 
-def explain_plan(plan: S.PlanNode) -> str:
-    """Render the plan tree (EXPLAIN)."""
+def _operators_of(plan: S.PlanNode, root_op) -> dict[int, object]:
+    """id(plan node) -> its operator, by the walk EXPLAIN ANALYZE makes;
+    where the two trees part, the nodes below stay without one."""
+    from ..flow.fuse import unwrap
+
+    found: dict[int, object] = {}
+
+    def walk(n: S.PlanNode, op):
+        if isinstance(n, S.Exchange):  # single-device builds elide it
+            walk(n.input, op)
+            return
+        op = unwrap(op)
+        found[id(n)] = op
+        kids, kid_ops = _children(n), op.children()
+        if len(kids) == len(kid_ops):
+            for c, co in zip(kids, kid_ops):
+                walk(c, co)
+
+    walk(plan, root_op)
+    return found
+
+
+def explain_plan(plan: S.PlanNode, catalog=None) -> str:
+    """Render the plan tree (EXPLAIN). With the catalog, the operator tree
+    is built (not run) beside it, so a line can name the route its
+    operator takes (a group-by's `(ordered)`)."""
     lines: list[str] = []
     groups = _fusion_groups(plan)
+    operators: dict[int, object] = {}
+    if catalog is not None:
+        from . import builder
+
+        operators = _operators_of(plan, builder.build(plan, catalog))
 
     def walk(n: S.PlanNode, depth: int):
         lines.append(
-            "  " * depth + "-> " + _node_label(n) + _group_tag(groups, n))
+            "  " * depth + "-> " + _node_label(n, operators.get(id(n)))
+            + _group_tag(groups, n))
         for c in _children(n):
             walk(c, depth + 1)
 
@@ -143,7 +177,7 @@ def explain_analyze(plan: S.PlanNode, root_op) -> str:
                if getattr(st, "max_mem_bytes", 0) else "")
         spill = " spilled" if getattr(st, "spilled", False) else ""
         lines.append(
-            "  " * depth + "-> " + _node_label(n)
+            "  " * depth + "-> " + _node_label(n, op)
             + f"  [rows={st.rows} batches={st.batches} "
             f"bytes={st.bytes} "
             f"time={st.time_s*1e3:.1f}ms self={excl*1e3:.1f}ms{mem}{spill}]"

@@ -821,10 +821,16 @@ class Binder:
                 s.keep_frac *= self._kept_fraction(e, st, p, s)
             s.est_rows = self._estimate_source_rows(s, preds)
 
+        # `col IN (SELECT ...)` over a column of one source filters that
+        # source: its semi-join goes below the joins, so the source enters
+        # the ordering as a reducing build side
+        sub_joins = [sj for sj in sub_joins
+                     if not self._semi_filter_source(sj, scope)]
+
         # greedy join order: start at the largest source
         joined = self._join_sources(sources, equi_edges, scope)
 
-        # decorrelated EXISTS / IN-select as semi/anti joins
+        # decorrelated EXISTS / NOT IN as semi/anti joins
         for node, negate in sub_joins:
             joined = self._apply_sub_join(joined, node, negate, scope, sources)
 
@@ -1043,24 +1049,19 @@ class Binder:
         unproven stays False and keeps the duplicate-key probe.
 
         Filters and column-reference projections only remove rows or
-        rename columns: the walk sees through them down to either a
+        rename columns, and a semi or anti join only removes rows of its
+        probe side: the walk sees through them down to either a
         grouping joined on (at least) all its group keys, or the scan of
         `table` (the source's own base table: derived tables and CTEs pass
         None) whose host rows prove it (catalog Table.unique_key). KV
         tables have no such proof (nothing ties a cached plan to a
-        snapshot); join outputs, set operations and computed keys stop the
-        walk."""
+        snapshot); other join outputs, set operations and computed keys
+        stop the walk."""
         from ..plan import spec as S
 
-        cols = [b if isinstance(b, int) else build.idx(b) for _, b in on]
-        node = build.plan
-        while isinstance(node, (S.Filter, S.Project)):
-            if isinstance(node, S.Project):
-                exprs = [node.exprs[c] for c in cols]
-                if not all(isinstance(e, ex.ColRef) for e in exprs):
-                    return False
-                cols = [e.idx for e in exprs]
-            node = node.input
+        node, cols = self._below_row_filters(
+            build.plan,
+            [b if isinstance(b, int) else build.idx(b) for _, b in on])
         if isinstance(node, S.Aggregate):
             return (node.mode == "complete"
                     and set(range(len(node.group_cols))) <= set(cols))
@@ -1072,6 +1073,30 @@ class Binder:
             return False
         names = node.columns or tbl.schema.names
         return prove(tuple(names[c] for c in cols))
+
+    @staticmethod
+    def _below_row_filters(node, cols: list[int]):
+        """(node, cols): the plan node under every operator on top of
+        `node` that only removes rows or renames columns (Filter, a Project
+        of column references, the probe side of a semi or anti join), and
+        `cols` as that node's positions; (None, cols) where a projection
+        computes one of them."""
+        from ..plan import spec as S
+
+        while True:
+            if isinstance(node, S.Project):
+                exprs = [node.exprs[c] for c in cols]
+                if not all(isinstance(e, ex.ColRef) for e in exprs):
+                    return None, cols
+                cols = [e.idx for e in exprs]
+                node = node.input
+            elif isinstance(node, S.Filter):
+                node = node.input
+            elif (isinstance(node, S.HashJoin)
+                    and node.spec.join_type in ("semi", "anti")):
+                node = node.probe
+            else:
+                return node, cols
 
     # -- cardinality estimation (statistics_builder.go reduction) -----------
 
@@ -1295,6 +1320,72 @@ class Binder:
                 colmap[(nxt, p)] = off + p
             placed.add(nxt)
         return BoundQuery(rel, {i: sources[i] for i in range(n)}, colmap)
+
+    def _semi_filter_source(self, sub_join, scope: Scope) -> bool:
+        """`col IN (SELECT ...)` (uncorrelated, not negated) filters the one
+        source `col` belongs to: semi-join that source now, before the join
+        order is chosen, and say so. NOT IN, EXISTS and anything whose
+        argument is not a plain column stay on top of the joined rows."""
+        node, negate = sub_join
+        if (negate or not isinstance(node, P.InSelect) or node.negated
+                or not isinstance(node.arg, P.Ident)):
+            return False
+        i, pos = scope.resolve(node.arg)
+        s = scope.sources[i]
+        sub = self.bind_subquery_for_in(node.select)
+        on = [(pos, sub.schema.names[0])]
+        s.rel = s.rel.join(sub, on=on, how="semi",
+                           build_unique=self._build_unique(sub, on, None))
+        frac = self._semi_kept_fraction(s, pos, sub)
+        s.keep_frac = frac * (1.0 if s.keep_frac is None else s.keep_frac)
+        st = self._source_stats(s)
+        if s.est_rows is None and st is not None:
+            s.est_rows = st.row_count
+        if s.est_rows is not None:
+            s.est_rows = max(1, int(round(s.est_rows * frac)))
+        return True
+
+    def _semi_kept_fraction(self, s: "Source", pos: int, sub: Rel) -> float:
+        """Share of a source's rows `col IN (subquery)` keeps: the
+        subquery's estimated rows over the column's distinct values where
+        ANALYZE statistics give both, else the unknown-selectivity constant
+        (what a HAVING gets)."""
+        st = self._source_stats(s)
+        rows = self._plan_est_rows(sub.plan)
+        if st is None or rows is None:
+            return self._DEFAULT_PRED_FRAC
+        return min(1.0, rows / self._col_ndv(s, pos, float(st.row_count)))
+
+    def _plan_est_rows(self, node) -> float | None:
+        """Rows a subquery's plan returns, from ANALYZE statistics: a scan's
+        row count, a grouping's distinct keys (independence), the
+        unknown-selectivity constant a filter. None without statistics or
+        through anything else."""
+        from ..plan import spec as S
+
+        if isinstance(node, S.TableScan):
+            st = getattr(self.catalog.tables.get(node.table), "table_stats",
+                         None)
+            return None if st is None else float(st.row_count)
+        if isinstance(node, S.Project):
+            return self._plan_est_rows(node.input)
+        if isinstance(node, S.Filter):
+            rows = self._plan_est_rows(node.input)
+            return None if rows is None else rows * self._DEFAULT_PRED_FRAC
+        if isinstance(node, S.Aggregate):
+            rows = self._plan_est_rows(node.input)
+            scan, cols = self._below_row_filters(node.input,
+                                                 list(node.group_cols))
+            if rows is None or not isinstance(scan, S.TableScan):
+                return rows
+            tbl = self.catalog.tables[scan.table]
+            names = scan.columns or tbl.schema.names
+            stats = [tbl.table_stats.cols.get(names[c]) for c in cols]
+            if any(cs is None or cs.ndv <= 0 for cs in stats):
+                return rows
+            groups = float(np.prod([cs.ndv for cs in stats]))
+            return max(1.0, min(rows, groups))
+        return None
 
     def _apply_sub_join(self, joined: "BoundQuery", node, negate, scope,
                         sources) -> "BoundQuery":

@@ -58,7 +58,7 @@ def _plan_sections(session, text: str) -> dict:
     try:
         stmt = parser.parse_statement(text)
         rel = Binder(session.catalog).bind(stmt)
-        out["plan"] = explain_plan(rel.optimized_plan())
+        out["plan"] = explain_plan(rel.optimized_plan(), session.catalog)
         out["planCacheStatus"] = plancache.probe(rel)
     except Exception:  # crlint: allow-broad-except(bundle capture is best-effort; the statement may not plan)
         out["plan"] = None

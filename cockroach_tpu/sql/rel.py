@@ -446,7 +446,7 @@ class Rel:
     def explain(self) -> str:
         from ..plan.explain import explain_plan
 
-        return explain_plan(self.optimized_plan())
+        return explain_plan(self.optimized_plan(), self.catalog)
 
     def explain_analyze(self) -> tuple[str, dict[str, np.ndarray]]:
         """Run with ComponentStats collection; returns (rendered tree,

@@ -39,7 +39,8 @@ _SETTLE = 3
 # one budget a served text, warm at sf 0.001 / 1,024-row tiles (6 lineitem
 # tiles; every join stays transparent there: the compaction cap's floor
 # equals the tile). Each budget IS the CPU's reading, taken in PR 30
-# through Session.execute: the counts do not vary, so none has slack.
+# (q18: PR 32) through Session.execute: the counts do not vary, so none
+# has slack.
 BUDGETS = {
     # 6 fused slice+filter+project+group+merge dispatches (fold seed + 5
     # fold steps) + finalize + 2 sort (the ORDER BY's spool with the
@@ -55,11 +56,16 @@ BUDGETS = {
     # fold, finalize, 2 sort; an unfused chain pays one dispatch +
     # readback per join per tile and blows well past this
     "q9": 21,
-    # 4 build spools + hashjoin_lut, the IN (SELECT ... HAVING) grouping's
-    # 6 fold + finalize, the outer aggregate's 6 hashagg_partial_fused +
-    # merge + finalize, and ORDER BY ... LIMIT as a folded device top-k
-    # (topk_fold_seed + limit_tile) instead of a full sort spool
-    "q18": 22,
+    # the IN (SELECT ... HAVING) grouping's 6 fold + finalize and its
+    # keys' pipe_project_build_spool; the semi-join on `orders` BELOW both
+    # joins (PR 32): 2 pipe_hashjoin_build_spool (orders' two tiles probed
+    # inside lineitem's build spool) + 2 hashjoin_lut (the subquery's keys,
+    # the kept orders); customer's pipe_scan_build_spool; the outer
+    # aggregate's 6 hashagg_partial_fused (both inner probes inside) +
+    # merge + finalize; ORDER BY ... LIMIT as a folded device top-k
+    # (topk_fold_seed + limit_tile). 22 with the semi-join on top of the
+    # joined rows (PR 30): one orders tile more is one spool more
+    "q18": 23,
 }
 # q9 where the joins DO compact (sf 0.01, one lineitem tile at the default
 # tile size): the part join cuts the tile to its cap and emits; the four

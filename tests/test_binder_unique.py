@@ -171,6 +171,30 @@ def test_subquery_join_sites_take_the_helper(sess, monkeypatch, text,
     assert int(sess.execute(text)["n"][0]) == want
 
 
+@pytest.mark.parametrize("where,semi_below,proven", [
+    # the build side behind its own IN-subquery's semi-join: still the
+    # scan of `dim` with rows removed, so still proven (PR 32)
+    ("fk = dim.k and dim.k in (select k from dup group by k)", True, True),
+    # the probe side filtered the same way; `dim` is untouched
+    ("fk = dim.k and fk in (select k from dup group by k)", True, True),
+    # NOT IN stays an anti join on top of the joined rows
+    ("fk = dim.k and dim.k not in (select k from dup group by k)", False,
+     True),
+], ids=["in_on_the_build", "in_on_the_probe", "not_in_on_top"])
+def test_an_in_subquery_filters_its_source_below_the_join(sess, where,
+                                                          semi_below, proven):
+    text = SEL + "fact, dim where " + where
+    lines = [ln.strip() for ln in explain(sess.catalog, text).splitlines()]
+    inner = next(i for i, ln in enumerate(lines) if "hash-join (inner)" in ln)
+    sub = next(i for i, ln in enumerate(lines)
+               if "hash-join (semi)" in ln or "hash-join (anti)" in ln)
+    assert (sub > inner) == semi_below, lines
+    assert ("(unique build)" in lines[inner]) == proven
+    in_dup = np.isin(_col(sess, "dim", "k"), _col(sess, "dup", "k"))
+    keep = ~in_dup if "not in" in where else in_dup
+    assert _run(sess, text) == _oracle(sess, "dim", pred=lambda v: keep)
+
+
 def test_rehosted_table_with_a_duplicate_is_not_served_the_unique_plan(sess):
     """A plan built on the proof does not outlive it: re-hosting the build
     table under its name bumps the catalog version the plan cache keys on,
