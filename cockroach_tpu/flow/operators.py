@@ -33,7 +33,9 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..catalog import SHAPE_BUCKETS, Table
-from ..coldata.batch import Batch, Column, Dictionary, concat
+from ..coldata.batch import (
+    Batch, Column, Dictionary, concat, empty_batch, pad_rows,
+)
 from ..coldata.types import FLOAT64, Family, Schema
 from ..ops import aggregation as agg_ops
 from ..ops.aggregation import partial_layout
@@ -737,6 +739,13 @@ class AggregateOp(OneInputOperator):
     - complete: input rows -> final results
     - partial:  input rows -> state columns (feeds an Exchange)
     - final:    state columns (partial layout) -> final results
+
+    A complete aggregate over input clustered on its group keys
+    (``ordered``) STREAMS, as colexec's orderedAggregator does: a tile's
+    groups are final but the one its edge may cut, so each input tile
+    leaves at once as one output tile and one open group is carried to the
+    next (_stream); nothing is spooled, merged or spilled. Every other
+    aggregate spools its tiles' partial states and merges them (_spool).
     """
 
     KERNEL = "hashagg"
@@ -847,6 +856,9 @@ class AggregateOp(OneInputOperator):
                 raise ValueError(
                     "grouping by a string_agg result is not supported"
                 )
+        # decided by the plan (the builder's ordered, the mode, the
+        # aggregates), never by a setting; EXPLAIN prints it
+        self.streaming = ordered and mode == "complete" and not self._sagg
         self._acc = None
         self._emitted = False
         self._spool_alloc = None
@@ -867,6 +879,7 @@ class AggregateOp(OneInputOperator):
         self._tiles: list[Batch] = []
         self._emitted = False
         self._external = None
+        self._streamed = None
         self._close_spool()  # cached-plan re-run: prior account is dead
         self._sagg_rows = {j: {} for j, _ in self._sagg}
         if hasattr(self, "_partial_fn"):
@@ -892,36 +905,87 @@ class AggregateOp(OneInputOperator):
         ordered = self.ordered
         prefix_live = self.prefix_live
 
-        def partial_fn(b):
+        def grouped(b):
             # out_capacity == input capacity: groups <= live rows, so this
             # CANNOT overflow — no device->host sync on the hot tile loop
-            part, _ = agg_ops.sort_groupby(
+            return agg_ops.sort_groupby(
                 b, schema, gcols, pspecs, out_capacity=b.capacity,
                 col_stats=in_stats,
                 presorted=ordered, compact=not prefix_live,
             )
-            return part
+
+        def partial_fn(b):
+            return grouped(b)[0]
 
         @functools.partial(dispatch.jit, static_argnames=("cap",),
                            name="hashagg_merge")
         def merge_fn(tiles, cap):
             both = concat(list(tiles), capacity=cap)
-            # ordered partials stay in scan order per tile, so their
-            # concatenation is still clustered; only dead pad rows between
-            # tiles need compacting (the cheap single-operand sort)
             return agg_ops.sort_groupby(both, sschema, mcols, mspecs,
                                         out_capacity=cap,
-                                        col_stats=merge_stats,
-                                        presorted=ordered, compact=True)
+                                        col_stats=merge_stats)
+
+        def stream_fn(b, carry):
+            # the presorted partial, the carried group met with it, closed
+            # groups finalized: one kernel a tile
+            closed, carry = agg_ops.stitch_ordered_partial(
+                *grouped(b), carry, sschema, mcols, mspecs, merge_stats)
+            return self._finalize(closed), carry
+
+        def stream_tail_fn(carry):
+            # the group still open after the last tile, at the ladder's
+            # lowest rung
+            return self._finalize(jax.tree_util.tree_map(
+                lambda x: pad_rows(x, _LOWEST_RUNG), carry))
 
         self._partial_raw = partial_fn
         self._partial_fn = dispatch.jit(partial_fn, name="hashagg_partial")
         self._merge_fn = merge_fn
         self._finalize_fn = dispatch.jit(self._finalize,
                                          name="hashagg_finalize")
+        if self.streaming:
+            self._stream_raw = stream_fn
+            self._stream_fn = dispatch.jit(stream_fn, name="hashagg_stream")
+            self._stream_tail_fn = dispatch.jit(stream_tail_fn,
+                                                name="hashagg_stream_tail")
+            self._no_carry = empty_batch(sschema, 1)
 
     def _finalize(self, state: Batch) -> Batch:
         return agg_ops.finalize_states(state, self.final_map, self.num_keys)
+
+    def _stream(self):
+        """One output tile an input tile (fused with the streaming chain
+        beneath, as _consume fuses a partial), the open group threaded
+        through as a device argument: no spool, no merge, no host sync.
+        Then the group still open, as one last tile; nothing on no input.
+        Output tiles claim no order among themselves."""
+        parts = (None if (self._collect or not _fusion_enabled())
+                 else self.child.stream_parts())
+        if parts is None:
+            step, args = self._stream_fn, ()
+            tiles = iter(self.child.next_batch, None)
+        else:
+            src, cfn, args = parts
+            raw = self._stream_raw
+            step = _per_chain(
+                self, "_fused_stream", cfn,
+                lambda: dispatch.jit(
+                    lambda t, carry, *a: raw(cfn(t, *a), carry),
+                    name="hashagg_stream_fused"))
+            tiles = src.stream_tiles()
+        # the pull span's tags (host-known, no sync): `agg_ordered_tiles`
+        # as _spool counts it; `agg_streamed_tiles`, input tiles that left
+        # as an output tile at once
+        sp = tracing.current()
+        carry = self._no_carry
+        for t in tiles:
+            out, carry = step(t, carry, *args)
+            if sp is not None:
+                sp.inc_tag("agg_ordered_tiles", 1)
+                sp.inc_tag("agg_streamed_tiles", 1)
+            yield out
+        if carry is not self._no_carry:
+            yield self._stream_tail_fn(carry)
 
     def _spool(self):
         """Spool per-tile partial states (fused with the streaming chain
@@ -1099,6 +1163,10 @@ class AggregateOp(OneInputOperator):
             cap = _canonical_cap(int(ng))
 
     def _next(self):
+        if self.streaming:
+            if self._streamed is None:
+                self._streamed = self._stream()
+            return next(self._streamed, None)
         if self._external is not None:
             return self._external.next_batch()  # spilled: stream partitions
         if self._emitted:
@@ -1127,6 +1195,7 @@ class AggregateOp(OneInputOperator):
 
     def close(self):
         super().close()
+        self._streamed = None  # a consumer may stop early (LIMIT)
         self._close_spool()
 
 
@@ -1749,8 +1818,6 @@ class HashJoinOp(OneInputOperator):
                     return
                 tiles.append(b)
         if not tiles:
-            from ..coldata.batch import empty_batch
-
             self._build_batch = empty_batch(self.build.output_schema, 1024)
             self._index = join_ops.build_index(
                 self._build_batch, self.build.output_schema, self.build_keys,
@@ -2348,8 +2415,6 @@ class OrderedSyncOp(Operator):
         self._done = [False] * len(self._children)
         self._carry: Batch | None = None
         self._flushed = False
-        from ..coldata.batch import empty_batch
-
         probe = empty_batch(self.output_schema, 16)
         self._streaming = self._packed_words(probe) is not None
         self._spooled = None
@@ -2668,7 +2733,6 @@ class MergeJoinOp(OneInputOperator):
             return
         tiles = list(_consume_op(self.build, "build_spool"))
         if not tiles:
-            from ..coldata.batch import empty_batch
             from ..ops import merge_join as mj_ops
 
             self._build_batch = empty_batch(self.build.output_schema, 1024)

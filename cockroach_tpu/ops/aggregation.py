@@ -205,6 +205,25 @@ def _scan_agg_entries(spec: AggSpec, col: Column | None, live,
     raise ValueError(f"unknown aggregate {spec.func}")
 
 
+def _packed_group_keys(batch: Batch, schema: Schema,
+                       group_cols: tuple[int, ...], col_stats: dict) -> list:
+    """Every row's (dead?, group keys) bit-packed into uint64 words: live
+    rows sort first, then by group keys, and word equality IS group-key
+    equality (nulls are their own group; NULL rows' garbage data is zeroed
+    inside key_segments so the NULL group is contiguous even with later
+    key columns in play)."""
+    from . import keys as key_ops
+
+    segs: list = [key_ops.BitSeg(1, (~batch.mask).astype(jnp.uint64))]
+    for gi in group_cols:
+        c = batch.cols[gi]
+        segs.extend(key_ops.key_segments(
+            c.data, c.valid, schema.types[gi], desc=False, nulls_first=False,
+            stats=col_stats.get(gi), order_semantics=False,
+        ))
+    return key_ops.pack_operands(segs)
+
+
 def sort_groupby(
     batch: Batch,
     schema: Schema,
@@ -234,24 +253,12 @@ def sort_groupby(
     pushes dead rows last (needed when filters interleave dead rows);
     compact=False additionally asserts live rows form a prefix (pure
     scan tiles), making the whole grouping sort-free."""
-    from . import keys as key_ops
-
     cap = batch.capacity
     cap_out = out_capacity or cap
     live = batch.mask
     col_stats = col_stats or {}
 
-    # Sort live rows first, then by group keys (nulls are their own group;
-    # NULL rows' garbage data is zeroed inside key_segments so the NULL
-    # group is contiguous even with later key columns in play).
-    segs: list = [key_ops.BitSeg(1, (~live).astype(jnp.uint64))]
-    for gi in group_cols:
-        c = batch.cols[gi]
-        segs.extend(key_ops.key_segments(
-            c.data, c.valid, schema.types[gi], desc=False, nulls_first=False,
-            stats=col_stats.get(gi), order_semantics=False,
-        ))
-    operands = key_ops.pack_operands(segs)
+    operands = _packed_group_keys(batch, schema, group_cols, col_stats)
     perm = jnp.arange(cap, dtype=jnp.int32)
     if not presorted:
         sorted_res = jax.lax.sort(
@@ -523,19 +530,96 @@ def smallgroup_partial_states(
     return out, group_rows
 
 
+def combine_state(func: str, a, b):
+    """Two states of one group met: the ONE place the per-function combine
+    lives (dense states, scalar states, the ordered aggregate's carried
+    group). A state that saw no value must hold the function's identity
+    (state_identity), as every partial here leaves it."""
+    if func in ("sum", "count", "count_rows"):
+        return a + b
+    if func == "min":
+        return jnp.minimum(a, b)
+    if func in ("max", "any_not_null"):
+        return jnp.maximum(a, b)
+    if func == "bool_and":
+        return a & b
+    if func == "bool_or":
+        return a | b
+    raise ValueError(func)
+
+
+def state_identity(func: str, dtype):
+    """What combine_state leaves unchanged."""
+    if func in ("sum", "count", "count_rows"):
+        return jnp.zeros((), dtype)
+    if func in ("bool_and", "bool_or"):
+        return jnp.array(func == "bool_and", dtype)
+    return _minmax_sentinel(dtype, func == "min")
+
+
 def merge_dense_states(specs: tuple[AggSpec, ...], acc, new):
     """Elementwise merge of positionally-aligned dense states."""
-    out = []
-    for spec, (ad, av), (nd, nv) in zip(specs, acc, new):
-        if spec.func in ("sum", "count", "count_rows"):
-            out.append((ad + nd, av | nv))
-        elif spec.func == "min":
-            out.append((jnp.minimum(ad, nd), av | nv))
-        elif spec.func in ("max", "any_not_null"):
-            out.append((jnp.maximum(ad, nd), av | nv))
-        else:
-            raise ValueError(spec.func)
-    return out
+    return [(combine_state(spec.func, ad, nd), av | nv)
+            for spec, (ad, av), (nd, nv) in zip(specs, acc, new)]
+
+
+def stitch_ordered_partial(part: Batch, num_groups, carry: Batch,
+                           schema: Schema, group_cols: tuple[int, ...],
+                           specs: tuple[AggSpec, ...], col_stats: dict):
+    """Meet one tile's presorted partial states with the group the tiles
+    before it left open — the orderedAggregator's carry across batches.
+
+    Input clustered on the group keys cuts at most one group at each tile
+    edge, so of ``part`` (sort_groupby(presorted=True): ``num_groups`` live
+    rows in arrival order, state layout ``schema``, merge specs ``specs``)
+    every group is final but the first, which may continue ``carry`` (one
+    row, live or not), and the last, which the next tile may continue.
+
+    Returns (closed, carry'): ``closed`` is ``part`` with the carried group
+    combined into row 0 where the keys are equal (packed-word equality as
+    sort_groupby compares, NULL = NULL), its last group masked out, and in
+    that vacated row the carried group where it did NOT continue (it
+    ended with the tile before); ``carry'`` is the last group after the
+    combine, or ``carry`` itself when the tile has no live row. All
+    elementwise at the tile's capacity: no sort, scatter or gather."""
+    k = len(group_cols)
+    head = jax.tree_util.tree_map(lambda x: x[:1], part)
+    same = carry.mask[0] & head.mask[0]
+    for a, b in zip(_packed_group_keys(carry, schema, group_cols, col_stats),
+                    _packed_group_keys(head, schema, group_cols, col_stats)):
+        same = same & (a[0] == b[0])
+    has = num_groups > 0
+    last = num_groups - 1
+    at = jnp.maximum(last, 0)
+    idx = jnp.arange(part.capacity, dtype=jnp.int32)
+    stitch = (idx == 0) & same
+    # the carried group ended before this tile: it takes the vacated row
+    put = (idx == last) & carry.mask[0] & ~same
+    mask = (idx < last) | put
+
+    def rows(m, like):  # BYTES keys are [capacity, W]
+        return m.reshape(m.shape + (1,) * (like.ndim - 1))
+
+    closed, open_row = [], []
+    for i, (col, c) in enumerate(zip(part.cols, carry.cols)):
+        data, valid = col.data, col.valid
+        if i >= k:
+            func = specs[i - k].func
+            zero = state_identity(func, data.dtype)
+            met = combine_state(func, jnp.where(c.valid, c.data, zero),
+                                jnp.where(valid[:1], data[:1], zero))
+            data = jnp.where(rows(stitch, data), met, data)
+            valid = jnp.where(stitch, c.valid | valid[:1], valid)
+        open_row.append(Column(
+            data=jnp.where(has, jax.lax.dynamic_slice_in_dim(data, at, 1),
+                           c.data),
+            valid=jnp.where(has, jax.lax.dynamic_slice_in_dim(valid, at, 1),
+                            c.valid)))
+        closed.append(Column(
+            data=jnp.where(rows(put, data), c.data, data),
+            valid=jnp.where(put, c.valid, valid) & mask))
+    return (Batch(cols=tuple(closed), mask=mask),
+            Batch(cols=tuple(open_row), mask=carry.mask | has))
 
 
 # ---------------------------------------------------------------------------
@@ -734,24 +818,14 @@ def scalar_merge_states(aggs: tuple[AggSpec, ...], acc, new):
     for spec, (a, av), (n, nv) in zip(aggs, acc, new):
         if spec.func in ("count", "count_rows"):
             out.append((a + n, jnp.bool_(True)))
-        elif spec.func == "sum":
-            out.append((a + n, av | nv))
         elif spec.func == "avg":
             out.append(((a[0] + n[0], a[1] + n[1]), av | nv))
         elif spec.func in STAT_FUNCS:
             cnt = a[2] + n[2]
             ok = cnt > 0 if spec.func.endswith("_pop") else cnt > 1
             out.append(((a[0] + n[0], a[1] + n[1], cnt), ok))
-        elif spec.func == "min":
-            out.append((jnp.minimum(a, n), av | nv))
-        elif spec.func == "max":
-            out.append((jnp.maximum(a, n), av | nv))
-        elif spec.func == "bool_and":
-            out.append((a & n, av | nv))
-        elif spec.func == "bool_or":
-            out.append((a | n, av | nv))
         else:
-            raise ValueError(spec.func)
+            out.append((combine_state(spec.func, a, n), av | nv))
     return out
 
 

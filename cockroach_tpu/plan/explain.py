@@ -30,8 +30,11 @@ def _node_label(n: S.PlanNode, op=None) -> str:
         mode = f" mode={n.mode}" if n.mode != "complete" else ""
         dense = " dense" if n.key_sizes else ""
         # the route the operator takes: its input arrives clustered on the
-        # group keys, so a tile is grouped without a key sort
-        ordered = " (ordered)" if getattr(op, "ordered", False) else ""
+        # group keys, so a tile is grouped without a key sort, and (a
+        # complete aggregate) leaves at once with one open group carried
+        ordered = ("" if not getattr(op, "ordered", False)
+                   else " (ordered, streaming)" if op.streaming
+                   else " (ordered)")
         return (f"group-by keys={list(n.group_cols)} aggs={aggs}{mode}{dense}"
                 f"{ordered}")
     if isinstance(n, S.ScalarAggregate):
@@ -124,7 +127,7 @@ def _operators_of(plan: S.PlanNode, root_op) -> dict[int, object]:
 def explain_plan(plan: S.PlanNode, catalog=None) -> str:
     """Render the plan tree (EXPLAIN). With the catalog, the operator tree
     is built (not run) beside it, so a line can name the route its
-    operator takes (a group-by's `(ordered)`)."""
+    operator takes (a group-by's `(ordered)` or `(ordered, streaming)`)."""
     lines: list[str] = []
     groups = _fusion_groups(plan)
     operators: dict[int, object] = {}

@@ -75,6 +75,16 @@ BUDGETS = {
 # emitting and compacting for itself adds one a tile (the parent of PR 29
 # read 14), so the budget has no slack.
 BUDGET_Q9_COMPACT = 10
+# q18 on the route the chip takes at SF1, where 1.5M order keys pass the
+# dense aggregate's state budget (`sql.distsql.dense_agg_states` at its
+# floor here): the subquery's ordered GROUP BY streams (PR 33). Read 29:
+# q18's 23 with the subquery's 6 fold + finalize replaced by 6
+# hashagg_stream_fused (one a lineitem tile: partial, carried group,
+# finalize) + hashagg_stream_tail, and pipe_project_build_spool once a
+# streamed tile (7) where it ran once over the merged tile. The parent of
+# PR 33 read 24 (6 partials + merge + finalize, one build spool); a
+# hashagg_merge or a finalize of its own coming back adds to 29.
+BUDGET_Q18_STREAMED = 29
 # ONE fused pre-aggregation kernel per extra input tile (acceptance
 # criterion of the fusion work; read exactly 1.0) — the accumulator merge
 # rides inside the fold step kernel. The unfused engine pays 5.
@@ -86,7 +96,7 @@ BUDGET_PER_TILE = 1.0
 # to this accounting).
 BUDGET_SPMD = 2
 
-CASES = (*BUDGETS, "q1_per_tile", "q9_compact", "spmd")
+CASES = (*BUDGETS, "q18_streamed", "q1_per_tile", "q9_compact", "spmd")
 
 
 def catalog(sf: float = _SF):
@@ -178,6 +188,20 @@ def case(name: str, cat=None) -> list[str]:
                     "into its consumer"]
         return []
     cat = cat if cat is not None else catalog()
+    if name == "q18_streamed":
+        from cockroach_tpu.utils import settings
+
+        settings.set("sql.distsql.dense_agg_states", 64)
+        try:
+            got = _steady_dispatches(cat, _TILE, "q18")
+        finally:
+            settings.reset("sql.distsql.dense_agg_states")
+        if got > BUDGET_Q18_STREAMED:
+            return [f"q18 (ordered GROUP BY streaming) steady-state kernel "
+                    f"dispatches {got} exceed the recorded budget "
+                    f"{BUDGET_Q18_STREAMED} — the ordered aggregate spools "
+                    "and merges again, or finalizes in a launch of its own"]
+        return []
     if name == "q1_per_tile":
         tiles = -(-cat.get("lineitem").num_rows // _TILE)
         steady = _steady_dispatches(cat, _TILE, "q1")
@@ -212,7 +236,8 @@ def main() -> int:
     if not problems:
         print("dispatch budget clean: "
               + ", ".join(f"{q} within {b}" for q, b in BUDGETS.items())
-              + f", q9 compacting within {BUDGET_Q9_COMPACT}, "
+              + f", q18 streaming within {BUDGET_Q18_STREAMED}"
+              f", q9 compacting within {BUDGET_Q9_COMPACT}, "
               f"{BUDGET_PER_TILE} a tile, distributed plan within "
               f"{BUDGET_SPMD}")
     return 1 if problems else 0

@@ -4,8 +4,10 @@ flow, held to the benchmark's float64 pandas reference
 (benchmarks/oracles/tpch_q18.py). The IN-subquery's semi-join filters
 `orders` below both joins, so `lineitem` is joined with the kept orders
 only; the subquery's GROUP BY l_orderkey takes the ordered (sort-free)
-route over the clustered `lineitem`; a new QUANTITY is a plan-cache hit
-that compiles nothing; the tags the cell's per-layer metrics read.
+route over the clustered `lineitem` and streams (PR 33: a tile's closed
+groups leave at once, the group on the tile's edge is carried, nothing is
+spooled or merged); a new QUANTITY is a plan-cache hit that compiles
+nothing; the tags the cell's per-layer metrics read.
 
 The dense scatter aggregate is what the CPU picks for 15,000 order keys;
 the chip at SF1 (1.5M keys over a budget of 524,288 states) takes
@@ -29,8 +31,8 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 BENCH = os.path.join(ROOT, "benchmarks")
 Q18 = " ".join(TPCH_SQL["q18"].split()).replace("> 300", "> {quantity}")
 SEED = 2**31 + 32
-TAGS = ("agg_ordered_tiles", "agg_merge_rows", "agg_spills",
-        "semijoin_probe_tile_rows", "join_probe_tile_rows",
+TAGS = ("agg_ordered_tiles", "agg_streamed_tiles", "agg_merge_rows",
+        "agg_spills", "semijoin_probe_tile_rows", "join_probe_tile_rows",
         "join_unique_tiles", "join_general_tiles", "join_late_emit_tiles")
 
 
@@ -143,10 +145,12 @@ def test_the_semi_join_filters_orders_below_both_joins(cat):
     assert lines[i + 3].startswith("-> scan orders")
     assert lines[i + 4] == "-> project ['l_orderkey']"
     assert lines[i + 5].startswith("-> filter Cmp(op='gt'")
-    # the subquery groups the clustered lineitem without a key sort; the
-    # outer group-by, over joined rows, does not
+    # the subquery groups the clustered lineitem without a key sort and
+    # without a spool; the outer group-by, over joined rows, does not
     groups = [ln for ln in lines if ln.startswith("-> group-by")]
-    assert [ln.endswith("(ordered)") for ln in groups] == [False, True]
+    assert [ln.endswith("(ordered, streaming)") for ln in groups] == [
+        False, True]
+    assert "(ordered" not in groups[0]
     assert lines[-1].startswith("-> scan customer")
 
 
@@ -168,13 +172,14 @@ def _pulls():
 
 def test_the_tags_the_cells_metrics_read(sess, settled):
     """One tile a table at SF0.01 and the default tile size: the subquery's
-    one lineitem tile is grouped presorted and never merged; the semi-join
+    one lineitem tile is grouped presorted, streamed and never merged (the
+    outer aggregate's one partial needs no merge either); the semi-join
     is handed orders' one tile; the lineitem join cuts its tile to the cap
     learned from a dozen orders' lines before it gathers a build column."""
     t0 = _tags()
     sess.execute(Q18.format(quantity=260))
     d = _delta(t0)
-    assert d["agg_ordered_tiles"] == 1
+    assert d["agg_ordered_tiles"] == 1 and d["agg_streamed_tiles"] == 1
     assert d["agg_merge_rows"] == 0 and d["agg_spills"] == 0
     orders_tile = d["semijoin_probe_tile_rows"]
     assert orders_tile >= sess.catalog.get("orders").num_rows
@@ -226,11 +231,12 @@ def _spy_merge_caps(monkeypatch):
 
 def test_the_tags_count_every_tile_and_every_merge(fresh, small_tiles,
                                                    monkeypatch):
-    """1,024-row tiles: 59 lineitem tiles grouped presorted, 15 orders tiles
-    handed to the semi-join, and `agg_merge_rows` is the sum of the static
-    caps the hashagg_merge launches ran at (the subquery's 15,000 groups
-    merge once, at the shape ladder's 65,536; the outer aggregate's few
-    hundred rows at 1,024)."""
+    """1,024-row tiles: 59 lineitem tiles grouped presorted and streamed,
+    15 orders tiles handed to the semi-join, and `agg_merge_rows` is the sum
+    of the static caps the hashagg_merge launches ran at: the subquery's
+    15,000 groups are never merged (the parent of PR 33 merged them once,
+    at the shape ladder's 65,536), only the outer aggregate's few hundred
+    rows, at 1,024."""
     cat, host = fresh
     caps = _spy_merge_caps(monkeypatch)
     s = Session(cat)
@@ -243,16 +249,29 @@ def test_the_tags_count_every_tile_and_every_merge(fresh, small_tiles,
     _assert_answer(got, _reference(host, 250))
     tiles = -(-cat.get("lineitem").num_rows // small_tiles)
     assert d["agg_ordered_tiles"] == tiles == 59
+    assert d["agg_streamed_tiles"] == 59
     assert d["semijoin_probe_tile_rows"] == 15 * small_tiles
-    assert caps == [65536, 1024] and d["agg_merge_rows"] == sum(caps)
+    assert caps == [1024] and d["agg_merge_rows"] == sum(caps)
     assert d["agg_spills"] == 0 and d["join_general_tiles"] == 0
 
 
-def test_a_spool_over_its_budget_spills_and_is_counted(fresh):
-    """15,000 groups against 8,192 rows of work memory: the merged partials
-    do not fit, the spool goes to the Grace aggregator, the answer stands
-    and `agg_spills` says so."""
+@pytest.mark.parametrize("clustered", [False, True],
+                         ids=["unordered_spills", "ordered_streams"])
+def test_a_spool_over_its_budget_spills_and_is_counted(fresh, clustered,
+                                                       monkeypatch):
+    """15,000 groups against 8,192 rows of work memory. With
+    `Table.ordering` cleared the merged partials do not fit, the spool goes
+    to the Grace aggregator, the answer stands and `agg_spills` says so.
+    Clustered, the aggregate holds one tile and one row whatever the
+    budget: no spool is opened, nothing spills, every tile streams."""
     cat, host = fresh
+    if not clustered:
+        cat.get("lineitem").ordering = ()
+    spooled = []
+    real = operators.AggregateOp._spool
+    monkeypatch.setattr(
+        operators.AggregateOp, "_spool",
+        lambda self: (spooled.append(self.ordered), real(self))[1])
     settings.set("sql.distsql.workmem_rows", 8192)
     s = Session(cat)
     try:
@@ -262,8 +281,12 @@ def test_a_spool_over_its_budget_spills_and_is_counted(fresh):
     finally:
         s.close()
     _assert_answer(got, _reference(host, 250))
-    assert d["agg_spills"] == 1
-    assert 0 < d["agg_ordered_tiles"] < 59  # the rest went to Grace unspooled
+    if clustered:
+        assert d["agg_spills"] == 0 and d["agg_streamed_tiles"] == 59
+        assert spooled == [False]  # the outer aggregate's, not the subquery's
+    else:
+        assert d["agg_spills"] == 1 and spooled == [False, False]
+        assert d["agg_ordered_tiles"] == d["agg_streamed_tiles"] == 0
 
 
 @pytest.mark.parametrize("tile", [1024, 1 << 20])
@@ -304,8 +327,12 @@ def test_ordered_and_unordered_aggregates_agree_bit_for_bit(cat, tile):
 
 def test_a_group_that_straddles_a_tile_edge_is_summed_whole(small_tiles):
     """An order whose lines lie on both sides of a 1,024-row tile edge, each
-    side's quantity under the threshold and their sum over it: its two
-    partial groups meet in the merge, and the order is in the answer."""
+    side's quantity under the threshold and their sum over it: its left half
+    leaves the first tile's kernel as the carried row and meets the right
+    half in slot 0 of the next tile's (ops/aggregation.py
+    stitch_ordered_partial; before PR 33 the two partial groups met in
+    hashagg_merge), so the HAVING above sees the group once, whole, and the
+    order is in the answer."""
     cat = tpch.gen_tpch(sf=0.01, seed=SEED)
     li = cat.get("lineitem")
     keys = np.asarray(li.columns["l_orderkey"])

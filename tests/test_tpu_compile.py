@@ -143,6 +143,48 @@ def test_packed_key_sort_compiles(one_chip):
     assert compiled.as_text()
 
 
+def test_streaming_ordered_aggregate_tile_kernel_compiles(one_chip,
+                                                         monkeypatch):
+    """AggregateOp's per-tile kernel in streaming mode (presorted partial,
+    the carried group met with it, finalize) and its tail, on the branch an
+    accelerator traces (segmented scans; `use_scans` asks the default
+    backend, which is the CPU here, so the test steers it). Kept small: the
+    kernel took 115.8 s to compile at 1 << 20 rows in this sandbox against
+    the partial's 110.5 s (a scratch script, PR 33), 2.3 s at 4,096."""
+    from cockroach_tpu.catalog import Catalog, Table
+    from cockroach_tpu.coldata import DECIMAL, INT64, Schema
+    from cockroach_tpu.coldata.batch import empty_batch
+    from cockroach_tpu.ops import segscan
+    from cockroach_tpu.plan import builder
+    from cockroach_tpu.sql.rel import Rel
+
+    monkeypatch.setattr(segscan, "use_scans", lambda: True)
+    n = 5000
+    cat = Catalog()
+    cat.add(Table.from_strings(
+        "li", Schema.of(k=INT64, q=DECIMAL(12, 2)),
+        {"k": np.arange(n, dtype=np.int64) // 4 * 7_000_003,
+         "q": np.arange(n, dtype=np.int64)}, ordering=("k",)))
+    op = builder.build(
+        Rel.scan(cat, "li").groupby(["k"], [("s", "sum", "q"),
+                                            ("a", "avg", "q")]).plan, cat)
+    assert op.streaming
+    op.init()
+
+    def described(batch, rows):
+        return jax.tree.map(
+            lambda x: jax.ShapeDtypeStruct((rows,) + x.shape[1:], x.dtype,
+                                           sharding=one_chip), batch)
+
+    tile = described(empty_batch(op.base_schema, 8), 4096)
+    carry = described(op._no_carry, 1)
+    out, carried = jax.eval_shape(op._stream_fn._jitted, tile, carry)
+    assert out.capacity == 4096 and carried.capacity == 1
+    text = op._stream_fn._jitted.lower(tile, carry).compile().as_text()
+    assert "scatter" not in text  # the stitch is elementwise, as the scans are
+    assert op._stream_tail_fn._jitted.lower(carry).compile().as_text()
+
+
 def test_described_devices_are_not_attached(topo):
     """The process still runs on the CPU mesh: describing a chip must not
     change what jax.devices() reports to the rest of the suite."""
