@@ -242,6 +242,9 @@ def gen_tpch(sf: float = 0.01, seed: int = 19920101,
             ],
             "c_comment": comments(n_cust),
         },
+        # dbgen emits every table in primary-key order; c_custkey is
+        # np.arange above
+        ordering=("c_custkey",),
     ))
 
     # orders: only customers with custkey % 3 != 0 place orders (spec)

@@ -2080,6 +2080,7 @@ class HashJoinOp(OneInputOperator):
                 out, cnt = kern(t, *args)
                 self._emit_counts.append(cnt)
                 self._note_tilecap(out, t, src)
+                self._note_emit_tile(out)
                 yield out
             return
         kern = None
@@ -2095,7 +2096,18 @@ class HashJoinOp(OneInputOperator):
             out, cnt = kern(b, self._build_batch, self._index)
             self._emit_counts.append(cnt)
             self._note_tilecap(out, b)
+            self._note_emit_tile(out)
             yield out
+
+    def _note_emit_tile(self, out: Batch) -> None:
+        """The static capacity of a tile hash_join_general emitted (a join
+        whose build key may repeat: general mode, or the per-tile retry of
+        _next) into the pull span's ``join_emit_tile_rows``: the rows every
+        consumer kernel of the tile pays for, whatever the join matched.
+        Says which learned cap ran (host-known, no sync)."""
+        sp = tracing.current()
+        if sp is not None and not self._fusable:
+            sp.inc_tag("join_emit_tile_rows", out.capacity)
 
     def _note_tilecap(self, out: Batch, t, src=None) -> None:
         """The widest probe tile seen, for post_run_update to judge a cap
@@ -2205,6 +2217,7 @@ class HashJoinOp(OneInputOperator):
                 p, self._build_batch, self._index, out_cap=self._out_cap
             )
             if int(total) <= self._out_cap:
+                self._note_emit_tile(out)
                 return out
             self._out_cap = _canonical_cap(int(total))
 

@@ -169,6 +169,11 @@ def run_operator(root) -> dict[str, np.ndarray]:
                 outs: list[dict[str, np.ndarray]] = []
                 shrink = _ReadbackShrink()
                 with tracing.leaf_span("flow/pull", attempt=attempt) as psp:
+                    if attempt and psp is not None:
+                        # only a join's emission cap (general or compact)
+                        # that overflowed sends a statement round again
+                        # (HashJoinOp.post_run_update)
+                        psp.add_tag("join_overflow_reruns", 1)
                     root.init()
                     if overlap:
                         # one-tile lag: materialize tile k (blocking host

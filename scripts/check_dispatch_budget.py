@@ -66,6 +66,12 @@ BUDGETS = {
     # (topk_fold_seed + limit_tile). 22 with the semi-join on top of the
     # joined rows (PR 30): one orders tile more is one spool more
     "q18": 23,
+    # the one text whose join runs hash_join_general (PR 34): orders' 2
+    # pipe_filter_build_spool (the NOT LIKE table bound), hashjoin_build
+    # (the sorted index of a build key that repeats), customer's one tile
+    # through hashjoin_emit in general mode, the dense aggregate's fold
+    # seed + finalize, the outer aggregate's partial + finalize, 2 sort
+    "q13": 10,
 }
 # q9 where the joins DO compact (sf 0.01, one lineitem tile at the default
 # tile size): the part join cuts the tile to its cap and emits; the four
