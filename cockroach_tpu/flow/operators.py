@@ -1961,7 +1961,9 @@ class HashJoinOp(OneInputOperator):
     def _note_probe_tile(self, t, src=None, composed=False) -> None:
         """One probe tile into the pull span's ``join_unique_tiles`` (served
         by a unique-build strategy: analytic, LUT, sorted-unique) or
-        ``join_general_tiles`` (by hash_join_general), and its capacity
+        ``join_general_tiles`` (by hash_join_general; under an exact packed
+        key, where that emits by run expansion and runs no loop, also into
+        ``join_expanded_tiles``), and its capacity
         into ``join_probe_tile_rows`` (rows the probe pays for, live or
         dead: known on the host, no sync; a semi or anti join's also into
         ``semijoin_probe_tile_rows``), beside the dispatch tags;
@@ -1981,6 +1983,8 @@ class HashJoinOp(OneInputOperator):
         unique = self._probe_raw is not None and (
             self.spec.build_unique or self._probe_kind != "sorted")
         sp.inc_tag("join_unique_tiles" if unique else "join_general_tiles", 1)
+        if not unique and self.exact_layout is not None:
+            sp.inc_tag("join_expanded_tiles", 1)
         rows = _tile_rows(t, src)
         sp.inc_tag("join_probe_tile_rows", rows)
         if self.spec.join_type in ("semi", "anti"):

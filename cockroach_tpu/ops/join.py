@@ -10,11 +10,17 @@ loop. Two probe paths:
 - ``hash_join_unique``: build keys are unique (FK->PK joins — most TPC-H
   joins). Output is probe-aligned, fully static shapes: inner / left-outer /
   semi / anti.
-- ``hash_join_general``: duplicate build keys; per-probe match counts + a
-  bounded emission loop into a caller-sized output tile (capacity bucketing:
-  the host re-invokes with the next power-of-two capacity on overflow —
-  reported via the returned total). This mirrors how the reference's probe
-  emits variable-size output batches per input batch.
+- ``hash_join_general``: duplicate build keys; per-probe match counts and
+  an emission into a caller-sized output tile (capacity bucketing: the host
+  re-invokes with the next power-of-two capacity on overflow — reported via
+  the returned total). This mirrors how the reference's probe emits
+  variable-size output batches per input batch. Two emissions, chosen by
+  the key the caller planned: under an EXACT packed key a probe row's
+  matches are the run [lo, hi) of the sorted build index, so the count is
+  hi - lo and the output is a run-length expansion (``expand_runs``: no
+  loop, one scatter); under a 64-bit HASH a run may hold a collision, so a
+  loop of max-run iterations verifies the key columns, counts, and
+  scatters the matches out.
 
 SQL semantics: NULL join keys never match (NULL != NULL); anti-join keeps
 NULL-key probe rows (NOT EXISTS semantics, matching CRDB's anti join).
@@ -489,21 +495,37 @@ def hash_join_general(
 ):
     """General join (duplicate build keys). Returns (out_batch, total_rows);
     if total_rows > out_capacity the caller must retry with a larger tile
-    (capacity bucketing keeps shapes static per bucket)."""
-    cap = probe.capacity
-    bcap = build.capacity
+    (capacity bucketing keeps shapes static per bucket).
+
+    With an exact_layout the sorted index holds every live, key-equal build
+    row of an active probe row in its run [lo, hi), and nothing else (dead
+    and NULL-key build rows carry the sentinel): the count is hi - lo and
+    the emission is `expand_runs`, straight-line. Without one the index is
+    sorted by a 64-bit hash and a run may hold a collision: a loop of
+    max-run iterations compares the key columns to count the matches, and
+    a second one scatters them out. The two lay the same rows out in the
+    same slots."""
     sh, order = index if index is not None else build_index(
         build, build_schema, build_keys, build_hash_tables,
         exact_layout=exact_layout, exact_remaps=exact_remaps,
     )
     if exact_layout is not None:
         ph, p_active = exact_keys(probe, probe_keys, exact_layout)
-        phs = ph
-    else:
-        ph, p_active = _key_hashes(
-            probe, probe_keys, probe_schema, probe_hash_tables
-        )
-        phs = jnp.where(p_active, ph, _SENTINEL)
+        lo = bsearch(sh, ph, side="left")
+        hi = bsearch(sh, ph, side="right")
+        cnt = jnp.where(p_active, hi - lo, 0)
+        if spec.join_type == "semi":
+            return probe.with_mask(probe.mask & (cnt > 0)), jnp.sum(cnt > 0)
+        if spec.join_type == "anti":
+            return probe.with_mask(probe.mask & (cnt == 0)), jnp.sum(cnt == 0)
+        return expand_runs(probe, build, spec, lo, cnt, order, out_capacity)
+
+    cap = probe.capacity
+    bcap = build.capacity
+    ph, p_active = _key_hashes(
+        probe, probe_keys, probe_schema, probe_hash_tables
+    )
+    phs = jnp.where(p_active, ph, _SENTINEL)
     lo = bsearch(sh, phs, side="left")
     hi = bsearch(sh, phs, side="right")
     run = jnp.where(p_active, hi - lo, 0)
@@ -513,9 +535,6 @@ def hash_join_general(
         posc = jnp.clip(lo + k, 0, bcap - 1)
         bidx = order[posc]
         valid_k = (k < run) & p_active & build.mask[bidx]
-        if exact_layout is not None:
-            # packed-key equality is exact: the [lo, hi) run IS the match set
-            return bidx, valid_k
         return bidx, valid_k & _keys_equal(
             probe, probe_keys, build, build_keys, bidx, build_code_remaps
         )
@@ -581,6 +600,73 @@ def hash_join_general(
         for c in build.cols
     )
     return Batch(cols=pcols + bcols, mask=out_live), total
+
+
+_OWNER_ROW = 1024
+
+
+def _slot_owner(out_rows, base, out_capacity: int):
+    """Which probe row owns each output slot, when row i owns the
+    ``out_rows[i]`` slots from ``base[i]`` (its exclusive prefix) on: every
+    row that owns a slot writes its index at its first one (ONE scatter;
+    the destinations are distinct and rise with the row, those at or past
+    the capacity drop), and a running maximum carries it over the rest of
+    the run. Slots past the last run read the last owner: the caller masks
+    them by the total.
+
+    The running maximum goes in two levels, inside rows of 1,024 slots and
+    then over the rows' last slots (row indices are >= 0, so 0 is its
+    identity): one `lax.cummax` over 2,097,152 slots costs the chip's
+    compiler 24 s against half a second, and q13's launch a tenth more
+    (PERF.md section 6, PR 36)."""
+    rows = jnp.arange(out_rows.shape[0], dtype=jnp.int32)
+    padded = -(-out_capacity // _OWNER_ROW) * _OWNER_ROW
+    first = jnp.where(out_rows > 0, base, padded)
+    heads = jnp.zeros((padded,), jnp.int32).at[first].set(rows, mode="drop")
+    inner = jax.lax.cummax(heads.reshape(-1, _OWNER_ROW), axis=1)
+    before = jnp.concatenate(
+        [jnp.zeros((1,), jnp.int32), jax.lax.cummax(inner[:-1, -1])])
+    return jnp.maximum(inner, before[:, None]).reshape(-1)[:out_capacity]
+
+
+def expand_runs(probe: Batch, build: Batch, spec: JoinSpec, lo, cnt, order,
+                out_capacity: int):
+    """Emit an inner or left join whose probe row i matches the ``cnt[i]``
+    build rows ``order[lo[i]] .. order[lo[i] + cnt[i] - 1]`` (a contiguous
+    run of a sorted build index; cnt is 0 for a row that must not match):
+    (out_batch, total_rows). The m-th match of row i lands in slot
+    base[i] + m, base the exclusive prefix of the rows each probe row
+    emits; a live LEFT row with no match emits one slot with its build
+    side NULL. A run-length expansion: the owner of each slot
+    (`_slot_owner`), then gathers; no loop, whatever the longest run. The
+    first ``out_capacity`` rows are kept and the returned total is the
+    true one, so a caller sees an overflow."""
+    left = spec.join_type == "left"
+    out_rows = jnp.where(left & probe.mask, jnp.maximum(cnt, 1), cnt)
+    # exclusive prefix
+    base = (jnp.cumsum(out_rows) - out_rows).astype(jnp.int32)
+    total = jnp.sum(out_rows)
+
+    slot = jnp.arange(out_capacity, dtype=jnp.int32)
+    owner = _slot_owner(out_rows, base, out_capacity)
+    live = slot < total
+    # slot j of owner i is its match number j - base[i]: found while that
+    # is under cnt[i], at sorted position lo[i] + j - base[i]
+    found = live & (slot < (base + cnt)[owner])
+    pos = slot + (lo - base)[owner]
+    out_pidx = jnp.where(live, owner, 0)
+    out_bidx = jnp.where(
+        found, order[jnp.clip(pos, 0, build.capacity - 1)], 0)
+
+    pcols = tuple(
+        Column(data=c.data[out_pidx], valid=c.valid[out_pidx] & live)
+        for c in probe.cols
+    )
+    bcols = tuple(
+        Column(data=c.data[out_bidx], valid=c.valid[out_bidx] & found)
+        for c in build.cols
+    )
+    return Batch(cols=pcols + bcols, mask=live), total
 
 
 def join_output_schema(

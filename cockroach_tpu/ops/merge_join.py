@@ -7,7 +7,8 @@ vectorized binary search over order-preserving uint64 key lanes
 (sort_ops.order_keys): with EXACT keys (not hashes) there are no
 collisions, so each probe row's match run is just [searchsorted left,
 searchsorted right) in the build tile — no advance loop at all. Duplicate
-handling reuses the count+emit pattern of the hash join.
+keys emit through the exact-key hash join's run-length expansion
+(join.expand_runs): the active matches of a run are contiguous.
 
 Composite keys compare lexicographically: the build side sorts on all key
 lanes at once (multi-operand lax.sort), and the probe's binary search
@@ -22,9 +23,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..coldata.batch import Batch, Column
+from ..coldata.batch import Batch
 from ..coldata.types import Schema
-from .join import JoinSpec
+from .join import JoinSpec, expand_runs
 
 _SENTINEL = np.uint64(0xFFFFFFFFFFFFFFFF)
 
@@ -178,8 +179,6 @@ def merge_join(  # crlint: allow-mem-accounting(traced kernel: buffers are XLA t
     ordered keys, compared lexicographically)."""
     pkeys = _norm_keys(probe_key)
     bkeys = _norm_keys(build_key)
-    cap = probe.capacity
-    bcap = build.capacity
     if build_index is None:
         build_index = build_merge_index(
             build, build_schema, bkeys, build_rank_table
@@ -192,57 +191,11 @@ def merge_join(  # crlint: allow-mem-accounting(traced kernel: buffers are XLA t
     # count only ACTIVE build rows in the run (dead/NULL rows share the key
     # lanes of inactive rows and sort to the run's tail)
     cnt = jnp.where(p_active, prefix[hi] - prefix[lo], 0)
-    max_run = jnp.max(cnt)
-
     if spec.join_type == "semi":
         return probe.with_mask(probe.mask & (cnt > 0)), jnp.sum(cnt > 0)
     if spec.join_type == "anti":
         return probe.with_mask(probe.mask & (cnt == 0)), jnp.sum(cnt == 0)
-
-    left = spec.join_type == "left"
-    out_rows = jnp.where(left & probe.mask, jnp.maximum(cnt, 1), cnt)
-    base = jnp.cumsum(out_rows) - out_rows
-    total = jnp.sum(out_rows)
-
-    OC = out_capacity
-    out_pidx = jnp.zeros((OC,), jnp.int32)
-    out_bidx = jnp.zeros((OC,), jnp.int32)
-    out_found = jnp.zeros((OC,), jnp.bool_)
-    out_live = jnp.zeros((OC,), jnp.bool_)
-    if left:
-        unmatched = probe.mask & (cnt == 0)
-        dest0 = jnp.where(unmatched, base.astype(jnp.int32), OC)
-        out_pidx = out_pidx.at[dest0].set(
-            jnp.arange(cap, dtype=jnp.int32), mode="drop")
-        out_live = out_live.at[dest0].set(True, mode="drop")
-
-    def emit_body(state):
-        k, op, ob, of, ol = state
-        m = k < cnt
-        posc = jnp.clip(lo + k, 0, bcap - 1)
-        bidx = order[posc]
-        dest = jnp.where(m, (base + k).astype(jnp.int32), OC)
-        op = op.at[dest].set(jnp.arange(cap, dtype=jnp.int32), mode="drop")
-        ob = ob.at[dest].set(bidx, mode="drop")
-        of = of.at[dest].set(True, mode="drop")
-        ol = ol.at[dest].set(True, mode="drop")
-        return k + 1, op, ob, of, ol
-
-    _, out_pidx, out_bidx, out_found, out_live = jax.lax.while_loop(
-        lambda s: s[0] < max_run,
-        emit_body,
-        (jnp.int32(0), out_pidx, out_bidx, out_found, out_live),
-    )
-
-    pcols = tuple(
-        Column(data=c.data[out_pidx], valid=c.valid[out_pidx] & out_live)
-        for c in probe.cols
-    )
-    bcols = tuple(
-        Column(data=c.data[out_bidx], valid=c.valid[out_bidx] & out_found)
-        for c in build.cols
-    )
-    return Batch(cols=pcols + bcols, mask=out_live), total
+    return expand_runs(probe, build, spec, lo, cnt, order, out_capacity)
 
 
 def build_merge_index(build: Batch, schema: Schema, key, rank_table=None):  # crlint: allow-mem-accounting(traced kernel: index lanes are shaped like the build batch the operator already charged)

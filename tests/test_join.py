@@ -132,6 +132,195 @@ def test_general_join_overflow_reports_total(rng):
     assert int(out.length()) == 2500
 
 
+# ---------------------------------------------------------------------------
+# The exact-key emission (expand_runs) against the loop it replaced
+#
+# hash_join_general lays a probe tile's output out by a prefix sum: the m-th
+# match of probe row i at slot base[i] + m. Under an ExactKeyLayout it does
+# so by a run-length expansion with no loop; without one (64-bit hashes)
+# the count loop and the emit loop stay. The same tables through both have
+# to give the same tile slot for slot, truncation included; merge_join,
+# which emits through the same helper, is held to a numpy oracle.
+
+_PSCHEMA = cd.Schema.of(pk=cd.INT64, pv=cd.INT64)
+_BSCHEMA = cd.Schema.of(bk=cd.INT64, bv=cd.INT64)
+_KEY_BITS = 6  # keys 0..63
+_LAYOUT = jn.ExactKeyLayout((("int", 0, _KEY_BITS),), _KEY_BITS)
+
+
+def _run_tables(scenario):
+    """(probe keys, key valid, live), (build keys, key valid, live): 96
+    probe rows in a 128-row tile, 200 build rows in a 256-row tile."""
+    rng = np.random.default_rng(sum(map(ord, scenario)))
+    n_p, n_b = 96, 200
+    pk = rng.integers(0, 24, n_p)
+    bk = rng.integers(0, 24, n_b)
+    pkv = np.ones(n_p, bool)
+    bkv = np.ones(n_b, bool)
+    plive = np.ones(n_p, bool)
+    blive = np.ones(n_b, bool)
+    if scenario == "mixed":  # dead rows and NULL keys on both sides
+        pkv = rng.random(n_p) > 0.15
+        bkv = rng.random(n_b) > 0.15
+        plive = rng.random(n_p) > 0.2
+        blive = rng.random(n_b) > 0.2
+        plive[0] = False  # the tile's first slot belongs to a later row
+    elif scenario == "no_match":
+        bk = bk + 32
+    elif scenario == "long_run":  # one key owns more rows than any capacity
+        bk[:] = 40 + rng.integers(0, 8, n_b)
+        bk[20:170] = 7
+        pk[3] = 7
+        pk[50] = 7
+        blive[60:70] = False  # a hole in the run
+    elif scenario == "max_run_1":  # a build key never repeats
+        bk = np.concatenate([rng.permutation(64), np.zeros(n_b - 64, np.int64)])
+        blive[64:] = False
+        pk = rng.integers(0, 64, n_p)
+    elif scenario == "max_run_48":
+        bk[:48] = 5
+        bk[48:] = rng.integers(6, 24, n_b - 48)
+        pkv = rng.random(n_p) > 0.1
+    else:
+        raise AssertionError(scenario)
+    return (pk, pkv, plive), (bk, bkv, blive)
+
+
+def _run_batches(scenario):
+    (pk, pkv, plive), (bk, bkv, blive) = _run_tables(scenario)
+    bvv = np.arange(len(bk)) % 7 != 3  # a NULL build VALUE is not a NULL key
+    p = cd.from_host(_PSCHEMA, {"pk": pk, "pv": np.arange(len(pk)) + 1000},
+                     valids={"pk": pkv}, capacity=128)
+    b = cd.from_host(_BSCHEMA, {"bk": bk, "bv": np.arange(len(bk)) * 10},
+                     valids={"bk": bkv, "bv": bvv}, capacity=256)
+    p = p.with_mask(p.mask & np.pad(plive, (0, 128 - len(pk))))
+    b = b.with_mask(b.mask & np.pad(blive, (0, 256 - len(bk))))
+    return p, b
+
+
+def _oracle_slots(scenario, join_type):
+    """Every output row in slot order: (probe row, build row or None)."""
+    (pk, pkv, plive), (bk, bkv, blive) = _run_tables(scenario)
+    slots = []
+    for i in np.flatnonzero(plive):
+        js = (np.flatnonzero(blive & bkv & (bk == pk[i])) if pkv[i] else [])
+        slots += [(i, j) for j in js]
+        if join_type == "left" and len(js) == 0:
+            slots.append((i, None))
+    return slots
+
+
+def _assert_semi_anti(out, p, slots, join_type):
+    matched = {i for i, j in slots if j is not None}
+    live = np.flatnonzero(np.asarray(p.mask))
+    want = [i for i in live if (i in matched) == (join_type == "semi")]
+    np.testing.assert_array_equal(np.flatnonzero(np.asarray(out.mask)), want)
+
+
+def _capacity(kind, n_slots):
+    return {"fits": 2048, "one": 1,
+            "truncates": max(2, min(100, n_slots // 2))}[kind]
+
+
+def _assert_same_tile(got, want):
+    np.testing.assert_array_equal(np.asarray(got.mask), np.asarray(want.mask))
+    assert len(got.cols) == len(want.cols)
+    for i, (g, w) in enumerate(zip(got.cols, want.cols)):
+        gv, wv = np.asarray(g.valid), np.asarray(w.valid)
+        np.testing.assert_array_equal(gv, wv, err_msg=f"valid of column {i}")
+        np.testing.assert_array_equal(np.asarray(g.data)[gv],
+                                      np.asarray(w.data)[wv],
+                                      err_msg=f"data of column {i}")
+
+
+_SCENARIOS = ["mixed", "no_match", "long_run", "max_run_1", "max_run_48"]
+
+
+@pytest.mark.parametrize("scenario", _SCENARIOS)
+@pytest.mark.parametrize("cap_kind", ["fits", "truncates", "one"])
+@pytest.mark.parametrize("join_type", ["inner", "left", "semi", "anti"])
+def test_exact_key_emission_equals_the_hash_loop_slot_for_slot(
+        join_type, cap_kind, scenario):
+    p, b = _run_batches(scenario)
+    slots = _oracle_slots(scenario, "left" if join_type == "anti"
+                          else join_type)
+    cap = _capacity(cap_kind, len(slots))
+    spec = jn.JoinSpec(join_type, False)
+    looped, total_l = jn.hash_join_general(
+        p, _PSCHEMA, (0,), b, _BSCHEMA, (0,), spec, cap)
+    expanded, total_e = jn.hash_join_general(
+        p, _PSCHEMA, (0,), b, _BSCHEMA, (0,), spec, cap,
+        exact_layout=_LAYOUT)
+    assert int(total_e) == int(total_l)
+    _assert_same_tile(expanded, looped)
+    if join_type in ("inner", "left"):
+        assert int(total_e) == len(slots)  # the true total, cut or not
+        assert expanded.capacity == cap
+        assert int(expanded.length()) == min(cap, len(slots))
+        if cap_kind == "truncates":
+            assert len(slots) > cap or not slots  # inner, no match
+        if scenario == "long_run":
+            run = sum(1 for i, j in slots if i == 3)
+            assert run == 140 and (cap_kind == "fits" or run > cap)
+    else:
+        _assert_semi_anti(expanded, p, slots, join_type)
+
+
+def test_the_run_scenarios_cover_what_they_name():
+    def longest(scenario):
+        by_probe = {}
+        for i, j in _oracle_slots(scenario, "inner"):
+            by_probe[i] = by_probe.get(i, 0) + 1
+        return max(by_probe.values(), default=0)
+
+    assert longest("no_match") == 0
+    assert longest("max_run_1") == 1
+    assert longest("max_run_48") == 48
+    assert longest("long_run") == 140
+    (_, pkv, plive), (_, bkv, blive) = _run_tables("mixed")
+    assert not pkv.all() and not plive.all()
+    assert not bkv.all() and not blive.all()
+
+
+@pytest.mark.parametrize("scenario", _SCENARIOS)
+@pytest.mark.parametrize("cap_kind", ["fits", "truncates", "one"])
+@pytest.mark.parametrize("join_type", ["inner", "left", "semi", "anti"])
+def test_merge_join_emission_vs_oracle_slot_for_slot(
+        join_type, cap_kind, scenario):
+    from cockroach_tpu.ops import merge_join as mj
+
+    p, b = _run_batches(scenario)
+    slots = _oracle_slots(scenario, "left" if join_type == "anti"
+                          else join_type)
+    cap = _capacity(cap_kind, len(slots))
+    out, total = mj.merge_join(p, _PSCHEMA, 0, b, _BSCHEMA, 0,
+                               jn.JoinSpec(join_type, False), cap)
+    if join_type in ("semi", "anti"):
+        _assert_semi_anti(out, p, slots, join_type)
+        return
+    assert int(total) == len(slots)
+    kept = slots[:cap]
+    mask = np.asarray(out.mask)
+    np.testing.assert_array_equal(mask, np.arange(cap) < len(kept))
+    pk, pv, bk, bv = (np.asarray(c.data)[:len(kept)] for c in out.cols)
+    pkv, pvv, bkv, bvv = (np.asarray(c.valid) for c in out.cols)
+    (tpk, tpkv, _), (tbk, _, _) = _run_tables(scenario)
+    pi = np.array([i for i, _ in kept], np.int64)
+    found = np.array([j is not None for _, j in kept], bool)
+    bj = np.array([j if j is not None else 0 for _, j in kept], np.int64)
+    np.testing.assert_array_equal(pv, pi + 1000)
+    np.testing.assert_array_equal(pvv, mask)
+    np.testing.assert_array_equal(pkv[:len(kept)], tpkv[pi])
+    np.testing.assert_array_equal(pk[tpkv[pi]], tpk[pi][tpkv[pi]])
+    # a NULL-extended row: live, its build side NULL; past the cut: nothing
+    np.testing.assert_array_equal(bkv[:len(kept)], found)
+    np.testing.assert_array_equal(bvv[:len(kept)], found & (bj % 7 != 3))
+    assert not bkv[len(kept):].any() and not bvv[len(kept):].any()
+    np.testing.assert_array_equal(bk[found], tbk[bj[found]])
+    np.testing.assert_array_equal(bv[bvv[:len(kept)]],
+                                  (bj * 10)[bvv[:len(kept)]])
+
+
 def test_string_key_join_cross_dictionary(rng):
     d1 = cd.Dictionary(np.array(["a", "b", "c"], dtype=object))
     d2 = cd.Dictionary(np.array(["c", "a"], dtype=object))

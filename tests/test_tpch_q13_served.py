@@ -34,9 +34,9 @@ INNER = ("select c_custkey, count(o_orderkey) as c_count from customer "
          "left outer join orders on c_custkey = o_custkey and o_comment "
          "not like '%{word1}%{word2}%' group by c_custkey")
 SEED = 2**31 + 34
-TAGS = ("join_general_tiles", "join_unique_tiles", "join_probe_tile_rows",
-        "join_emit_tile_rows", "join_overflow_reruns", "agg_ordered_tiles",
-        "agg_streamed_tiles", "agg_merge_rows")
+TAGS = ("join_general_tiles", "join_expanded_tiles", "join_unique_tiles",
+        "join_probe_tile_rows", "join_emit_tile_rows", "join_overflow_reruns",
+        "agg_ordered_tiles", "agg_streamed_tiles", "agg_merge_rows")
 
 
 class _Host:
@@ -186,10 +186,12 @@ def test_a_new_word_pair_compiles_nothing_and_runs_once(
 
 def test_the_tags_the_cells_metrics_read(sess, settled):
     """One tile a table at SF0.01 and the default tile size: customer's one
-    tile probes hash_join_general, which emits one tile at the learned
-    capacity (15,000 rows: the ladder's 65,536) into the dense aggregate
-    (no tile is grouped presorted); the NOT LIKE table rides as a device
-    argument; the outer aggregate's one partial needs no merge."""
+    tile probes hash_join_general (under the exact packed key the binder
+    plans for c_custkey = o_custkey, so by run expansion: PR 36), which
+    emits one tile at the learned capacity (15,000 rows: the ladder's
+    65,536) into the dense aggregate (no tile is grouped presorted); the
+    NOT LIKE table rides as a device argument; the outer aggregate's one
+    partial needs no merge."""
     t0 = _tags()
     bound = tracing.totals()["query"]["tags"]["lookup_tables_bound"]
     sess.execute(Q13.format(word1="pending", word2="accounts"))
@@ -197,6 +199,7 @@ def test_the_tags_the_cells_metrics_read(sess, settled):
     assert (tracing.totals()["query"]["tags"]["lookup_tables_bound"]
             == bound + 1)
     assert d["join_general_tiles"] == 1 and d["join_unique_tiles"] == 0
+    assert d["join_expanded_tiles"] == 1
     assert d["join_probe_tile_rows"] == 8192  # 1,500 customers' rung
     assert d["join_emit_tile_rows"] == 65536
     assert d["join_overflow_reruns"] == 0
@@ -233,7 +236,7 @@ def test_a_first_statement_that_overflows_is_run_again_and_counted(
     _assert_answer(got, _reference(host, "special", "requests"))
     assert n == 2 and d["join_overflow_reruns"] == 1
     # two customer tiles an attempt: 2 x 4,096, then 2 x 65,536
-    assert d["join_general_tiles"] == 4
+    assert d["join_general_tiles"] == d["join_expanded_tiles"] == 4
     assert d["join_emit_tile_rows"] == 2 * 4096 + 2 * 65536
     assert d2["join_overflow_reruns"] == 0 and d2["join_general_tiles"] == 2
     assert d2["join_emit_tile_rows"] == 2 * 65536

@@ -190,6 +190,54 @@ def test_streaming_ordered_aggregate_tile_kernel_compiles(one_chip,
     assert op._stream_tail_fn._jitted.lower(carry).compile().as_text()
 
 
+def test_general_join_emit_at_q13_shapes_has_no_loop(one_chip):
+    """`hash_join_general` as `hashjoin_emit` runs it for the served Q13 at
+    SF1: `customer`'s one 524,288-row probe tile (8 columns) LEFT-joined to
+    the 2,097,152-row build of `orders` (9 columns) under an exact
+    one-segment key, the build index passed in, at the first speculative
+    capacity. Under an exact key the emission is a run-length expansion:
+    the compiled program holds no `while` (the opcode, not a frame's name)
+    and one scatter, the owner index."""
+    from cockroach_tpu.coldata import DATE, DECIMAL, INT64, STRING, Schema
+    from cockroach_tpu.coldata.batch import empty_batch
+    from cockroach_tpu.ops import join as jn
+
+    dec = DECIMAL(38, 2)
+    pschema = Schema.of(c_custkey=INT64, c_name=STRING, c_address=STRING,
+                        c_nationkey=INT64, c_phone=STRING, c_acctbal=dec,
+                        c_mktsegment=STRING, c_comment=STRING)
+    bschema = Schema.of(o_orderkey=INT64, o_custkey=INT64,
+                        o_orderstatus=STRING, o_totalprice=dec,
+                        o_orderdate=DATE, o_orderpriority=STRING,
+                        o_clerk=STRING, o_shippriority=INT64,
+                        o_comment=STRING)
+    layout = jn.ExactKeyLayout((("int", 1, 18),), 18)
+    spec = jn.JoinSpec("left", False)
+    prows, brows, cap = 1 << 19, 1 << 21, 1 << 21
+
+    def described(schema, rows):
+        return jax.tree.map(
+            lambda x: jax.ShapeDtypeStruct((rows,) + x.shape[1:], x.dtype,
+                                           sharding=one_chip),
+            empty_batch(schema, 8))
+
+    def emit(p, b, index):
+        return jn.hash_join_general(p, pschema, (0,), b, bschema, (1,), spec,
+                                    cap, index=index, exact_layout=layout)
+
+    index = (jax.ShapeDtypeStruct((brows,), jnp.uint64, sharding=one_chip),
+             jax.ShapeDtypeStruct((brows,), jnp.int32, sharding=one_chip))
+    probe, build = described(pschema, prows), described(bschema, brows)
+    out, total = jax.eval_shape(emit, probe, build, index)
+    assert out.capacity == cap and len(out.cols) == 17 and total.shape == ()
+    # crlint: allow-raw-jit(AOT compile for a described chip: nothing is dispatched)
+    compiled = jax.jit(emit).lower(probe, build, index).compile()
+    text = compiled.as_text()
+    assert not re.search(r"\bwhile\(", text)
+    assert len(re.findall(r"\bscatter\(", text)) == 1
+    print("q13 emit memory:", compiled.memory_analysis())
+
+
 def test_described_devices_are_not_attached(topo):
     """The process still runs on the CPU mesh: describing a chip must not
     change what jax.devices() reports to the rest of the suite."""
