@@ -22,6 +22,22 @@ observable so the win is measurable and regressions are catchable:
 - ``scripts/check_dispatch_budget.py`` turns the per-query count into a
   tier-1 regression budget.
 
+Which operator a dispatch belongs to: ``section(op)`` is open around the
+code in which a plan operator drives kernels (``Operator.next_batch``, and
+through ``sectioned`` every resume of a tile source's generator); sections
+nest as operators pull each other, on the thread that runs the statement.
+While ``flow/runtime.run_operator`` holds a ``flow/pull`` span open
+(``operator_record``), every section and every counted call adds to that
+statement's row for the operator's ``label`` (plan/builder.py): wall,
+what nested sections and jitted calls covered, seconds inside jitted
+calls, calls by kernel name. A call outside any section goes to the row
+``none``. At the span's close the rows become ONE record on the span
+(``Span.to_dict`` carries it to bundles, /_status/spans and the debug zip)
+and each row's wall and self seconds go into ``tracing.totals()`` under
+``flow.op.<KERNEL>``. Nothing here grows a span tree, enters a profiler
+annotation or takes a lock a tile; with no ``flow/pull`` span open a
+section is a null context.
+
 Compile-wall accounting (the L1 cache of the plan/kernel cache hierarchy —
 see README "Cache hierarchy"):
 
@@ -47,6 +63,8 @@ import functools
 import re
 import threading
 import time
+from contextlib import contextmanager, nullcontext
+from contextvars import ContextVar
 
 import jax
 
@@ -56,8 +74,168 @@ _NAME = re.compile(r"[a-z][a-z0-9_]{0,47}")
 _lock = threading.Lock()
 _total = 0
 _compiles = 0
-_cache_hits = 0
 _kernel_cache: dict = {}
+
+NO_OPERATOR = "none"  # the row of a dispatch outside every section
+_NULL = nullcontext()
+
+
+class _Row:
+    """One operator label's sums over one ``flow/pull``. ``child_s`` and
+    ``tags`` are what ``Tracer._account`` reads of a span: the seconds
+    that nested sections and the jitted calls covered, and no tag sums
+    (the span's record carries the counts)."""
+
+    __slots__ = ("label", "what", "kernel", "wall_s", "child_s", "jit_s",
+                 "compile_s", "dispatches", "kernels")
+    tags: dict = {}
+
+    def __init__(self, label: str, what: str, kernel: str):
+        self.label, self.what, self.kernel = label, what, kernel
+        self.wall_s = self.child_s = self.jit_s = self.compile_s = 0.0
+        self.dispatches = 0
+        self.kernels: dict[str, int] = {}
+
+    def self_s(self) -> float:
+        """Wall seconds inside this operator's sections that neither a
+        nested section nor a jitted call covers."""
+        return max(0.0, self.wall_s - self.child_s)
+
+
+class _Section:
+    """One open operator section: the record's innermost row while open;
+    on exit its wall (``wall_s``, which EXPLAIN ANALYZE's operator time
+    reads too) goes to the row and to the enclosing row's ``child_s``. An
+    operator nested in itself is counted right by the same sums: the inner
+    wall is both in its ``wall_s`` and its ``child_s``. With no record it
+    only keeps its wall."""
+
+    __slots__ = ("_rec", "_row", "_prev", "_t0", "wall_s")
+
+    def __init__(self, rec: "OperatorRecord | None", row: "_Row | None"):
+        self._rec, self._row = rec, row
+
+    def __enter__(self) -> "_Section":
+        rec = self._rec
+        if rec is not None:
+            self._prev = rec.cur
+            rec.cur = self._row
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        dt = self.wall_s = time.perf_counter() - self._t0
+        rec = self._rec
+        if rec is None:
+            return False
+        prev = rec.cur = self._prev
+        self._row.wall_s += dt
+        if prev is None:
+            rec.top_s += dt
+        else:
+            prev.child_s += dt
+        return False
+
+
+class OperatorRecord:
+    """The operator rows of one ``flow/pull`` span, written by the one
+    thread that runs the statement (a thread an operator starts has a
+    context of its own and finds no record)."""
+
+    def __init__(self):
+        self.rows: dict[str, _Row] = {}
+        self.cur: _Row | None = None  # the innermost open section's row
+        self.top_s = 0.0  # wall of the sections no other section encloses
+
+    def row(self, op) -> _Row:
+        label = op.label or op.KERNEL
+        row = self.rows.get(label)
+        if row is None:
+            row = self.rows[label] = _Row(label, op.what, op.KERNEL)
+        return row
+
+    def open_row(self) -> _Row:
+        """Where a dispatch lands: the innermost open section's row, else
+        the row ``none``."""
+        row = self.cur
+        if row is None:
+            row = self.rows.get(NO_OPERATOR)
+            if row is None:
+                row = self.rows[NO_OPERATOR] = _Row(NO_OPERATOR, "",
+                                                    NO_OPERATOR)
+        return row
+
+    def close(self, span) -> None:
+        """The rows as one record on ``span``, and each operator's wall
+        and self seconds into the totals under its class's name, as a
+        span's close is. On the span's own clock the wall so far is tiled
+        by the rows' ``host_self_ms`` + ``jit_ms``, the readback and
+        ``pull_self_ms``: the pull loop's own time, what no section, no
+        readback and no dispatch outside a section covers."""
+        wall_s = time.perf_counter() - span.start
+        out = []
+        outside = self.rows.get(NO_OPERATOR)
+        for r in self.rows.values():
+            d = {"label": r.label, "what": r.what,
+                 "dispatches": r.dispatches, "kernels": r.kernels,
+                 "jit_ms": round(r.jit_s * 1e3, 3),
+                 "host_self_ms": round(r.self_s() * 1e3, 3)}
+            if r.compile_s:
+                d["compile_ms"] = round(r.compile_s * 1e3, 3)
+            out.append(d)
+            if r is not outside:
+                tracing.account(f"flow.op.{r.kernel}", r.wall_s, r)
+        pull_self_s = (wall_s - self.top_s
+                       - span.tags.get("readback_ms", 0.0) / 1e3
+                       - (outside.jit_s if outside is not None else 0.0))
+        span.record({"operators": out,
+                     "pull_self_ms": round(max(0.0, pull_self_s) * 1e3, 3)})
+
+
+_record: ContextVar[OperatorRecord | None] = ContextVar(
+    "crdb_tpu_operator_record", default=None)
+
+
+@contextmanager
+def operator_record(span):
+    """Collect operator rows while ``span`` (a ``flow/pull``) is open;
+    nothing where ``span`` is None (no statement is being traced)."""
+    if span is None:
+        yield None
+        return
+    rec = OperatorRecord()
+    token = _record.set(rec)
+    try:
+        yield rec
+    finally:
+        _record.reset(token)
+        rec.close(span)
+
+
+def section(op, timed: bool = False):
+    """The context in which ``op`` drives kernels (module docstring).
+    ``timed``: the caller reads the section's ``wall_s``, so it keeps its
+    clock where no statement is being traced too."""
+    rec = _record.get()
+    if rec is None:
+        return _Section(None, None) if timed else _NULL
+    return _Section(rec, rec.row(op))
+
+
+def sectioned(op, tiles):
+    """``tiles`` (a tile source's generator) resumed inside ``op``'s
+    section every time, the section closed while the consumer holds the
+    tile: a generator's body runs between its consumer's lines."""
+    try:
+        while True:
+            with section(op):
+                try:
+                    t = next(tiles)
+                except StopIteration:
+                    return
+            yield t
+    finally:
+        tiles.close()  # a consumer may stop early (LIMIT)
 
 
 def note(n: int = 1) -> None:
@@ -95,7 +273,7 @@ def compiles() -> int:
 def kernel_cache_hits() -> int:
     """Process-lifetime kernel-cache hits (jit(key=...) lookups answered
     by an already-built wrapper)."""
-    return _cache_hits
+    return int(metric.KERNEL_CACHE_HITS.value)
 
 
 def clear_kernel_cache() -> None:
@@ -129,12 +307,9 @@ def jit(fn=None, key=None, name=None, **jit_kwargs):
             f"dispatch.jit needs a static name=<operator>_<role> "
             f"(lower case, digits, '_'), got {name!r}")
     if key is not None:
-        global _cache_hits
         with _lock:
             cached = _kernel_cache.get(key)
         if cached is not None:
-            with _lock:
-                _cache_hits += 1
             metric.KERNEL_CACHE_HITS.inc()
             return cached
 
@@ -155,26 +330,35 @@ def jit(fn=None, key=None, name=None, **jit_kwargs):
         sp = tracing.current()
         if sp is None:
             return jitted(*args, **kwargs)
-        # traced call: split wall time into compile (trace happened under
-        # this call) vs execute, folded into the enclosing span's tags so
-        # EXPLAIN ANALYZE (DEBUG) shows where dispatch time went, and
-        # tracing.totals() sums them over a window
+        # traced call: the wall time of a call that compiled nothing goes
+        # into the enclosing span's tags (tracing.totals() sums them over a
+        # window); every call goes to its operator's row of the statement's
+        # record, a call under which a trace happened as compile time, and
+        # on the profiler's clock the region names kernel and operator
+        rec = _record.get()
+        row = None if rec is None else rec.open_row()
         # crlint: allow-race-coverage(_compiles is a monotonic counter: every write holds _lock; these lockless GIL-atomic snapshot reads only split telemetry into compile-vs-dispatch buckets — taking _lock per dispatch on the serving hot path buys nothing a stale-by-one read can break)
         c0 = _compiles
         t0 = time.perf_counter()
-        with tracing.annotation("flow.dispatch", kernel=name):
+        with tracing.annotation(
+                "flow.dispatch", kernel=name,
+                op=NO_OPERATOR if row is None else row.label):
             out = jitted(*args, **kwargs)
-        dt_ms = (time.perf_counter() - t0) * 1e3
-        if _compiles > c0:
-            sp.inc_tag("jit_compiles", _compiles - c0)
-            sp.inc_tag("jit_compile_ms", round(dt_ms, 3))
-        else:
+        dt = time.perf_counter() - t0
+        compiled = _compiles > c0
+        if not compiled:
             sp.inc_tag("jit_dispatches", 1)
-            sp.inc_tag("jit_dispatch_ms", round(dt_ms, 3))
+            sp.inc_tag("jit_dispatch_ms", round(dt * 1e3, 3))
+        if row is not None:
+            row.dispatches += 1
+            row.jit_s += dt
+            row.child_s += dt
+            row.kernels[name] = row.kernels.get(name, 0) + 1
+            if compiled:
+                row.compile_s += dt
         return out
 
     counted._jitted = jitted  # uncounted handle (AOT lowering/inspection)
-    counted._kernel_key = key
     if key is not None:
         with _lock:
             # racing builders: first insert wins so every caller shares it

@@ -13,12 +13,11 @@ columnar string representation).
 
 from __future__ import annotations
 
-import time
-
 import numpy as np
 
 from ..coldata.batch import Batch, Dictionary
 from ..coldata.types import Schema
+from . import dispatch
 
 
 class ComponentStats:
@@ -69,6 +68,14 @@ class Operator:
     # name=<KERNEL>_<role>): the class name in lower case without "op"
     # unless a class gives a shorter one. Static — never a per-query value.
     KERNEL = "operator"
+    # which operator of its plan this is, set once when the tree is built
+    # (plan/builder.py): `<KERNEL>.<pre-order position>` and what the plan
+    # says of it (a scan's table, a join's type and sources). Static a plan,
+    # never a per-query value, never part of a kernel's name or key: the
+    # label of this operator's section (flow/dispatch.py). An operator built
+    # outside a plan goes by its KERNEL alone.
+    label: str | None = None
+    what = ""
     # a tile comes out at the capacity it went in at, row for row in place
     # (the operator masks or computes columns, never moves rows): a join
     # above reads the capacity it will be handed through such links
@@ -94,19 +101,22 @@ class Operator:
         if not self._initialized:
             self.init()
         if not self._collect:
-            return self._next()
-        t0 = time.perf_counter()
-        b = self._next()
-        if b is not None:
-            # row counting forces a device sync, so exact per-operator times
-            # and rows are an EXPLAIN ANALYZE-only cost (like the reference's
-            # stats collection wrappers in colflow/stats.go)
-            from .memory import batch_bytes
+            with dispatch.section(self):
+                return self._next()
+        # one clock: the section's wall is the operator's time, the row
+        # count's wait for the device included
+        with dispatch.section(self, timed=True) as sec:
+            b = self._next()
+            if b is not None:
+                # row counting forces a device sync, so exact per-operator
+                # times and rows are an EXPLAIN ANALYZE-only cost (like the
+                # reference's stats collection wrappers in colflow/stats.go)
+                from .memory import batch_bytes
 
-            self.stats.rows += int(np.asarray(b.mask).sum())
-            self.stats.batches += 1
-            self.stats.bytes += batch_bytes(b)
-        self.stats.time_s += time.perf_counter() - t0
+                self.stats.rows += int(np.asarray(b.mask).sum())
+                self.stats.batches += 1
+                self.stats.bytes += batch_bytes(b)
+        self.stats.time_s += sec.wall_s
         return b
 
     def children(self) -> list["Operator"]:

@@ -390,6 +390,9 @@ class ScanOp(SourceOperator):
 
     def stream_tiles(self):
         """Yield raw tile tokens for the fused path (reset scan position)."""
+        return dispatch.sectioned(self, self._stream_tiles())
+
+    def _stream_tiles(self):
         self._offset = 0
         if self.streaming:
             self._prefetched = None
@@ -2056,6 +2059,9 @@ class HashJoinOp(OneInputOperator):
 
     def stream_tiles(self):
         """Source-mode drive loop (learn/compact emission)."""
+        return dispatch.sectioned(self, self._stream_tiles())
+
+    def _stream_tiles(self):
         self._ensure_built()
         if getattr(self, "_grace", None) is not None:
             # build spilled mid-spool: serve grace output as plain tiles
@@ -2254,6 +2260,9 @@ class _CountedProbeTiles:
         self.join = join
 
     def stream_tiles(self):
+        return dispatch.sectioned(self.join, self._stream_tiles())
+
+    def _stream_tiles(self):
         for t in self.src.stream_tiles():
             self.join._note_probe_tile(t, self.src, composed=True)
             yield t
@@ -2261,7 +2270,8 @@ class _CountedProbeTiles:
 
 def _consume_op(op: Operator, tag: str):
     """Pull every tile from `op`, fused with its streaming chain when
-    possible (build-side spools ride one jit instead of one per operator)."""
+    possible (build-side spools ride one jit instead of one per operator).
+    The composed kernel is `op`'s, as a tile next_batch hands out is."""
     parts = (None if (op._collect or not _fusion_enabled())
              else op.stream_parts())
     if parts is None:
@@ -2271,6 +2281,10 @@ def _consume_op(op: Operator, tag: str):
                 return
             yield b
         return
+    yield from dispatch.sectioned(op, _drive_chain(op, tag, parts))
+
+
+def _drive_chain(op: Operator, tag: str, parts):
     src, cfn, args = parts
     fn = _per_chain(
         op, f"_fused_src_{tag}", cfn,

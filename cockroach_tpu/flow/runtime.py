@@ -168,7 +168,10 @@ def run_operator(root) -> dict[str, np.ndarray]:
             for attempt in range(4):
                 outs: list[dict[str, np.ndarray]] = []
                 shrink = _ReadbackShrink()
-                with tracing.leaf_span("flow/pull", attempt=attempt) as psp:
+                # the span carries the attempt's operator rows as one
+                # record (flow/dispatch.py)
+                with tracing.leaf_span("flow/pull", attempt=attempt) as psp, \
+                        dispatch.operator_record(psp):
                     if attempt and psp is not None:
                         # only a join's emission cap (general or compact)
                         # that overflowed sends a statement round again

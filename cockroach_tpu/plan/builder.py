@@ -122,7 +122,72 @@ def build(plan: S.PlanNode, catalog: Catalog, params=None) -> Operator:
         from ..flow import fuse
 
         op = fuse.fuse_operators(op)
+    _label_operators(op)
     return op
+
+
+def _label_operators(root: Operator) -> None:
+    """Give every operator of the tree as built its ``label``,
+    ``<KERNEL>.<n>`` with n the pre-order position, and its ``what``, the
+    plan's own words for it: what a statement's operator record and the
+    profiler's ``flow.dispatch`` regions name it by (flow/dispatch.py). A
+    dot and not ``#``, ``,`` or ``=``: the profiler's annotations delimit
+    their arguments by those, and ``op=join#1`` arrives as ``join``.
+    Once a tree, so once a plan-cache entry; EXPLAIN prints neither. A
+    wrapper the fusion pass put in goes by the operator it wraps."""
+    from ..flow.fuse import unwrap
+
+    n = 0
+
+    def walk(op: Operator) -> None:
+        nonlocal n
+        inner = unwrap(op)
+        if inner is not op:
+            walk(inner)
+            while op is not inner:  # every wrapper on the way down
+                op.label, op.what = inner.label, inner.what
+                (op,) = op.children()
+            return
+        op.label = f"{op.KERNEL}.{n}"
+        n += 1
+        for c in op.children():
+            walk(c)
+        op.what = _what(op)  # its sources are labelled by now
+
+    walk(root)
+
+
+def _source(op: Operator) -> str:
+    """What feeds a join's side: the table its chain of per-tile links
+    scans, else the label of the operator the chain ends at."""
+    from ..flow.fuse import unwrap
+
+    op = unwrap(op)
+    while isinstance(op, (ops.FilterOp, ops.ProjectOp, ops.HashBucketOp)):
+        op = unwrap(op.child)
+    if isinstance(op, (ops.ScanOp, ops.IndexScanOp)):
+        return op.table.name
+    return op.label
+
+
+def _what(op: Operator) -> str:
+    if isinstance(op, ops.ScanOp):
+        return op.table.name
+    if isinstance(op, ops.IndexScanOp):
+        return f"{op.table.name}@{op.ix.name}"
+    if isinstance(op, (ops.HashJoinOp, ops.MergeJoinOp)):
+        unique = " unique" if op.spec.build_unique else ""
+        return (f"{op.spec.join_type} probe={_source(op.child)} "
+                f"build={_source(op.build)}{unique}")
+    if isinstance(op, ops.AggregateOp):
+        route = (" streaming" if op.streaming
+                 else " ordered" if op.ordered else "")
+        return f"mode={op.mode} keys={op.num_keys}{route}"
+    if isinstance(op, ops.SmallGroupAggregateOp):
+        return f"dense keys={len(op.group_cols)}"
+    if isinstance(op, ops.TopKOp):
+        return f"k={op.k}"
+    return ""
 
 
 def _build(plan: S.PlanNode, catalog: Catalog, params=None) -> Operator:

@@ -32,7 +32,11 @@ depends on which roots the ring still holds. ``timed(name)`` is the entry
 for work that must NOT grow a tree — the node's background loops, the wire
 — it feeds the same totals and names an *owner* for the compiles that
 happen inside it (``compiles_by_owner()``), but is never ``current()``, in
-the in-flight table or in the ring.
+the in-flight table or in the ring. Sections that keep their own times
+(the flow layer's operator sections, flow/dispatch.py) close into the same
+totals a statement at a time (``account``). ``compile_seconds()``
+holds what JAX reported of every backend compile by program name, a load
+from the persistent cache told apart from a compile.
 
 The profiler's clock: while ``sql.trace.xla_profile`` is on, every span
 and timed section also enters a ``jax.profiler.TraceAnnotation`` of its
@@ -168,6 +172,9 @@ MAX_CHILDREN = 128  # per-span child cap (hot leaf sites: WAL appends)
 # JAX's own event for one backend compile (the benchmark's
 # kernels.xla_compiles_in_window counts the same event)
 COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+# reported on the compiling thread, inside the compile event's stretch, only
+# where the persistent cache answered (jax/_src/compiler.py)
+CACHE_LOAD_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"
 OWNER_STATEMENT = "statement"  # a span was current on the compiling thread
 OWNER_OTHER = "other"          # neither a timed section nor a span
 
@@ -259,6 +266,9 @@ class Tracer:
         # backend compiles by owner; one lock for both, taken once per close
         self._totals: dict[str, list] = {}
         self._compiles: dict[str, int] = {}
+        # program name -> [compiles, compile_s, cache_loads, cache_load_s]
+        self._programs: dict[str, list] = {}
+        self._cache_load = threading.local()  # .seen: a load precedes
         self._tot_lock = threading.Lock()
 
     # -- span lifecycle ----------------------------------------------------
@@ -442,16 +452,39 @@ class Tracer:
         with self._tot_lock:
             return dict(self._compiles)
 
-    def _on_duration(self, event: str, _seconds: float, **_kw) -> None:
-        # runs on the compiling thread, so both contextvars are its own
+    def compile_seconds(self) -> dict[str, dict]:
+        """What JAX reported of every backend compile since the listener
+        went in, by program name as JAX gives it (``jit(<kernel>)``): ``compiles`` and
+        ``compile_s`` for programs XLA compiled, ``cache_loads`` and
+        ``cache_load_s`` for those the persistent cache answered (the
+        seconds are the compile event's either way: what the caller
+        waited)."""
+        with self._tot_lock:
+            return {n: {"compiles": r[0], "compile_s": r[1],
+                        "cache_loads": r[2], "cache_load_s": r[3]}
+                    for n, r in self._programs.items()}
+
+    def _on_duration(self, event: str, seconds: float, fun_name=None,
+                     **_kw) -> None:
+        # runs on the compiling thread, so both contextvars and the
+        # thread-local are its own
+        if event == CACHE_LOAD_EVENT:
+            self._cache_load.seen = True
+            return
         if event != COMPILE_EVENT:
             return
+        loaded = getattr(self._cache_load, "seen", False)
+        self._cache_load.seen = False
         owner = self._owner.get()
         if owner is None:
             owner = (OWNER_STATEMENT if self._current.get() is not None
                      else OWNER_OTHER)
         with self._tot_lock:
             self._compiles[owner] = self._compiles.get(owner, 0) + 1
+            rec = self._programs.setdefault(str(fun_name), [0, 0.0, 0, 0.0])
+            at = 2 if loaded else 0
+            rec[at] += 1
+            rec[at + 1] += seconds
 
 
 # process-global default tracer (the reference hangs one off every Server)
@@ -494,6 +527,14 @@ def totals() -> dict[str, dict]:
     return DEFAULT.totals()
 
 
+def account(name: str, total_s: float, covered) -> None:
+    """One close of a section that kept its own clock (an operator's row
+    of a statement, flow/dispatch.py) into the totals as a span's close
+    is. ``covered`` holds what that reads of a span: ``child_s``, the
+    seconds nested sections and calls covered, and ``tags``."""
+    DEFAULT._account(name, total_s, covered, None)
+
+
 _listener_lock = threading.Lock()
 _listening = False
 
@@ -516,6 +557,11 @@ def install_compile_listener() -> None:
 def compiles_by_owner() -> dict[str, int]:
     install_compile_listener()
     return DEFAULT.compiles_by_owner()
+
+
+def compile_seconds() -> dict[str, dict]:
+    install_compile_listener()
+    return DEFAULT.compile_seconds()
 
 
 def synthetic_span(parent: Span, name: str, duration_s: float,
