@@ -1975,7 +1975,10 @@ class HashJoinOp(OneInputOperator):
         a tile it did not emit and compact itself, counted into
         ``join_passthrough_tiles`` as well; a tile it did emit, and cut
         to its cap before it gathered a build column (`_emits_late`),
-        counts into ``join_late_emit_tiles``. ``t`` is a Batch, or a
+        counts into ``join_late_emit_tiles``. Every tile also adds the
+        join's output width to ``join_output_columns``: the columns its
+        emitted (or composed) tile carries, read above or not
+        (plan/prune.py cuts them to those read). ``t`` is a Batch, or a
         resident scan's (table batch, offset) token whose tile size
         ``src`` knows."""
         sp = tracing.current()
@@ -1988,6 +1991,7 @@ class HashJoinOp(OneInputOperator):
         sp.inc_tag("join_unique_tiles" if unique else "join_general_tiles", 1)
         if not unique and self.exact_layout is not None:
             sp.inc_tag("join_expanded_tiles", 1)
+        sp.inc_tag("join_output_columns", len(self.output_schema))
         rows = _tile_rows(t, src)
         sp.inc_tag("join_probe_tile_rows", rows)
         if self.spec.join_type in ("semi", "anti"):

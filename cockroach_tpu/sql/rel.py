@@ -401,12 +401,15 @@ class Rel:
 
     def optimized_plan(self) -> S.PlanNode:
         """Plan after local optimization passes (index selection —
-        plan/indexopt.py; top-k pushdown — plan/topkopt.py). Distribution
-        has its own rewrite."""
+        plan/indexopt.py; top-k pushdown — plan/topkopt.py; column
+        pruning — plan/prune.py, last, over the nodes the other two
+        leave). Distribution has its own rewrite."""
         from ..plan.indexopt import use_indexes
+        from ..plan.prune import prune_columns
         from ..plan.topkopt import push_topk
 
-        return push_topk(use_indexes(self.plan, self.catalog))
+        return prune_columns(
+            push_topk(use_indexes(self.plan, self.catalog)), self.catalog)
 
     def run(self) -> dict[str, np.ndarray]:
         return run_plan(self.optimized_plan(), self.catalog)

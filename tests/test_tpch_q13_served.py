@@ -160,9 +160,13 @@ def test_the_plan_is_a_general_left_join_under_a_group_by(cat):
     (join,) = [ln for ln in lines if "hash-join" in ln]
     assert join == "-> hash-join (left) probe=[0] build=[1]"  # NOT unique
     i = lines.index(join)
-    assert lines[i + 1].startswith("-> scan customer")
-    assert lines[i + 2].startswith("-> filter Not(arg=CodeLookup(")
-    assert lines[i + 3].startswith("-> scan orders")
+    # plan/prune.py (PR 38): the join carries 3 columns, not 17; o_comment
+    # is read by the NOT LIKE alone and stops above the filter
+    assert lines[i + 1] == "-> scan customer columns=['c_custkey']"
+    assert lines[i + 2] == "-> project ['o_orderkey', 'o_custkey']"
+    assert lines[i + 3].startswith("-> filter Not(arg=CodeLookup(col=2,")
+    assert lines[i + 4] == ("-> scan orders columns=['o_orderkey', "
+                            "'o_custkey', 'o_comment']")
     inner, outer = [ln for ln in reversed(lines)
                     if ln.startswith("-> group-by")]
     # the ordering customer declares stops at the join: neither is ordered
