@@ -1830,6 +1830,7 @@ class HashJoinOp(OneInputOperator):
                 self._set_probe("sorted")
         else:
             cap = _spool_cap(tiles)
+            self._note_build(cap)
             use_lut = (
                 self._fusable
                 and self.exact_layout is not None
@@ -1978,7 +1979,10 @@ class HashJoinOp(OneInputOperator):
         counts into ``join_late_emit_tiles``. Every tile also adds the
         join's output width to ``join_output_columns``: the columns its
         emitted (or composed) tile carries, read above or not
-        (plan/prune.py cuts them to those read). ``t`` is a Batch, or a
+        (plan/prune.py cuts them to those read). A LEFT join's tile on a
+        unique-build route (its unmatched rows NULL-extended in place: a
+        NOT EXISTS read as an anti-join by IS NULL above it) counts into
+        ``join_null_extended_tiles`` too. ``t`` is a Batch, or a
         resident scan's (table batch, offset) token whose tile size
         ``src`` knows."""
         sp = tracing.current()
@@ -1998,6 +2002,19 @@ class HashJoinOp(OneInputOperator):
             sp.inc_tag("semijoin_probe_tile_rows", rows)
         if not composed and self._emits_late(rows):
             sp.inc_tag("join_late_emit_tiles", 1)
+        if unique and self.spec.join_type == "left":
+            sp.inc_tag("join_null_extended_tiles", 1)
+
+    def _note_build(self, cap: int) -> None:
+        """The static capacity of a build side this join makes or re-makes
+        (``hashjoin_lut`` / ``hashjoin_build``; an analytic build launches
+        neither) into the pull span's ``join_build_rows``: which rung each
+        build of a statement ran at, and whether a build is redone a
+        statement. ``cap`` comes of the spool's one live count: no sync of
+        its own."""
+        sp = tracing.current()
+        if sp is not None:
+            sp.inc_tag("join_build_rows", cap)
 
     def _emits_late(self, tile_rows: int) -> bool:
         """Whether this join's own emit of a ``tile_rows`` probe tile cuts

@@ -36,7 +36,8 @@ INNER = ("select c_custkey, count(o_orderkey) as c_count from customer "
 SEED = 2**31 + 34
 TAGS = ("join_general_tiles", "join_expanded_tiles", "join_unique_tiles",
         "join_probe_tile_rows", "join_emit_tile_rows", "join_overflow_reruns",
-        "agg_ordered_tiles", "agg_streamed_tiles", "agg_merge_rows")
+        "agg_ordered_tiles", "agg_streamed_tiles", "agg_merge_rows",
+        "join_build_rows", "join_null_extended_tiles")
 
 
 class _Host:
@@ -208,6 +209,11 @@ def test_the_tags_the_cells_metrics_read(sess, settled):
     assert d["join_emit_tile_rows"] == 65536
     assert d["join_overflow_reruns"] == 0
     assert d["agg_ordered_tiles"] == 0 and d["agg_streamed_tiles"] == 0
+    # PR 39's two tags: the one build (hashjoin_build over 15,000 orders at
+    # the ladder's 65,536) is redone every statement; the LEFT join is the
+    # general one, so no tile counts as NULL-extended on a unique route
+    assert d["join_build_rows"] == 65536
+    assert d["join_null_extended_tiles"] == 0
     sess.execute(" ".join(TPCH_SQL["q1"].split()))
     assert _delta(t0) == d  # q1 has no join and no AggregateOp
 
