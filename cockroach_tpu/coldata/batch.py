@@ -299,7 +299,14 @@ def concat(batches: list[Batch], capacity: int) -> Batch:
     (must fit; caller checks). Each source batch gathers its live rows once
     (per-batch nonzero index) and scatters them at its running offset —
     never materializing the full-capacity concatenation the previous design
-    paid for (O(sum cap_in) per column)."""
+    paid for (O(sum cap_in) per column).
+
+    This is the route for tiles whose live rows may lie anywhere: every
+    spool but one compacts through it (the aggregate's merge, the sort,
+    window, merge-join and Grace spools, the fan-in syncs, a join build fed
+    by a filter, a scan or a join). A join build whose producer proves its
+    tiles live-prefix (`Operator.emits_live_prefix`) places them through
+    `concat_prefix`: same result, no index."""
     if len(batches) == 1:
         return compact(batches[0], capacity)
     ncols = len(batches[0].cols)
@@ -335,4 +342,48 @@ def concat(batches: list[Batch], capacity: int) -> Batch:
             valid = valid.at[dest].set(vrows, mode="drop")
         cols.append(Column(data=data, valid=valid))
     mask = jnp.arange(capacity, dtype=jnp.int32) < total
+    return Batch(cols=tuple(cols), mask=mask)
+
+
+def concat_prefix(batches: list[Batch], capacity: int) -> Batch:
+    """`concat` for tiles whose live rows are a dense PREFIX
+    (``mask[i] == (i < n)``, the producer's guarantee: the caller holds an
+    `Operator.emits_live_prefix` proof, nothing here checks it): the same
+    compacted tile of ``capacity``, by placement, not by index. Tile k's
+    rows belong at ``[off_k, off_k + n_k)``, ``n_k = sum(mask_k)`` and
+    ``off_k`` the running sum (both in-kernel: no host sync), so each
+    column is written whole at ``off_k``, in tile order, and tile k + 1
+    overwrites tile k's dead tail: a block copy a tile where `concat` runs a
+    `nonzero`, a gather and a scatter.
+
+    XLA clamps a `dynamic_update_slice` whose end passes the buffer's (the
+    tile would shift DOWN over live rows), so the buffer has the widest
+    tile's rows of slack and is cut to ``capacity`` at the end. Rows past
+    the total then hold some tile's dead tail: they are zeroed and their
+    valid bits cleared, as `concat` leaves them."""
+    slack = max(b.capacity for b in batches)
+    total = jnp.int32(0)
+    offs = []
+    for b in batches:
+        offs.append(total)
+        total = total + jnp.sum(b.mask, dtype=jnp.int32)
+    mask = jnp.arange(capacity, dtype=jnp.int32) < total
+
+    def place(parts):
+        if len(parts) == 1:  # a slice or a pad: nothing to offset
+            return pad_rows(parts[0], capacity)[:capacity]
+        buf = jnp.zeros((capacity + slack,) + parts[0].shape[1:],
+                        parts[0].dtype)
+        for x, off in zip(parts, offs):
+            buf = jax.lax.dynamic_update_slice_in_dim(buf, x, off, axis=0)
+        return buf[:capacity]
+
+    cols = []
+    for i in range(len(batches[0].cols)):
+        data = place([b.cols[i].data for b in batches])
+        valid = place([b.cols[i].valid for b in batches])
+        live = mask.reshape(mask.shape + (1,) * (data.ndim - 1))
+        cols.append(Column(data=jnp.where(live, data,
+                                          jnp.zeros((), data.dtype)),
+                           valid=valid & mask))
     return Batch(cols=tuple(cols), mask=mask)

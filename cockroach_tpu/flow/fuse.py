@@ -93,6 +93,10 @@ class _BarrierSource(Operator):
         self.dictionaries = inner.dictionaries
         self.col_stats = inner.col_stats
 
+    @property
+    def emits_live_prefix(self) -> bool:
+        return self.inner.emits_live_prefix  # its tiles are the barrier's
+
     def children(self):
         return [self.inner]
 

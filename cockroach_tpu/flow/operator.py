@@ -80,6 +80,15 @@ class Operator:
     # (the operator masks or computes columns, never moves rows): a join
     # above reads the capacity it will be handed through such links
     _passes_tiles = False
+    # every tile this operator hands out has its live rows as a dense
+    # prefix, mask[i] == (i < n): true only where the code that builds the
+    # tile's mask guarantees it (a streaming AggregateOp) or passes such a
+    # mask on untouched (ProjectOp, the fusion pass's barrier adapter). Known
+    # from the plan's structure, never synced or set; a join build over
+    # such a producer places its tiles at a running offset
+    # (coldata/batch.py `concat_prefix`) where any other compacts them.
+    # tests/test_live_prefix.py pulls every claimant's tiles and checks
+    emits_live_prefix = False
 
     def __init_subclass__(cls, **kw):
         super().__init_subclass__(**kw)

@@ -34,7 +34,7 @@ import numpy as np
 
 from ..catalog import SHAPE_BUCKETS, Table
 from ..coldata.batch import (
-    Batch, Column, Dictionary, concat, empty_batch, pad_rows,
+    Batch, Column, Dictionary, concat, concat_prefix, empty_batch, pad_rows,
 )
 from ..coldata.types import FLOAT64, Family, Schema
 from ..ops import aggregation as agg_ops
@@ -96,7 +96,9 @@ def _live_total(tiles: list[Batch]) -> int:
 
 
 def _spool_cap(tiles: list[Batch]) -> int:
-    """Canonical capacity fitting the spool's LIVE rows (concat compacts)."""
+    """Canonical capacity fitting the spool's LIVE rows: every spool
+    compacts into it through `concat`, but a join build whose producer
+    proves its tiles live-prefix, which places them (`concat_prefix`)."""
     return _canonical_cap(max(1, _live_total(tiles)))
 
 
@@ -694,6 +696,10 @@ class ProjectOp(OneInputOperator):
     def stream_parts(self):
         return _compose_parts(self, self.child, self._raw, key=self._key)
 
+    @property
+    def emits_live_prefix(self) -> bool:
+        return self.child.emits_live_prefix  # `raw` hands the mask on
+
     def _next(self):
         b = self.child.next_batch()
         return None if b is None else self._fn(b)
@@ -865,6 +871,13 @@ class AggregateOp(OneInputOperator):
         self._acc = None
         self._emitted = False
         self._spool_alloc = None
+
+    @property
+    def emits_live_prefix(self) -> bool:
+        """A streaming aggregate's tiles: `stitch_ordered_partial` sets the
+        mask to the closed groups' prefix whatever lay below, `_finalize`
+        hands it on, and the tail is one padded row."""
+        return self.streaming
 
     def _close_spool(self) -> None:
         if self._spool_alloc is not None:
@@ -1657,11 +1670,16 @@ class HashJoinOp(OneInputOperator):
         bht = self.build_hash_tables or None
         layout = self.exact_layout
         eremaps = self.build_code_remaps or None
+        # two programs, chosen once from the plan: a producer that proves
+        # its tiles live-prefix has them placed at a running offset, any
+        # other compacted through a nonzero index a tile
+        self._places_build = self.build.emits_live_prefix
+        into_one = concat_prefix if self._places_build else concat
 
         @functools.partial(dispatch.jit, static_argnames=("cap",),
                            name="hashjoin_build")
         def build_fn(tiles, cap):
-            big = concat(list(tiles), capacity=cap)
+            big = into_one(list(tiles), capacity=cap)
             index = join_ops.build_index(big, bschema, bkeys, bht,
                                          exact_layout=layout,
                                          exact_remaps=eremaps)
@@ -1672,7 +1690,7 @@ class HashJoinOp(OneInputOperator):
         @functools.partial(dispatch.jit, static_argnames=("cap",),
                            name="hashjoin_lut")
         def lut_fn(tiles, cap):
-            big = concat(list(tiles), capacity=cap)
+            big = into_one(list(tiles), capacity=cap)
             return big, join_ops.build_dense_lut(big, bkeys, layout, eremaps)
 
         self._lut_fn = lut_fn
@@ -1830,7 +1848,7 @@ class HashJoinOp(OneInputOperator):
                 self._set_probe("sorted")
         else:
             cap = _spool_cap(tiles)
-            self._note_build(cap)
+            self._note_build(cap, len(tiles))
             use_lut = (
                 self._fusable
                 and self.exact_layout is not None
@@ -2005,16 +2023,21 @@ class HashJoinOp(OneInputOperator):
         if unique and self.spec.join_type == "left":
             sp.inc_tag("join_null_extended_tiles", 1)
 
-    def _note_build(self, cap: int) -> None:
+    def _note_build(self, cap: int, tiles: int) -> None:
         """The static capacity of a build side this join makes or re-makes
         (``hashjoin_lut`` / ``hashjoin_build``; an analytic build launches
         neither) into the pull span's ``join_build_rows``: which rung each
         build of a statement ran at, and whether a build is redone a
         statement. ``cap`` comes of the spool's one live count: no sync of
-        its own."""
+        its own. ``join_build_placed_tiles``: of the ``tiles`` that launch
+        is handed, those it places at a running offset (`concat_prefix`:
+        all of them where the build side proves its tiles live-prefix, 0
+        on the gather route)."""
         sp = tracing.current()
         if sp is not None:
             sp.inc_tag("join_build_rows", cap)
+            sp.inc_tag("join_build_placed_tiles",
+                       tiles if self._places_build else 0)
 
     def _emits_late(self, tile_rows: int) -> bool:
         """Whether this join's own emit of a ``tile_rows`` probe tile cuts

@@ -37,7 +37,8 @@ SEED = 2**31 + 34
 TAGS = ("join_general_tiles", "join_expanded_tiles", "join_unique_tiles",
         "join_probe_tile_rows", "join_emit_tile_rows", "join_overflow_reruns",
         "agg_ordered_tiles", "agg_streamed_tiles", "agg_merge_rows",
-        "join_build_rows", "join_null_extended_tiles")
+        "join_build_rows", "join_null_extended_tiles",
+        "join_build_placed_tiles")
 
 
 class _Host:
@@ -214,6 +215,9 @@ def test_the_tags_the_cells_metrics_read(sess, settled):
     # general one, so no tile counts as NULL-extended on a unique route
     assert d["join_build_rows"] == 65536
     assert d["join_null_extended_tiles"] == 0
+    # PR 40: `orders` comes from under the NOT LIKE Filter, which proves no
+    # live prefix, so hashjoin_build compacts its tiles through `concat`
+    assert d["join_build_placed_tiles"] == 0
     sess.execute(" ".join(TPCH_SQL["q1"].split()))
     assert _delta(t0) == d  # q1 has no join and no AggregateOp
 

@@ -33,7 +33,8 @@ Q18 = " ".join(TPCH_SQL["q18"].split()).replace("> 300", "> {quantity}")
 SEED = 2**31 + 32
 TAGS = ("agg_ordered_tiles", "agg_streamed_tiles", "agg_merge_rows",
         "agg_spills", "semijoin_probe_tile_rows", "join_probe_tile_rows",
-        "join_unique_tiles", "join_general_tiles", "join_late_emit_tiles")
+        "join_unique_tiles", "join_general_tiles", "join_late_emit_tiles",
+        "join_build_placed_tiles")
 
 
 class _Host:
@@ -189,6 +190,9 @@ def test_the_tags_the_cells_metrics_read(sess, settled):
     assert d["join_unique_tiles"] == 3 and d["join_general_tiles"] == 0
     assert d["join_late_emit_tiles"] == 1
     assert d["join_probe_tile_rows"] > orders_tile
+    # PR 40: the streamed aggregate reaches its join through the HAVING's
+    # Filter, which leaves holes: both hashjoin_lut builds keep `concat`
+    assert d["join_build_placed_tiles"] == 0
     sess.execute(" ".join(TPCH_SQL["q1"].split()))
     assert _delta(t0) == d  # q1 has no AggregateOp spool and no join
 
@@ -253,6 +257,7 @@ def test_the_tags_count_every_tile_and_every_merge(fresh, small_tiles,
     assert d["semijoin_probe_tile_rows"] == 15 * small_tiles
     assert caps == [1024] and d["agg_merge_rows"] == sum(caps)
     assert d["agg_spills"] == 0 and d["join_general_tiles"] == 0
+    assert d["join_build_placed_tiles"] == 0  # 60 tiles under the HAVING
 
 
 @pytest.mark.parametrize("clustered", [False, True],

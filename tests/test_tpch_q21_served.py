@@ -37,7 +37,7 @@ NATIONS = ("SAUDI ARABIA", "CANADA", "FRANCE", "CHINA", "UNITED STATES")
 TAGS = ("join_build_rows", "join_null_extended_tiles", "join_unique_tiles",
         "join_general_tiles", "join_probe_tile_rows", "join_overflow_reruns",
         "agg_ordered_tiles", "agg_streamed_tiles", "agg_merge_rows",
-        "agg_spills")
+        "agg_spills", "join_build_placed_tiles")
 
 
 class _Host:
@@ -142,6 +142,8 @@ def test_q21_streamed_equals_the_reference_and_never_merges(
     _assert_answer(got, _reference(host, nation))
     assert d["agg_ordered_tiles"] == d["agg_streamed_tiles"] == 2
     assert d["agg_merge_rows"] == 0 and d["agg_spills"] == 0
+    # PR 40: both builds place their aggregate's streamed tile and its tail
+    assert d["join_build_placed_tiles"] == 4
     assert d["join_null_extended_tiles"] == 1
     assert d["join_general_tiles"] == 0
 
@@ -332,6 +334,7 @@ def test_the_two_tags_pr39_added(sess, settled):
     assert d["join_null_extended_tiles"] == 1
     assert d["join_unique_tiles"] == 5 and d["join_general_tiles"] == 0
     assert d["agg_streamed_tiles"] == 0  # the dense aggregate, on the CPU
+    assert d["join_build_placed_tiles"] == 0  # which proves no live prefix
     sess.execute(" ".join(TPCH_SQL["q1"].split()))
     assert _delta(t0) == d  # q1 has no join
     out = explain(sess.catalog, "explain analyze (debug) "
@@ -351,3 +354,71 @@ def test_a_slow_query_bundle_carries_the_two_tags(sess, settled):
     finally:
         settings.reset("sql.log.slow_query.latency_threshold")
     assert "join_build_rows" in text and "join_null_extended_tiles" in text
+
+
+# ---- PR 40: the two builds place their aggregates' tiles
+
+@pytest.fixture(scope="module")
+def tiny():
+    return tpch.gen_tpch(sf=0.001, seed=SEEDS[0])
+
+
+@pytest.fixture()
+def six_tiles(streamed):
+    """The chip's shape at SF1 in small: `lineitem` in six tiles."""
+    settings.set("sql.distsql.tile_size", 1024)
+    yield
+    settings.reset("sql.distsql.tile_size")
+
+
+@pytest.mark.parametrize("nation", NATIONS)
+def test_q21_on_the_placed_route_equals_the_reference(cat, host, streamed,
+                                                      nation):
+    """Four lineitem tiles a pass: each build places four streamed tiles
+    and a tail at their running offsets, the last short of its capacity."""
+    settings.set("sql.distsql.tile_size", 1 << 14)
+    plancache.cache_for(cat).clear()
+    s = Session(cat)
+    try:
+        t0 = _tags()
+        got = s.execute(Q21.format(nation=nation))
+        d = _delta(t0)
+    finally:
+        s.close()
+        plancache.cache_for(cat).clear()
+        settings.reset("sql.distsql.tile_size")
+    _assert_answer(got, _reference(host, nation))
+    assert d["agg_streamed_tiles"] == 8 and d["agg_merge_rows"] == 0
+    assert d["join_build_placed_tiles"] == 10
+    assert d["join_build_rows"] == 2 * 65536
+
+
+def test_the_tag_pr40_added_reads_fourteen_on_the_streamed_route(
+        tiny, six_tiles):
+    """Two builds of seven tiles each (six streamed and the tail), as the
+    chip's at SF1; the count is host-known and needs the tile's producer
+    only, so EXPLAIN ANALYZE's batch-by-batch pull reads the same, and a
+    slow-query bundle carries it."""
+    from cockroach_tpu.sql import diagnostics
+
+    s = Session(tiny)
+    try:
+        t0 = _tags()
+        s.execute(Q21.format(nation="JAPAN"))
+        d = _delta(t0)
+        assert d["agg_streamed_tiles"] == 12
+        assert d["join_build_placed_tiles"] == 14
+        assert d["join_build_rows"] == 2 * 8192
+        out = explain(tiny, "explain analyze (debug) "
+                      + Q21.format(nation="JAPAN"))
+        assert "'join_build_placed_tiles': 14" in out
+        settings.set("sql.log.slow_query.latency_threshold", 1e-9)
+        s.execute(Q21.format(nation="INDIA"))
+        listing = diagnostics.bundles()
+        assert listing and listing[0]["trigger"] == "slow_query"
+        text = repr(diagnostics.get(listing[0]["id"])["trace"])
+        assert "'join_build_placed_tiles': 14" in text
+    finally:
+        settings.reset("sql.log.slow_query.latency_threshold")
+        s.close()
+        plancache.cache_for(tiny).clear()

@@ -238,6 +238,39 @@ def test_general_join_emit_at_q13_shapes_has_no_loop(one_chip):
     print("q13 emit memory:", compiled.memory_analysis())
 
 
+def test_placed_join_build_at_q21_shapes_has_one_scatter(one_chip):
+    """`hashjoin_lut` as the served Q21 builds it at SF1 from a streaming
+    aggregate's output (PR 40): six 1,048,576-row tiles and the 1,024-row
+    tail, three INT64 columns, into 2,097,152 rows under a 23-bit key.
+    Placed by `concat_prefix` the program holds one scatter, the LUT's, and
+    a dynamic-update-slice a tile (`concat`'s holds a scatter for every
+    tile, column and bitmap, and compiles ten times as long)."""
+    from cockroach_tpu.coldata import INT64, Schema
+    from cockroach_tpu.coldata.batch import concat_prefix, empty_batch
+    from cockroach_tpu.ops import join as jn
+
+    schema = Schema.of(l_orderkey=INT64, lo=INT64, hi=INT64)
+    layout = jn.ExactKeyLayout((("int", 1, 23),), 23)
+    cap = 1 << 21
+    tiles = tuple(
+        jax.tree.map(
+            lambda x: jax.ShapeDtypeStruct((rows,) + x.shape[1:], x.dtype,
+                                           sharding=one_chip),
+            empty_batch(schema, 8))
+        for rows in (1 << 20,) * 6 + (1024,))
+
+    def lut(ts):
+        big = concat_prefix(list(ts), capacity=cap)
+        return big, jn.build_dense_lut(big, (0,), layout, None)
+
+    big, index = jax.eval_shape(lut, tiles)
+    assert big.capacity == cap and index.shape == (1 << 23,)
+    # crlint: allow-raw-jit(AOT compile for a described chip: nothing is dispatched)
+    text = jax.jit(lut).lower(tiles).compile().as_text()
+    assert len(re.findall(r"\bscatter\(", text)) == 1
+    assert len(re.findall(r"\bdynamic-update-slice\(", text)) >= 7
+
+
 def test_described_devices_are_not_attached(topo):
     """The process still runs on the CPU mesh: describing a chip must not
     change what jax.devices() reports to the rest of the suite."""
