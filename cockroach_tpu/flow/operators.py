@@ -34,7 +34,8 @@ import numpy as np
 
 from ..catalog import SHAPE_BUCKETS, Table
 from ..coldata.batch import (
-    Batch, Column, Dictionary, concat, concat_prefix, empty_batch, pad_rows,
+    Batch, Column, Dictionary, concat, concat_prefix, empty_batch, from_host,
+    pad_rows,
 )
 from ..coldata.types import FLOAT64, Family, Schema
 from ..ops import aggregation as agg_ops
@@ -469,6 +470,43 @@ class IndexScanOp(SourceOperator):
 
         pks = ixm.scan_pks(self.table, self.ix, self.lo, self.hi)
         self._batch = ixm.Streamer(self.table).fetch(pks, self.names)
+        super().init()
+
+    def _next(self):
+        b, self._batch = self._batch, None
+        return b
+
+
+class PointLookupOp(SourceOperator):
+    """Primary-key point read (plan/spec.PointLookup): each key is read
+    through the transaction the statement runs in (``KVTable.point_rows``:
+    kv.Txn.Get inside one, the retrying autocommit read outside), the
+    values decode on the host (storage/rowcodec.py) and land as one batch
+    of a fixed capacity. The keys are host values: literals, or the plan
+    cache's ``Param`` slots read at init, so another key is another
+    argument to the same operator and compiles nothing."""
+
+    def __init__(self, table, keys, columns: tuple[str, ...] | None = None,
+                 params=None):
+        super().__init__()
+        self.table = table
+        self.keys = tuple(keys)
+        self.names = tuple(columns or table.schema.names)
+        self._params = params
+        _wire_source_metadata(self, table, self.names)
+        self._batch = None
+
+    def _pks(self) -> list[int]:
+        args = self._params.args() if self._params is not None else ()
+        return [int(args[k.slot]) if isinstance(k, ex.Param)
+                else int(k.value) for k in self.keys]
+
+    def init(self):
+        from ..plan.spec import PointLookup
+
+        arrays, valids = self.table.point_rows(self._pks(), self.names)
+        self._batch = from_host(self.output_schema, arrays, valids,
+                                capacity=PointLookup.MAX_KEYS)
         super().init()
 
     def _next(self):

@@ -159,7 +159,8 @@ class Streamer:
         if len(pks) == 0:
             return empty_batch(schema, cap_out)
         eng = tbl.db.engine
-        view = eng._merged_view()
+        with eng.mu:
+            view = eng._merged_view()
         if view is None:
             return empty_batch(schema, cap_out)
         ts = tbl.read_ts if tbl.read_ts is not None else tbl.db.clock.now()

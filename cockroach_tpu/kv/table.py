@@ -22,6 +22,8 @@ string path.
 
 from __future__ import annotations
 
+import contextlib
+
 import jax.numpy as jnp
 import numpy as np
 
@@ -32,6 +34,15 @@ from ..storage.lsm import Engine, WriteIntentError
 from .txn import DB, Txn
 
 _UNSUPPORTED = (Family.BYTES, Family.JSON)
+
+
+def _merged_view(eng):
+    """The engine's merged view, built under its mutex: the build reads the
+    memtable's lists, which a writer of another session appends to (an
+    unlocked build met a list longer than the length it had read: ROADMAP
+    D11 (a), found by tests/test_kv95.py's 64 sessions)."""
+    with getattr(eng, "mu", contextlib.nullcontext()):
+        return eng._merged_view()
 
 
 class _TableDict:
@@ -97,6 +108,9 @@ class KVTable:
         # explicit-txn SELECT path sets both around each statement)
         self.read_ts: int | None = None
         self.reader_txn: int = 0
+        # the transaction itself, for reads made key by key through it
+        # (point_rows); None = autocommit
+        self.reader: Txn | None = None
         # STRING columns: dictionary-coded in the value slots; the mapping
         # persists in a companion key space of the same engine
         self._string_cols = tuple(
@@ -283,14 +297,18 @@ class KVTable:
 
     def bulk_load(self, columns: dict[str, np.ndarray],
                   valids: dict[str, np.ndarray] | None = None,
-                  chunk: int = 1 << 18) -> int:
+                  chunk: int = 1 << 18, presorted: bool = False) -> int:
         """Bulk-load typed host columns through the AddSSTable path: string
         columns dictionary-encode vectorized (np.unique + merge), values
         encode in one numpy pass (rowcodec.encode_rows), keys batch-encode,
         and each chunk lands as ONE sorted engine run — the IMPORT
         discipline (bulk writes skip the memtable/WAL and the per-row txn
         machinery; the load is atomic per chunk and idempotent to re-run
-        at a higher timestamp)."""
+        at a higher timestamp). ``presorted=True`` promises primary keys
+        that ascend strictly (the key encoding keeps their order): each
+        chunk then lands as it is, with no device sort at all (an
+        11-operand sort of a 262,144-row chunk is minutes of compile on
+        the chip)."""
         cols = dict(columns)
         n = len(next(iter(cols.values())))
         # vectorized dictionary encoding for STRING columns
@@ -324,7 +342,15 @@ class KVTable:
         from ..storage import ingest as bulk
 
         use_bulk = bulk.enabled()
-        if use_bulk:
+        if presorted:
+            if n > 1 and not (np.diff(pks) > 0).all():
+                raise ValueError("presorted bulk_load needs primary keys "
+                                 "that ascend strictly")
+            for lo in range(0, n, chunk):
+                hi = min(lo + chunk, n)
+                self.db.engine.ingest(keys[lo:hi], values[lo:hi], ts=ts,
+                                      presorted=True)
+        elif use_bulk:
             # run-builder route: chunks accumulate into device-built
             # sorted/deduped runs (storage/ingest.py) and link into the
             # LSM with one WAL record per run
@@ -415,6 +441,32 @@ class KVTable:
                 row[name] = self._dicts[i].values[int(code)]
         return row
 
+    def point_rows(self, pks, names: tuple[str, ...]):
+        """The rows whose primary key is in `pks`, read key by key through
+        the transaction: kv.Txn.Get inside one (the read lands in its read
+        spans, a foreign intent is its retryable conflict), else the
+        autocommit read that waits a foreign intent out and retries
+        (kv.DB.get_committed). Never a decode of the table. -> (host
+        columns, valid masks) of `names` for the keys found, in the order
+        asked and once each; STRING columns stay dictionary codes."""
+        from ..utils import metric
+
+        t = self.reader
+        rows = []
+        for pk in dict.fromkeys(int(p) for p in pks):
+            key = rowcodec.encode_pk(self.table_id, pk)
+            v = t.get(key) if t is not None else self.db.get_committed(key)
+            if v is not None:
+                rows.append(rowcodec.decode_row(self.schema, v))
+        metric.KV_POINT_READS.inc(len(pks))
+        arrays, valids = {}, {}
+        for n in names:
+            vals = [r[n] for r in rows]
+            valids[n] = np.array([v is not None for v in vals], dtype=bool)
+            arrays[n] = np.array([0 if v is None else v for v in vals],
+                                 dtype=self.schema.type_of(n).dtype)
+        return arrays, valids
+
     def get_row(self, pk: int, ts: int | None = None) -> dict | None:
         v = self.db.get(rowcodec.encode_pk(self.table_id, int(pk)), ts=ts)
         if v is None:
@@ -443,7 +495,7 @@ class KVTable:
         # which change visibility without consuming a write sequence
         if self._count_cache is not None and self._count_cache[0] == key:
             return self._count_cache[1]
-        view = eng._merged_view()
+        view = _merged_view(eng)
         if view is None:
             n = 0
         else:
@@ -465,8 +517,17 @@ class KVTable:
         self.table_stats = st
 
     def estimated_rows(self) -> int:
+        """What planning reads at every bind (join ordering): ANALYZE's
+        count, else the store's host-side count of the span's versions
+        (an upper bound; no device work, no merged view), else, on a
+        backend without that count, the exact ``num_rows``."""
         st = getattr(self, "table_stats", None)
-        return st.row_count if st is not None else self.num_rows
+        if st is not None:
+            return st.row_count
+        estimate = getattr(self.db.engine, "span_versions_estimate", None)
+        if estimate is None:
+            return self.num_rows
+        return estimate(*rowcodec.table_span(self.table_id))
 
     def col_stats(self) -> dict[str, tuple]:
         st = getattr(self, "table_stats", None)
@@ -489,7 +550,7 @@ class KVTable:
         from ..storage import rowcodec
 
         eng: Engine = self.db.engine
-        view = eng._merged_view()
+        view = _merged_view(eng)
         if view is None:
             return 0
         start, end = rowcodec.table_span(self.table_id)
@@ -550,12 +611,14 @@ class KVTable:
         the span, exactly like the row read path."""
         from ..storage import keys as K
         from ..storage import mvcc
+        from ..utils import metric
 
+        metric.KV_TABLE_DECODES.inc()
         names = names or self.schema.names
         idxs = tuple(self.schema.index(n) for n in names)
         ts = self.read_ts if self.read_ts is not None else self.db.clock.now()
         eng: Engine = self.db.engine
-        view = eng._merged_view()
+        view = _merged_view(eng)
         if view is None:
             from ..coldata.batch import empty_batch
 

@@ -22,6 +22,7 @@ multi-host lands.
 from __future__ import annotations
 
 import itertools
+import time
 from dataclasses import dataclass, field
 
 from ..storage.lsm import Engine, WriteIntentError
@@ -46,6 +47,21 @@ _rp(TransactionAbortedError)
 
 
 _txn_ids = itertools.count(1)
+
+# tries a server-side retry loop makes before the conflict goes to the
+# client (kv.DB.Txn's and the autocommit point read's)
+MAX_RETRIES = 16
+# how long a write stands in line for a key another transaction holds
+# before it gives up as a retryable conflict
+LOCK_WAIT_S = 0.5
+
+
+def _backoff(attempt: int) -> None:
+    """Wait out the holder of a conflicting intent before the next try:
+    1 ms doubling to 64 ms (the reference queues the waiter in the lock
+    table; a retry that comes back at once only meets the same intent, and
+    takes the engine's mutex from the transaction that would resolve it)."""
+    time.sleep(min(0.064, 0.001 * (1 << attempt)))
 
 
 @dataclass
@@ -116,27 +132,50 @@ class Txn:
 
     def _write(self, key: bytes, value, tomb: bool) -> None:
         self._check_open()
-        # the lock-check + write pair holds the engine mutex so a concurrent
-        # txn can't interleave between the check and the intent landing
-        # (latch-acquisition atomicity, concurrency_manager.SequenceReq)
-        with self.db.engine.mu:
-            other = self.db.engine.other_intent(key, self.txn_id)
-            if other is not None:
-                _record_contention(
-                    WriteIntentError([key], [other]), self.txn_id
-                )
+        eng = self.db.engine
+        deadline = time.monotonic() + LOCK_WAIT_S
+        nap = 0.001
+        while True:
+            # the lock-check + write pair holds the engine mutex so a
+            # concurrent txn can't interleave between the check and the
+            # intent landing (latch-acquisition atomicity,
+            # concurrency_manager.SequenceReq)
+            with eng.mu:
+                other = eng.other_intent(key, self.txn_id)
+                if other is None:
+                    if eng.newest_committed_ts(key) > self.read_ts:
+                        self._refresh_past(key)
+                    if tomb:
+                        eng.delete(key, ts=self.read_ts, txn=self.txn_id)
+                    else:
+                        eng.put(key, value, ts=self.read_ts,
+                                txn=self.txn_id)
+                    break
+            # another transaction holds the key: stand in line for it, the
+            # mutex released (the lock table's wait queue, reduced to a
+            # poll), and give up as a retryable conflict only at the
+            # deadline, which is also what breaks a wait cycle
+            if time.monotonic() >= deadline:
+                _record_contention(WriteIntentError([key], [other]),
+                                   self.txn_id)
                 raise TransactionRetryError(
-                    f"key {key!r} locked by txn {other}"
-                )
-            if self.db.engine.newest_committed_ts(key) > self.read_ts:
-                # WriteTooOld: someone committed above our snapshot
-                raise TransactionRetryError(f"write too old on {key!r}")
-            if tomb:
-                self.db.engine.delete(key, ts=self.read_ts, txn=self.txn_id)
-            else:
-                self.db.engine.put(key, value, ts=self.read_ts,
-                                   txn=self.txn_id)
+                    f"key {key!r} locked by txn {other}")
+            time.sleep(nap)
+            nap = min(0.016, 2 * nap)
         self._write_keys.append(key)
+
+    def _refresh_past(self, key: bytes) -> None:
+        """WriteTooOld on `key`: someone committed above this transaction's
+        timestamp. Move the timestamp up to now if every read made so far
+        still holds there (the span refresher; a blind write has no read
+        and always may), else the transaction must restart. Called with
+        the engine mutex held."""
+        now = self.db.clock.now()
+        for s, e, is_point in self._read_spans:
+            if self.db.engine.has_committed_writes_in(
+                    s, e, self.read_ts, now, point=is_point):
+                raise TransactionRetryError(f"write too old on {key!r}")
+        self.read_ts = now
 
     # -- lifecycle ----------------------------------------------------------
 
@@ -261,6 +300,27 @@ class DB:
         return self.engine.get(_b(key), ts=ts if ts is not None
                                else self.clock.now())
 
+    def get_committed(self, key, max_retries: int = MAX_RETRIES
+                      ) -> bytes | None:
+        """Autocommit point read that never reads through an intent and
+        hands one to the caller only as a last resort: a foreign intent at
+        or below the read timestamp is waited out (its transaction is a
+        single statement about to commit or abort) and the read is made
+        again at a fresh timestamp, ``max_retries`` times; then the
+        conflict surfaces as TransactionRetryError (SQLSTATE 40001)."""
+        from ..utils import metric
+
+        k = _b(key)
+        for attempt in range(max_retries):
+            try:
+                return self.get(k)
+            except WriteIntentError as e:
+                _record_contention(e, 0)
+                metric.TXN_RETRIES.inc()
+                _backoff(attempt)
+        raise TransactionRetryError(
+            f"read of {k!r} gave up after {max_retries} retries")
+
     def scan(self, start, end, ts: int | None = None, max_keys=None):
         return self.engine.scan(
             _b(start) if start is not None else None,
@@ -272,7 +332,7 @@ class DB:
     def new_txn(self) -> Txn:
         return Txn(self, next(_txn_ids), self.clock.now())
 
-    def txn(self, fn, max_retries: int = 16):
+    def txn(self, fn, max_retries: int = MAX_RETRIES):
         """Run fn(txn) with commit; retry on TransactionRetryError with a
         fresh timestamp (the kv.DB.Txn closure contract: fn must be
         idempotent across retries).
@@ -282,7 +342,7 @@ class DB:
         the closure could commit it twice. It rolls back local intents
         and surfaces — the application decides whether to read-verify
         and resume (TxnCoordSender surfaces ambiguity the same way)."""
-        for _ in range(max_retries):
+        for attempt in range(max_retries):
             t = self.new_txn()
             try:
                 out = fn(t)
@@ -293,6 +353,7 @@ class DB:
 
                 metric.TXN_RETRIES.inc()
                 t.rollback()
+                _backoff(attempt)
                 continue
             except BaseException:
                 # any other error: roll back so the intents don't wedge the

@@ -165,7 +165,7 @@ def _source(op: Operator) -> str:
     op = unwrap(op)
     while isinstance(op, (ops.FilterOp, ops.ProjectOp, ops.HashBucketOp)):
         op = unwrap(op.child)
-    if isinstance(op, (ops.ScanOp, ops.IndexScanOp)):
+    if isinstance(op, (ops.ScanOp, ops.IndexScanOp, ops.PointLookupOp)):
         return op.table.name
     return op.label
 
@@ -175,6 +175,8 @@ def _what(op: Operator) -> str:
         return op.table.name
     if isinstance(op, ops.IndexScanOp):
         return f"{op.table.name}@{op.ix.name}"
+    if isinstance(op, ops.PointLookupOp):
+        return f"{op.table.name}@primary"
     if isinstance(op, (ops.HashJoinOp, ops.MergeJoinOp)):
         unique = " unique" if op.spec.build_unique else ""
         return (f"{op.spec.join_type} probe={_source(op.child)} "
@@ -202,6 +204,9 @@ def _build(plan: S.PlanNode, catalog: Catalog, params=None) -> Operator:
             catalog.get(plan.table), plan.index, plan.lo, plan.hi,
             plan.columns,
         )
+    if isinstance(plan, S.PointLookup):
+        return ops.PointLookupOp(catalog.get(plan.table), plan.keys,
+                                 plan.columns, params=params)
     if isinstance(plan, S.HashBucket):
         return ops.HashBucketOp(_build(plan.input, catalog, params), plan.keys,
                                 plan.n_parts, plan.part)

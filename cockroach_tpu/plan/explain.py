@@ -20,6 +20,9 @@ def _node_label(n: S.PlanNode, op=None) -> str:
         lo = "-inf" if n.lo is None else n.lo
         hi = "+inf" if n.hi is None else n.hi
         return f"index-scan {n.table}@{n.index} [{lo}, {hi}]"
+    if isinstance(n, S.PointLookup):
+        cols = f" columns={list(n.columns)}" if n.columns else ""
+        return f"point-lookup {n.table}@primary keys={len(n.keys)}{cols}"
     if isinstance(n, S.Filter):
         return f"filter {n.predicate}"
     if isinstance(n, S.Project):

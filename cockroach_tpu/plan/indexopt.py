@@ -1,4 +1,5 @@
-"""Index selection — rewrite Filter(TableScan) into IndexScan.
+"""Index selection — rewrite Filter(TableScan) into IndexScan, or into
+PointLookup where a conjunct pins the primary key.
 
 Reference: the optimizer's GenerateIndexScans / GenerateConstrainedScans
 exploration rules turn filtered full scans into constrained index scans
@@ -12,6 +13,12 @@ conjuncts — possibly as separate stacked Filter nodes, which the rewrite
 walks as one chain). The whole original predicate stays as a residual
 filter over the fetched rows — re-applying the bound conjunct is one fused mask op,
 and it keeps boundary/NULL semantics independent of the span math.
+
+The primary key is a route of its own, taken first and behind no setting
+or statistic: ``pk = c`` or ``pk IN (c1 .. cn)`` (the binder lowers IN to an
+OR of equalities) over a KV-backed table becomes ``PointLookup``; that
+conjunct is answered by the lookup itself and the others stay as Filters
+above it.
 
 Selectivity gate: the scan flips to the index only when the constrained
 value range is estimated under ``sql.opt.index_scan_max_frac`` of the
@@ -108,14 +115,49 @@ def _selective_enough(table, ix, lo, hi) -> bool:
     return frac <= settings.get("sql.opt.index_scan.max_frac")
 
 
+def _pk_points(c: ex.Expr, pk_pos: int) -> tuple[ex.Const, ...] | None:
+    """The literals of `pk = c` or `pk = c1 OR pk = c2 ...`, else None."""
+    parts = c.args if isinstance(c, ex.BoolOp) and c.op == "or" else (c,)
+    keys = []
+    for part in parts:
+        m = _col_bound(part)
+        if m is None or m[0] != pk_pos or m[1] != "eq":
+            return None
+        keys.append(part.right if isinstance(part.right, ex.Const)
+                    else part.left)
+    return tuple(keys) if len(keys) <= S.PointLookup.MAX_KEYS else None
+
+
+def _point_lookup(scan: S.TableScan, table, preds) -> S.PlanNode | None:
+    """Filter chain over `scan` -> PointLookup with the residual Filters,
+    when a conjunct pins the primary key."""
+    names = scan.columns or table.schema.names
+    if table.pk not in names:
+        return None
+    pk_pos = names.index(table.pk)
+    levels = [_conjuncts(p) for p in preds]
+    for conjs in levels:
+        for c in conjs:
+            keys = _pk_points(c, pk_pos)
+            if keys is None:
+                continue
+            node: S.PlanNode = S.PointLookup(scan.table, keys, scan.columns)
+            for rest in reversed([[x for x in lv if x is not c]
+                                  for lv in levels]):
+                if rest:
+                    node = S.Filter(node, rest[0] if len(rest) == 1
+                                    else ex.BoolOp("and", tuple(rest)))
+            return node
+    return None
+
+
 def use_indexes(plan: S.PlanNode, catalog) -> S.PlanNode:
     """Recursively rewrite eligible Filter(TableScan) subtrees."""
-    if not settings.get("sql.opt.index_scan.enabled"):
-        return plan
-    return _rewrite(plan, catalog)
+    return _rewrite(plan, catalog,
+                    settings.get("sql.opt.index_scan.enabled"))
 
 
-def _rewrite(plan, catalog):
+def _rewrite(plan, catalog, secondary: bool):
     from ..kv.table import KVTable
 
     if isinstance(plan, S.Filter):
@@ -132,7 +174,11 @@ def _rewrite(plan, catalog):
         if isinstance(inner, S.TableScan):
             scan = inner
             table = catalog.tables.get(scan.table)
-            if (isinstance(table, KVTable) and table.indexes
+            if isinstance(table, KVTable) and scan.shard is None:
+                node = _point_lookup(scan, table, preds)
+                if node is not None:
+                    return node
+            if (secondary and isinstance(table, KVTable) and table.indexes
                     and scan.shard is None):
                 names = scan.columns or table.schema.names
                 indexed = {ix.col: ix for ix in table.indexes}
@@ -155,11 +201,11 @@ def _rewrite(plan, catalog):
     for f in dataclasses.fields(plan):
         v = getattr(plan, f.name)
         if isinstance(v, S.PlanNode):
-            nv = _rewrite(v, catalog)
+            nv = _rewrite(v, catalog, secondary)
             if nv is not v:
                 changes[f.name] = nv
         elif isinstance(v, tuple) and v and isinstance(v[0], S.PlanNode):
-            nv = tuple(_rewrite(x, catalog) for x in v)
+            nv = tuple(_rewrite(x, catalog, secondary) for x in v)
             if any(a is not b for a, b in zip(nv, v)):
                 changes[f.name] = nv
     return dataclasses.replace(plan, **changes) if changes else plan

@@ -48,6 +48,25 @@ class IndexScan(PlanNode):
 
 
 @dataclass(frozen=True)
+class PointLookup(PlanNode):
+    """Primary-key point read of a KV-backed table: the rows whose primary
+    key is one of ``keys``, each read by key through the transaction
+    (kv.Txn.Get / kv.DB point read: bloom, host seek, one small window a
+    run), never a decode of the table. ``keys`` are INT literals, or
+    ``Param`` slots once the plan cache has parameterized the plan: a
+    statement with another key binds the same plan. Planned for
+    ``pk = c`` and ``pk IN (c1 .. cn)`` by plan/indexopt.py, whatever
+    secondary indexes the table has."""
+
+    # its batch has one capacity; a longer IN list stays a scan
+    MAX_KEYS = 128
+
+    table: str
+    keys: tuple[Expr, ...]
+    columns: tuple[str, ...] | None = None
+
+
+@dataclass(frozen=True)
 class HashBucket(PlanNode):
     """Keep only rows whose key-hash bucket equals `part` of `n_parts` —
     one outgoing stream of a HashRouter (colflow/routers.go:420): a

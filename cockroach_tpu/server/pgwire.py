@@ -174,8 +174,8 @@ class _Conn:
             if "rows_affected" in res:
                 n = res["rows_affected"]
                 low = sql_text.strip().lower()
-                if low.startswith("insert"):
-                    tag = b"INSERT 0 %d" % n
+                if low.startswith(("insert", "upsert")):
+                    tag = b"INSERT 0 %d" % n  # CockroachDB's tag for UPSERT too
                 elif low.startswith("update"):
                     tag = b"UPDATE %d" % n
                 elif low.startswith("delete"):

@@ -39,7 +39,8 @@ KEYWORDS = {
     "year", "month", "day", "date", "interval", "join", "inner", "left",
     "right", "outer", "on", "asc", "desc", "distinct", "all", "union",
     "substring", "for", "true", "false", "any", "some", "with",
-    "create", "table", "primary", "key", "insert", "into", "values",
+    "create", "table", "primary", "key", "insert", "upsert", "into",
+    "values",
     "update", "set", "delete", "default", "alter", "add", "column", "drop",
     "index",
     "over", "partition", "rows", "range", "groups", "unbounded",
@@ -339,6 +340,10 @@ class Insert(Node):
     columns: tuple[str, ...] | None  # None = all, in schema order
     rows: tuple[tuple[Node, ...], ...]  # VALUES literal rows
     select: Optional["Select"] = None  # INSERT INTO ... SELECT
+    # UPSERT INTO: a row whose primary key exists is overwritten, blind
+    # (no read first). INSERT ... VALUES happens to do the same today,
+    # where SQL asks for a duplicate-key error (ROADMAP D11)
+    upsert: bool = False
 
 
 @dataclass(frozen=True)
@@ -425,7 +430,7 @@ class Parser:
 
     def parse_statement(self) -> Node:
         """Statement entry: SELECT (incl. WITH) | CREATE TABLE | INSERT |
-        UPDATE | DELETE. Reference grammar: pkg/sql/parser/sql.y."""
+        UPSERT | UPDATE | DELETE. Reference grammar: pkg/sql/parser/sql.y."""
         if self.at_kw("create"):
             if self.peek(1).value.lower() == "index":
                 s = self.parse_create_index()
@@ -435,7 +440,7 @@ class Parser:
             s = self.parse_drop_index()
         elif self.at_kw("alter"):
             s = self.parse_alter_table()
-        elif self.at_kw("insert"):
+        elif self.at_kw("insert", "upsert"):
             s = self.parse_insert()
         elif self.at_kw("update"):
             s = self.parse_update()
@@ -546,7 +551,9 @@ class Parser:
         )
 
     def parse_insert(self) -> Insert:
-        self.expect_kw("insert")
+        upsert = bool(self.eat_kw("upsert"))
+        if not upsert:
+            self.expect_kw("insert")
         self.expect_kw("into")
         table = self.next().value
         columns = None
@@ -557,7 +564,7 @@ class Parser:
             self.expect_op(")")
         if self.at_kw("select", "with"):
             return Insert(table, tuple(columns) if columns else None, (),
-                          select=self.parse())
+                          select=self.parse(), upsert=upsert)
         self.expect_kw("values")
         rows = []
         while True:
@@ -570,7 +577,7 @@ class Parser:
             if not self.eat_op(","):
                 break
         return Insert(table, tuple(columns) if columns else None,
-                      tuple(rows))
+                      tuple(rows), upsert=upsert)
 
     def parse_update(self) -> Update:
         self.expect_kw("update")

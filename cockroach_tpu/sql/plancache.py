@@ -120,8 +120,8 @@ class ParamStore:
 
 
 def parameterize(plan, tables: bool = True):
-    """Rewrite numeric Filter-predicate literals into Param slots and
-    string-predicate lookup tables into ParamLookup slots.
+    """Rewrite numeric Filter-predicate literals and PointLookup keys into
+    Param slots and string-predicate lookup tables into ParamLookup slots.
 
     Returns ``(pplan, values, types)``: the parameterized plan (shared
     across every statement with the same shape), the extracted literal
@@ -185,6 +185,8 @@ def parameterize(plan, tables: bool = True):
             v = getattr(n, f.name)
             if isinstance(n, S.Filter) and f.name == "predicate":
                 nv = walk_expr(v)
+            elif isinstance(n, S.PointLookup) and f.name == "keys":
+                nv = walk_field(v)
             elif isinstance(v, S.PlanNode):
                 nv = walk_plan(v)
             elif (isinstance(v, tuple) and v
@@ -241,7 +243,7 @@ def _table_names(plan) -> list[str]:
     names: set[str] = set()
 
     def walk(n):
-        if isinstance(n, (S.TableScan, S.IndexScan)):
+        if isinstance(n, (S.TableScan, S.IndexScan, S.PointLookup)):
             names.add(n.table)
         for f in ("input", "probe", "build"):
             c = getattr(n, f, None)
@@ -500,11 +502,18 @@ def _run_entry(entry, values, status: str):
     other)."""
     from ..flow import runtime
 
-    with entry.lock:
+    if not entry.lock.acquire(blocking=False):
+        # operator trees hold pull state: sessions sending one statement
+        # shape queue here, and a traced statement says for how long
+        with tracing.leaf_span("sql.plancache.entry_wait"):
+            entry.lock.acquire()
+    try:
         entry.store.set_values(values)
         with tracing.leaf_span("query", cache=status,
                                lookup_tables_bound=entry.store.tables):
             return runtime.run_operator(entry.root)
+    finally:
+        entry.lock.release()
 
 
 def run_memoized(catalog, text: str):

@@ -479,11 +479,13 @@ class Session:
                 for t in kv_tables:
                     t.read_ts = txn.read_ts
                     t.reader_txn = txn.txn_id
+                    t.reader = txn
                 yield
             finally:
                 for t in kv_tables:
                     t.read_ts = None
                     t.reader_txn = 0
+                    t.reader = None
 
         return ctx()
 
@@ -514,13 +516,18 @@ class Session:
         txn = self._txn
         with self._read_as(txn):
             rel = Binder(self.catalog).bind(stmt)
-            for t in self._scanned_kv_tables(rel.plan):
+            # of the plan as it runs: a PointLookup reads through txn.get,
+            # which notes its own point span, not the table's
+            plan = rel.optimized_plan()
+            for t in self._scanned_kv_tables(plan):
                 from ..storage import rowcodec as _rc
 
                 start, end = _rc.table_span(t.table_id)
                 txn.note_read_span(start, end)
+            from ..flow.runtime import run_plan
+
             try:
-                return rel.run()
+                return run_plan(plan, self.catalog)
             except WriteIntentError as e:
                 self._txn_aborted = True
                 raise TransactionRetryError(
@@ -528,11 +535,12 @@ class Session:
                 ) from e
 
     def _scanned_kv_tables(self, plan):
-        """KVTables named by TableScan nodes anywhere in a plan tree."""
+        """KVTables named by TableScan or IndexScan nodes anywhere in a
+        plan tree."""
         from ..plan import spec as S
 
         out = []
-        if isinstance(plan, S.TableScan):
+        if isinstance(plan, (S.TableScan, S.IndexScan)):
             t = self.catalog.tables.get(plan.table)
             if isinstance(t, KVTable):
                 out.append(t)
@@ -1004,6 +1012,12 @@ class Session:
         raise NotALiteral(f"not a literal: {e}")
 
     def _insert(self, stmt: P.Insert):
+        """INSERT and UPSERT. Both write blind puts through
+        KVTable.insert_rows / insert (no read first unless the table has a
+        secondary index to maintain), in an implicit transaction the
+        server retries (``_run_write``): UPSERT by its meaning; INSERT ...
+        VALUES because it does not check for the key yet, where SQL asks
+        for a duplicate-key error (ROADMAP D11)."""
         t = self._kv_table(stmt.table)
         names = stmt.columns or t.schema.names
         for n in names:

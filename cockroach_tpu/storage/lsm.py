@@ -196,9 +196,51 @@ class _Memtable:
     value: list[bytes] = field(default_factory=list)  # inline slot bytes
     vlen: list[int] = field(default_factory=list)  # LOGICAL value length
     # (vlen > engine.val_width marks an overflow pointer record)
+    # txn id -> positions of its intents in the lists above: a commit
+    # rewrites exactly those rows, without a walk over the memtable
+    intents: dict[int, list[int]] = field(default_factory=dict)
+    # the keys above as a set: a point read of a key that is not here
+    # leaves the memtable out (no block built, nothing uploaded)
+    keyset: set[bytes] = field(default_factory=set)
 
     def __len__(self) -> int:
         return len(self.ts)
+
+    def append(self, key: bytes, ts: int, seq: int, txn: int, tomb: bool,
+               value: bytes, vlen: int) -> None:
+        if txn != 0:
+            self.intents.setdefault(txn, []).append(len(self.ts))
+        self.keyset.add(key)
+        self.keys.append(key)
+        self.ts.append(ts)
+        self.seq.append(seq)
+        self.txn.append(txn)
+        self.tomb.append(tomb)
+        self.value.append(value)
+        self.vlen.append(vlen)
+
+    def resolve(self, txn: int, commit_ts: int, commit: bool) -> int:
+        """Commit (ts := commit_ts, txn := 0) or abort (drop) txn's intents
+        in place; -> how many rows it touched."""
+        rows = self.intents.pop(txn, None)
+        if not rows:
+            return 0
+        if commit:
+            for i in rows:
+                self.ts[i] = commit_ts
+                self.txn[i] = 0
+            return len(rows)
+        dead = set(rows)
+        keep = [i for i in range(len(self.ts)) if i not in dead]
+        for name in ("keys", "ts", "seq", "txn", "tomb", "value", "vlen"):
+            col = getattr(self, name)
+            setattr(self, name, [col[i] for i in keep])
+        self.keyset = set(self.keys)
+        self.intents = {}
+        for i, t in enumerate(self.txn):
+            if t != 0:
+                self.intents.setdefault(t, []).append(i)
+        return len(rows)
 
 
 class _TsCache:
@@ -299,7 +341,9 @@ class Engine:
         compact_width: int = 4,
     ):
         assert key_width % 8 == 0
-        self.mu = locks.rlock("storage.engine")
+        # a statement's wait for the store shows in its trace
+        self.mu = locks.rlock("storage.engine",
+                              wait_span="storage/engine.lock_wait")
         from ..utils import settings
 
         self.key_width = key_width
@@ -335,7 +379,16 @@ class Engine:
         # the per-write WriteTooOld check off the device
         self._newest_committed = _TsCache(key_width)
         # read caches, invalidated by generation counters
-        self._gen = 0  # bumps whenever the run set changes
+        self._gen = 0  # bumps whenever anything but an append changes what
+        # a read sees: the run set, or intents resolved in the memtable
+        self._runs_gen = 0  # bumps only when the run set changes
+        # runs that hold intents, by id with a strong run ref (as
+        # _run_meta): (run, {txn ids}). Learned where rows with txn != 0
+        # enter a run (flush, import, checkpoint restore) and carried
+        # across rewrites, so a commit touches only the runs that hold
+        # its transaction's intents — none, unless a flush came between
+        # the write and the commit
+        self._run_intents: dict[int, tuple[mvcc.KVBlock, set[int]]] = {}
         # per-run read-path metadata — seek keys + split-block bloom +
         # the token namespacing the run's block-cache entries
         # (storage/blockcache.py); keyed by id with a strong run ref so
@@ -343,7 +396,7 @@ class Engine:
         self._run_meta: dict[int, tuple[mvcc.KVBlock, blockcache.RunMeta]] = {}
         self._runs_view_cache: tuple[int, mvcc.KVBlock] | None = None
         self._scan_windows: dict[int, int] = {}  # max_keys -> learned window
-        self._mem_cache: tuple[int, mvcc.KVBlock] | None = None
+        self._mem_cache = None  # ((mem len, gen), sorted block)
         self._overlay_cache = None  # ((gen, mem len), merged view)
         # variable-width value overflow heap (the WiscKey / pebble
         # value-separation shape): values longer than the fixed inline
@@ -587,13 +640,7 @@ class Engine:
             off = len(self._blob)
             self._blob += v
             v = off.to_bytes(8, "little")
-        self.mem.keys.append(b)
-        self.mem.ts.append(ts)
-        self.mem.seq.append(seq)
-        self.mem.txn.append(txn)
-        self.mem.tomb.append(tomb)
-        self.mem.value.append(v)
-        self.mem.vlen.append(n)
+        self.mem.append(b, ts, seq, txn, tomb, v, n)
 
     # -- exactly-once RPC batches -------------------------------------------
     # (kvserver's replay protection reduced: the server consults this
@@ -702,12 +749,27 @@ class Engine:
 
     # -- flush / compaction -------------------------------------------------
 
+    def _runs_changed(self) -> None:
+        self._gen += 1
+        self._runs_gen += 1
+
+    def _note_run_intents(self, run: mvcc.KVBlock, txns) -> None:
+        txns = {int(t) for t in txns if int(t) != 0}
+        if txns:
+            self._run_intents[id(run)] = (run, txns)
+
+    def _take_run_intents(self, run: mvcc.KVBlock) -> set[int]:
+        """The txn ids whose intents `run` holds; the run is leaving the
+        run set (merged, rewritten or dropped), so its entry goes."""
+        c = self._run_intents.pop(id(run), None)
+        return c[1] if c is not None and c[0] is run else set()
+
     def _mem_block(self) -> mvcc.KVBlock | None:
         if not len(self.mem):
             return None
-        if self._mem_cache is not None and self._mem_cache[0] == len(self.mem):
-            return self._mem_cache[1]
         n = len(self.mem)
+        if self._mem_cache is not None and self._mem_cache[0] == (n, self._gen):
+            return self._mem_cache[1]
         keys = K.encode_keys(self.mem.keys, self.key_width)
         vals = np.zeros((n, self.val_width), dtype=np.uint8)
         vlen = np.asarray(self.mem.vlen, dtype=np.int32)
@@ -732,7 +794,10 @@ class Engine:
             np.asarray(self.mem.tomb)[order],
             vals[order],
             vlen[order],
-            cap=_pad(n),
+            # one capacity from the first row to the flush (a memtable of
+            # the default size): the kernels that read the block, and the
+            # run it becomes, have one shape, not one a power of two
+            cap=_pad(max(n, min(self.memtable_size, 4096))),
             seq=seq_arr[order],
         )
         from ..flow import memory as flowmem
@@ -742,7 +807,7 @@ class Engine:
         # entry is replaced and the old block is GC'd
         flowmem.charge_object("storage/run-residency", blk,
                               _block_nbytes(blk))
-        self._mem_cache = (n, blk)
+        self._mem_cache = ((n, self._gen), blk)
         return blk
 
     @_locked
@@ -827,7 +892,7 @@ class Engine:
         run = blk if presorted else mvcc.sort_block(blk)
         _charge_run(run)
         self.runs.insert(0, run)
-        self._gen += 1
+        self._runs_changed()
         self.stats.flushes += 1
         self.stats.runs = len(self.runs)
         from ..utils import metric
@@ -854,9 +919,10 @@ class Engine:
         if blk is None:
             return
         self.runs.insert(0, blk)
+        self._note_run_intents(blk, self.mem.intents)
         self.mem = _Memtable()
         self._mem_cache = None
-        self._gen += 1
+        self._runs_changed()
         self.stats.flushes += 1
         self.stats.runs = len(self.runs)
         from ..utils import metric
@@ -905,14 +971,16 @@ class Engine:
                 txn=merged.txn, tomb=merged.tomb, value=merged.value,
                 vlen=merged.vlen, mask=merged.mask & keep,
             )
-            merged = _shrink(mvcc.sort_block(merged))
+            # dead rows last at the power of two over the live ones; the
+            # order is planned on the host (see mvcc.host_order)
+            merged = mvcc.sort_block_host(merged, _pad)
             kept = [r for i, r in enumerate(self.runs)
                     if i not in set(picked)]
             # the merged run replaces its sources at the oldest picked
             # position
             kept.insert(min(len(kept), picked[0]), merged)
             self.runs = kept
-            self._gen += 1
+            self._runs_changed()
             from ..utils import faults
 
             try:
@@ -927,6 +995,8 @@ class Engine:
                 for b in blocks:
                     self._drop_run_meta(b)
                 self._register_run(merged)
+                self._note_run_intents(merged, set().union(
+                    *(self._take_run_intents(b) for b in blocks)))
             self.stats.compactions += 1
             from ..utils import log, metric
 
@@ -939,7 +1009,9 @@ class Engine:
     def _merge_for_compaction(self, blocks, total: int) -> mvcc.KVBlock:
         """Pick the compaction merge: the bitonic-merge Pallas kernel
         (pallas_merge.py — pebble mergingIter role, log2(N) stages over
-        pre-sorted runs) when enabled and VMEM-sized, else concat+sort.
+        pre-sorted runs) when enabled and VMEM-sized, else a concat whose
+        order is planned on the host (mvcc.merge_blocks_host: no device
+        sort, whose compile at 64-byte keys is minutes a shape).
         Kernel output capacity is the padded power of two; the post-GC
         sort+_shrink in compact() trims it either way."""
         from ..utils import settings
@@ -953,7 +1025,7 @@ class Engine:
             return pm.merge_runs(blocks,
                                  interpret=self._pallas_merge_interpret)
         mvcc.KERNEL_CALLS["merge.jnp"] += 1
-        return mvcc.merge_blocks(blocks, cap=_pad(total))
+        return mvcc.merge_blocks_host(blocks, cap=_pad(total))
 
     # -- read views ---------------------------------------------------------
 
@@ -963,7 +1035,7 @@ class Engine:
         if not self.runs:
             return None
         if (self._runs_view_cache is not None
-                and self._runs_view_cache[0] == self._gen):
+                and self._runs_view_cache[0] == self._runs_gen):
             return self._runs_view_cache[1]
         if len(self.runs) == 1:
             view = self.runs[0]
@@ -972,7 +1044,7 @@ class Engine:
             view = _shrink(
                 mvcc.merge_blocks(tuple(self.runs), cap=_pad(total))
             )
-        self._runs_view_cache = (self._gen, view)
+        self._runs_view_cache = (self._runs_gen, view)
         return view
 
     def _merged_view(self) -> mvcc.KVBlock | None:
@@ -1010,7 +1082,9 @@ class Engine:
         of their versions may have been cut — and callers must not emit
         them. boundary None means nothing was truncated."""
         sources = []
-        mb = self._mem_block()
+        # a point read of a key the memtable does not hold leaves it out
+        mb = (self._mem_block()
+              if point is None or point in self.mem.keyset else None)
         if mb is not None:
             sources.append((mb, False))  # memtable is unsorted: never seek
         sources.extend((r, True) for r in self.runs)
@@ -1062,6 +1136,15 @@ class Engine:
                         if boundary is None or cut < boundary:
                             boundary = cut
                 m, cnt = _range_mask(win, swj, ewj)
+                if point is not None:
+                    # a point read's window IS its candidate tile (sorted,
+                    # a few hundred rows): masked, not counted and
+                    # compacted, which would cost a sync and a launch
+                    parts.append(mvcc.KVBlock(
+                        key=win.key, ts=win.ts, seq=win.seq, txn=win.txn,
+                        tomb=win.tomb, value=win.value, vlen=win.vlen,
+                        mask=m))
+                    continue
                 cnt = int(np.asarray(cnt))
                 if cnt == 0:
                     continue
@@ -1077,8 +1160,14 @@ class Engine:
         if len(parts) == 1:
             return parts[0], boundary
         total = sum(p.capacity for p in parts)
-        view = mvcc.merge_blocks(tuple(parts), cap=_pad(total, _CAND_ALIGN))
-        return view, boundary
+        # the tiles' order is planned on the host: a device merge is one
+        # sort shape for every count and size of sources (the tsdb's prune
+        # scans its whole span: a 128-row tile and a 16,384-row run made a
+        # sort of 32,768 rows that the chip's compiler had not finished
+        # after 16 minutes, under the store's mutex: my chip run, PR 41)
+        return (mvcc.merge_blocks_host(tuple(parts),
+                                       cap=_pad(total, _CAND_ALIGN)),
+                boundary)
 
     # -- per-run read metadata (blockcache.RunMeta: seek keys + bloom) ------
 
@@ -1327,54 +1416,63 @@ class Engine:
             view, jnp.int64(ts), jnp.int64(txn),
             jnp.asarray(sw), jnp.asarray(ew),
         )
-        if np.asarray(conflict).any():
-            idx = np.nonzero(np.asarray(conflict))[0]
+        # one wait for the device, not one an array
+        conflict, sel, vlen, value = jax.device_get(
+            (conflict, sel, view.vlen, view.value))
+        if conflict.any():
+            idx = np.nonzero(conflict)[0]
             raise WriteIntentError(
                 K.decode_keys(np.asarray(view.key)[idx]),
                 [int(t) for t in np.asarray(view.txn)[idx]],
             )
-        idx = np.nonzero(np.asarray(sel))[0]
+        idx = np.nonzero(sel)[0]
         if not len(idx):
             return None
         i = idx[0]
-        n = int(np.asarray(view.vlen)[i])
-        return self._resolve_value(np.asarray(view.value)[i], n)
+        return self._resolve_value(value[i], int(vlen[i]))
 
     # -- intents ------------------------------------------------------------
 
     @_locked
     def resolve_intents(self, txn: int, commit_ts: int, commit: bool):
-        """Commit or abort all of txn's intents across memtable + runs.
+        """Commit or abort all of txn's intents, where they are: in the
+        memtable in place (ts := commit_ts, txn := 0; an abort drops the
+        rows), and in a run only if that run holds an intent of this
+        transaction, which happens when a flush came between the write
+        and the commit (``_run_intents``). No flush, and no run is
+        rewritten or re-sorted, in the common case.
         WAL-logged: without a resolution record, crash replay would
         resurrect an acknowledged commit's writes as unresolved intents."""
+        from ..utils import metric
+
+        txn, commit_ts = int(txn), int(commit_ts)
         if self._wal is not None and not self._replaying:
-            self._wal_record(_REC_RESOLVE, b"", b"", int(commit_ts), 0,
-                             int(txn), commit)
+            self._wal_record(_REC_RESOLVE, b"", b"", commit_ts, 0, txn,
+                             commit)
         if commit:
+            metric.ENGINE_COMMITS.inc()
             for k, t in self._locks.items():
                 if t == txn:
-                    self._newest_committed.put(k, int(commit_ts))
+                    self._newest_committed.put(k, commit_ts)
         self._locks = {k: t for k, t in self._locks.items() if t != txn}
-        self.flush_mem_only()
-        old_runs = self.runs
-        self.runs = [
-            mvcc.sort_block(
-                mvcc.resolve_intents(
-                    r, jnp.int64(txn), jnp.int64(commit_ts), commit
-                )
-            )
-            for r in old_runs
-        ]
-        # every run object was replaced: retire their read metadata (and
-        # block-cache entries); rebuilds stay lazy — see _meta_for
-        for r in old_runs:
+        touched = self.mem.resolve(txn, commit_ts, commit)
+        for i, r in enumerate(self.runs):
+            held = self._run_intents.get(id(r))
+            if held is None or held[0] is not r or txn not in held[1]:
+                continue
+            mvcc.KERNEL_CALLS["resolve.sort_block"] += 1
+            metric.ENGINE_RESOLVE_RUN_SORTS.inc()
+            new = mvcc.sort_block_host(mvcc.resolve_intents(
+                r, jnp.int64(txn), jnp.int64(commit_ts), commit))
+            self.runs[i] = new
+            # the run object was replaced: retire its read metadata (and
+            # block-cache entries); the rebuild stays lazy, see _meta_for
             self._drop_run_meta(r)
-        self._gen += 1
-        # the per-commit memtable flush above mints a new run every commit;
-        # without a compaction hook here a commit-heavy workload grows
-        # `runs` without bound and every cold _merged_view() rebuild pays
-        # ~8ms/run — same trigger + IOGovernor pacing as the write path
-        self._maybe_compact()
+            self._note_run_intents(new, self._take_run_intents(r) - {txn})
+            self._runs_gen += 1
+            touched += 1
+        if touched:
+            self._gen += 1
 
     @_locked
     def has_committed_writes_in(
@@ -1446,6 +1544,27 @@ class Engine:
         n = int(mask.sum())
         vbytes = int(np.asarray(view.vlen)[mask].sum()) if n else 0
         return {"versions": n, "logical_bytes": n * self.key_width + vbytes}
+
+    @_locked
+    def span_versions_estimate(self, start: bytes, end: bytes) -> int:
+        """Versions in [start, end), counted on the host: two binary
+        searches over each run's seek keys and a walk of the memtable's
+        key list. An upper bound on the span's live keys (old versions,
+        tombstones and intents count too) that costs no device work and
+        merges nothing: what planning asks before every statement over a
+        KV-backed table (KVTable.estimated_rows)."""
+        n = sum(1 for k in self.mem.keys if start <= k < end)
+        for r in self.runs:
+            vkeys, n_live = self._run_keys(r)
+            if not n_live:
+                continue
+            lo, hi = (np.frombuffer(b.ljust(self.key_width, b"\x00"),
+                                    dtype=vkeys.dtype)[0]
+                      for b in (start, end))
+            live = vkeys[:n_live]
+            n += int(np.searchsorted(live, hi, side="left")
+                     - np.searchsorted(live, lo, side="left"))
+        return n
 
     @_locked
     def export_span(self, start: bytes | None, end: bytes | None) -> dict:
@@ -1588,12 +1707,13 @@ class Engine:
             vlen=jnp.asarray(padrow(rows["vlen"])),
             mask=jnp.asarray(np.arange(cap) < n),
         )
-        run = mvcc.sort_block(blk)
+        run = mvcc.sort_block_host(blk)
         _charge_run(run)
         self.runs.insert(0, run)
-        self._gen += 1
+        self._runs_changed()
         self.stats.runs = len(self.runs)
         self._register_run(run)
+        self._note_run_intents(run, np.unique(rows["txn"]))
         committed = rows["txn"] == 0
         if committed.any():
             self._newest_committed.bulk(
@@ -1628,6 +1748,7 @@ class Engine:
             # this run is rewritten or dropped: retire its read metadata
             # and block-cache entries (untouched runs keep theirs)
             self._drop_run_meta(r)
+            held = self._take_run_intents(r)
             keep = r.mask & ~m
             kept = int(np.asarray(jnp.sum(keep)))
             if kept == 0:
@@ -1636,7 +1757,9 @@ class Engine:
                 key=r.key, ts=r.ts, seq=r.seq, txn=r.txn, tomb=r.tomb,
                 value=r.value, vlen=r.vlen, mask=keep,
             )
-            new_runs.append(_shrink(mvcc.sort_block(r2)))
+            new_runs.append(mvcc.sort_block_host(r2, _pad))
+            # a superset: intents inside the cleared span went with it
+            self._note_run_intents(new_runs[-1], held)
         self.runs = new_runs
         # drop lock-table entries for the departed span
         def _in(k: bytes) -> bool:
@@ -1644,7 +1767,7 @@ class Engine:
                 return False
             return end is None or k < end
         self._locks = {k: t for k, t in self._locks.items() if not _in(k)}
-        self._gen += 1
+        self._runs_changed()
         self.stats.runs = len(self.runs)
 
     # -- stats / checkpoint -------------------------------------------------
@@ -1754,7 +1877,7 @@ class Engine:
                 )
             )
         eng.stats.runs = len(eng.runs)
-        eng._gen += 1
+        eng._runs_changed()
         # restore the write-sequence high-water mark so post-restore writes
         # keep winning same-(key, ts) tie-breaks over persisted rows, and
         # rebuild the host lock table from persisted intents
@@ -1777,6 +1900,7 @@ class Engine:
                 ts = np.asarray(r.txn)[np.nonzero(im)[0]]
                 for kk, tt in zip(ks, ts):
                     eng._locks[kk] = int(tt)
+                eng._note_run_intents(r, np.unique(ts))
         if wal_path is not None:
             # replay records that postdate the checkpoint, then arm the WAL
             eng._arm_wal(wal_path)

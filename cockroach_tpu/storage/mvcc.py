@@ -169,6 +169,73 @@ def merge_blocks(blocks: tuple[KVBlock, ...], cap: int) -> KVBlock:
     return sort_block(big)
 
 
+# -- the same order, planned on the host ------------------------------------
+# `lax.sort` over the canonical operands is 3 + key_width / 8 operands wide:
+# for the node store's 64-byte keys the chip's compiler takes 19 s at 4,096
+# rows and 650 s at 16,384 (a described v5e, PR 41), once a shape, under the
+# store's mutex. The engine's WRITE path (compaction, a run rewritten by an
+# intent resolution, a cleared span, an imported snapshot) therefore plans
+# the order on the host (one `np.lexsort` over the same operands, read back
+# from the device: 100 B a row) and moves the rows with one device gather,
+# which compiles in a second at any size. Reads keep `merge_blocks` for
+# their candidate tiles of a few hundred rows.
+
+
+def host_order(block: KVBlock) -> tuple[np.ndarray, int]:
+    """(permutation into canonical MVCC order, live rows): what
+    `_mvcc_sort_operands` + a stable `lax.sort` give, computed by
+    `np.lexsort` over host copies of mask, key, ts and seq."""
+    mask = np.asarray(block.mask)
+    words = np.ascontiguousarray(np.asarray(block.key)).view(">u8")
+    flip = np.uint64(1 << 63)
+    ts = ~(np.asarray(block.ts).astype(np.uint64) ^ flip)
+    seq = ~(np.asarray(block.seq).astype(np.uint64) ^ flip)
+    # lexsort's LAST key is the primary one
+    keys = [seq, ts] + [words[:, i] for i in range(words.shape[1] - 1, -1,
+                                                   -1)] + [~mask]
+    return np.lexsort(keys), int(mask.sum())
+
+
+@functools.partial(jax.jit, static_argnames=("cap",))  # crlint: allow-raw-jit(storage-plane kernel: dispatch budget scopes the SQL flow layer)
+def _take_rows(block: KVBlock, perm: jax.Array, n_live: jax.Array,
+               cap: int) -> KVBlock:
+    """Rows `perm[:cap]` of `block`; the first `n_live` are live, the rest
+    dead and zeroed (a dead row holds zero key bytes, as everywhere)."""
+    live = jnp.arange(cap, dtype=jnp.int32) < n_live
+
+    def take(x):
+        y = x[perm]
+        m = live.reshape((cap,) + (1,) * (y.ndim - 1))
+        return jnp.where(m, y, jnp.zeros((), y.dtype))
+
+    out = jax.tree_util.tree_map(take, block)
+    return KVBlock(key=out.key, ts=out.ts, seq=out.seq, txn=out.txn,
+                   tomb=out.tomb, value=out.value, vlen=out.vlen, mask=live)
+
+
+def sort_block_host(block: KVBlock, cap=None) -> KVBlock:
+    """`sort_block` with its order planned on the host: canonical MVCC
+    order, dead rows last, at capacity `cap`: an int, a function of the
+    live count (the engine's `_pad`: the block shrinks to its rows), or
+    None for the block's own. A `cap` under the live count is refused."""
+    perm, n_live = host_order(block)
+    cap = (block.capacity if cap is None
+           else cap(n_live) if callable(cap) else cap)
+    if n_live > cap:
+        raise ValueError(f"{n_live} live rows do not fit capacity {cap}")
+    if cap > len(perm):
+        perm = np.concatenate([perm, np.zeros(cap - len(perm), perm.dtype)])
+    return _take_rows(block, jnp.asarray(perm[:cap], jnp.int32),
+                      jnp.int32(n_live), cap)
+
+
+def merge_blocks_host(blocks: tuple[KVBlock, ...], cap: int) -> KVBlock:
+    """`merge_blocks` with its order planned on the host."""
+    big = jax.tree_util.tree_map(
+        lambda *xs: jnp.concatenate(xs, axis=0), *blocks)
+    return sort_block_host(big, cap)
+
+
 # ---------------------------------------------------------------------------
 # The scan-filter kernel
 

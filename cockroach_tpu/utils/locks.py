@@ -32,6 +32,7 @@ from . import settings
 
 __all__ = [
     "LockOrderError", "OrderedLock", "OrderedRLock", "OrderedCondition",
+    "WaitTracedRLock",
     "lock", "rlock", "condition", "reset",
 ]
 
@@ -164,6 +165,29 @@ class OrderedRLock(OrderedLock):
         return True
 
 
+class WaitTracedRLock(OrderedRLock):
+    """An OrderedRLock whose contended waits show in a statement's trace: a
+    blocking acquire first tries without blocking (the owner's re-entry and
+    a free lock end there, at no cost), and only a wait opens a leaf span
+    named ``wait_span`` around it (utils/tracing: nothing outside a traced
+    statement), so the span's total says how long statements stood in line
+    for this lock."""
+
+    def __init__(self, name: str, wait_span: str):
+        super().__init__(name)
+        self.wait_span = wait_span
+
+    def acquire(self, blocking: bool = True, timeout: float = -1) -> bool:
+        if not blocking or timeout != -1:
+            return super().acquire(blocking, timeout)
+        if super().acquire(False):
+            return True
+        from . import tracing
+
+        with tracing.leaf_span(self.wait_span):
+            return super().acquire()
+
+
 class OrderedCondition:
     """``threading.Condition`` over an OrderedRLock. ``wait`` releases the
     underlying lock, so the held-stack entry is dropped for the duration —
@@ -233,7 +257,9 @@ def lock(name: str) -> OrderedLock:
     return OrderedLock(name)
 
 
-def rlock(name: str) -> OrderedRLock:
+def rlock(name: str, wait_span: str | None = None) -> OrderedRLock:
+    if wait_span is not None:
+        return WaitTracedRLock(name, wait_span)
     return OrderedRLock(name)
 
 

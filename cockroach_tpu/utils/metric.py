@@ -226,6 +226,21 @@ PG_CONNS = DEFAULT.counter("pgwire_conns", "pgwire connections accepted")
 QUERY_SECONDS = DEFAULT.histogram(
     "sql_query_seconds", "end-to-end query latency")
 TXN_COMMITS = DEFAULT.counter("txn_commits", "committed transactions")
+KV_POINT_READS = DEFAULT.counter(
+    "sql_kv_point_reads",
+    "primary keys read by the point-lookup plan route (KVTable.point_rows)")
+KV_TABLE_DECODES = DEFAULT.counter(
+    "sql_kv_table_decodes",
+    "whole-table columnar decodes of a KV-backed table "
+    "(KVTable.device_batch: the merged view of every run, filtered)")
+ENGINE_COMMITS = DEFAULT.counter(
+    "storage_intent_commits",
+    "intent resolutions that committed (Engine.resolve_intents)")
+ENGINE_RESOLVE_RUN_SORTS = DEFAULT.counter(
+    "storage_resolve_run_sorts",
+    "runs rewritten and re-sorted by an intent resolution: a run is "
+    "touched only when it holds an intent of the resolved transaction (a "
+    "flush came between the write and the commit)")
 TXN_RETRIES = DEFAULT.counter("txn_retries", "transaction retries")
 RANGE_SPLITS = DEFAULT.counter("range_splits", "admin range splits")
 BLOOM_SKIPS = DEFAULT.counter(

@@ -77,11 +77,32 @@ def test_txn_write_write_conflict():
 
 
 def test_txn_write_too_old():
+    """A write under a newer committed version restarts the transaction
+    only if a read it made no longer holds at a later timestamp; a blind
+    write moves its timestamp up instead (the reference pushes a
+    WriteTooOld write and refreshes: since PR 41, kv.Txn._refresh_past)."""
     db = mkdb()
     t1 = db.new_txn()
+    t1.get(b"k")  # the read that the newer version invalidates
     db.put(b"k", b"newer")  # commits above t1.read_ts
     with pytest.raises(TransactionRetryError):
         t1.put(b"k", b"stale")
+    t1.rollback()
+    # a read of ANOTHER key still holds: the timestamp moves, the write lands
+    t2 = db.new_txn()
+    assert t2.get(b"other") is None
+    ts0 = t2.read_ts
+    db.put(b"k", b"newer still")
+    t2.put(b"k", b"mine")
+    assert t2.read_ts > ts0
+    t2.commit()
+    assert db.get(b"k") == b"mine"
+    # a blind write has no read to refresh
+    t3 = db.new_txn()
+    db.put(b"k", b"newest")
+    t3.put(b"k", b"blind")
+    t3.commit()
+    assert db.get(b"k") == b"blind"
 
 
 def test_txn_read_refresh_invalidation():

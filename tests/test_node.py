@@ -190,16 +190,27 @@ def test_fenced_node_stops_all_loops():
         # a peer declares node 1 dead: wait out the ttl, fence it
         lv9 = NodeLiveness(db, 9, ttl_ms=5000)
         lv9.heartbeat()
-        # freeze node 1's heartbeats by fencing as soon as its record lapses
+        # blackhole node 1's heartbeats (the scoped chaos site) so that its
+        # record lapses, and fence it as soon as it has. The record used to
+        # lapse on its own: a heartbeat's commit flushed and re-sorted the
+        # store and often outlasted the 150 ms ttl; since PR 41 a healthy
+        # node's heartbeats keep their record alive
         from cockroach_tpu.kv.liveness import StillLiveError
+        from cockroach_tpu.utils import faults
+        from cockroach_tpu.utils.faults import FaultSpec
 
-        deadline = time.time() + 10
-        while time.time() < deadline:
-            try:
-                lv9.increment_epoch(1)
-                break
-            except StillLiveError:
-                time.sleep(0.05)
+        faults.arm(41, {"liveness.heartbeat.n1": FaultSpec(kind="error",
+                                                           p=1.0)})
+        try:
+            deadline = time.time() + 10
+            while time.time() < deadline:
+                try:
+                    lv9.increment_epoch(1)
+                    break
+                except StillLiveError:
+                    time.sleep(0.05)
+        finally:
+            faults.disarm()
         # node 1's next heartbeat hits the fence and stops the WHOLE node
         deadline = time.time() + 10
         while time.time() < deadline and not n1._stop.is_set():

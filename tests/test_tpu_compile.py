@@ -271,6 +271,25 @@ def test_placed_join_build_at_q21_shapes_has_one_scatter(one_chip):
     assert len(re.findall(r"\bdynamic-update-slice\(", text)) >= 7
 
 
+def test_host_planned_run_order_at_node_store_shapes_has_no_sort(one_chip):
+    """The engine's write path at the node store's widths (64-byte keys,
+    128-byte values): a compaction's output is one gather by a permutation
+    planned on the host (mvcc.sort_block_host) and its GC filter, at a run
+    four flushed memtables make. Neither program holds a sort: `sort_block`
+    itself at these widths took the chip's compiler 18.7 s at 4,096 rows and
+    650.3 s at 16,384 in this sandbox (a scratch script, PR 41), held under
+    the store's mutex; these take seconds at any size."""
+    n = 16384
+    blk = _kvblock_shape(n, one_chip, key_width=64, val_width=128)
+    perm = jax.ShapeDtypeStruct((n,), jnp.int32, sharding=one_chip)
+    live = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    take = mvcc._take_rows.lower(blk, perm, live, cap=n).compile()
+    ts = jax.ShapeDtypeStruct((), jnp.int64, sharding=one_chip)
+    gc = mvcc.mvcc_gc_filter.lower(blk, ts, bottom=False).compile()
+    for compiled in (take, gc):
+        assert not re.search(r"\bsort\(", compiled.as_text())
+
+
 def test_described_devices_are_not_attached(topo):
     """The process still runs on the CPU mesh: describing a chip must not
     change what jax.devices() reports to the rest of the suite."""
