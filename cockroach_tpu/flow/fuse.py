@@ -84,6 +84,7 @@ class _BarrierSource(Operator):
     # it, so chain walks (HashJoinOp._composes) stop counting here
     _chain_split = True
     _passes_tiles = True
+    stateless_between_runs = True
 
     def __init__(self, inner: Operator):
         super().__init__()
@@ -107,6 +108,10 @@ class _BarrierSource(Operator):
     def stream_parts(self):
         if not self._initialized:
             self.init()
+        # a chain head like a streaming scan's: the links above carry
+        # their own structural keys, so two trees of one plan share one
+        # traced chain and one jitted pipeline (flow/dispatch.py)
+        self._parts_key = ("barrier",)
         return self, _identity_fn, ()
 
     def stream_tiles(self):
@@ -130,6 +135,7 @@ class FusedPipeline(Operator):
     pull per-operator)."""
 
     _passes_tiles = True
+    stateless_between_runs = True  # `_pipe_fn` is code, shared by key
 
     def __init__(self, top: Operator, members: list[Operator]):
         super().__init__()

@@ -90,6 +90,14 @@ class Operator:
     # tests/test_live_prefix.py pulls every claimant's tiles and checks
     emits_live_prefix = False
 
+    # between two runs this operator keeps nothing on the device and has
+    # learned nothing: no spool, build side, shared stream or emission cap,
+    # only what init() makes again. A plan made of such operators alone
+    # may be built more than once, a tree a concurrent session
+    # (sql/plancache.py `_Entry`); one operator that says False keeps its
+    # plan at one tree. Known from the operator's class, never set
+    stateless_between_runs = False
+
     def __init_subclass__(cls, **kw):
         super().__init_subclass__(**kw)
         if "KERNEL" not in cls.__dict__:

@@ -486,6 +486,8 @@ class PointLookupOp(SourceOperator):
     cache's ``Param`` slots read at init, so another key is another
     argument to the same operator and compiles nothing."""
 
+    stateless_between_runs = True  # init() reads the keys' rows anew
+
     def __init__(self, table, keys, columns: tuple[str, ...] | None = None,
                  params=None):
         super().__init__()
@@ -620,6 +622,7 @@ class FilterOp(OneInputOperator):
     zero new traces (the prepared-plan fast path)."""
 
     _passes_tiles = True
+    stateless_between_runs = True
 
     def __init__(self, child: Operator, predicate: ex.Expr, params=None):
         super().__init__(child)
@@ -695,6 +698,7 @@ def _compose_parts(op, child, raw_fn, key=None, extra=()):
 
 class ProjectOp(OneInputOperator):
     _passes_tiles = True
+    stateless_between_runs = True
 
     def __init__(self, child: Operator, exprs: tuple[ex.Expr, ...],
                  names: tuple[str, ...], dict_overrides: tuple = ()):
@@ -744,6 +748,8 @@ class ProjectOp(OneInputOperator):
 
 
 class LimitOp(OneInputOperator):
+    stateless_between_runs = True  # init() starts the count again
+
     def __init__(self, child: Operator, limit: int, offset: int = 0):
         super().__init__(child)
         self.output_schema = child.output_schema

@@ -23,10 +23,20 @@ pipeline, so the cache holds the BUILT operator tree:
   INDEX, ALTER) and tuning changes can never serve a stale plan; the
   session's DDL handlers additionally sweep dead-version entries out
   eagerly (``invalidate``).
-- A per-entry lock serializes concurrent sessions through one entry:
-  operator trees hold mutable pull state, so two sessions never drive
-  the same tree at once (they queue; distinct statements run in
-  parallel).
+- Operator trees hold mutable pull state, so two sessions never drive
+  the same tree at once. An entry keeps the trees built for its plan and
+  lends a session one no other session holds. Where every operator of
+  the plan keeps nothing between runs (``Operator.stateless_between_runs``:
+  a primary-key point read and the per-tile wrappers above it), a session
+  that finds every tree out builds one more from the entry's parameterized
+  plan, each tree with a ``ParamStore`` of its own, up to what the process
+  admits at once (``admission.sql.slots``); a further tree compiles
+  nothing, its kernels are shared by ``dispatch.kernel_key``. Any other
+  plan (spools, join build sides, learned emission caps, shared scan
+  streams) keeps exactly ONE tree and its sessions queue for it under the
+  ``sql.plancache.entry_wait`` span: a second tree would hold the build
+  sides again and learn its caps again. Distinct statements run in
+  parallel either way.
 
 Execution-stats collection (EXPLAIN ANALYZE / the cluster setting)
 bypasses the cache: stats need a fresh per-operator tree, and cached
@@ -285,16 +295,73 @@ def _settings_sig() -> tuple:
     return tuple((n, str(reg[n].get())) for n in sorted(reg))
 
 
-class _Entry:
-    __slots__ = ("root", "store", "version", "fingerprint", "lock", "hits")
+def _stateless(op) -> bool:
+    return op.stateless_between_runs and all(
+        _stateless(c) for c in op.children())
 
-    def __init__(self, root, store, version, fingerprint):
-        self.root = root
-        self.store = store
-        self.version = version
+
+class _Entry:
+    """One cached plan: what a tree is built from, and the trees built,
+    each a ``(root, ParamStore)`` pair lent to one session at a time.
+
+    ``cap`` is how many trees the entry may hold, read off the first one:
+    the process's admission slots where every operator keeps nothing
+    between runs, else 1 (the one tree IS the entry's lock then). The
+    settings signature is part of an entry's key, so the slots it was
+    created under are the slots it lives under."""
+
+    __slots__ = ("pplan", "types", "catalog", "version", "fingerprint",
+                 "hits", "first", "cap", "_free", "_trees", "_cond")
+
+    def __init__(self, pplan, types, catalog, fingerprint, tree):
+        self.pplan = pplan
+        self.types = types
+        self.catalog = catalog
+        self.version = catalog.version
         self.fingerprint = fingerprint
-        self.lock = threading.Lock()
         self.hits = 0
+        self.first = tree[0]
+        self.cap = (int(settings.get("admission.sql.slots"))
+                    if _stateless(tree[0]) else 1)
+        self._free = [tree]
+        self._trees = 1  # in `_free` or out with a session
+        self._cond = threading.Condition(threading.Lock())
+
+    def take(self):
+        """A tree no other session drives: a free one, else one more
+        where the entry may grow, else the wait for one to come back."""
+        with self._cond:
+            if not self._free and self._trees >= self.cap:
+                # sessions sending one statement shape queue here, and a
+                # traced statement says for how long
+                with tracing.leaf_span("sql.plancache.entry_wait"):
+                    while not self._free and self._trees >= self.cap:
+                        self._cond.wait()
+            if self._free:
+                return self._free.pop()
+            self._trees += 1
+        try:
+            tree = _build_tree(self.pplan, self.types, self.catalog)
+        except BaseException:
+            self.give_back(None)
+            raise
+        metric.PLAN_CACHE_POOL_BUILDS.inc()
+        return tree
+
+    def give_back(self, tree) -> None:
+        """Return a tree taken, or with None its place: a tree that holds
+        nothing is not kept after a run that raised."""
+        with self._cond:
+            if tree is None:
+                self._trees -= 1
+            else:
+                self._free.append(tree)
+            self._cond.notify()
+
+
+def _build_tree(pplan, types, catalog):
+    store = ParamStore(types)
+    return plan_builder.build(pplan, catalog, params=store), store
 
 
 class PlanCache:
@@ -467,10 +534,8 @@ def run_cached_ex(rel, text: str | None = None):
     status = "hit"
     if entry is None:
         status = "miss"
-        store = ParamStore(types)
-        store.set_values(values)
-        root = plan_builder.build(pplan, rel.catalog, params=store)
-        entry = _Entry(root, store, rel.catalog.version, _fingerprint(text))
+        entry = _Entry(pplan, types, rel.catalog, _fingerprint(text),
+                       _build_tree(pplan, types, rel.catalog))
         # run BEFORE publishing: a plan whose first execution fails never
         # enters the cache (concurrent first executions may both build;
         # insert keeps whichever published first)
@@ -502,18 +567,22 @@ def _run_entry(entry, values, status: str):
     other)."""
     from ..flow import runtime
 
-    if not entry.lock.acquire(blocking=False):
-        # operator trees hold pull state: sessions sending one statement
-        # shape queue here, and a traced statement says for how long
-        with tracing.leaf_span("sql.plancache.entry_wait"):
-            entry.lock.acquire()
+    tree = entry.take()
+    root, store = tree
+    if root is not entry.first:
+        metric.PLAN_CACHE_POOL_RUNS.inc()
+    keep = entry.cap == 1  # the one tree of a plan with state serves again
     try:
-        entry.store.set_values(values)
+        store.set_values(values)
         with tracing.leaf_span("query", cache=status,
-                               lookup_tables_bound=entry.store.tables):
-            return runtime.run_operator(entry.root)
+                               lookup_tables_bound=store.tables):
+            res = runtime.run_operator(root)
+        keep = True
+        return res
     finally:
-        entry.lock.release()
+        # after a run that raised, a tree that holds nothing is cheaper
+        # built anew than trusted half-pulled
+        entry.give_back(tree if keep else None)
 
 
 def run_memoized(catalog, text: str):

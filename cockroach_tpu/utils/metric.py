@@ -389,6 +389,15 @@ PLAN_CACHE_EVICTIONS = DEFAULT.counter(
     "sql_plan_cache_evictions",
     "prepared plans dropped by LRU capacity or catalog-version bumps "
     "(DDL invalidation)")
+PLAN_CACHE_POOL_BUILDS = DEFAULT.counter(
+    "sql_plan_cache_pool_builds",
+    "operator trees built beyond a plan-cache entry's first, because a "
+    "session found every tree of a plan that keeps nothing between runs "
+    "out with another session (sql/plancache.py)")
+PLAN_CACHE_POOL_RUNS = DEFAULT.counter(
+    "sql_plan_cache_pool_runs",
+    "statements that ran on a tree other than their plan-cache entry's "
+    "first")
 SQL_MEM_CURRENT = DEFAULT.gauge(
     "sql_mem_current",
     "logical SQL bytes currently reserved against the node's root memory "

@@ -15,11 +15,13 @@ import cockroach_tpu.catalog as catalog_mod
 from cockroach_tpu import coldata as cd
 from cockroach_tpu.bench import queries as Q
 from cockroach_tpu.bench import tpcds, tpch
+from cockroach_tpu.flow import dispatch
+from cockroach_tpu.flow import operators as ops
 from cockroach_tpu.kv import DB, ManualClock
 from cockroach_tpu.sql import Session, plancache
 from cockroach_tpu.storage import rowcodec
 from cockroach_tpu.storage.lsm import Engine
-from cockroach_tpu.utils import settings
+from cockroach_tpu.utils import metric, settings, tracing
 
 _FAST_TPCH = {"q1", "q3", "q6", "q9", "q18"}
 _FAST_TPCDS = {"q3", "q42"}
@@ -251,3 +253,218 @@ def test_warmup_thread_precompiles():
         assert dispatch.compiles() == c0  # warmed entirely off-path
     finally:
         settings.reset("sql.plan_cache.warmup.enabled")
+
+
+# --------------------------------------------------------------------------
+# an entry's trees: a plan that keeps nothing between runs is built once a
+# concurrent session, any other keeps one tree and its sessions queue
+
+POINT = "SELECT qty FROM items WHERE id = {}"
+GROUPED = "SELECT grp, sum(qty) AS s FROM items WHERE id < {} GROUP BY grp"
+
+
+def _peer(sess):
+    return Session(catalog=sess.catalog, db=sess.db, bootstrap=False)
+
+
+def _waits() -> int:
+    return tracing.totals().get("sql.plancache.entry_wait",
+                                {"count": 0})["count"]
+
+
+class _Held:
+    """Patches ``cls.init`` so that its first caller stands still inside it
+    (its tree is out with that session) until ``release``."""
+
+    def __init__(self, monkeypatch, cls):
+        self.entered, self.release = threading.Event(), threading.Event()
+        first = threading.Lock()
+        real = cls.init
+
+        def init(op):
+            if first.acquire(blocking=False):
+                self.entered.set()
+                assert self.release.wait(60)
+            real(op)
+
+        monkeypatch.setattr(cls, "init", init)
+
+    def start(self, sess, text):
+        """Send `text` on a thread that gets held; -> (thread, its box)."""
+        box = {}
+        t = _sender(sess, text, box)
+        t.start()
+        assert self.entered.wait(60)
+        return t, box
+
+
+def _sender(sess, text, box):
+    def run():
+        try:
+            box["res"] = sess.execute(text)
+        except Exception as e:  # noqa: BLE001 - asserted by the test
+            box["err"] = e
+
+    return threading.Thread(target=run)
+
+
+def _only_entry(sess):
+    (entry,) = _cache(sess)._entries.values()
+    return entry
+
+
+def test_concurrent_point_reads_each_get_a_tree_and_their_own_row(
+        monkeypatch):
+    s = _session()
+    _cache(s).clear()
+    assert list(s.execute(POINT.format(1))["qty"]) == [1]
+    held = _Held(monkeypatch, ops.PointLookupOp)
+    t0, box0 = held.start(s, POINT.format(9))
+    builds, runs = (metric.PLAN_CACHE_POOL_BUILDS.value,
+                    metric.PLAN_CACHE_POOL_RUNS.value)
+    waits = _waits()
+    peers = [(_peer(s), k, {}) for k in range(10, 18)]
+    ts = [_sender(p, POINT.format(k), box) for p, k, box in peers]
+    for t in ts:
+        t.start()
+    for t in ts:
+        t.join(60)
+    # all served while the first tree is still out: nobody stood in line
+    assert not any(t.is_alive() for t in ts) and t0.is_alive()
+    for _, k, box in peers:
+        assert "err" not in box and list(box["res"]["qty"]) == [k % 7]
+    assert metric.PLAN_CACHE_POOL_BUILDS.value > builds
+    assert metric.PLAN_CACHE_POOL_RUNS.value == runs + len(peers)
+    assert _waits() == waits
+    held.release.set()
+    t0.join(60)
+    assert list(box0["res"]["qty"]) == [9 % 7]
+    e = _only_entry(s)
+    assert e.cap == settings.get("admission.sql.slots")
+    assert 2 <= e._trees == len(e._free) <= 1 + len(peers)
+
+
+def test_a_pooled_trees_first_run_compiles_nothing(monkeypatch):
+    s = _session()
+    _cache(s).clear()
+    s.execute(POINT.format(1))
+    held = _Held(monkeypatch, ops.PointLookupOp)
+    t0, _ = held.start(s, POINT.format(2))
+    builds, c0 = metric.PLAN_CACHE_POOL_BUILDS.value, dispatch.compiles()
+    try:
+        assert list(_peer(s).execute(POINT.format(12))["qty"]) == [12 % 7]
+    finally:
+        held.release.set()
+        t0.join(60)
+    assert metric.PLAN_CACHE_POOL_BUILDS.value == builds + 1
+    assert dispatch.compiles() == c0
+
+
+def test_a_plan_with_state_keeps_one_tree_and_its_sessions_queue(
+        monkeypatch):
+    s = _session()
+    _cache(s).clear()
+    want = s.execute(GROUPED.format(30))
+    assert _only_entry(s).cap == 1
+    held = _Held(monkeypatch, ops.ScanOp)
+    t0, box0 = held.start(s, GROUPED.format(30))
+    builds, waits = metric.PLAN_CACHE_POOL_BUILDS.value, _waits()
+    box1 = {}
+    t1 = _sender(_peer(s), GROUPED.format(30), box1)
+    t1.start()
+    t1.join(0.5)
+    assert t1.is_alive()  # in line for the one tree
+    held.release.set()
+    t0.join(60)
+    t1.join(60)
+    assert not t0.is_alive() and not t1.is_alive()
+    for box in (box0, box1):
+        assert sorted(zip(box["res"]["grp"], box["res"]["s"])) == sorted(
+            zip(want["grp"], want["s"]))
+    assert metric.PLAN_CACHE_POOL_BUILDS.value == builds
+    assert _waits() == waits + 1
+    e = _only_entry(s)
+    assert e._trees == len(e._free) == 1
+
+
+@pytest.mark.parametrize("text,cls,trees_after", [
+    (POINT, ops.PointLookupOp, 0),  # holds nothing: dropped, built anew
+    (GROUPED, ops.ScanOp, 1),       # the one tree with state serves again
+], ids=["stateless", "with_state"])
+def test_a_run_that_raises_leaves_the_entry_serving(monkeypatch, text, cls,
+                                                    trees_after):
+    s = _session()
+    _cache(s).clear()
+    want = s.execute(text.format(5))
+    real = cls.init
+    fail = [True]
+
+    def init(op):
+        if fail[0]:
+            fail[0] = False
+            raise RuntimeError("injected")
+        real(op)
+
+    monkeypatch.setattr(cls, "init", init)
+    with pytest.raises(Exception, match="injected"):
+        s.execute(text.format(6))
+    e = _only_entry(s)
+    assert e._trees == len(e._free) == trees_after
+    got = s.execute(text.format(5))
+    assert {n: list(v) for n, v in got.items()} == {
+        n: list(v) for n, v in want.items()}
+    assert e._trees == len(e._free) == 1
+
+
+def test_ddl_drops_an_entry_whose_tree_is_out_without_an_error(monkeypatch):
+    s = _session()
+    c = _cache(s)
+    c.clear()
+    s.execute(POINT.format(1))
+    held = _Held(monkeypatch, ops.PointLookupOp)
+    p = _peer(s)
+    t0, box0 = held.start(p, POINT.format(11))
+    e = _only_entry(s)
+    s.execute("CREATE INDEX qty_idx ON items (qty)")
+    assert len(c) == 0
+    held.release.set()
+    t0.join(60)
+    assert "err" not in box0 and list(box0["res"]["qty"]) == [11 % 7]
+    assert e._trees == len(e._free) == 1  # came back to an entry nobody reads
+    assert list(p.execute(POINT.format(12))["qty"]) == [12 % 7]
+    assert len(c) == 1 and _only_entry(s) is not e
+
+
+def test_point_read_storm_loses_no_tree_and_crosses_no_key():
+    """16 sessions on a shortened switch interval: a tree lent twice would
+    answer one session with another's key; a lost give_back would leave
+    `_trees` above what lies free."""
+    import sys
+
+    s = _session()
+    _cache(s).clear()
+    s.execute(POINT.format(0))
+    errs = []
+
+    def work(sess, base):
+        try:
+            for i in range(25):
+                k = (base + 5 * i) % 40
+                assert list(sess.execute(POINT.format(k))["qty"]) == [k % 7]
+        except BaseException as e:  # noqa: BLE001 - asserted below
+            errs.append(e)
+
+    ts = [threading.Thread(target=work, args=(_peer(s), b))
+          for b in range(16)]
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        for t in ts:
+            t.start()
+        for t in ts:
+            t.join(120)
+    finally:
+        sys.setswitchinterval(old)
+    assert not errs and not any(t.is_alive() for t in ts)
+    e = _only_entry(s)
+    assert 1 <= e._trees == len(e._free) <= 16
