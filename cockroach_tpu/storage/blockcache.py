@@ -198,9 +198,14 @@ def block_nbytes(block) -> int:
 class BlockCache:
     """Node-wide clock cache of decoded KVBlock windows.
 
-    Lock order: callers (Engine) hold ``storage.engine`` before
-    ``storage.blockcache``; the cache never calls back into the engine,
-    so the reverse edge cannot form.
+    Lock order: ``storage.engine`` before ``storage.blockcache``, never
+    the other way round: the cache never calls back into the engine, so
+    the reverse edge cannot form. The engine's scans, and its metadata
+    pruning and ``invalidate_run``, enter with ``storage.engine`` held; a
+    point read (``Engine.get``) enters with no engine lock held, on a
+    snapshot's immutable runs. A window such a reader puts under the
+    token of a run that was dropped meanwhile is unreachable (tokens are
+    never reused) and leaves by the clock sweep.
     """
 
     def __init__(self, name: str = "storage/block-cache"):

@@ -16,7 +16,10 @@ Host-side orchestration of device-resident sorted runs:
   tiles and merge THOSE (the merging-iterator role, pebble_mvcc_scanner.go
   :381 semantics via ``mvcc_scan_filter``), so point/short-range cost is
   O(candidates·log), not O(total history). Unbounded reads use a merged
-  view cached per run-set generation;
+  view cached per run-set generation. A point read holds the engine mutex
+  only to take a ``_Snapshot`` of what it will read (the run set, each
+  run's seek keys and bloom, the memtable's block when it holds the key)
+  and runs its searches, launches and readback with the mutex released;
 - ``checkpoint``/``open_checkpoint`` persist runs to .npz files and
   truncate the WAL (pkg/storage/pebble.go:2077 CreateCheckpoint analog);
   a crash between checkpoints recovers by WAL replay at open.
@@ -35,6 +38,7 @@ import os
 import struct
 import time
 from dataclasses import dataclass, field
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -312,12 +316,36 @@ def _locked(fn):
     (liveness heartbeats, the tsdb ticker, jobs adoption) race
     resolve_intents' run-set rewrite against concurrent memtable appends and
     leave orphaned intent rows behind (observed: a committed heartbeat's
-    intent resurrected by a racing flush)."""
+    intent resurrected by a racing flush).
+
+    Every write, flush, compaction, resolution, ``scan``, ``scan_batch``,
+    ``has_committed_writes_in`` and the span / checkpoint methods keep the
+    mutex for their whole body. Two do not carry this decorator. ``get``
+    holds it only while it takes a ``_Snapshot`` and does its searches,
+    launches and readback on that with the mutex released (a caller that
+    already holds it keeps it: the lock is reentrant).
+    ``span_versions_estimate`` reads the published run snapshot with no
+    mutex at all, and takes it only to build one where the run set has
+    just changed: an estimate needs no instant."""
     @functools.wraps(fn)
     def wrapper(self, *a, **kw):
         with self.mu:
             return fn(self, *a, **kw)
     return wrapper
+
+
+class _Snapshot(NamedTuple):
+    """What a read reads, as of one instant under the engine mutex: the
+    run set, each run's read metadata, and the memtable as a sorted block
+    (None where the read leaves the memtable out). Runs and their metadata
+    never change once built and a memtable block is a copy, so a reader
+    may use a snapshot with the mutex released; the blocks live as long as
+    a snapshot refers to them."""
+
+    runs: tuple[mvcc.KVBlock, ...]
+    metas: tuple[blockcache.RunMeta, ...]
+    memtable: _Memtable  # the host memtable this run set goes with
+    mem: mvcc.KVBlock | None = None  # its sorted block, where a read needs it
 
 
 class Engine:
@@ -395,6 +423,7 @@ class Engine:
         # ids can't be reused
         self._run_meta: dict[int, tuple[mvcc.KVBlock, blockcache.RunMeta]] = {}
         self._runs_view_cache: tuple[int, mvcc.KVBlock] | None = None
+        self._snap_cache: tuple[int, _Snapshot] | None = None
         self._scan_windows: dict[int, int] = {}  # max_keys -> learned window
         self._mem_cache = None  # ((mem len, gen), sorted block)
         self._overlay_cache = None  # ((gen, mem len), merged view)
@@ -752,6 +781,7 @@ class Engine:
     def _runs_changed(self) -> None:
         self._gen += 1
         self._runs_gen += 1
+        self._snap_cache = None  # and its hold on the runs that left
 
     def _note_run_intents(self, run: mvcc.KVBlock, txns) -> None:
         txns = {int(t) for t in txns if int(t) != 0}
@@ -1068,11 +1098,48 @@ class Engine:
         self._overlay_cache = (key, view)
         return view
 
-    def _bounded_view(self, sw, ew, limit_rows: int | None = None,
+    def _published_snapshot(self) -> _Snapshot | None:
+        """The cached run snapshot if it is of the current run set, else
+        None; one attribute read, safe with or without the mutex."""
+        c = self._snap_cache
+        return c[1] if c is not None and c[0] == self._runs_gen else None
+
+    def _run_snapshot(self) -> _Snapshot:
+        """The run set and each run's read metadata as of now, built once
+        a run-set generation (``_meta_for``'s pruning and block-cache
+        invalidation happen here); the caller holds the mutex."""
+        snap = self._published_snapshot()
+        if snap is None:
+            from ..utils import metric
+
+            runs = tuple(self.runs)
+            snap = _Snapshot(
+                runs, tuple(self._meta_for(r) for r in runs), self.mem)
+            self._snap_cache = (self._runs_gen, snap)
+            metric.ENGINE_SNAPSHOT_BUILDS.inc()
+        return snap
+
+    def _snapshot(self, point: bytes | None = None) -> _Snapshot:
+        """What a read reads, as of now; the caller holds the mutex. A
+        point read of a key the memtable does not hold leaves the memtable
+        out (no block built, nothing uploaded). Runs, the key-set test and
+        the memtable's block come from ONE hold of the mutex: an old run
+        tuple paired with the empty memtable a flush left behind would
+        lose the flushed rows."""
+        snap = self._run_snapshot()
+        if point is not None and point not in self.mem.keyset:
+            return snap
+        mb = self._mem_block()
+        return snap if mb is None else snap._replace(mem=mb)
+
+    def _bounded_view(self, snap: _Snapshot, sw, ew,
+                      limit_rows: int | None = None,
                       point: bytes | None = None):
         """Candidate view for a bounded read: gather only in-range rows of
-        each source into small tiles and merge those — point/short-scan
-        cost scales with matching rows, not total history.
+        each source of the snapshot it is handed into small tiles and merge
+        those — point/short-scan cost scales with matching rows, not total
+        history. Reads nothing of the engine that a writer changes, so it
+        runs with or without the mutex.
 
         limit_rows clamps each SORTED run to its first limit_rows in-range
         entries (the pebbleMVCCScanner pagination discipline): a scan with
@@ -1082,19 +1149,16 @@ class Engine:
         of their versions may have been cut — and callers must not emit
         them. boundary None means nothing was truncated."""
         sources = []
-        # a point read of a key the memtable does not hold leaves it out
-        mb = (self._mem_block()
-              if point is None or point in self.mem.keyset else None)
-        if mb is not None:
-            sources.append((mb, False))  # memtable is unsorted: never seek
-        sources.extend((r, True) for r in self.runs)
-        swj = None if sw is None else jnp.asarray(sw)
-        ewj = None if ew is None else jnp.asarray(ew)
+        if snap.mem is not None:
+            # the memtable's block never seeks: it has no seek keys
+            sources.append((snap.mem, None))
+        sources.extend(zip(snap.runs, snap.metas))
         parts = []
         boundary: bytes | None = None
-        for src, sorted_run in sources:
+        for src, meta in sources:
+            sorted_run = meta is not None
             if (point is not None and sorted_run
-                    and not self._bloom_might_contain(src, point)):
+                    and not self._bloom_might_contain(meta, point)):
                 # per-run bloom filter: the key is definitely absent —
                 # skip the run's range-mask/gather entirely (pebble's
                 # table-filter point-read pruning)
@@ -1107,7 +1171,6 @@ class Engine:
                 # key bytes finds the start position, one device
                 # dynamic-slice lands the window — O(window), never
                 # O(run length) (the pebble iterator SeekGE discipline)
-                meta = self._meta_for(src)
                 vkeys, n_live = meta.void_keys, meta.n_live
                 if n_live == 0:
                     continue
@@ -1135,22 +1198,20 @@ class Engine:
                     if ew is None or cut < _words_to_bytes(ew):
                         if boundary is None or cut < boundary:
                             boundary = cut
-                m, cnt = _range_mask(win, swj, ewj)
                 if point is not None:
                     # a point read's window IS its candidate tile (sorted,
-                    # a few hundred rows): masked, not counted and
-                    # compacted, which would cost a sync and a launch
-                    parts.append(mvcc.KVBlock(
-                        key=win.key, ts=win.ts, seq=win.seq, txn=win.txn,
-                        tomb=win.tomb, value=win.value, vlen=win.vlen,
-                        mask=m))
+                    # a few hundred rows), as it lies in the cache: not
+                    # masked, counted or compacted, each of which would
+                    # cost a launch; mvcc_scan_filter applies the bounds
+                    parts.append(win)
                     continue
+                m, cnt = _range_mask(win, sw, ew)
                 cnt = int(np.asarray(cnt))
                 if cnt == 0:
                     continue
                 parts.append(_gather_rows(win, m, _pad(cnt, _CAND_ALIGN)))
                 continue
-            m, cnt = _range_mask(src, swj, ewj)
+            m, cnt = _range_mask(src, sw, ew)
             cnt = int(np.asarray(cnt))
             if cnt == 0:
                 continue
@@ -1206,11 +1267,13 @@ class Engine:
         if c is not None:
             blockcache.node_cache().invalidate_run(c[1].token)
 
-    def _bloom_might_contain(self, run: mvcc.KVBlock, key: bytes) -> bool:
+    def _bloom_might_contain(self, meta: blockcache.RunMeta,
+                             key: bytes) -> bool:
         """Per-run split-block bloom probe (pebble's table-filter role).
         False is a CRC-backed proof of absence; a filterless or corrupt
-        run always answers maybe."""
-        bloom = self._meta_for(run).bloom()
+        run always answers maybe (and so does a lazy filter another
+        reader is still building)."""
+        bloom = meta.bloom()
         if bloom is None:
             return True
         kb = np.zeros((1, self.key_width), np.uint8)  # crlint: allow-mem-accounting(single-key probe buffer, key_width bytes)
@@ -1221,17 +1284,10 @@ class Engine:
         )
         return bloom.might_contain(int(h1[0]), int(h2[0]))
 
-    def _run_keys(self, run: mvcc.KVBlock):
-        """Host copy of a sorted run's key bytes as a void array (memcmp
-        ordering) + its live count — the SST block-index analog backing
-        host-side iterator seeks."""
-        m = self._meta_for(run)
-        return m.void_keys, m.n_live
-
     def _view_for(self, sw, ew) -> mvcc.KVBlock | None:
         if sw is None and ew is None:
             return self._merged_view()
-        return self._bounded_view(sw, ew)[0]
+        return self._bounded_view(self._snapshot(), sw, ew)[0]
 
     # -- reads --------------------------------------------------------------
 
@@ -1261,7 +1317,8 @@ class Engine:
             limit = max(16, 4 * max_keys)
         while True:
             if limit is not None:
-                view, boundary = self._bounded_view(sw, ew, limit)
+                view, boundary = self._bounded_view(self._snapshot(), sw,
+                                                    ew, limit)
             else:
                 view, boundary = self._view_for(sw, ew), None
             if view is None:
@@ -1389,7 +1446,6 @@ class Engine:
                 ])
             return out
 
-    @_locked
     def get(self, key: bytes | str, ts: int, txn: int = 0) -> bytes | None:
         """Point read. The full consult order is bloom -> block cache ->
         device slice: each surviving run is seeked to a small candidate
@@ -1397,28 +1453,45 @@ class Engine:
         node block cache when hot — a point read on a cached key set
         dispatches no device gather at all. A window cut inside the
         key's version set (boundary) grows geometrically, the pagination
-        discipline scan() uses."""
+        discipline scan() uses.
+
+        The mutex is held for the snapshot only: the read sees the store
+        as of that instant (its linearization point; ``ts`` was taken
+        before it, and a write that lands after it either commits above
+        ``ts`` or is an intent the read must not see), and the searches,
+        launches and the readback run on immutable blocks with the mutex
+        released. A caller that holds the mutex itself keeps it."""
+        from ..utils import metric, tracing
+
         b = key.encode() if isinstance(key, str) else bytes(key)
         sw = K.encode_bound(b, self.key_width)
         ew = K.bound_next(sw)
-        limit = 8
-        while True:
-            view, boundary = self._bounded_view(sw, ew, limit_rows=limit,
-                                                point=b)
-            if boundary is None:
-                break
-            # some run's window was cut inside [key, next(key)) — a
-            # version of this key may be missing; widen and retry
-            limit *= 4
-        if view is None:
-            return None
-        sel, conflict = mvcc.mvcc_scan_filter(
-            view, jnp.int64(ts), jnp.int64(txn),
-            jnp.asarray(sw), jnp.asarray(ew),
-        )
-        # one wait for the device, not one an array
-        conflict, sel, vlen, value = jax.device_get(
-            (conflict, sel, view.vlen, view.value))
+        with tracing.leaf_span("storage/engine.get") as span:
+            with self.mu:
+                t0 = time.perf_counter()
+                snap = self._snapshot(point=b)
+                if span is not None:
+                    span.tags["held_ms"] = 1e3 * (time.perf_counter() - t0)
+            metric.ENGINE_SNAPSHOT_READS.inc()
+            limit = 8
+            while True:
+                view, boundary = self._bounded_view(
+                    snap, sw, ew, limit_rows=limit, point=b)
+                if boundary is None:
+                    break
+                # some run's window was cut inside [key, next(key)) — a
+                # version of this key may be missing; widen and retry
+                limit *= 4
+            if view is None:
+                return None
+            # host values go in as they are and the jitted call moves
+            # them: an eager jnp.asarray / jnp.int64 is a dispatch of its
+            # own through the interpreter
+            sel, conflict = mvcc.mvcc_scan_filter(
+                view, np.int64(ts), np.int64(txn), sw, ew)
+            # one wait for the device, not one an array
+            conflict, sel, vlen, value = jax.device_get(
+                (conflict, sel, view.vlen, view.value))
         if conflict.any():
             idx = np.nonzero(conflict)[0]
             raise WriteIntentError(
@@ -1429,6 +1502,8 @@ class Engine:
         if not len(idx):
             return None
         i = idx[0]
+        # _blob is append-only and an overflow pointer's bytes are there
+        # before its row is visible: safe to follow without the mutex
         return self._resolve_value(value[i], int(vlen[i]))
 
     # -- intents ------------------------------------------------------------
@@ -1545,23 +1620,30 @@ class Engine:
         vbytes = int(np.asarray(view.vlen)[mask].sum()) if n else 0
         return {"versions": n, "logical_bytes": n * self.key_width + vbytes}
 
-    @_locked
     def span_versions_estimate(self, start: bytes, end: bytes) -> int:
         """Versions in [start, end), counted on the host: two binary
         searches over each run's seek keys and a walk of the memtable's
         key list. An upper bound on the span's live keys (old versions,
         tombstones and intents count too) that costs no device work and
         merges nothing: what planning asks before every statement over a
-        KV-backed table (KVTable.estimated_rows)."""
-        n = sum(1 for k in self.mem.keys if start <= k < end)
-        for r in self.runs:
-            vkeys, n_live = self._run_keys(r)
-            if not n_live:
+        KV-backed table (KVTable.estimated_rows). It reads the published
+        run snapshot and its memtable's key list with no mutex at all (the
+        mutex only to build a snapshot where the run set has just changed):
+        an estimate needs no instant, a key appended during the walk is one
+        more or one less, and a bind that queued at the mutex was one of
+        the two turns a statement stood in line for."""
+        snap = self._published_snapshot()
+        if snap is None:
+            with self.mu:
+                snap = self._run_snapshot()
+        n = sum(1 for k in snap.memtable.keys if start <= k < end)
+        for m in snap.metas:
+            if not m.n_live:
                 continue
             lo, hi = (np.frombuffer(b.ljust(self.key_width, b"\x00"),
-                                    dtype=vkeys.dtype)[0]
+                                    dtype=m.void_keys.dtype)[0]
                       for b in (start, end))
-            live = vkeys[:n_live]
+            live = m.void_keys[:m.n_live]
             n += int(np.searchsorted(live, hi, side="left")
                      - np.searchsorted(live, lo, side="left"))
         return n

@@ -229,6 +229,15 @@ TXN_COMMITS = DEFAULT.counter("txn_commits", "committed transactions")
 KV_POINT_READS = DEFAULT.counter(
     "sql_kv_point_reads",
     "primary keys read by the point-lookup plan route (KVTable.point_rows)")
+ENGINE_SNAPSHOT_READS = DEFAULT.counter(
+    "storage_engine_snapshot_reads",
+    "point reads served off a snapshot (Engine.get): the store's mutex "
+    "held while the snapshot is taken, released for the searches, the "
+    "launches and the readback")
+ENGINE_SNAPSHOT_BUILDS = DEFAULT.counter(
+    "storage_engine_snapshot_builds",
+    "read snapshots of the run set rebuilt because the run set changed "
+    "(flush, ingest, compaction, a resolution that rewrote a run)")
 KV_TABLE_DECODES = DEFAULT.counter(
     "sql_kv_table_decodes",
     "whole-table columnar decodes of a KV-backed table "

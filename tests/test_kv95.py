@@ -13,6 +13,7 @@ What each part of the served path has to do for that:
 """
 
 import threading
+import time
 
 import numpy as np
 import pytest
@@ -579,3 +580,417 @@ def test_the_host_planned_order_is_the_device_sorts(kw, n, cap):
                           np.asarray(want.key)[:live])
     with pytest.raises(ValueError):
         mvcc.sort_block_host(blk, live - 1)
+
+
+# -- PR 43: a point read holds the store's mutex for its snapshot only -----
+
+
+def _stop_after_snapshot(eng, only: set):
+    """Stops a get of the threads in `only` right after its snapshot:
+    `_bounded_view` is the first thing a get calls with the mutex released.
+    -> (reached, release) events."""
+    reached, release = threading.Event(), threading.Event()
+    real = eng._bounded_view
+
+    def paused(*a, **kw):
+        if threading.current_thread().name in only:
+            reached.set()
+            assert release.wait(30)
+        return real(*a, **kw)
+
+    eng._bounded_view = paused
+    return reached, release
+
+
+def _in_thread(fn, name=None):
+    """Run fn on a thread -> (thread, out) with out["value"] or
+    out["error"] once it ends."""
+    out = {}
+
+    def run():
+        try:
+            out["value"] = fn()
+        except BaseException as e:
+            out["error"] = e
+
+    t = threading.Thread(target=run, name=name)
+    t.start()
+    return t, out
+
+
+def _mutex_is_free(eng) -> bool:
+    """Whether ANOTHER thread could take the store's mutex right now."""
+    got = []
+
+    def probe():
+        ok = eng.mu.acquire(False)
+        got.append(ok)
+        if ok:
+            eng.mu.release()
+
+    t = threading.Thread(target=probe)
+    t.start()
+    t.join(5)
+    return got == [True]
+
+
+def test_a_get_waiting_for_the_device_does_not_hold_the_mutex(monkeypatch):
+    """(a) While one get waits in its `device_get`, a put and a second get
+    from other threads run to their end: the read holds `storage.engine`
+    across no launch and no readback."""
+    import jax
+
+    eng = _engine()
+    for i in range(40):
+        eng.put(b"k%03d" % i, b"v%03d" % i, ts=10)
+    eng.flush()
+    waiting, release = threading.Event(), threading.Event()
+    real = jax.device_get
+
+    def held(x):
+        if threading.current_thread().name == "held-reader":
+            waiting.set()
+            assert release.wait(30)
+        return real(x)
+
+    monkeypatch.setattr(jax, "device_get", held)
+    reader, out = _in_thread(lambda: eng.get(b"k005", ts=20), "held-reader")
+    try:
+        assert waiting.wait(30)
+        assert _mutex_is_free(eng)
+        w, wout = _in_thread(lambda: eng.put(b"k005", b"new", ts=30))
+        r, rout = _in_thread(lambda: eng.get(b"k006", ts=20))
+        w.join(30)
+        r.join(30)
+        assert not w.is_alive() and not r.is_alive()
+        assert "error" not in wout and rout == {"value": b"v006"}
+        assert reader.is_alive()  # still inside its device_get
+    finally:
+        release.set()
+        reader.join(30)
+    assert not reader.is_alive() and out == {"value": b"v005"}
+    assert eng.get(b"k005", ts=40) == b"new"
+
+
+@pytest.mark.parametrize("old_in", ["run", "memtable"])
+def test_a_read_keeps_its_instant_across_a_flush_and_a_compaction(old_in):
+    """(b) A reader stopped right after its snapshot, then a put of its key,
+    a flush and a compaction: it answers as of its instant (the old value,
+    never None: the runs it holds outlive the run set that dropped them),
+    and a reader that snapshots afterwards answers the new one."""
+    eng = _engine(l0_trigger=64)
+    for i in range(40):
+        eng.put(b"k%03d" % i, b"old%03d" % i, ts=10)
+    eng.flush_mem_only()
+    if old_in == "memtable":
+        eng.put(b"k005", b"older", ts=11)  # the snapshot takes the memtable
+    old = b"older" if old_in == "memtable" else b"old005"
+    reached, release = _stop_after_snapshot(eng, {"early"})
+    early, out = _in_thread(lambda: eng.get(b"k005", ts=100), "early")
+    try:
+        assert reached.wait(30)
+        gen = eng._runs_gen
+        eng.put(b"k005", b"new", ts=20)
+        eng.flush_mem_only()
+        eng.compact()
+        assert eng._runs_gen > gen and len(eng.runs) == 1
+        assert eng.get(b"k005", ts=100) == b"new"  # snapshots after
+        assert eng.get(b"k005", ts=15) == old
+    finally:
+        release.set()
+        early.join(30)
+    assert not early.is_alive() and out == {"value": old}
+
+
+def test_an_intent_in_the_snapshot_conflicts_even_if_it_resolves_first():
+    """(c) The reader's instant holds a foreign intent at or below its
+    timestamp: it raises WriteIntentError though the intent commits before
+    the reader's launches, and never reads through it; the next read, at
+    a fresh instant, sees the committed value."""
+    eng = _engine()
+    eng.put(b"a", b"base", ts=5)
+    eng.flush_mem_only()
+    eng.put(b"a", b"mine", ts=10, txn=7)
+    reached, release = _stop_after_snapshot(eng, {"early"})
+    early, out = _in_thread(lambda: eng.get(b"a", ts=50), "early")
+    try:
+        assert reached.wait(30)
+        eng.resolve_intents(7, 20, commit=True)
+        assert eng.get(b"a", ts=50) == b"mine"
+    finally:
+        release.set()
+        early.join(30)
+    assert not early.is_alive()
+    assert isinstance(out.get("error"), WriteIntentError)
+    assert out["error"].txns == [7]
+
+
+def test_a_get_inside_the_mutex_leaves_its_caller_holding_it():
+    """(d) The lock is reentrant: a caller that holds `storage.engine`
+    around a get (the coalescer's train, a transaction's section) still
+    holds it when the get returns."""
+    eng = _engine()
+    eng.put(b"a", b"1", ts=1)
+    with eng.mu:
+        assert eng.get(b"a", ts=5) == b"1"
+        assert not _mutex_is_free(eng)
+        assert eng.span_versions_estimate(b"a", b"b") == 1
+        assert not _mutex_is_free(eng)
+    assert _mutex_is_free(eng)
+
+
+def test_one_snapshot_a_run_set_generation():
+    """A snapshot is rebuilt only when the run set changed: one build a
+    generation that a read saw, none for writes that stay in the memtable
+    or for an in-memtable commit."""
+    eng = _engine(l0_trigger=64)
+    builds, reads = (metric.ENGINE_SNAPSHOT_BUILDS.value,
+                     metric.ENGINE_SNAPSHOT_READS.value)
+    seen = set()
+
+    def read(k, want):
+        assert eng.get(k, ts=1000) == want
+        seen.add(eng._runs_gen)
+
+    read(b"a", None)  # the empty store's snapshot
+    eng.put(b"a", b"1", ts=1)
+    read(b"a", b"1")
+    eng.put(b"a", b"2", ts=2, txn=9)
+    eng.resolve_intents(9, 3, commit=True)  # in the memtable: no new run
+    read(b"a", b"2")
+    assert len(seen) == 1
+    eng.flush_mem_only()
+    read(b"a", b"2")
+    eng.put(b"b", b"3", ts=4)
+    eng.compact()  # flushes, then merges: two generations, one read
+    read(b"b", b"3")
+    keys = np.zeros((1, 16), np.uint8)
+    keys[0, 0] = ord("c")
+    eng.ingest(keys, np.full((1, 1), ord("4"), np.uint8), ts=5)
+    read(b"c", b"4")
+    read(b"c", b"4")
+    assert metric.ENGINE_SNAPSHOT_BUILDS.value - builds == len(seen) == 4
+    assert metric.ENGINE_SNAPSHOT_READS.value - reads == 7
+
+
+def test_16_readers_against_flushes_commits_and_compactions():
+    """(e) 16 threads x 200 gets against 2 writers (puts, transactional
+    writes and their commits, flushes, compactions): every value read is
+    one its key held at some instant between the read's start and its end,
+    a reader never sees a key go back, every get is counted once,
+    snapshots are rebuilt no more often than the run set changed, and the
+    lock-free estimate never pairs an old run set with a new memtable (it
+    never counts fewer versions than there are keys)."""
+    import sys
+
+    eng = _engine(memtable_size=64, l0_trigger=3)
+    nkeys, readers, per, writers = 32, 16, 200, 2
+    keys = [b"key%03d" % i for i in range(nkeys)]
+    clock = iter(range(10, 1 << 40))
+    tick = threading.Lock()
+
+    def now() -> int:
+        with tick:
+            return next(clock)
+
+    for k in keys:
+        eng.put(k, b"%08d" % 0, ts=now())
+    eng.flush()
+    # per key: the newest version whose write has returned, and the newest
+    # whose write has begun (each key has one writer)
+    done = {k: 0 for k in keys}
+    begun = {k: 0 for k in keys}
+    stop = threading.Event()
+    errors, wrong = [], []
+    builds, reads, gen = (metric.ENGINE_SNAPSHOT_BUILDS.value,
+                          metric.ENGINE_SNAPSHOT_READS.value, eng._runs_gen)
+    gets = [0] * readers
+
+    def writer(w: int):
+        rng = np.random.default_rng([43, w])
+        mine = keys[w::writers]
+        n = 0
+        try:
+            while not stop.is_set():
+                n += 1
+                k = mine[int(rng.integers(len(mine)))]
+                v = begun[k] = begun[k] + 1
+                if n % 3:
+                    eng.put(k, b"%08d" % v, ts=now())
+                else:
+                    txn = 1000 * (w + 1) + n
+                    with eng.mu:
+                        eng.put(k, b"%08d" % v, ts=now(), txn=txn)
+                    eng.resolve_intents(txn, now(), commit=True)
+                done[k] = v
+                if n % 37 == 0:
+                    eng.flush()
+                if n % 151 == 0:
+                    eng.compact()
+                time.sleep(0.002)  # the readers are the test: leave them
+                # the interpreter
+        except BaseException as e:
+            errors.append(f"writer {w}: {type(e).__name__}: {e}")
+
+    def reader(r: int):
+        rng = np.random.default_rng([44, r])
+        last = {}
+        try:
+            for _ in range(per):
+                k = keys[int(rng.integers(nkeys))]
+                lo = done[k]
+                gets[r] += 1
+                try:
+                    got = eng.get(k, ts=1 << 50)
+                except WriteIntentError:
+                    continue  # its instant held the writer's intent
+                hi = begun[k]
+                if gets[r] % 16 == 0 and eng.span_versions_estimate(
+                        b"key", b"kez") < nkeys:
+                    wrong.append(("estimate under the keys", k))
+                v = None if got is None else int(got)
+                if v is None or not max(lo, last.get(k, 0)) <= v <= hi:
+                    wrong.append((k, lo, v, hi, last.get(k)))
+                else:
+                    last[k] = v
+        except BaseException as e:
+            errors.append(f"reader {r}: {type(e).__name__}: {e}")
+
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-3)
+    try:
+        ws = [threading.Thread(target=writer, args=(w,))
+              for w in range(writers)]
+        rs = [threading.Thread(target=reader, args=(r,))
+              for r in range(readers)]
+        for t in ws + rs:
+            t.start()
+        for t in rs:
+            t.join(timeout=300)
+        stop.set()
+        for t in ws:
+            t.join(timeout=60)
+    finally:
+        sys.setswitchinterval(interval)
+    assert not [t for t in ws + rs if t.is_alive()], "a thread never ended"
+    assert not errors, errors[:3]
+    assert not wrong, wrong[:5]
+    assert sum(gets) == readers * per
+    assert metric.ENGINE_SNAPSHOT_READS.value - reads == sum(gets)
+    changes = eng._runs_gen - gen
+    assert changes >= 3, "the writers never changed the run set"
+    assert 1 <= metric.ENGINE_SNAPSHOT_BUILDS.value - builds <= changes + 1
+    for k in keys:  # quiescent: every key reads its last write
+        assert int(eng.get(k, ts=1 << 50)) == done[k] == begun[k]
+
+
+def test_a_point_read_of_runs_is_one_launch_and_no_eager_put(monkeypatch):
+    """A point read's window goes to `mvcc_scan_filter` as it lies in the
+    cache (the filter applies the bounds: no `_range_mask` launch), and
+    the bounds and timestamps go in as host values (no `jnp.asarray` /
+    `jnp.int64` dispatch a read); a key in the memtable still masks and
+    compacts the memtable's block."""
+    import jax.numpy as jnp
+
+    from cockroach_tpu.storage import lsm
+
+    eng = _engine(l0_trigger=64)
+    for r in range(3):  # three runs, three versions a key
+        for i in range(40):
+            eng.put(b"k%03d" % i, b"v%d-%03d" % (r, i), ts=10 + r)
+        eng.flush_mem_only()
+    calls = {"mask": 0, "asarray": 0, "int64": 0}
+
+    def counted(name, fn):
+        def wrapper(*a, **kw):
+            calls[name] += 1
+            return fn(*a, **kw)
+        return wrapper
+
+    monkeypatch.setattr(lsm, "_range_mask",
+                        counted("mask", lsm._range_mask))
+    monkeypatch.setattr(jnp, "asarray", counted("asarray", jnp.asarray))
+    monkeypatch.setattr(jnp, "int64", counted("int64", jnp.int64))
+    assert eng.get(b"k005", ts=100) == b"v2-005"
+    assert eng.get(b"k005", ts=11) == b"v1-005"
+    assert eng.get(b"k005", ts=9) is None and eng.get(b"zzz", ts=100) is None
+    assert calls["mask"] == 0 and calls["int64"] == 0
+    eng.compact()  # one run, the cell's shape: its window is the view
+    calls.update(mask=0, asarray=0, int64=0)
+    assert eng.get(b"k005", ts=100) == b"v2-005"
+    assert eng.get(b"k005", ts=11) == b"v1-005"
+    assert calls == {"mask": 0, "asarray": 0, "int64": 0}
+    eng.put(b"k005", b"mem", ts=20)
+    assert eng.get(b"k005", ts=100) == b"mem"
+    assert calls["mask"] == 1
+
+
+def test_the_estimate_off_the_mutex_counts_what_the_locked_one_did():
+    """(f) On a quiescent store `span_versions_estimate` (the published
+    snapshot, no mutex) counts every version in the span: the same number
+    with and without the caller holding the mutex, and the merged view's
+    own count; another thread's hold of the mutex does not stop it."""
+    eng = _engine(l0_trigger=64)
+    for i in range(60):
+        eng.put(b"k%03d" % i, b"a", ts=10)
+    eng.flush_mem_only()
+    for i in range(0, 60, 2):
+        eng.put(b"k%03d" % i, b"b", ts=20)
+    eng.flush_mem_only()
+    eng.delete(b"k007", ts=30)
+    eng.put(b"k008", b"c", ts=30, txn=5)
+    for lo, hi in ((b"k000", b"k999"), (b"k010", b"k020"), (b"k007", b"k009"),
+                   (b"x", b"y")):
+        free = eng.span_versions_estimate(lo, hi)
+        with eng.mu:
+            held = eng.span_versions_estimate(lo, hi)
+        assert free == held == eng.span_stats(lo, hi)["versions"]
+    assert eng.span_versions_estimate(b"k000", b"k999") == 92
+    # the published snapshot is current: the estimate stands in no line
+    held, done = threading.Event(), threading.Event()
+
+    def holder():
+        with eng.mu:
+            held.set()
+            done.wait(30)
+
+    t = threading.Thread(target=holder)
+    t.start()
+    try:
+        assert held.wait(30)
+        est, out = _in_thread(
+            lambda: eng.span_versions_estimate(b"k000", b"k999"))
+        est.join(10)
+        assert not est.is_alive() and out == {"value": 92}
+    finally:
+        done.set()
+        t.join(30)
+    # a new run set: the next estimate builds its snapshot under the mutex
+    eng.compact()
+    builds = metric.ENGINE_SNAPSHOT_BUILDS.value
+    assert eng.span_versions_estimate(b"k000", b"k999") == 92
+    assert metric.ENGINE_SNAPSHOT_BUILDS.value == builds + 1
+
+
+def test_a_traced_statement_carries_the_read_and_its_hold(served):
+    """(g) `storage/engine.get` spans the whole read inside a traced
+    statement, with the milliseconds it held the mutex in `held_ms`."""
+    _, sess = served
+    sess.execute("SELECT k, v FROM kv WHERE k IN (5)")  # bind and compile
+    before = tracing.totals().get("storage/engine.get",
+                                  {"count": 0, "tags": {}})
+    with tracing.span("test.statement") as sp:
+        assert _rows(sess.execute(
+            "SELECT k, v FROM kv WHERE k IN (6)")) == [[6, value_of(7, 6)]]
+    gets = [s for s in sp.walk() if s.name == "storage/engine.get"]
+    assert len(gets) == 1
+    held = gets[0].tags["held_ms"]
+    assert 0 <= held <= 1e3 * gets[0].duration
+    rec = tracing.totals()["storage/engine.get"]
+    assert rec["count"] == before["count"] + 1
+    assert rec["tags"]["held_ms"] == pytest.approx(
+        before["tags"].get("held_ms", 0) + held)
+    # outside a traced operation the read opens no span
+    n = tracing.totals()["storage/engine.get"]["count"]
+    sess.db.engine.get(b"nokey", ts=1)
+    assert tracing.totals()["storage/engine.get"]["count"] == n
