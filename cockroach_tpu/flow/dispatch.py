@@ -264,8 +264,7 @@ def note_compile(n: int = 1) -> None:
 def compiles() -> int:
     """Process-lifetime trace/compile count (monotonic — snapshot around a
     query to assert the zero-recompile serving path). Read under the
-    counter lock: warm-menu workers poll this for their budget check
-    concurrently with serving-path note_compile writes."""
+    counter lock: sessions read it while others' note_compile writes."""
     with _lock:
         return _compiles
 

@@ -91,7 +91,7 @@ class Operator:
     emits_live_prefix = False
 
     # between two runs this operator keeps nothing on the device and has
-    # learned nothing: no spool, build side, shared stream or emission cap,
+    # learned nothing: no spool, build side or emission cap,
     # only what init() makes again. A plan made of such operators alone
     # may be built more than once, a tree a concurrent session
     # (sql/plancache.py `_Entry`); one operator that says False keeps its

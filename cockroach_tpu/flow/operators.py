@@ -260,7 +260,6 @@ class ScanOp(SourceOperator):
         self.tile = tile
         self._offset = 0
         self.streaming = False
-        self._shared = None
 
     def init(self):
         from ..utils import settings
@@ -274,24 +273,8 @@ class ScanOp(SourceOperator):
             self._init_streaming()
         else:
             self._init_resident()
-            # concurrent scans of the same resident table share one tile
-            # stream (flow/sharedscan.py): attach returns None for solo
-            if self._res_tile < self._batch.capacity:
-                from . import sharedscan
-
-                if self._shared is not None:  # re-init (capacity retry)
-                    sharedscan.detach(self, self._shared)
-                self._shared = sharedscan.attach(self)
         self._offset = 0
         super().init()
-
-    def close(self):
-        if self._shared is not None:
-            from . import sharedscan
-
-            sharedscan.detach(self, self._shared)
-            self._shared = None
-        super().close()
 
     # -- resident mode ------------------------------------------------------
 
@@ -309,13 +292,7 @@ class ScanOp(SourceOperator):
                 None if i == n - 1 else (i + 1) * rows // n)
 
     def _init_resident(self):
-        # snapshot token bracketing the decode: valid only when nothing
-        # wrote between the two reads (sharedscan's adopt-batch guard)
-        tok_fn = getattr(self.table, "snapshot_token", None)
-        tok0 = tok_fn() if callable(tok_fn) else None
         self._batch = self.table.device_batch(self.output_schema.names)
-        self._snap = (tok0 if tok0 is not None and tok0 == tok_fn()
-                      else None)
         bounds = self._shard_bounds()
         if bounds is not None:
             # shard by LIVE-ROW RANK, not raw position: KV-backed tables'
@@ -436,13 +413,6 @@ class ScanOp(SourceOperator):
         if self._res_tile == cap:
             self._offset = cap
             return self._batch
-        if self._shared is not None:
-            kind, t = self._shared.next_tile(
-                self, self._offset // self._res_tile)
-            if kind == "tile":
-                self._offset += self._res_tile
-                return t
-            # window trimmed past us: slice this tile solo (catch-up)
         out = self._slice(self._batch, jnp.int32(self._offset))
         self._offset += self._res_tile
         return out

@@ -5,7 +5,7 @@ colexec/materializer.go:30 converts the final columnar batches to rows for
 pgwire. Here run_plan pulls every tile from the root operator and materializes
 live rows to host numpy columns (decoding string dictionaries).
 
-The pull loop is double-buffered (sql.distsql.readback_overlap): tile k's
+The pull loop is double-buffered: tile k's
 device->host copies are kicked off asynchronously as soon as the tile is
 dispatched, and the blocking materialization of tile k happens while the
 root computes tile k+1 — so the device->host readback (bandwidth not
@@ -149,7 +149,6 @@ def run_operator(root) -> dict[str, np.ndarray]:
     t0 = time.perf_counter()
     d0 = dispatch.total()
     c0 = dispatch.compiles()
-    overlap = settings.get("sql.distsql.readback_overlap")
     # joins the session's statement monitor when sql/session.py opened one;
     # otherwise (direct rel-API use) an ephemeral query monitor under ROOT.
     # Entered manually so the exit lands AFTER root.close() in the finally:
@@ -178,28 +177,20 @@ def run_operator(root) -> dict[str, np.ndarray]:
                         # (HashJoinOp.post_run_update)
                         psp.add_tag("join_overflow_reruns", 1)
                     root.init()
-                    if overlap:
-                        # one-tile lag: materialize tile k (blocking host
-                        # copy) while the root's async dispatches compute
-                        # tile k+1
-                        prev = None
-                        while True:
-                            b = root.next_batch()
-                            if b is not None:
-                                b = shrink.shrink(b)
-                                _start_readback(b)
-                            if prev is not None:
-                                outs.append(_readback(prev, root, psp))
-                            prev = b
-                            if b is None:
-                                break
-                    else:
-                        while True:
-                            b = root.next_batch()
-                            if b is None:
-                                break
+                    # one-tile lag: materialize tile k (blocking host
+                    # copy) while the root's async dispatches compute
+                    # tile k+1
+                    prev = None
+                    while True:
+                        b = root.next_batch()
+                        if b is not None:
                             b = shrink.shrink(b)
-                            outs.append(_readback(b, root, psp))
+                            _start_readback(b)
+                        if prev is not None:
+                            outs.append(_readback(prev, root, psp))
+                        prev = b
+                        if b is None:
+                            break
                     if psp is not None:
                         psp.add_tag("tiles", len(outs))
                 if not _post_run_updates(root):

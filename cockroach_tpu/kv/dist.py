@@ -389,36 +389,6 @@ class DistSender:
         return store.engine.get(k, ts=ts, txn=txn)
 
     @_sender_locked
-    def apply_rpc_batch(self, cid: str, seq: int, muts, resp,
-                        sync: bool = True) -> None:
-        """Stamped-batch surface for the cross-session coalescer
-        (kv/coalesce.py): truncate the train by range — DistSender's
-        batch truncation applied to a mutation batch — and apply one
-        range-addressed stamped sub-batch per range, so the atomic
-        WAL-record + dedup discipline survives splits (a replay after a
-        split dedups against the range that actually applied it).
-        ``sync=False`` defers every store's WAL fsync to wal_sync()."""
-        by_range: dict[int, list] = {}
-        stores: dict[int, Store] = {}
-        for m in muts:
-            k = m[0]
-            store, d = self._route_point(k)
-            self._record_write(d, k, len(m[1]))
-            by_range.setdefault(d.range_id, []).append(m)
-            stores[d.range_id] = store
-        for rid, ms in by_range.items():
-            sub = {"ts": [m[2] for m in ms]}
-            stores[rid].engine.apply_rpc_batch(f"{cid}.r{rid}", seq, ms,
-                                               sub, sync=sync)
-
-    def wal_sync(self) -> None:
-        """Sync every store's WAL (the coalescer cannot know which ranges
-        a train touched once apply returns; syncing an untouched store's
-        WAL is a no-op fsync). Unlocked like Engine.wal_sync."""
-        for s in self.stores.values():
-            s.engine.wal_sync()
-
-    @_sender_locked
     def scan(self, start, end, ts: int, txn: int = 0, max_keys=None):
         out: list[tuple[bytes, bytes]] = []
         s = _b(start) if start is not None else None

@@ -585,24 +585,6 @@ class KVTable:
             "device by device_batch()"
         )
 
-    def snapshot_token(self):
-        """Identity of the snapshot ``device_batch`` decodes RIGHT NOW:
-        equal tokens guarantee bit-identical decodes. The engine write
-        seq pins the version set (the clock only moves forward, so two
-        current-time reads at the same seq see the same newest-visible
-        rows); read_ts/reader_txn pin time-travel and intent visibility.
-        flow/sharedscan.py uses this to let concurrent scans adopt one
-        shared decoded batch. None when the backend has no seq surface."""
-        eng = self.db.engine
-        seq = getattr(eng, "_seq", None)
-        if seq is None:
-            stores = getattr(eng, "stores", None)  # DistSender backend
-            if stores is None:
-                return None
-            seq = tuple(sorted(
-                (sid, s.engine._seq) for sid, s in stores.items()))
-        return (id(eng), seq, self.read_ts, self.reader_txn)
-
     def device_batch(self, names: tuple[str, ...] | None = None) -> Batch:
         """Columnar snapshot of the newest-visible rows, decoded on device.
 

@@ -26,7 +26,6 @@ import time
 from dataclasses import dataclass, field
 
 from ..storage.lsm import Engine, WriteIntentError
-from ..utils import settings
 from . import hlc
 
 
@@ -251,17 +250,7 @@ class DB:
     # another txn's intent conflicts (WriteIntentError) instead of silently
     # laying a committed version beneath the intent; non-txn reads surface
     # the same WriteIntentError (callers retry after the owner resolves).
-    # When kv.batch.coalesce.enabled, concurrent point ops from different
-    # sessions merge into one stamped batch (kv/coalesce.py commit train)
-    # — gate checked BEFORE any engine lock so riders park lock-free.
     def put(self, key, value) -> int:
-        if settings.get("kv.batch.coalesce.enabled"):
-            from .coalesce import for_db
-
-            return for_db(self).put(key, value)
-        return self._put_solo(_b(key), value)
-
-    def _put_solo(self, key, value) -> int:
         k = _b(key)
         with self.engine.mu:
             self._check_lock(k)
@@ -270,13 +259,6 @@ class DB:
         return ts
 
     def delete(self, key) -> int:
-        if settings.get("kv.batch.coalesce.enabled"):
-            from .coalesce import for_db
-
-            return for_db(self).delete(key)
-        return self._delete_solo(_b(key))
-
-    def _delete_solo(self, key) -> int:
         k = _b(key)
         with self.engine.mu:
             self._check_lock(k)
@@ -290,13 +272,6 @@ class DB:
             raise WriteIntentError([key], [other])
 
     def get(self, key, ts: int | None = None) -> bytes | None:
-        if settings.get("kv.batch.coalesce.enabled"):
-            from .coalesce import for_db
-
-            return for_db(self).get(key, ts)
-        return self._get_solo(_b(key), ts)
-
-    def _get_solo(self, key, ts: int | None = None) -> bytes | None:
         return self.engine.get(_b(key), ts=ts if ts is not None
                                else self.clock.now())
 

@@ -60,15 +60,6 @@ HOT_PATHS = (
     # both must charge the matview staging account
     "cockroach_tpu/flow/viewmaint.py",
     "cockroach_tpu/sql/matview.py",
-    # the serving-path coalescing planes buffer cross-session state —
-    # pending write payloads and shared tile windows — sized by load;
-    # both must charge their staging accounts
-    "cockroach_tpu/kv/coalesce.py",
-    "cockroach_tpu/flow/sharedscan.py",
-    # warm-menu compilation workers materialize exemplar batches (one
-    # per menu rung) to drive AOT lowering — rung capacities are
-    # bucketed but still monitor-sized, so warming must account them
-    "cockroach_tpu/sql/warmmenu.py",
 )
 
 # materializing constructors: allocate fresh host/device buffers sized by

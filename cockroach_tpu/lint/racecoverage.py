@@ -19,8 +19,7 @@ instrumentation map:
      call in the defining module naming the field as a string literal,
      so ``debug.race_detector.enabled`` runs actually check it.
 
-New subsystems (coalesce trains, sharedscan subscriber maps, warm-menu
-registries) therefore cannot land shared state the sanitizer never
+New subsystems therefore cannot land shared state the sanitizer never
 sees: the lint gate trips until the state is either provably guarded or
 instrumented. Deliberately lock-free structures that neither hold nor
 want instrumentation carry

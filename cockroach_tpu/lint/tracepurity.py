@@ -47,7 +47,6 @@ SHAPE_HOT = (
     "cockroach_tpu/flow/external.py",
     "cockroach_tpu/flow/fuse.py",
     "cockroach_tpu/flow/viewmaint.py",
-    "cockroach_tpu/flow/sharedscan.py",
     "cockroach_tpu/ops/merge_join.py",
     "cockroach_tpu/ops/sort.py",
     "cockroach_tpu/parallel/shuffle.py",

@@ -211,24 +211,11 @@ class Node:
                     interval_s=max(self._hb_interval * 5, 0.25),
                 )
                 self.ranger.start()
-        if (getattr(self, "_sql_catalog", None) is not None
-                and settings.get("sql.warmup.menu.enabled")):
-            # AOT kernel menu: compile the shape-ladder/hot-statement
-            # kernels BEFORE advertising readiness, bounded by
-            # sql.warmup.menu.budget_s — a fresh node joins pre-warmed
-            from ..sql import warmmenu
-
-            warmmenu.warm_node(self)
         log.info(log.OPS, "node started", node=self.node_id)
         return self
 
     def stop(self) -> None:
         self._stop.set()
-        if getattr(self, "_warmmenu_run", None) is not None:
-            # a budget-bound menu straggler stops at its next statement
-            # boundary; join so no warm-menu thread survives teardown
-            self._warmmenu_run.stop_join()
-            self._warmmenu_run = None
         admission.set_io_health_provider(None)
         if self.ranger is not None:
             self.ranger.stop()

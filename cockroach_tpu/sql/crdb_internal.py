@@ -339,25 +339,6 @@ def _node_materialized_views(catalog) -> Table:
     ])
 
 
-def _node_warmup_menu(catalog) -> Table:
-    """Ahead-of-time kernel menu state (sql/warmmenu.py): one row per
-    menu item with its source course (explicit/hot/ladder), outcome
-    (compiled/failed/skipped), kernels minted, build seconds, and
-    serving-path hits — so EXPLAIN-reachable SQL can audit what the cold
-    wall cost at startup and what it is saving now."""
-    from . import warmmenu
-
-    rows = warmmenu.menu_rows()
-    return _table("crdb_internal.node_warmup_menu", [
-        ("fingerprint", T.STRING, _strs(r["fingerprint"] for r in rows)),
-        ("source", T.STRING, _strs(r["source"] for r in rows)),
-        ("status", T.STRING, _strs(r["status"] for r in rows)),
-        ("kernels", T.INT64, _ints(r["kernels"] for r in rows)),
-        ("seconds", T.FLOAT64, _floats(r["seconds"] for r in rows)),
-        ("hits", T.INT64, _ints(r["hits"] for r in rows)),
-    ])
-
-
 _BUILDERS = {
     "crdb_internal.node_statement_statistics": _stmt_statistics,
     "crdb_internal.cluster_queries": _cluster_queries,
@@ -370,7 +351,6 @@ _BUILDERS = {
     "crdb_internal.node_tenant_admission": _node_tenant_admission,
     "crdb_internal.node_changefeed_subscribers": _node_changefeed_subscribers,
     "crdb_internal.node_materialized_views": _node_materialized_views,
-    "crdb_internal.node_warmup_menu": _node_warmup_menu,
 }
 
 

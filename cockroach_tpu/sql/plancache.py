@@ -32,8 +32,8 @@ pipeline, so the cache holds the BUILT operator tree:
   plan, each tree with a ``ParamStore`` of its own, up to what the process
   admits at once (``admission.sql.slots``); a further tree compiles
   nothing, its kernels are shared by ``dispatch.kernel_key``. Any other
-  plan (spools, join build sides, learned emission caps, shared scan
-  streams) keeps exactly ONE tree and its sessions queue for it under the
+  plan (spools, join build sides, learned emission caps) keeps exactly
+  ONE tree and its sessions queue for it under the
   ``sql.plancache.entry_wait`` span: a second tree would hold the build
   sides again and learn its caps again. Distinct statements run in
   parallel either way.
@@ -372,7 +372,6 @@ class PlanCache:
     def __init__(self):
         self._entries: OrderedDict = OrderedDict()
         self._lock = threading.Lock()
-        self._texts: OrderedDict = OrderedDict()  # fingerprint -> last text
         self._memo: OrderedDict = OrderedDict()   # exact text -> (key, values)
         self.hits = 0
         self.misses = 0
@@ -450,28 +449,6 @@ class PlanCache:
             while len(self._memo) > self._MEMO_CAP:
                 self._memo.popitem(last=False)
 
-    # -- warmup bookkeeping ----------------------------------------------
-
-    _TEXT_CAP = 256
-
-    def note_text(self, fingerprint: str, text: str) -> None:
-        with self._lock:
-            self._texts[fingerprint] = text
-            self._texts.move_to_end(fingerprint)
-            while len(self._texts) > self._TEXT_CAP:
-                self._texts.popitem(last=False)
-
-    def hot_texts(self, limit: int = 32) -> list[str]:
-        """Recorded statement texts for the hottest fingerprints, by the
-        sqlstats execution counts (sql/sqlstats.py)."""
-        from . import sqlstats
-
-        with self._lock:
-            texts = dict(self._texts)
-        counts = {s.fingerprint: s.count for s in sqlstats.DEFAULT.all()}
-        order = sorted(texts, key=lambda fp: -counts.get(fp, 0))
-        return [texts[fp] for fp in order[:limit]]
-
 
 def cache_for(catalog) -> PlanCache:
     pc = getattr(catalog, "_plan_cache", None)
@@ -543,15 +520,7 @@ def run_cached_ex(rel, text: str | None = None):
         entry = cache.insert(key, entry)
     else:
         res = _run_entry(entry, values, "hit")
-        if entry.fingerprint:
-            # warm-menu hit accounting: a serving-path hit on a statement
-            # the AOT menu compiled means the cold wall was paid at start
-            from . import warmmenu
-
-            warmmenu.note_serving_hit(entry.fingerprint)
     if text is not None:
-        if entry.fingerprint:
-            cache.note_text(entry.fingerprint, text)
         low = text.lower()
         if not any(tok in low for tok in _VOLATILE):
             # verbatim repeats can skip parse/bind next time; statements
@@ -613,13 +582,6 @@ def run_memoized_ex(catalog, text: str):
     entry = cache.lookup(key)
     if entry is None:
         return None
-    if entry.fingerprint:
-        # the memo path is still a plan-cache hit — warm-menu accounting
-        # must see it, or menu-compiled statements that repeat verbatim
-        # (the common serving shape) would never count as menu hits
-        from . import warmmenu
-
-        warmmenu.note_serving_hit(entry.fingerprint)
     return _run_entry(entry, values, "memo"), entry.fingerprint
 
 
@@ -666,83 +628,3 @@ def maybe_enable_compile_cache() -> None:
 
     enable_compile_cache()
     _compile_cache_on = True
-
-
-# -- background pre-warming --------------------------------------------------
-
-
-def start_warmup(session, statements=None) -> threading.Thread | None:
-    """Re-execute hot statements on a background session so their plans
-    and kernel specializations are compiled OFF the serving path (after
-    process start or a DDL invalidation). Gated on
-    ``sql.plan_cache.warmup.enabled``; returns the daemon thread (join it
-    in tests) or None when disabled / nothing to warm.
-
-    Replaying the hottest recorded statement texts warms every level at
-    once: the plan cache entry, each kernel at its current canonical
-    tile shape (catalog.SHAPE_BUCKETS keeps that menu small), and — when
-    enabled — the on-disk XLA cache.
-
-    Lifecycle: the thread checks a stop event between statements and the
-    owning session joins it in ``close()`` (via :func:`stop_warmup`), so
-    a warmup racing server shutdown stops at the next statement boundary
-    instead of executing against a torn-down store — the no-leak census
-    asserts no ``plan-warmup`` thread survives teardown. Re-invalidation
-    (back-to-back DDL) stops the previous warmup before starting the
-    next, so at most one warmup thread exists per session."""
-    if not settings.get("sql.plan_cache.warmup.enabled"):
-        return None
-    texts = (list(statements) if statements is not None
-             else cache_for(session.catalog).hot_texts())
-    if not texts:
-        return None
-    from .session import Session
-
-    # one warmup per session: a DDL burst must not stack threads
-    stop_warmup(session)
-    # a PRIVATE session over the shared catalog/store: the warmup thread
-    # must never touch the serving session's transaction state
-    bg = Session(catalog=session.catalog, db=session.db, bootstrap=False)
-    stop = threading.Event()
-
-    def _run():
-        try:
-            for t in texts:
-                if stop.is_set():
-                    return
-                try:
-                    # twice: the first execution compiles; the second
-                    # settles adaptive capacities (join emission caps learn
-                    # from run 1 and re-specialize once), so the SERVING
-                    # repeat is pure dispatch — scripts/check_recompiles.py
-                    # holds it to zero
-                    bg.execute(t)
-                    if stop.is_set():
-                        return
-                    bg.execute(t)
-                except Exception:  # noqa: BLE001 — warmup is best-effort
-                    continue
-        finally:
-            bg.close()
-
-    th = threading.Thread(target=_run, name="plan-warmup", daemon=True)
-    session._warmup_stop = stop
-    session._warmup_thread = th
-    th.start()
-    return th
-
-
-def stop_warmup(session, timeout: float = 5.0) -> None:
-    """Signal and join the session's warmup thread (idempotent; no-op
-    when none is running). Called from Session.close() and before a new
-    warmup replaces a running one."""
-    th = getattr(session, "_warmup_thread", None)
-    if th is None:
-        return
-    stop = getattr(session, "_warmup_stop", None)
-    if stop is not None:
-        stop.set()
-    if th is not threading.current_thread():
-        th.join(timeout=timeout)
-    session._warmup_thread = None
-    session._warmup_stop = None

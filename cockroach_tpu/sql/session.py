@@ -158,13 +158,9 @@ class Session:
 
     def close(self) -> None:
         """Drop this session from the live registry (idempotent; a session
-        that is never closed falls off the registry's bounded end). Joins
-        the background plan-warmup thread first: a warmup racing teardown
-        must stop at its next statement boundary, not execute against a
-        closed store."""
-        from . import activity, plancache
+        that is never closed falls off the registry's bounded end)."""
+        from . import activity
 
-        plancache.stop_warmup(self)
         activity.deregister_session(self._session_id)
         self._mem_mon.close()
 
@@ -842,14 +838,11 @@ class Session:
 
     def _invalidate_plans(self) -> None:
         """Schema-change barrier: bump the catalog version (re-keying every
-        cached plan), eagerly sweep the dead entries, and — when
-        ``sql.plan_cache.warmup.enabled`` — kick the background warmup
-        thread so hot statements recompile off the serving path."""
+        cached plan) and eagerly sweep the dead entries."""
         from . import plancache
 
         self.catalog.bump_version()
         plancache.cache_for(self.catalog).invalidate(self.catalog.version)
-        plancache.start_warmup(self)
 
     def _create_table(self, stmt: P.CreateTable):
         if stmt.name.startswith("__"):

@@ -473,8 +473,7 @@ class Engine:
             self._wal.flush()
 
     def _wal_record(self, kind: int, key: bytes, value: bytes, ts: int,
-                    seq: int, txn: int, flag: bool,
-                    sync: bool = True) -> None:
+                    seq: int, txn: int, flag: bool) -> None:
         from ..utils import faults, tracing
 
         rec = _WAL_REC.pack(kind, ts, seq, txn, 1 if flag else 0,
@@ -495,9 +494,7 @@ class Engine:
                 raise faults.InjectedFault("storage.wal.append", "partial")
             self._wal.write(payload)
             self._wal.flush()
-            # sync=False defers the fsync to an explicit wal_sync() call
-            # (group-commit pipelining: the caller acks only after it)
-            if self.wal_fsync and sync:
+            if self.wal_fsync:
                 with tracing.leaf_span("storage/wal.fsync"):
                     faults.fire("storage.wal.fsync")
                     os.fsync(self._wal.fileno())
@@ -694,26 +691,8 @@ class Engine:
             self._replay_cache.pop(next(iter(self._replay_cache)))
         self._replay_cache[cid] = (int(seq), resp)
 
-    def wal_sync(self) -> None:
-        """fsync the WAL, covering every record appended with
-        ``sync=False``. Deliberately NOT engine-locked: fsync flushes the
-        whole file, so a sync racing later appends only over-delivers
-        durability. Group-commit pipelining hinges on this — append +
-        memtable apply under the mutex, sync outside it (the next batch
-        forms and applies while this one's sync is on the disk), ack
-        riders only after the sync returns."""
-        from ..utils import faults, tracing
-
-        w = self._wal
-        if w is None or not self.wal_fsync:
-            return
-        with tracing.leaf_span("storage/wal.fsync"):
-            faults.fire("storage.wal.fsync")
-            os.fsync(w.fileno())
-
     @_locked
-    def apply_rpc_batch(self, cid: str, seq: int, muts, resp,
-                        sync: bool = True) -> None:
+    def apply_rpc_batch(self, cid: str, seq: int, muts, resp) -> None:
         """Apply a stamped mutation batch exactly once.
 
         muts: [(key bytes, value bytes, ts, txn, tomb), ...] as evaluated
@@ -747,8 +726,7 @@ class Engine:
             # klen/vlen are uint16: struct.pack rejects a batch payload
             # past 64 KiB, surfacing as a typed error before any byte of
             # WAL or memtable state changes
-            self._wal_record(_REC_BATCH, b"", payload, 0, base, 0, False,
-                             sync=sync)
+            self._wal_record(_REC_BATCH, b"", payload, 0, base, 0, False)
         for i, (k, v, ts, txn, tomb) in enumerate(muts):
             metric.ENGINE_WRITES.inc()
             self._raw_append(k, v, int(ts), base + i, int(txn), bool(tomb))

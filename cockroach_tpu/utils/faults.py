@@ -81,19 +81,6 @@ Reference mapping (each named site's CockroachDB analogue):
   write/send failure (changefeedccl's frontier persistence): the
   frontier stays stale, so a resume re-delivers (idempotent by (ts,
   key)) rather than ever skipping events.
-- ``kv.batch.coalesce``     — the coalesced-batch flush failing between
-  collection and apply (the group-commit leader's window): every rider
-  degrades to its own per-session solo batch — bit-identical results,
-  typed per-key errors preserved, nothing applied twice (the merged
-  batch's (cid, seq) stamp never reached the WAL).
-- ``flow.sharedscan.attach`` — attaching a scan to a shared tile stream
-  failing (the fan-out attach window): the query falls back to a solo
-  scan of its own tiles; results identical, only the dispatch saving is
-  lost.
-- ``sql.warmup.compile``    — an ahead-of-time menu item's compile
-  failing at server start (warmup is best-effort): the item is recorded
-  as failed in crdb_internal.node_warmup_menu and serving compiles that
-  kernel on first use instead — never blocks readiness.
 - ``matview.flush`` / ``matview.delta.apply`` /
   ``matview.frontier.checkpoint`` — materialized-view maintenance
   failures at flush start, inside a delta-kernel apply, and between
@@ -163,15 +150,6 @@ SITES: dict[str, str] = {
                                       "subscriber checkpoint frame): "
                                       "resume re-delivers past the stale "
                                       "frontier, never skips",
-    "kv.batch.coalesce": "coalesced-batch flush failure: every rider "
-                         "degrades to its own per-session solo batch, "
-                         "bit-identical, nothing applied twice",
-    "flow.sharedscan.attach": "shared tile stream attach failure: the "
-                              "scan falls back to slicing its own tiles "
-                              "(identical results, dispatch saving lost)",
-    "sql.warmup.compile": "ahead-of-time menu compile failure at server "
-                          "start: item marked failed, serving compiles "
-                          "on first use, readiness never blocked",
     "matview.delta.apply": "materialized-view delta kernel failure "
                            "mid-flush: no state swapped, buffered delta "
                            "retained, retry from frontier is bit-exact",

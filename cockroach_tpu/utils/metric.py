@@ -363,29 +363,6 @@ KERNEL_CACHE_HITS = DEFAULT.counter(
     "sql_kernel_cache_hits",
     "kernel constructions answered by the process-global dispatch.jit "
     "key= cache (structurally identical kernels share one wrapper)")
-SQL_WARMUP_KERNELS_COMPILED = DEFAULT.counter(
-    "sql_warmup_kernels_compiled",
-    "kernels compiled ahead of time by the warm menu (sql/warmmenu.py) "
-    "before the node advertised readiness — cold-wall compiles paid off "
-    "the serving path")
-SQL_WARMUP_MENU_HITS = DEFAULT.counter(
-    "sql_warmup_menu_hits",
-    "serving-path plan-cache hits on statements the warm menu had "
-    "already compiled (a first-ever foreground execution that skipped "
-    "the cold compile wall)")
-KV_BATCH_COALESCED = DEFAULT.counter(
-    "kv_batch_coalesced",
-    "point reads/writes that rode a coalesced multi-op KV batch "
-    "(kv/coalesce.py) instead of a solo engine pass — each is a saved "
-    "WAL record/lock acquisition")
-SQL_SHARED_SCAN_ATTACHED = DEFAULT.counter(
-    "sql_shared_scan_attached",
-    "scans that attached to an already-live shared tile stream "
-    "(flow/sharedscan.py) instead of slicing their own tiles")
-SQL_SHARED_SCAN_DISPATCHES_SAVED = DEFAULT.counter(
-    "sql_shared_scan_dispatches_saved",
-    "tile slice dispatches avoided because a subscriber consumed a tile "
-    "another query had already sliced on the shared stream")
 PLAN_CACHE_HITS = DEFAULT.counter(
     "sql_plan_cache_hits",
     "statements served by a cached prepared plan (build->fuse->compile "

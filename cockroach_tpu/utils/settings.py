@@ -502,14 +502,6 @@ RACE_DETECTOR = register_bool(
     "the per-thread held-lock stack live. Off (default) every "
     "note_read/note_write is a single settings check",
 )
-READBACK_OVERLAP = register_bool(
-    "sql.distsql.readback_overlap", True,
-    "double-buffer the root pull loop (flow/runtime.py): tile k's "
-    "device->host readback is issued asynchronously (copy_to_host_async) "
-    "and materialized while tile k+1 computes, overlapping the readback "
-    "with device work instead of serializing after it",
-    metamorphic=True,
-)
 SHAPE_BUCKETS_ENABLED = register_bool(
     "sql.distsql.shape_buckets.enabled", True,
     "pad sub-tile resident tables up the canonical pow2 shape ladder "
@@ -539,63 +531,6 @@ COMPILE_CACHE_ENABLED = register_bool(
     "cache hierarchy) so process restarts reuse executables instead of "
     "recompiling the fleet; the directory is JAX_COMPILATION_CACHE_DIR "
     "when set, else <checkout>/.jax_cache (utils/backend.py)",
-)
-PLAN_WARMUP_ENABLED = register_bool(
-    "sql.plan_cache.warmup.enabled", False,
-    "background warmup thread: speculatively re-trace hot cached plans "
-    "(by sqlstats fingerprint) off the serving path after DDL or process "
-    "start, so the first foreground execution finds warm kernels",
-)
-WARMUP_MENU_ENABLED = register_bool(
-    "sql.warmup.menu.enabled", False,
-    "ahead-of-time kernel menu (sql/warmmenu.py): at server start compile "
-    "the canonical shape-ladder operator templates plus sqlstats-ranked "
-    "hot statements through flow/dispatch.jit into the process-global "
-    "kernel cache BEFORE the node advertises readiness, so a fresh node "
-    "serves first-ever queries without the cold compile wall",
-)
-WARMUP_MENU_BUDGET_S = register_float(
-    "sql.warmup.menu.budget_s", 30.0,
-    "wall-clock budget for the ahead-of-time kernel menu build; when it "
-    "expires the remaining menu items are skipped (recorded as 'skipped' "
-    "in crdb_internal.node_warmup_menu) and the node starts serving",
-    lo=0.0,
-)
-WARMUP_MENU_MAX_KERNELS = register_int(
-    "sql.warmup.menu.max_kernels", 512,
-    "cap on new kernel compilations the warm menu may mint; menu items "
-    "past the cap are skipped (a runaway template enumeration must not "
-    "exhaust compile-cache or startup time)",
-    lo=1,
-)
-KV_COALESCE_ENABLED = register_bool(
-    "kv.batch.coalesce.enabled", False,
-    "inter-query batching (kv/coalesce.py): concurrent same-range "
-    "non-transactional point reads/writes from different sessions merge "
-    "into one stamped KV batch (group commit through the (cid,seq) "
-    "replay cache — one WAL record, one engine pass) with per-session "
-    "result demux and typed per-key errors",
-)
-KV_COALESCE_MAX_OPS = register_int(
-    "kv.batch.coalesce.max_ops", 128,
-    "cap on point ops merged into one coalesced KV batch; arrivals past "
-    "the cap start the next batch train (bounds WAL record size and "
-    "per-key error fan-out)",
-    lo=2,
-)
-SHAREDSCAN_ENABLED = register_bool(
-    "sql.distsql.sharedscan.enabled", False,
-    "shared tile streams (flow/sharedscan.py): concurrent resident scans "
-    "of the same table attach to one stream — one query slices each tile "
-    "(one dispatch), attached queries consume the shared tile and apply "
-    "their own filter masks downstream",
-)
-SHAREDSCAN_WINDOW = register_int(
-    "sql.distsql.sharedscan.window", 8,
-    "shared-scan buffer window in tiles: a subscriber lagging more than "
-    "this many tiles behind the head is detached to a solo scan "
-    "(slow-consumer eviction), bounding the staging account",
-    lo=1,
 )
 SLOW_QUERY_THRESHOLD = register_float(
     "sql.log.slow_query.latency_threshold", 0.0,

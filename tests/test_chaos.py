@@ -1562,274 +1562,3 @@ def test_race_sanitizer_guards_fanout_frontier():
         hub.close()
         a.close()
         b.close()
-
-
-# -- PR 19 serving-path sites: coalesce, sharedscan attach, warmup compile ---
-
-
-def _coalesce_tape(tid: int, n: int = 40):
-    """Deterministic per-thread mixed-DML tape over private keys."""
-    ops = []
-    for i in range(n):
-        k = f"cz{tid}-{i % 6}"
-        if i % 5 == 4:
-            ops.append(("delete", k, None))
-        elif i % 3 == 2:
-            ops.append(("get", k, None))
-        else:
-            ops.append(("put", k, f"v{tid}.{i}"))
-    return ops
-
-
-def _play_tape(db, tape, out):
-    for kind, k, v in tape:
-        if kind == "put":
-            out.append(db.put(k, v))
-        elif kind == "delete":
-            out.append(db.delete(k))
-        else:
-            out.append(db.get(k))
-
-
-def test_coalesce_fault_degrades_to_solo_bit_identical():
-    """A fault mid-coalesce ("kv.batch.coalesce") degrades every rider of
-    that train to its own per-session solo batch: nothing errors, nothing
-    applies twice, and the surviving state is bit-identical to the same
-    tapes run uncoalesced (p=0.5 under the harness seed: degraded and
-    merged trains interleave within one run)."""
-    from cockroach_tpu.kv import coalesce
-
-    tapes = [_coalesce_tape(t) for t in range(6)]
-
-    # solo oracle first, before any injection
-    solo = DB(Engine())
-    solo_outs = [[] for _ in tapes]
-    for t, tape in enumerate(tapes):
-        _play_tape(solo, tape, solo_outs[t])
-    want = dict(solo.scan(None, None))
-
-    db = DB(Engine())
-    settings.set("kv.batch.coalesce.enabled", True)
-    faults.arm(1229, {"kv.batch.coalesce": FaultSpec(kind="error", p=0.5)})
-    outs = [[] for _ in tapes]
-    errs = []
-    try:
-        def worker(t):
-            try:
-                _play_tape(db, tapes[t], outs[t])
-            except Exception as e:  # pragma: no cover - fail loudly below
-                errs.append(e)
-
-        ts = [threading.Thread(target=worker, args=(t,))
-              for t in range(len(tapes))]
-        for t in ts:
-            t.start()
-        for t in ts:
-            t.join(60)
-    finally:
-        faults.disarm()
-        settings.reset("kv.batch.coalesce.enabled")
-        coalesce.reset_db(db)
-    assert not errs, errs
-    assert dict(db.scan(None, None)) == want
-    # get results are deterministic (thread-private keys): bit-identical
-    # to the solo oracle even across degraded trains
-    for t, tape in enumerate(tapes):
-        for (kind, _k, _v), got, exp in zip(tape, outs[t], solo_outs[t]):
-            if kind == "get":
-                assert got == exp
-
-
-def test_coalesce_fault_every_train_still_serves():
-    """p=1.0: EVERY train degrades — the coalescer must transparently
-    become the solo path, and the fault log must show the site fired."""
-    from cockroach_tpu.kv import coalesce
-
-    db = DB(Engine())
-    settings.set("kv.batch.coalesce.enabled", True)
-    faults.arm(7, {"kv.batch.coalesce": FaultSpec(kind="error", p=1.0)})
-    try:
-        ts1 = db.put("deg-a", "1")
-        ts2 = db.put("deg-b", "2")
-        assert isinstance(ts1, int) and isinstance(ts2, int)
-        assert db.get("deg-a") == b"1"
-        assert db.delete("deg-a") > ts1
-        assert db.get("deg-a") is None
-        assert ("kv.batch.coalesce", "error") in faults.fired()
-    finally:
-        faults.disarm()
-        settings.reset("kv.batch.coalesce.enabled")
-        coalesce.reset_db(db)
-
-
-def test_sharedscan_attach_fault_runs_solo_identical():
-    """An injected fault at "flow.sharedscan.attach" degrades that scan
-    to slicing its own tiles — identical rows, no stream joined. With
-    max_fires=1 the SECOND scan attaches normally, so one run covers
-    both the degraded and the shared path over the same table."""
-    from cockroach_tpu.flow import sharedscan
-    from cockroach_tpu.flow.operators import ScanOp
-
-    cat = _mini_catalog()
-    table = cat.get("orders")
-
-    def rows(op):
-        out = []
-        while True:
-            t = op._next()
-            if t is None:
-                return out
-            mask = np.asarray(t.mask)
-            cols = [np.asarray(c.data) for c in t.cols]
-            out.extend(tuple(c[i] for c in cols)
-                       for i in np.nonzero(mask)[0])
-
-    solo = ScanOp(table, tile=128)
-    solo.init()
-    want = rows(solo)
-    solo.close()
-
-    settings.set("sql.distsql.sharedscan.enabled", True)
-    faults.arm(31, {"flow.sharedscan.attach":
-                    FaultSpec(kind="error", p=1.0, max_fires=1)})
-    try:
-        a = ScanOp(table, tile=128)
-        a.init()
-        assert a._shared is None  # fault: degraded to solo slicing
-        b = ScanOp(table, tile=128)
-        b.init()
-        assert b._shared is not None  # max_fires spent: normal attach
-        got_a, got_b = rows(a), rows(b)
-        a.close()
-        b.close()
-        assert got_a == want
-        assert got_b == want
-        assert not sharedscan._streams
-        assert ("flow.sharedscan.attach", "error") in faults.fired()
-    finally:
-        faults.disarm()
-        settings.reset("sql.distsql.sharedscan.enabled")
-        sharedscan.reset()
-
-
-def test_warmup_compile_fault_records_failed_serves_cold():
-    """A fault at "sql.warmup.compile" marks that menu item failed; the
-    build still completes inside its budget, readiness is never blocked,
-    and the statement serves correctly on first use (compile-on-first-use
-    degrade) — warmup is best-effort by contract."""
-    from cockroach_tpu.sql import warmmenu
-    from cockroach_tpu.sql.session import Session
-
-    warmmenu.reset()
-    cat = _mini_catalog(n=300, seed=23)
-    boot = Session(catalog=cat)
-    settings.set("sql.warmup.menu.enabled", True)
-    faults.arm(47, {"sql.warmup.compile":
-                    FaultSpec(kind="error", p=1.0, max_fires=2)})
-    try:
-        run = warmmenu.build_menu(cat, boot.db, block=True)
-        assert run is not None
-        run.join(10)
-        rows = warmmenu.menu_rows()
-        statuses = [r["status"] for r in rows]
-        assert statuses.count("failed") == 2
-        assert "compiled" in statuses
-        # no warmup thread survives the blocking build
-        assert not [t for t in threading.enumerate()
-                    if t.name.startswith("warm-menu")]
-        # failed items still serve (cold) — and their results match a
-        # fault-free session over the same data
-        faults.disarm()
-        serve = Session(catalog=cat, db=boot.db, bootstrap=False)
-        oracle = Session(catalog=_mini_catalog(n=300, seed=23))
-        try:
-            for s in warmmenu._ladder_statements(cat):
-                got, exp = serve.execute(s), oracle.execute(s)
-                assert set(got) == set(exp)
-                for name in exp:
-                    np.testing.assert_array_equal(
-                        np.asarray(got[name]), np.asarray(exp[name]),
-                        err_msg=f"{s}: {name}")
-        finally:
-            oracle.close()
-            serve.close()
-    finally:
-        faults.disarm()
-        settings.reset("sql.warmup.menu.enabled")
-        boot.close()
-        warmmenu.reset()
-
-
-def test_race_sanitizer_tracks_coalescer_pending():
-    """The coalescer's cross-session meeting point (``_pending``) is
-    racesan-tracked: a rogue thread touching it under the WRONG lock
-    refines the candidate lockset to empty against the product path's
-    ``kv.coalesce`` lock and the next product access raises — the seeded
-    two-thread schedule for the commit train."""
-    from cockroach_tpu.kv import coalesce
-
-    db = DB(Engine())
-    settings.set("kv.batch.coalesce.enabled", True)
-    try:
-        db.put("rs-seed", "1")  # product path: note under kv.coalesce
-        co = db._coalescer
-        rogue = locks.lock("chaos.race.coalesce")
-        transfer_errs = []
-
-        def writer_rogue():
-            try:
-                with rogue:
-                    racesan.note_write(co, "_pending")
-            except racesan.DataRaceError as e:  # pragma: no cover
-                transfer_errs.append(e)
-
-        t = threading.Thread(target=writer_rogue,
-                             name="chaos-coalesce-rogue")
-        t.start()
-        t.join(5)
-        assert not t.is_alive()
-        assert not transfer_errs  # transfer only seeds C = {rogue}
-        # next product-path boarding proves disjointness and raises
-        with pytest.raises(racesan.DataRaceError, match="_pending"):
-            db.put("rs-seed2", "2")
-    finally:
-        settings.reset("kv.batch.coalesce.enabled")
-        coalesce.reset_db(db)
-
-
-def test_race_sanitizer_tracks_sharedscan_subs():
-    """Same seeded schedule for the shared stream's subscriber map: a
-    rogue-locked ``_subs`` write races the product path's
-    ``flow.sharedscan`` lock and detach raises at the access."""
-    from cockroach_tpu.flow import sharedscan
-    from cockroach_tpu.flow.operators import ScanOp
-
-    cat = _mini_catalog()
-    table = cat.get("orders")
-    settings.set("sql.distsql.sharedscan.enabled", True)
-    try:
-        op = ScanOp(table, tile=128)
-        op.init()  # product path: _subs write under flow.sharedscan
-        stream = op._shared
-        assert stream is not None
-        rogue = locks.lock("chaos.race.sharedscan")
-        transfer_errs = []
-
-        def writer_rogue():
-            try:
-                with rogue:
-                    racesan.note_write(stream, "_subs")
-            except racesan.DataRaceError as e:  # pragma: no cover
-                transfer_errs.append(e)
-
-        t = threading.Thread(target=writer_rogue,
-                             name="chaos-sharedscan-rogue")
-        t.start()
-        t.join(5)
-        assert not t.is_alive()
-        assert not transfer_errs
-        with pytest.raises(racesan.DataRaceError, match="_subs"):
-            op.close()  # detach: product-path _subs write
-    finally:
-        settings.reset("sql.distsql.sharedscan.enabled")
-        sharedscan.reset()

@@ -4,7 +4,7 @@
 one-jit-per-operator pulls (both the plan-build pass in flow/fuse.py and
 the consumer-driven spool fusion in flow/operators.py) — the oracle every
 fused run must match bit-for-bit, including the speculative-capacity retry
-path and both readback-overlap modes.
+path; the pull loop's one-tile readback lag returns every tile in order.
 
 A representative subset runs tier-1; the full TPC-H + TPC-DS corpus is
 marked slow (compile-bound: each fused chain jits per query)."""
@@ -32,14 +32,12 @@ def dcat():
     return tpcds.gen_tpcds(sf=0.01)
 
 
-def _run(rel, fusion: bool, overlap: bool = True):
+def _run(rel, fusion: bool):
     settings.set("sql.distsql.fusion.enabled", fusion)
-    settings.set("sql.distsql.readback_overlap", overlap)
     try:
         return rel.run()
     finally:
         settings.reset("sql.distsql.fusion.enabled")
-        settings.reset("sql.distsql.readback_overlap")
 
 
 def _assert_identical(got, want):
@@ -74,10 +72,30 @@ def test_tpcds_fusion_equivalence(dcat, qname):
     _assert_identical(_run(rel, fusion=True), _run(rel, fusion=False))
 
 
-def test_readback_overlap_equivalence(hcat):
-    rel = Q.QUERIES["q3"](hcat)
-    _assert_identical(_run(rel, fusion=True, overlap=True),
-                      _run(rel, fusion=True, overlap=False))
+@pytest.mark.parametrize("tiles", [0, 1, 3])
+def test_pull_loop_returns_every_tile_in_order(tiles):
+    """The pull loop materializes tile k while the root computes tile
+    k+1: the lag must neither drop the last tile nor reorder any, for a
+    root that yields no tile, one tile, and several."""
+    from cockroach_tpu.catalog import Table
+    from cockroach_tpu.coldata.types import INT64, Schema
+    from cockroach_tpu.flow import runtime
+    from cockroach_tpu.flow.operators import ScanOp
+
+    tile = 128
+
+    class FirstTiles(ScanOp):
+        def _next(self):
+            if self._offset >= tiles * tile:
+                return None
+            return super()._next()
+
+    table = Table(name="seq", schema=Schema(("k",), (INT64,)),
+                  columns={"k": np.arange(4 * tile, dtype=np.int64)})
+    got = runtime.run_operator(FirstTiles(table, tile=tile))
+    assert list(got) == ["k"]
+    np.testing.assert_array_equal(
+        got["k"], np.arange(tiles * tile, dtype=np.int64))
 
 
 def test_retry_path_equivalence(hcat):

@@ -1,6 +1,8 @@
 """KV txn layer tests — isolation, conflicts, retries, and a kvnemesis-style
 randomized serializability check (reference: pkg/kv tests + kvnemesis)."""
 
+import threading
+
 import numpy as np
 import pytest
 
@@ -536,3 +538,131 @@ def test_commit_heavy_intent_resolution_bounds_runs():
             assert db.get(b"k%d" % j) is not None
     finally:
         settings.set("storage.compaction.pacing.enabled", prev)
+
+
+# -- concurrent sessions on the non-transactional surface (DB.put / get /
+# delete): what the guarantees are under threads, whatever serves them ------
+
+
+def _session_tape(tid: int, n: int):
+    """Deterministic mixed-DML tape over keys private to one thread, so
+    the interleaving cannot change what any thread reads."""
+    ops = []
+    for i in range(n):
+        k = f"t{tid}-k{i % 8}"
+        if i % 5 == 4:
+            ops.append(("delete", k, None))
+        elif i % 3 == 2:
+            ops.append(("get", k, None))
+        else:
+            ops.append(("put", k, f"v{tid}.{i}"))
+    return ops
+
+
+def _play(db, ops, out):
+    for kind, k, v in ops:
+        if kind == "put":
+            out.append((kind, k, db.put(k, v)))
+        elif kind == "delete":
+            out.append((kind, k, db.delete(k)))
+        else:
+            out.append((kind, k, db.get(k)))
+
+
+def _in_threads(n, fn):
+    errs = []
+
+    def run(i):
+        try:
+            fn(i)
+        except Exception as e:  # noqa: BLE001 - reported by the assert
+            errs.append(e)
+
+    ts = [threading.Thread(target=run, args=(i,)) for i in range(n)]
+    for t in ts:
+        t.start()
+    for t in ts:
+        t.join(60)
+    assert not any(t.is_alive() for t in ts)
+    return errs
+
+
+def test_concurrent_sessions_leave_the_sequential_replays_state():
+    """Six threads' tapes of DB.put / get / delete leave the store in the
+    state the per-key sequential replay gives, and every read returns
+    what that replay reads."""
+    n = 6
+    tapes = [_session_tape(t, 60) for t in range(n)]
+    db = DB(Engine())
+    outs = [[] for _ in range(n)]
+    assert not _in_threads(n, lambda t: _play(db, tapes[t], outs[t]))
+
+    replay = DB(Engine())
+    want = [[] for _ in range(n)]
+    for t in range(n):
+        _play(replay, tapes[t], want[t])
+    assert dict(db.scan(None, None)) == dict(replay.scan(None, None))
+    for t in range(n):
+        for (kind, k, got), (kind2, k2, exp) in zip(outs[t], want[t]):
+            assert (kind, k) == (kind2, k2)
+            if kind == "get":
+                assert got == exp, (k, got, exp)
+            else:  # a write answers with its timestamp
+                assert isinstance(got, int) and isinstance(exp, int)
+
+
+def test_write_intent_error_reaches_the_conflicting_thread_only():
+    """A write under a foreign intent raises WriteIntentError in the
+    thread that sent it and in no other: the innocent write commits."""
+    from cockroach_tpu.storage.lsm import WriteIntentError
+
+    db = DB(Engine())
+    with db.engine.mu:  # a live transaction's intent, as the lock table has it
+        db.engine.put(b"locked", b"i", ts=db.clock.now(), txn=42)
+    results = {}
+    barrier = threading.Barrier(2)
+
+    def session(i):
+        barrier.wait()
+        if i == 0:
+            try:
+                db.put("locked", "v")
+                results["conflict"] = "committed"
+            except WriteIntentError:
+                results["conflict"] = "typed"
+        else:
+            results["innocent"] = db.put("innocent", "v")
+
+    assert not _in_threads(2, session)
+    assert results["conflict"] == "typed"
+    assert isinstance(results["innocent"], int)
+    assert db.get("innocent") == b"v"
+
+
+def test_every_acknowledged_concurrent_put_survives_a_reopen(tmp_path):
+    """Concurrent DB.put writers on an engine with wal_fsync=True,
+    reopened from the WAL: every acknowledged write is there."""
+    wal = str(tmp_path / "wal.log")
+    db = DB(Engine(wal_path=wal, wal_fsync=True))
+    n, per = 6, 15
+    barrier = threading.Barrier(n)
+    acked, mu = [], threading.Lock()
+
+    def writer(i):
+        barrier.wait()
+        got = []
+        for j in range(per):
+            db.put(f"w{i}-{j}", f"{i}.{j}")
+            got.append((f"w{i}-{j}".encode(), f"{i}.{j}".encode()))
+        with mu:
+            acked.extend(got)
+
+    assert not _in_threads(n, writer)
+    assert len(acked) == n * per
+    db.engine.close()
+
+    reopened = DB(Engine(wal_path=wal, wal_fsync=True))
+    state = dict(reopened.scan(None, None))
+    for k, v in acked:
+        assert state.get(k) == v, k
+    reopened.engine.close()

@@ -727,8 +727,8 @@ def test_an_intent_in_the_snapshot_conflicts_even_if_it_resolves_first():
 
 def test_a_get_inside_the_mutex_leaves_its_caller_holding_it():
     """(d) The lock is reentrant: a caller that holds `storage.engine`
-    around a get (the coalescer's train, a transaction's section) still
-    holds it when the get returns."""
+    around a get (a transaction's section) still holds it when the get
+    returns."""
     eng = _engine()
     eng.put(b"a", b"1", ts=1)
     with eng.mu:

@@ -236,25 +236,6 @@ def test_plan_cache_disabled_setting():
         settings.reset("sql.plan_cache.enabled")
 
 
-def test_warmup_thread_precompiles():
-    s = _session()
-    settings.set("sql.plan_cache.warmup.enabled", True)
-    try:
-        th = plancache.start_warmup(
-            s, statements=["SELECT qty FROM items WHERE id = 5"])
-        assert th is not None
-        th.join(timeout=120)
-        assert not th.is_alive()
-        from cockroach_tpu.flow import dispatch
-
-        c0 = dispatch.compiles()
-        r = s.execute("SELECT qty FROM items WHERE id = 6")
-        assert list(np.asarray(r["qty"])) == [6 % 7]
-        assert dispatch.compiles() == c0  # warmed entirely off-path
-    finally:
-        settings.reset("sql.plan_cache.warmup.enabled")
-
-
 # --------------------------------------------------------------------------
 # an entry's trees: a plan that keeps nothing between runs is built once a
 # concurrent session, any other keeps one tree and its sessions queue
