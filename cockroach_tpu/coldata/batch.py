@@ -220,7 +220,8 @@ def to_host(
 ) -> dict[str, np.ndarray]:
     """Materialize live rows to host numpy (the Materializer analog,
     pkg/sql/colexec/materializer.go:30). Decodes STRING via dictionaries
-    (column index -> Dictionary); NULLs become None in object arrays."""
+    (column index -> Dictionary) and CHAR(n) bytes to str; NULLs become None
+    in object arrays."""
     dictionaries = dictionaries or {}
     mask = np.asarray(batch.mask)
     out: dict[str, np.ndarray] = {}
@@ -229,6 +230,14 @@ def to_host(
         valid = np.asarray(batch.cols[i].valid)[mask]
         if t.family is Family.STRING and i in dictionaries:
             vals = dictionaries[i].decode(data)
+            vals[~valid] = None
+            out[name] = vals
+        elif t.family is Family.BYTES and t.text:
+            # CHAR(n): the zero padding carries the length (no NUL in text)
+            raw = np.ascontiguousarray(data).view(
+                f"S{data.shape[1]}").reshape(len(data))
+            vals = np.empty(len(raw), dtype=object)
+            vals[:] = [b.decode("utf-8", "replace") for b in raw]
             vals[~valid] = None
             out[name] = vals
         elif t.family is Family.DECIMAL:

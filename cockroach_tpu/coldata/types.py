@@ -20,7 +20,9 @@ Canonical device representations (all fixed-width; TPU-first):
 | INTERVAL  | int64        | microseconds                                         |
 | STRING    | int32        | dictionary code; dictionary lives host-side in the   |
 |           |              | column's Dictionary (see batch.py)                   |
-| BYTES     | uint8[N,W]   | fixed-width padded buffer + int32 length column      |
+| BYTES     | uint8[N,W]   | fixed-width zero-padded buffer; with `text` it is a  |
+|           |              | SQL CHAR(W): text without NUL bytes (PostgreSQL      |
+|           |              | refuses them), so the padding carries its length     |
 
 Selection vectors become masks: TPUs hate gathers, so the reference's
 ``sel []int`` (pkg/col/coldata/batch.go) is replaced by a boolean liveness mask
@@ -57,6 +59,9 @@ class SQLType:
     width: int = 64  # bit width for INT/FLOAT; max byte width for BYTES
     precision: int = 0  # DECIMAL precision (informational)
     scale: int = 0  # DECIMAL scale: value = data / 10**scale
+    # BYTES only: the column is SQL text of at most `width` bytes (CHAR(n)),
+    # stored raw; results and the wire give str, not bytes
+    text: bool = False
 
     def __repr__(self) -> str:
         if self.family is Family.DECIMAL:
@@ -65,6 +70,8 @@ class SQLType:
             return f"INT{self.width}"
         if self.family is Family.FLOAT:
             return f"FLOAT{self.width}"
+        if self.family is Family.BYTES and self.text:
+            return f"CHAR({self.width})"
         return self.family.name
 
     @property
@@ -122,6 +129,12 @@ def DECIMAL(precision: int = 19, scale: int = 2) -> SQLType:
 
 def BYTES(width: int = 64) -> SQLType:
     return SQLType(Family.BYTES, width=width)
+
+
+def CHAR(width: int) -> SQLType:
+    """SQL CHAR(n) / VARCHAR(n) / STRING(n): text of at most `width` bytes,
+    stored raw at that width (no dictionary)."""
+    return SQLType(Family.BYTES, width=width, text=True)
 
 
 @dataclass(frozen=True)

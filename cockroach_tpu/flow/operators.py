@@ -486,6 +486,47 @@ class PointLookupOp(SourceOperator):
         return b
 
 
+class PKRangeOp(SourceOperator):
+    """Primary-key range read (plan/spec.PKRange): the rows whose key is in
+    [lo, hi] come from the store as DEVICE tiles (``KVTable.range_batches``:
+    the span sought on the host, one window a source, MVCC filter and row
+    decode in one program), through the transaction the statement runs in.
+    A tile's capacity is the window's: a power of two from 128 rows (a
+    range of sysbench's 100 ids, one version each, in one run: 128, the
+    engine's smallest candidate tile and the shape a point read's window
+    already has; 256 only where versions or a second source fill it) up to
+    a page of a few thousand; a wider range streams page by page and never
+    falls back to a decode of the table. The bounds are host values:
+    literals, or the plan cache's ``Param`` slots read at init, so another
+    range is two other arguments to the same operator and compiles
+    nothing."""
+
+    stateless_between_runs = True  # init() starts the read anew
+
+    def __init__(self, table, lo, hi, columns: tuple[str, ...] | None = None,
+                 params=None):
+        super().__init__()
+        self.table = table
+        self.lo, self.hi = lo, hi
+        self.names = tuple(columns or table.schema.names)
+        self._params = params
+        _wire_source_metadata(self, table, self.names)
+        self._pages = None
+
+    def _bound(self, b) -> int:
+        if isinstance(b, ex.Param):
+            return int(self._params.args()[b.slot])
+        return int(b.value)
+
+    def init(self):
+        self._pages = self.table.range_batches(
+            self._bound(self.lo), self._bound(self.hi), self.names)
+        super().init()
+
+    def _next(self):
+        return next(self._pages, None)
+
+
 def _identity_fn(b):
     return b
 

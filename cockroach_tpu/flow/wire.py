@@ -26,12 +26,12 @@ from ..plan import spec as S
 
 def _enc_type(t: T.SQLType) -> dict:
     return {"family": t.family.name, "width": t.width,
-            "precision": t.precision, "scale": t.scale}
+            "precision": t.precision, "scale": t.scale, "text": t.text}
 
 
 def _dec_type(d: dict) -> T.SQLType:
     return T.SQLType(T.Family[d["family"]], d["width"], d["precision"],
-                     d["scale"])
+                     d["scale"], bool(d.get("text", False)))
 
 
 # -- expressions -------------------------------------------------------------
@@ -46,6 +46,9 @@ def enc_expr(e: ex.Expr) -> dict:
             v = int(v)
         elif isinstance(v, (np.floating,)):
             v = float(v)
+        elif isinstance(v, bytes):  # a literal beside a raw CHAR(n) column
+            return {"k": "const", "b": v.decode("latin-1"),
+                    "t": _enc_type(e.type)}
         return {"k": "const", "v": v, "t": _enc_type(e.type)}
     if isinstance(e, ex.Cmp):
         return {"k": "cmp", "op": e.op, "l": enc_expr(e.left),
@@ -77,6 +80,11 @@ def enc_expr(e: ex.Expr) -> dict:
         return {"k": "codes", "col": e.col,
                 "table": np.asarray(e.table).tolist(),
                 "t": _enc_type(e.out_type)}
+    if isinstance(e, ex.BytesLike):
+        return {"k": "byteslike", "a": enc_expr(e.arg),
+                "p": e.pattern.decode("latin-1"), "ci": bool(e.ci)}
+    if isinstance(e, ex.BytesLen):
+        return {"k": "byteslen", "a": enc_expr(e.arg)}
     raise TypeError(f"unencodable expr {type(e).__name__}")
 
 
@@ -85,6 +93,8 @@ def dec_expr(d: dict) -> ex.Expr:
     if k == "col":
         return ex.ColRef(d["i"])
     if k == "const":
+        if "b" in d:
+            return ex.Const(d["b"].encode("latin-1"), _dec_type(d["t"]))
         return ex.Const(d["v"], _dec_type(d["t"]))
     if k == "cmp":
         return ex.Cmp(d["op"], dec_expr(d["l"]), dec_expr(d["r"]))
@@ -112,6 +122,11 @@ def dec_expr(d: dict) -> ex.Expr:
     if k == "codes":
         return ex.CodeLookup(d["col"], np.asarray(d["table"]),
                              _dec_type(d["t"]))
+    if k == "byteslike":
+        return ex.BytesLike(dec_expr(d["a"]), d["p"].encode("latin-1"),
+                            d["ci"])
+    if k == "byteslen":
+        return ex.BytesLen(dec_expr(d["a"]))
     raise TypeError(f"unknown expr kind {k}")
 
 

@@ -15,9 +15,14 @@ is both:
 
 KVTable quacks like catalog.Table (schema / num_rows / dict_by_index /
 device_batch), so ScanOp, the flow engine and sql() work unchanged on
-KV-backed tables. Fixed-width column families only (INT/DECIMAL/DATE/
-TIMESTAMP/INTERVAL/FLOAT/BOOL); STRING/BYTES land with the high-cardinality
-string path.
+KV-backed tables. Fixed-width column families (INT/DECIMAL/DATE/TIMESTAMP/
+INTERVAL/FLOAT/BOOL); STRING is dictionary-coded in the row with its
+dictionary in a companion key space; CHAR(n) (coldata.types.CHAR) is stored
+raw in the row's value slot and decodes on the device as fixed-width bytes.
+
+Two reads never decode the table: ``point_rows`` (the plan's PointLookup)
+and ``range_batches`` (its PKRange: a span of the primary key, sought in the
+store and decoded window by window on the device).
 """
 
 from __future__ import annotations
@@ -27,13 +32,20 @@ import contextlib
 import jax.numpy as jnp
 import numpy as np
 
-from ..coldata.batch import Batch, Dictionary
+from ..coldata.batch import Batch, Column, Dictionary
 from ..coldata.types import Family, Schema
 from ..storage import rowcodec
 from ..storage.lsm import Engine, WriteIntentError
 from .txn import DB, Txn
 
-_UNSUPPORTED = (Family.BYTES, Family.JSON)
+# a window of a range read holds at most this many rows of each source; a
+# wider range streams page by page (the engine's pagination)
+RANGE_PAGE_ROWS = 2048
+
+
+def _unsupported(t) -> bool:
+    return t.family is Family.JSON or (
+        t.family is Family.BYTES and not t.text)
 
 
 def _merged_view(eng):
@@ -82,7 +94,7 @@ class KVTable:
                  table_id: int, dict_table_id: int | None = None,
                  indexes: list | None = None):
         for t in schema.types:
-            if t.family in _UNSUPPORTED:
+            if _unsupported(t):
                 raise TypeError(
                     f"KV tables support fixed-width columns only, got {t}"
                 )
@@ -122,6 +134,7 @@ class KVTable:
         # row write's txn, visible to the planner via plan/indexopt.py
         self.indexes: list = list(indexes or [])
         self._dicts: dict[int, _TableDict] = {}
+        self._range_programs: dict = {}  # columns -> pkrange_decode
         if self._string_cols:
             if dict_table_id is None:
                 raise ValueError(
@@ -310,6 +323,7 @@ class KVTable:
         11-operand sort of a 262,144-row chunk is minutes of compile on
         the chip)."""
         cols = dict(columns)
+        valids = valids or {}
         n = len(next(iter(cols.values())))
         # vectorized dictionary encoding for STRING columns
         for i in self._string_cols:
@@ -448,7 +462,8 @@ class KVTable:
         autocommit read that waits a foreign intent out and retries
         (kv.DB.get_committed). Never a decode of the table. -> (host
         columns, valid masks) of `names` for the keys found, in the order
-        asked and once each; STRING columns stay dictionary codes."""
+        asked and once each; STRING columns stay dictionary codes, CHAR(n)
+        columns are [n, width] zero-padded bytes."""
         from ..utils import metric
 
         t = self.reader
@@ -462,10 +477,86 @@ class KVTable:
         arrays, valids = {}, {}
         for n in names:
             vals = [r[n] for r in rows]
+            t = self.schema.type_of(n)
             valids[n] = np.array([v is not None for v in vals], dtype=bool)
+            if t.family is Family.BYTES:  # CHAR(n): the device's bytes
+                arrays[n] = rowcodec.char_matrix(
+                    np.array(["" if v is None else v for v in vals],
+                             dtype=object), t.width)
+                continue
             arrays[n] = np.array([0 if v is None else v for v in vals],
-                                 dtype=self.schema.type_of(n).dtype)
+                                 dtype=t.dtype)
         return arrays, valids
+
+    def _range_program(self, idxs: tuple[int, ...]):
+        """The device program of a range read over columns `idxs`: MVCC
+        filter and row decode of a candidate view in one launch ->
+        (Batch, any conflict, rows selected). One a (schema, columns),
+        shared by every table and operator tree with them (kept here by
+        columns: a statement takes no process-wide lock to find it)."""
+        prog = self._range_programs.get(idxs)
+        if prog is not None:
+            return prog
+        from ..flow import dispatch
+        from ..storage import mvcc
+
+        schema, pk_idx = self.schema, self.pk_idx
+
+        def pkrange_decode(view, ts, txn, sw, ew):
+            sel, conflict = mvcc.mvcc_scan_filter(view, ts, txn, sw, ew)
+            batch = rowcodec.decode_columns(view.value, sel, schema, idxs)
+            if pk_idx in idxs:  # from the key, as device_batch reads it
+                cols = list(batch.cols)
+                cols[idxs.index(pk_idx)] = Column(
+                    data=rowcodec.decode_pk_column(view.key), valid=sel)
+                batch = Batch(cols=tuple(cols), mask=sel)
+            return (batch, jnp.any(conflict),
+                    jnp.sum(sel, dtype=jnp.int32))
+
+        prog = self._range_programs[idxs] = dispatch.jit(
+            pkrange_decode, name="pkrange_decode",
+            key=("pkrange_decode", schema, idxs, pk_idx))
+        return prog
+
+    def range_batches(self, lo: int, hi: int, names: tuple[str, ...]):
+        """The rows whose primary key is in [lo, hi], as DEVICE tiles, in
+        key order, a page of at most RANGE_PAGE_ROWS rows of a source at a
+        time: the span is sought in the store (Engine.range_read: both
+        bounds on the host, one window a source), filtered and decoded on
+        the device, and no row comes to the host. Inside a transaction the
+        pages are read through it (its snapshot, its own intents visible,
+        the span in its read set for the commit's refresh, a foreign intent
+        its retryable conflict); outside, through the autocommit read that
+        waits a foreign intent out and retries (kv.DB.range_committed).
+        Every page of one call is read at ONE timestamp. Never a decode of
+        the table."""
+        from ..utils import metric
+
+        if lo > hi:
+            return
+        start = rowcodec.encode_pk(self.table_id, lo)
+        end = (rowcodec.encode_pk(self.table_id, hi + 1)
+               if hi < (1 << 63) - 1 else rowcodec.table_span(self.table_id)[1])
+        idxs = tuple(self.schema.index(n) for n in names)
+        page = {"limit_rows": RANGE_PAGE_ROWS,
+                "decode": self._range_program(idxs)}
+        t = self.reader
+        if t is not None:
+            t.note_read_span(start, end)
+        else:
+            ts = (self.read_ts if self.read_ts is not None
+                  else self.db.clock.now())
+        while True:
+            got = (t.range_read(start, end, **page) if t is not None
+                   else self.db.range_committed(start, end, ts, **page))
+            metric.KV_RANGE_READS.inc()
+            metric.KV_RANGE_ROWS.inc(got.rows)
+            metric.KV_RANGE_WINDOW_ROWS.inc(got.window_rows)
+            if got.out is not None:
+                yield got.out
+            if got.boundary is None:
+                return
+            start = got.boundary.rstrip(b"\x00")  # the next page's first key
 
     def get_row(self, pk: int, ts: int | None = None) -> dict | None:
         v = self.db.get(rowcodec.encode_pk(self.table_id, int(pk)), ts=ts)
@@ -626,8 +717,6 @@ class KVTable:
             # the key exercises/validates the key codec path
             pk_col = rowcodec.decode_pk_column(view.key)
             pos = idxs.index(self.pk_idx)
-            from ..coldata.batch import Column
-
             cols = list(batch.cols)
             cols[pos] = Column(data=pk_col, valid=sel)
             batch = Batch(cols=tuple(cols), mask=batch.mask)
@@ -654,8 +743,9 @@ def write_descriptor(db: DB, t: KVTable, writer=None) -> None:
         "name": t.name,
         "names": list(t.schema.names),
         "types": [
-            {"family": ty.family.name, "width": ty.width,
-             "precision": ty.precision, "scale": ty.scale}
+            dict({"family": ty.family.name, "width": ty.width,
+                  "precision": ty.precision, "scale": ty.scale},
+                 **({"text": True} if ty.text else {}))
             for ty in t.schema.types
         ],
         "pk": t.pk,
@@ -706,7 +796,8 @@ def load_catalog_from_engine(catalog, db: DB,
             continue
         types = tuple(
             SQLType(F[d["family"]], width=d["width"],
-                    precision=d["precision"], scale=d["scale"])
+                    precision=d["precision"], scale=d["scale"],
+                    text=bool(d.get("text", False)))
             for d in desc["types"]
         )
         from .index import IndexDesc

@@ -121,6 +121,21 @@ class Txn:
                 f"conflicting intent on {err.keys}"
             ) from err
 
+    def range_read(self, start: bytes, end: bytes, **page):
+        """One page of a device-resident range read (Engine.range_read)
+        at this transaction's snapshot: its own intents are visible, a
+        foreign intent is its retryable conflict. The caller notes the
+        whole span once (`note_read_span`) for the commit's refresh."""
+        self._check_open()
+        try:
+            return self.db.engine.range_read(
+                start, end, ts=self.read_ts, txn=self.txn_id, **page)
+        except WriteIntentError as err:
+            _record_contention(err, self.txn_id)
+            raise TransactionRetryError(
+                f"conflicting intent on {err.keys}"
+            ) from err
+
     # -- writes -------------------------------------------------------------
 
     def put(self, key: bytes | str, value: bytes | str) -> None:
@@ -295,6 +310,29 @@ class DB:
                 _backoff(attempt)
         raise TransactionRetryError(
             f"read of {k!r} gave up after {max_retries} retries")
+
+    def range_committed(self, start: bytes, end: bytes, ts: int,
+                        max_retries: int = MAX_RETRIES, **page):
+        """`get_committed` for one page of a range read
+        (Engine.range_read): a foreign intent inside the span is waited
+        out and the page read again, ``max_retries`` times, then the
+        conflict surfaces as TransactionRetryError (SQLSTATE 40001); never
+        read through. Every try reads at ``ts``, the statement's one
+        timestamp: the intent it waited for commits above it (a commit
+        takes its timestamp from the clock after this read took its own)
+        or aborts, so the snapshot at ``ts`` is what it was."""
+        from ..utils import metric
+
+        for attempt in range(max_retries):
+            try:
+                return self.engine.range_read(start, end, ts=ts, **page)
+            except WriteIntentError as e:
+                _record_contention(e, 0)
+                metric.TXN_RETRIES.inc()
+                _backoff(attempt)
+        raise TransactionRetryError(
+            f"read of [{start!r}, {end!r}) gave up after {max_retries} "
+            f"retries")
 
     def scan(self, start, end, ts: int | None = None, max_keys=None):
         return self.engine.scan(

@@ -202,6 +202,27 @@ class Greatest(Expr):
 
 
 @dataclass(frozen=True)
+class BytesLike(Expr):
+    """SQL LIKE over a raw text column (BYTES with `text`: CHAR(n)), on the
+    device: `pattern` is the pattern's UTF-8 bytes, `%` any run of
+    characters and `_` one character (the binder's LIKE has no escape).
+    `ci` (ILIKE) folds ASCII letters only. The pattern is part of the plan:
+    another pattern is another program."""
+
+    arg: Expr
+    pattern: bytes
+    ci: bool = False
+
+
+@dataclass(frozen=True)
+class BytesLen(Expr):
+    """Characters of a raw text column (its zero padding carries the end,
+    UTF-8 continuation bytes are not counted)."""
+
+    arg: Expr
+
+
+@dataclass(frozen=True)
 class Coalesce(Expr):
     """COALESCE(a, b, ...): first non-NULL argument."""
 
@@ -306,8 +327,10 @@ def expr_type(e: Expr, schema: Schema) -> SQLType:
         if e.func == "round2":
             return expr_type(e.left, schema)
         raise TypeError(f"unknown builtin {e.func}")
-    if isinstance(e, ExtractPart):
+    if isinstance(e, (ExtractPart, BytesLen)):
         return INT64
+    if isinstance(e, BytesLike):
+        return BOOL
     if isinstance(e, Greatest):
         ts = [expr_type(a, schema) for a in e.args]
         fams = {t.family for t in ts}
@@ -432,6 +455,11 @@ def eval_expr(e: Expr, cols, schema: Schema):
                 jnp.zeros((n,), jnp.bool_),
             )
         v = e.value
+        if e.type.family is Family.BYTES:
+            row = np.frombuffer(bytes(v).ljust(e.type.width, b"\0"),
+                                np.uint8)
+            return (jnp.broadcast_to(jnp.asarray(row), (n, row.shape[0])),
+                    jnp.ones((n,), jnp.bool_))
         if e.type.family is Family.DECIMAL:
             v = int(round(float(v) * 10**e.type.scale))
         return (
@@ -443,10 +471,10 @@ def eval_expr(e: Expr, cols, schema: Schema):
         # the value is a traced argument (see param_scope), NOT a baked
         # constant — rebinding it later never invalidates the executable
         n = cols[0].data.shape[0]
-        v = param_value(e.slot)
-        data = jnp.broadcast_to(
-            jnp.asarray(v).astype(e.type.dtype), (n,))
-        return data, jnp.ones((n,), jnp.bool_)
+        v = jnp.asarray(param_value(e.slot)).astype(e.type.dtype)
+        # a BYTES slot is one row of the type's width (uint8[W])
+        return (jnp.broadcast_to(v, (n,) + v.shape),
+                jnp.ones((n,), jnp.bool_))
 
     if isinstance(e, (CodeLookup, ParamLookup)):
         c = cols[e.col]
@@ -618,6 +646,15 @@ def eval_expr(e: Expr, cols, schema: Schema):
             d0, v0 = t, t | f
         return d0, v0
 
+    if isinstance(e, BytesLike):
+        d, v = eval_expr(e.arg, cols, schema)
+        return _bytes_like(d, e.pattern, e.ci), v
+
+    if isinstance(e, BytesLen):
+        d, v = eval_expr(e.arg, cols, schema)
+        chars = (d != 0) & ((d & 0xC0) != 0x80)
+        return jnp.sum(chars, axis=1, dtype=jnp.int64), v
+
     if isinstance(e, Cmp):
         lt, rt = expr_type(e.left, schema), expr_type(e.right, schema)
         if e.op not in ("eq", "ne") and not (
@@ -631,6 +668,10 @@ def eval_expr(e: Expr, cols, schema: Schema):
             )
         ld, lv = eval_expr(e.left, cols, schema)
         rd, rv = eval_expr(e.right, cols, schema)
+        if Family.BYTES in (lt.family, rt.family):
+            if lt.family is not rt.family:
+                raise TypeError(f"cannot compare {lt} with {rt}")
+            return _bytes_cmp(e.op, ld, rd), lv & rv
         ld, rd = _align_numeric(ld, lt, rd, rt)
         fns = {
             "lt": jnp.less,
@@ -686,6 +727,54 @@ def eval_expr(e: Expr, cols, schema: Schema):
         return out_d, out_v
 
     raise TypeError(f"cannot evaluate {e}")
+
+
+def _bytes_cmp(op: str, ld, rd):
+    """`ld <op> rd` over two zero-padded uint8[N, W] buffers, bytewise
+    lexicographic (the order ops/keys.py sorts BYTES by): big-endian words,
+    the first word that differs decides."""
+    from ..coldata.batch import pack_be_words
+
+    w = max(ld.shape[1], rd.shape[1])
+    a, b = (pack_be_words(jnp.pad(d, ((0, 0), (0, w - d.shape[1]))))
+            for d in (ld, rd))
+    eq = jnp.all(a == b, axis=1)
+    if op in ("eq", "ne"):
+        return eq if op == "eq" else ~eq
+    lt = jnp.zeros(eq.shape, jnp.bool_)
+    for i in reversed(range(a.shape[1])):
+        lt = (a[:, i] < b[:, i]) | ((a[:, i] == b[:, i]) & lt)
+    return {"lt": lt, "le": lt | eq, "gt": ~(lt | eq), "ge": ~lt}[op]
+
+
+def _bytes_like(data, pattern: bytes, ci: bool):
+    """LIKE over zero-padded UTF-8 rows uint8[N, W]: state j says that
+    pattern[:j] matches the text read so far; one pass over the W byte
+    columns, the padding (NUL, which text never holds) changes nothing."""
+    pct, one = ord("%"), ord("_")
+    if ci:
+        data = jnp.where((data >= 65) & (data <= 90), data + 32, data)
+    n = data.shape[0]
+    yes, no = jnp.ones((n,), jnp.bool_), jnp.zeros((n,), jnp.bool_)
+    dp = [yes]
+    for tok in pattern:  # a leading run of % matches the empty text
+        dp.append(dp[-1] if tok == pct else no)
+
+    def step(i, dp):
+        ch = jax.lax.dynamic_index_in_dim(data, i, axis=1, keepdims=False)
+        cont = (ch & 0xC0) == 0x80  # inside a multi-byte character
+        new = [no]
+        for j, tok in enumerate(pattern):
+            if tok == pct:
+                new.append(dp[j + 1] | new[j])
+            elif tok == one:
+                new.append((dp[j] & ~cont) | (dp[j + 1] & cont))
+            else:
+                new.append(dp[j] & (ch == tok))
+        live = ch != 0
+        return tuple(jnp.where(live, a, b) for a, b in zip(new, dp))
+
+    return jax.lax.fori_loop(0, data.shape[1], step, tuple(dp))[-1]
 
 
 def _align_numeric(ld, lt: SQLType, rd, rt: SQLType):

@@ -41,6 +41,16 @@ def _to_u64(data: jax.Array, t: SQLType) -> jax.Array:
                 ) | parts[..., 0].astype(jnp.uint64)
     if t.family is Family.BOOL:
         return data.astype(jnp.uint64)
+    if t.family is Family.BYTES:
+        # [N, W] zero-padded bytes: each non-zero big-endian word mixed with
+        # its position, summed, so the padding adds nothing and equal values
+        # of columns with different widths (CHAR(8) = CHAR(12)) hash alike
+        from ..coldata.batch import pack_be_words
+
+        words = pack_be_words(data)
+        pos = _splitmix64(jnp.arange(words.shape[1], dtype=jnp.uint64))
+        return jnp.sum(jnp.where(words != 0, _splitmix64(words ^ pos),
+                                 jnp.uint64(0)), axis=1, dtype=jnp.uint64)
     return data.astype(jnp.int64).astype(jnp.uint64)
 
 

@@ -346,7 +346,14 @@ def _keys_equal(probe: Batch, pkeys, build: Batch, bkeys, bidx, build_remaps=Non
         if pos in build_remaps:
             remap = jnp.asarray(build_remaps[pos])
             bdata = remap[jnp.clip(bdata, 0, remap.shape[0] - 1)]
-        eq = eq & (pc.data == bdata) & pc.valid & bc.valid[bidx]
+        if bdata.ndim == 2:  # BYTES keys: every byte, at the wider width
+            w = max(pc.data.shape[1], bdata.shape[1])
+            same = jnp.all(
+                jnp.pad(pc.data, ((0, 0), (0, w - pc.data.shape[1])))
+                == jnp.pad(bdata, ((0, 0), (0, w - bdata.shape[1]))), axis=1)
+        else:
+            same = pc.data == bdata
+        eq = eq & same & pc.valid & bc.valid[bidx]
     return eq
 
 

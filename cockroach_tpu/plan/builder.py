@@ -165,7 +165,8 @@ def _source(op: Operator) -> str:
     op = unwrap(op)
     while isinstance(op, (ops.FilterOp, ops.ProjectOp, ops.HashBucketOp)):
         op = unwrap(op.child)
-    if isinstance(op, (ops.ScanOp, ops.IndexScanOp, ops.PointLookupOp)):
+    if isinstance(op, (ops.ScanOp, ops.IndexScanOp, ops.PointLookupOp,
+                       ops.PKRangeOp)):
         return op.table.name
     return op.label
 
@@ -175,7 +176,7 @@ def _what(op: Operator) -> str:
         return op.table.name
     if isinstance(op, ops.IndexScanOp):
         return f"{op.table.name}@{op.ix.name}"
-    if isinstance(op, ops.PointLookupOp):
+    if isinstance(op, (ops.PointLookupOp, ops.PKRangeOp)):
         return f"{op.table.name}@primary"
     if isinstance(op, (ops.HashJoinOp, ops.MergeJoinOp)):
         unique = " unique" if op.spec.build_unique else ""
@@ -207,6 +208,9 @@ def _build(plan: S.PlanNode, catalog: Catalog, params=None) -> Operator:
     if isinstance(plan, S.PointLookup):
         return ops.PointLookupOp(catalog.get(plan.table), plan.keys,
                                  plan.columns, params=params)
+    if isinstance(plan, S.PKRange):
+        return ops.PKRangeOp(catalog.get(plan.table), plan.lo, plan.hi,
+                             plan.columns, params=params)
     if isinstance(plan, S.HashBucket):
         return ops.HashBucketOp(_build(plan.input, catalog, params), plan.keys,
                                 plan.n_parts, plan.part)

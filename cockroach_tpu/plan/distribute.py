@@ -260,7 +260,7 @@ def schema_of(plan: S.PlanNode, catalog: Catalog):
         return plan.schema
     if isinstance(plan, S.StreamUnion):
         return schema_of(plan.inputs[0], catalog)
-    if isinstance(plan, (S.IndexScan, S.PointLookup)):
+    if isinstance(plan, (S.IndexScan, S.PointLookup, S.PKRange)):
         t = catalog.get(plan.table)
         names = plan.columns or t.schema.names
         return t.schema.select(tuple(t.schema.index(n) for n in names))

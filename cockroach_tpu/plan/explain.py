@@ -23,6 +23,10 @@ def _node_label(n: S.PlanNode, op=None) -> str:
     if isinstance(n, S.PointLookup):
         cols = f" columns={list(n.columns)}" if n.columns else ""
         return f"point-lookup {n.table}@primary keys={len(n.keys)}{cols}"
+    if isinstance(n, S.PKRange):
+        lo, hi = (getattr(b, "value", b) for b in (n.lo, n.hi))
+        cols = f" columns={list(n.columns)}" if n.columns else ""
+        return f"pk-range {n.table}@primary [{lo}, {hi}]{cols}"
     if isinstance(n, S.Filter):
         return f"filter {n.predicate}"
     if isinstance(n, S.Project):

@@ -1,5 +1,6 @@
 """Index selection — rewrite Filter(TableScan) into IndexScan, or into
-PointLookup where a conjunct pins the primary key.
+PointLookup where a conjunct pins the primary key, or into PKRange where
+two conjuncts bound it on both sides.
 
 Reference: the optimizer's GenerateIndexScans / GenerateConstrainedScans
 exploration rules turn filtered full scans into constrained index scans
@@ -18,7 +19,11 @@ The primary key is a route of its own, taken first and behind no setting
 or statistic: ``pk = c`` or ``pk IN (c1 .. cn)`` (the binder lowers IN to an
 OR of equalities) over a KV-backed table becomes ``PointLookup``; that
 conjunct is answered by the lookup itself and the others stay as Filters
-above it.
+above it. Where no conjunct pins the key but the conjuncts bound it from
+below AND from above (``pk BETWEEN a AND b``, ``pk >= a AND pk < b``) the
+scan becomes ``PKRange``: a seek of the store for the span; the two
+conjuncts are answered by the read and the others stay as Filters. A
+one-sided bound stays a scan.
 
 Selectivity gate: the scan flips to the index only when the constrained
 value range is estimated under ``sql.opt.index_scan_max_frac`` of the
@@ -151,6 +156,49 @@ def _point_lookup(scan: S.TableScan, table, preds) -> S.PlanNode | None:
     return None
 
 
+_INT64_MIN, _INT64_MAX = -(1 << 63), (1 << 63) - 1
+
+
+def _pk_range(scan: S.TableScan, table, preds) -> S.PlanNode | None:
+    """Filter chain over `scan` -> PKRange with the residual Filters, when
+    one conjunct bounds the primary key from below and one from above. The
+    bounds stay expressions (the conjunct's own literal, or one more or
+    less for a strict comparison), so the plan cache makes them Params."""
+    import dataclasses
+
+    names = scan.columns or table.schema.names
+    if table.pk not in names:
+        return None
+    pk_pos = names.index(table.pk)
+    levels = [_conjuncts(p) for p in preds]
+    lo = hi = None  # (conjunct, bound expression)
+    for c in (c for conjs in levels for c in conjs):
+        m = _col_bound(c)
+        if m is None or m[0] != pk_pos or m[1] == "eq":
+            continue
+        _i, op, v = m
+        const = c.right if isinstance(c.right, ex.Const) else c.left
+        if op in ("ge", "gt") and lo is None:
+            if op == "gt" and v == _INT64_MAX:
+                return None
+            lo = (c, const if op == "ge"
+                  else dataclasses.replace(const, value=v + 1))
+        elif op in ("le", "lt") and hi is None:
+            if op == "lt" and v == _INT64_MIN:
+                return None
+            hi = (c, const if op == "le"
+                  else dataclasses.replace(const, value=v - 1))
+    if lo is None or hi is None:
+        return None
+    node: S.PlanNode = S.PKRange(scan.table, lo[1], hi[1], scan.columns)
+    for rest in reversed([[x for x in lv if x is not lo[0] and x is not hi[0]]
+                          for lv in levels]):
+        if rest:
+            node = S.Filter(node, rest[0] if len(rest) == 1
+                            else ex.BoolOp("and", tuple(rest)))
+    return node
+
+
 def use_indexes(plan: S.PlanNode, catalog) -> S.PlanNode:
     """Recursively rewrite eligible Filter(TableScan) subtrees."""
     return _rewrite(plan, catalog,
@@ -175,7 +223,8 @@ def _rewrite(plan, catalog, secondary: bool):
             scan = inner
             table = catalog.tables.get(scan.table)
             if isinstance(table, KVTable) and scan.shard is None:
-                node = _point_lookup(scan, table, preds)
+                node = (_point_lookup(scan, table, preds)
+                        or _pk_range(scan, table, preds))
                 if node is not None:
                     return node
             if (secondary and isinstance(table, KVTable) and table.indexes

@@ -100,7 +100,8 @@ class _Pruner:
         """(rewritten ``n``, {old output position: new position} over the
         columns that survive). ``need``: the positions ``parent`` reads;
         the survivors hold at least those."""
-        if isinstance(n, (S.TableScan, S.IndexScan, S.PointLookup)):
+        if isinstance(n, (S.TableScan, S.IndexScan, S.PointLookup,
+                          S.PKRange)):
             return self._scan(n, need)
         if isinstance(n, S.Filter):
             return self._filter(n, need, parent)

@@ -67,6 +67,25 @@ class PointLookup(PlanNode):
 
 
 @dataclass(frozen=True)
+class PKRange(PlanNode):
+    """Primary-key range read of a KV-backed table: the rows whose primary
+    key is in [``lo``, ``hi``] (both inclusive), sought in the store (both
+    bounds by host binary search over each run's seek keys, one window a
+    source) and decoded on the device window by window
+    (``KVTable.range_batches``), never a decode of the table. ``lo`` and
+    ``hi`` are INT literals, or ``Param`` slots once the plan cache has
+    parameterized the plan: a statement with another range binds the same
+    plan. Planned for ``pk BETWEEN a AND b`` and two-sided comparisons by
+    plan/indexopt.py where no PointLookup applies, whatever secondary
+    indexes the table has; a one-sided bound stays a scan."""
+
+    table: str
+    lo: Expr
+    hi: Expr
+    columns: tuple[str, ...] | None = None
+
+
+@dataclass(frozen=True)
 class HashBucket(PlanNode):
     """Keep only rows whose key-hash bucket equals `part` of `n_parts` —
     one outgoing stream of a HashRouter (colflow/routers.go:420): a

@@ -25,7 +25,7 @@ from dataclasses import dataclass
 import numpy as np
 
 from ..catalog import Catalog
-from ..coldata.types import BOOL, FLOAT64, INT64, Family, SQLType
+from ..coldata.types import BOOL, CHAR, FLOAT64, INT64, Family, SQLType
 from ..ops import expr as ex
 from . import parser as P
 from .rel import Rel
@@ -359,6 +359,25 @@ class ExprLowerer:
                 return i
         return None
 
+    def _is_text_col(self, e: P.Node) -> int | None:
+        """A raw text column (CHAR(n): BYTES with `text`): no dictionary,
+        its predicates compare the bytes on the device."""
+        if isinstance(e, P.Ident):
+            i = self.idx(e)
+            t = self.rel.schema.types[i]
+            if t.family is Family.BYTES and t.text:
+                return i
+        return None
+
+    def _text_const(self, i: int, value: str) -> ex.Const:
+        """A string literal as a zero-padded row of column i's type (one
+        plan for every literal that fits; a longer one can still order)."""
+        raw = value.encode()
+        if b"\0" in raw:
+            raise BindError("a string literal may not hold a NUL byte")
+        t = self.rel.schema.types[i]
+        return ex.Const(raw, t if len(raw) <= t.width else CHAR(len(raw)))
+
     def _colname(self, i: int) -> str:
         return self.rel.schema.names[i]
 
@@ -434,6 +453,14 @@ class ExprLowerer:
             )
             return ex.Not(b) if e.negated else b
         if isinstance(e, P.Like):
+            j = self._is_text_col(e.arg)
+            if j is not None:
+                pat = e.pattern.lower() if e.ci else e.pattern
+                if e.ci and not pat.isascii():
+                    raise BindError("ILIKE over a CHAR(n) column folds "
+                                    "ASCII letters only")
+                pred = ex.BytesLike(ex.ColRef(j), pat.encode(), e.ci)
+                return ex.Not(pred) if e.negated else pred
             i = self._is_string_col(e.arg)
             if i is None:
                 raise BindError("LIKE requires a string column")
@@ -465,6 +492,14 @@ class ExprLowerer:
             )
             return not_distinct if e.negated else ex.Not(not_distinct)
         if isinstance(e, P.InList):
+            j = self._is_text_col(e.arg)
+            if j is not None:
+                if not all(isinstance(x, P.StrLit) for x in e.items):
+                    raise BindError("string IN list must be all literals")
+                pred = ex.or_(*(
+                    ex.Cmp("eq", ex.ColRef(j), self._text_const(j, x.value))
+                    for x in e.items))
+                return ex.Not(pred) if e.negated else pred
             i = self._is_string_col(e.arg)
             if i is not None:
                 vals = [
@@ -622,8 +657,14 @@ class ExprLowerer:
         if (isinstance(e, P.FuncCall)
                 and e.name in ("starts_with", "strpos")
                 and len(e.args) == 2):
-            i = self._is_string_col(e.args[0])
             lit = e.args[1]
+            j = self._is_text_col(e.args[0])
+            if (j is not None and e.name == "starts_with"
+                    and isinstance(lit, P.StrLit)
+                    and not set("%_") & set(lit.value)):
+                return ex.BytesLike(ex.ColRef(j),
+                                    lit.value.encode() + b"%")
+            i = self._is_string_col(e.args[0])
             if i is None or not isinstance(lit, P.StrLit):
                 raise BindError(f"{e.name} requires (string column, "
                                 "string literal)")
@@ -659,6 +700,9 @@ class ExprLowerer:
         if (isinstance(e, P.FuncCall)
                 and e.name in ("length", "char_length")
                 and len(e.args) == 1):
+            j = self._is_text_col(e.args[0])
+            if j is not None:
+                return ex.BytesLen(ex.ColRef(j))
             i = self._is_string_col(e.args[0])
             if i is None:
                 raise BindError(f"{e.name} requires a string column")
@@ -674,11 +718,15 @@ class ExprLowerer:
         # string column vs string literal
         for a, b, flip in ((e.left, e.right, False), (e.right, e.left, True)):
             i = self._is_string_col(a)
-            if i is not None and isinstance(b, P.StrLit):
+            j = self._is_text_col(a) if i is None else None
+            if isinstance(b, P.StrLit) and (i is not None or j is not None):
                 op = e.op
                 if flip:
                     op = {"lt": "gt", "le": "ge", "gt": "lt", "ge": "le",
                           "eq": "eq", "ne": "ne"}[op]
+                if j is not None:
+                    return ex.Cmp(op, ex.ColRef(j),
+                                  self._text_const(j, b.value))
                 if op == "eq":
                     return self._str_eq_at(i, b.value)
                 if op == "ne":
@@ -702,6 +750,10 @@ class ExprLowerer:
         # scaled-int literal when representable (avoids fp rounding surprises)
         lt = ex.expr_type(l, self.rel.schema)
         rt = ex.expr_type(r, self.rel.schema)
+        if (Family.BYTES in (lt.family, rt.family)
+                and lt.family is not rt.family):
+            # a raw CHAR(n) against a dictionary code or a number
+            raise BindError(f"cannot compare {lt} with {rt}")
         if (lt.family is Family.DECIMAL and isinstance(r, ex.Const)
                 and rt.family is Family.FLOAT):
             scaled = r.value * (10 ** lt.scale)
@@ -2243,6 +2295,11 @@ class Binder:
                     continue
                 in_name = f"{name}_in"
                 pre.append((in_name, lower.lower(fc.args[0])))
+                if func != "count" and ex.expr_type(
+                        pre[-1][1], rel.schema).family is Family.BYTES:
+                    # the aggregate kernels fold scalars a row, not [N, W]
+                    raise BindError(
+                        f"{fc.name} over a CHAR(n) column is not supported")
                 if func == "string_agg":
                     if not group_items:
                         raise BindError(

@@ -65,9 +65,10 @@ from ..utils import metric, settings, tracing
 # here as a Const: string predicates lower to host-built CodeLookup tables,
 # which ride as table slots (_TableSlot). BOOL stays literal (structural
 # TRUE/FALSE branches), NULL stays literal (its valid-mask shape differs
-# from any bound value)
+# from any bound value). A BYTES literal (a string compared with a raw
+# CHAR(n) column) is a slot of one zero-padded row of the type's width
 _PARAM_FAMILIES = (Family.INT, Family.FLOAT, Family.DECIMAL, Family.DATE,
-                   Family.TIMESTAMP, Family.INTERVAL)
+                   Family.TIMESTAMP, Family.INTERVAL, Family.BYTES)
 
 
 class _Unkeyable(Exception):
@@ -120,6 +121,8 @@ class ParamStore:
                 # the same host-side fixed-point scaling Const evaluation
                 # applies (ops/expr.py) — device kernels see scaled ints
                 v = int(round(float(v) * 10 ** t.scale))
+            if t.family is Family.BYTES:
+                v = np.frombuffer(bytes(v).ljust(t.width, b"\0"), np.uint8)
             out.append(np.asarray(v, dtype=t.dtype))
         self._values = tuple(out)
 
@@ -130,8 +133,8 @@ class ParamStore:
 
 
 def parameterize(plan, tables: bool = True):
-    """Rewrite numeric Filter-predicate literals and PointLookup keys into
-    Param slots and string-predicate lookup tables into ParamLookup slots.
+    """Rewrite numeric Filter-predicate literals, PointLookup keys and
+    PKRange bounds into Param slots and string-predicate lookup tables into ParamLookup slots.
 
     Returns ``(pplan, values, types)``: the parameterized plan (shared
     across every statement with the same shape), the extracted literal
@@ -197,6 +200,8 @@ def parameterize(plan, tables: bool = True):
                 nv = walk_expr(v)
             elif isinstance(n, S.PointLookup) and f.name == "keys":
                 nv = walk_field(v)
+            elif isinstance(n, S.PKRange) and f.name in ("lo", "hi"):
+                nv = walk_field(v)
             elif isinstance(v, S.PlanNode):
                 nv = walk_plan(v)
             elif (isinstance(v, tuple) and v
@@ -253,7 +258,8 @@ def _table_names(plan) -> list[str]:
     names: set[str] = set()
 
     def walk(n):
-        if isinstance(n, (S.TableScan, S.IndexScan, S.PointLookup)):
+        if isinstance(n, (S.TableScan, S.IndexScan, S.PointLookup,
+                          S.PKRange)):
             names.add(n.table)
         for f in ("input", "probe", "build"):
             c = getattr(n, f, None)

@@ -55,7 +55,9 @@ def _col_type(c: P.ColumnDef) -> T.SQLType:
         return T.DECIMAL(c.precision or 19,
                          c.scale if c.scale is not None else 2)
     if tn in ("string", "text", "varchar", "char"):
-        return T.STRING
+        # with a width the column is stored raw at that many bytes
+        # (CHAR(n)); without one it is dictionary-coded
+        return T.CHAR(c.precision) if c.precision else T.STRING
     t = _TYPE_MAP.get(tn)
     if t is None:
         raise BindError(f"unknown column type {tn!r}")
@@ -999,6 +1001,8 @@ class Session:
                     raise BindError(
                         f"invalid DATE literal {e.value!r}: {err}"
                     ) from None
+            if t.family is T.Family.BYTES and t.text:
+                return e.value  # CHAR(n): rowcodec stores the bytes
             if t.family is not T.Family.STRING:
                 raise BindError("string literal for non-STRING column")
             return e.value  # KVTable dictionary-encodes on insert
@@ -1062,7 +1066,7 @@ class Session:
                 valid = np.array([v is not None for v in vals], dtype=bool)
                 if not valid.all():
                     valids[n] = valid
-                if typ.family is T.Family.STRING:
+                if typ.family in (T.Family.STRING, T.Family.BYTES):
                     cols[n] = np.array(
                         ["" if v is None else v for v in vals],
                         dtype=object,
@@ -1193,8 +1197,8 @@ def _from_result(v, t: T.SQLType):
     re-scale / re-encode for storage)."""
     if v is None:
         return None
-    if t.family is T.Family.STRING:
-        return str(v)  # KVTable dictionary-encodes on insert
+    if t.family in (T.Family.STRING, T.Family.BYTES):
+        return str(v)  # KVTable dictionary-encodes STRING on insert
     if t.family is T.Family.DECIMAL:
         return int(round(float(v) * (10 ** t.scale)))
     if t.family is T.Family.FLOAT:

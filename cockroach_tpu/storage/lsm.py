@@ -11,15 +11,18 @@ Host-side orchestration of device-resident sorted runs:
   bottom-level compaction runs only on explicit ``compact(bottom=True)``.
   Partial merges are always safe: the global write sequence resolves
   same-(key, ts) winners regardless of which runs have merged;
-- reads never mutate the run set. Bounded reads (get / short scans) gather
-  only the in-range rows of each run + the memtable into small candidate
-  tiles and merge THOSE (the merging-iterator role, pebble_mvcc_scanner.go
-  :381 semantics via ``mvcc_scan_filter``), so point/short-range cost is
-  O(candidates·log), not O(total history). Unbounded reads use a merged
-  view cached per run-set generation. A point read holds the engine mutex
-  only to take a ``_Snapshot`` of what it will read (the run set, each
-  run's seek keys and bloom, the memtable's block when it holds the key)
-  and runs its searches, launches and readback with the mutex released;
+- reads never mutate the run set. Bounded reads (get / range_read / scans
+  with a bound) SEEK each run and the memtable's block on the host (two
+  binary searches over its seek keys), take the one window that holds its
+  rows of the span from the block cache or a device slice, and merge THOSE
+  (the merging-iterator role, pebble_mvcc_scanner.go :381 semantics via
+  ``mvcc_scan_filter``), so point/range cost is O(rows of the span), not
+  O(total history). Unbounded reads use a merged view cached per run-set
+  generation. A point read and a range read hold the engine mutex only to
+  take a ``_Snapshot`` of what they will read (the run set, each run's
+  seek keys and bloom, the memtable's block when it may hold a key of
+  theirs) and run their searches, launches and readback with the mutex
+  released;
 - ``checkpoint``/``open_checkpoint`` persist runs to .npz files and
   truncate the WAL (pkg/storage/pebble.go:2077 CreateCheckpoint analog);
   a crash between checkpoints recovers by WAL replay at open.
@@ -51,6 +54,12 @@ from ..utils import locks
 
 _RUN_ALIGN = 1024
 _CAND_ALIGN = 128  # candidate tiles for bounded reads start smaller
+# a memtable block's seek keys go by this token: the next write replaces the
+# block, so its windows never enter the block cache (run tokens start at 1)
+_MEM_TOKEN = 0
+# windows over this many rows are not cached either: one of them would push
+# out thousands of point-read windows
+_CACHE_MAX_ROWS = 4096
 
 _WAL_MAGIC = b"CTWL"
 # kind (0=write, 1=intent resolution, 2=ingest link), ts, seq, txn,
@@ -146,23 +155,31 @@ def _slice_window(block: mvcc.KVBlock, pos, size: int) -> mvcc.KVBlock:
     )
 
 
-@functools.partial(jax.jit, static_argnames=("cap",))  # crlint: allow-raw-jit(storage-plane kernel: dispatch budget scopes the SQL flow layer)
-def _gather_rows(block: mvcc.KVBlock, m: jax.Array, cap: int) -> mvcc.KVBlock:
-    """Compact the rows where `m` into a tile of `cap` (row order kept, so a
-    sorted source yields a sorted candidate tile)."""
-    dest = jnp.where(m, jnp.cumsum(m.astype(jnp.int32)) - 1, cap)
-    n = jnp.sum(m, dtype=jnp.int32)
+def _seek(void_keys: np.ndarray, n_live: int, start: bytes | None,
+          end: bytes | None) -> tuple[int, int]:
+    """[lo, hi): the positions of a sorted source's live rows whose key is
+    in [start, end) (zero-padded key bytes; None = unbounded), by two host
+    binary searches over its seek keys: the iterator's SeekGE, for both
+    ends of a span."""
+    live = void_keys[:n_live]
+    lo = 0 if start is None else int(np.searchsorted(
+        live, np.frombuffer(start, dtype=void_keys.dtype)[0], side="left"))
+    hi = n_live if end is None else int(np.searchsorted(
+        live, np.frombuffer(end, dtype=void_keys.dtype)[0], side="left"))
+    return lo, hi
 
-    def take(x):
-        shape = (cap,) + x.shape[1:]
-        return jnp.zeros(shape, x.dtype).at[dest].set(x, mode="drop")
 
-    return mvcc.KVBlock(
-        key=take(block.key), ts=take(block.ts), seq=take(block.seq),
-        txn=take(block.txn), tomb=take(block.tomb), value=take(block.value),
-        vlen=take(block.vlen),
-        mask=jnp.arange(cap, dtype=jnp.int32) < n,
-    )
+class RangeRead(NamedTuple):
+    """What `Engine.range_read` hands back: `out` of its decode over the
+    candidate view (None where no source holds a row of the span), the
+    rows selected, the truncation boundary (None: the whole span was
+    read), and what it read for them."""
+
+    out: object
+    rows: int
+    boundary: bytes | None
+    window_rows: int
+    sources: int
 
 
 class WriteIntentError(Exception):
@@ -206,15 +223,25 @@ class _Memtable:
     # the keys above as a set: a point read of a key that is not here
     # leaves the memtable out (no block built, nothing uploaded)
     keyset: set[bytes] = field(default_factory=set)
+    # the first bytes of the keys above (a table's keys share theirs): a
+    # range read of a span none of them can fall in leaves the memtable out
+    heads: set[int] = field(default_factory=set)
 
     def __len__(self) -> int:
         return len(self.ts)
+
+    def may_hold(self, start: bytes | None, end: bytes | None) -> bool:
+        """Whether a key in [start, end) may be here, from `heads` alone."""
+        lo = start[0] if start else 0
+        hi = end[0] if end else 0xFF
+        return any(lo <= h <= hi for h in self.heads)
 
     def append(self, key: bytes, ts: int, seq: int, txn: int, tomb: bool,
                value: bytes, vlen: int) -> None:
         if txn != 0:
             self.intents.setdefault(txn, []).append(len(self.ts))
         self.keyset.add(key)
+        self.heads.add(key[0] if key else 0)
         self.keys.append(key)
         self.ts.append(ts)
         self.seq.append(seq)
@@ -320,10 +347,10 @@ def _locked(fn):
 
     Every write, flush, compaction, resolution, ``scan``, ``scan_batch``,
     ``has_committed_writes_in`` and the span / checkpoint methods keep the
-    mutex for their whole body. Two do not carry this decorator. ``get``
-    holds it only while it takes a ``_Snapshot`` and does its searches,
-    launches and readback on that with the mutex released (a caller that
-    already holds it keeps it: the lock is reentrant).
+    mutex for their whole body. Three do not carry this decorator. ``get``
+    and ``range_read`` hold it only while they take a ``_Snapshot`` and do
+    their searches, launches and readback on that with the mutex released
+    (a caller that already holds it keeps it: the lock is reentrant).
     ``span_versions_estimate`` reads the published run snapshot with no
     mutex at all, and takes it only to build one where the run set has
     just changed: an estimate needs no instant."""
@@ -346,6 +373,8 @@ class _Snapshot(NamedTuple):
     metas: tuple[blockcache.RunMeta, ...]
     memtable: _Memtable  # the host memtable this run set goes with
     mem: mvcc.KVBlock | None = None  # its sorted block, where a read needs it
+    # the block's seek keys (token _MEM_TOKEN: its windows are not cached)
+    mem_meta: blockcache.RunMeta | None = None
 
 
 class Engine:
@@ -426,6 +455,7 @@ class Engine:
         self._snap_cache: tuple[int, _Snapshot] | None = None
         self._scan_windows: dict[int, int] = {}  # max_keys -> learned window
         self._mem_cache = None  # ((mem len, gen), sorted block)
+        self._mem_meta = None  # that block's seek keys
         self._overlay_cache = None  # ((gen, mem len), merged view)
         # variable-width value overflow heap (the WiscKey / pebble
         # value-separation shape): values longer than the fixed inline
@@ -778,6 +808,7 @@ class Engine:
         n = len(self.mem)
         if self._mem_cache is not None and self._mem_cache[0] == (n, self._gen):
             return self._mem_cache[1]
+        self._mem_meta = None
         keys = K.encode_keys(self.mem.keys, self.key_width)
         vals = np.zeros((n, self.val_width), dtype=np.uint8)
         vlen = np.asarray(self.mem.vlen, dtype=np.int32)
@@ -816,6 +847,9 @@ class Engine:
         flowmem.charge_object("storage/run-residency", blk,
                               _block_nbytes(blk))
         self._mem_cache = ((n, self._gen), blk)
+        # the block's seek keys, as a run has them: a bounded read seeks
+        # the memtable's block like any other sorted source
+        self._mem_meta = blockcache.RunMeta(_MEM_TOKEN, void_keys[order], n)
         return blk
 
     @_locked
@@ -1097,71 +1131,77 @@ class Engine:
             metric.ENGINE_SNAPSHOT_BUILDS.inc()
         return snap
 
-    def _snapshot(self, point: bytes | None = None) -> _Snapshot:
+    def _snapshot(self, point: bytes | None = None,
+                  span: tuple | None = None) -> _Snapshot:
         """What a read reads, as of now; the caller holds the mutex. A
         point read of a key the memtable does not hold leaves the memtable
-        out (no block built, nothing uploaded). Runs, the key-set test and
-        the memtable's block come from ONE hold of the mutex: an old run
-        tuple paired with the empty memtable a flush left behind would
-        lose the flushed rows."""
+        out (no block built, nothing uploaded), and so does a read of a
+        ``span`` (start, end) that no key of the memtable can fall in.
+        Runs, the key tests and the memtable's block come from ONE hold of
+        the mutex: an old run tuple paired with the empty memtable a flush
+        left behind would lose the flushed rows."""
         snap = self._run_snapshot()
         if point is not None and point not in self.mem.keyset:
             return snap
+        if span is not None and not self.mem.may_hold(*span):
+            return snap
         mb = self._mem_block()
-        return snap if mb is None else snap._replace(mem=mb)
+        return snap if mb is None else snap._replace(
+            mem=mb, mem_meta=self._mem_meta)
 
     def _bounded_view(self, snap: _Snapshot, sw, ew,
                       limit_rows: int | None = None,
-                      point: bytes | None = None):
-        """Candidate view for a bounded read: gather only in-range rows of
-        each source of the snapshot it is handed into small tiles and merge
-        those — point/short-scan cost scales with matching rows, not total
-        history. Reads nothing of the engine that a writer changes, so it
-        runs with or without the mutex.
+                      point: bytes | None = None,
+                      note: dict | None = None):
+        """Candidate view for a bounded read: every source of the snapshot
+        it is handed is SOUGHT on the host (two binary searches over its
+        seek keys, `_seek`: a run's, or the memtable block's), the window
+        that holds its rows of the span comes from the block cache or one
+        device slice, and the windows are merged: cost scales with the
+        rows of the span, never with a source's length, and a source with
+        no row of the span costs no device work at all. A window is handed
+        on as it lies (a few rows of the neighbouring keys ride along):
+        every caller applies the bounds again (`mvcc_scan_filter`). Reads
+        nothing of the engine that a writer changes, so it runs with or
+        without the mutex.
 
-        limit_rows clamps each SORTED run to its first limit_rows in-range
-        entries (the pebbleMVCCScanner pagination discipline): a scan with
+        limit_rows clamps each source to its first limit_rows entries of
+        the span (the pebbleMVCCScanner pagination discipline): a scan with
         max_keys must not gather half the keyspace just because its end
         bound is open. Returns (view, boundary): rows at or past `boundary`
-        (the smallest truncation point across runs) are INCOMPLETE — some
-        of their versions may have been cut — and callers must not emit
-        them. boundary None means nothing was truncated."""
+        (the smallest truncation point across sources) are INCOMPLETE: some
+        of their versions may have been cut, and callers must not emit
+        them. boundary None means nothing was truncated. ``note``, where
+        given, takes what was read: `window_rows`, `sources`."""
         sources = []
         if snap.mem is not None:
-            # the memtable's block never seeks: it has no seek keys
-            sources.append((snap.mem, None))
+            sources.append((snap.mem, snap.mem_meta))
         sources.extend(zip(snap.runs, snap.metas))
+        start = None if sw is None else _words_to_bytes(sw)
+        end = None if ew is None else _words_to_bytes(ew)
         parts = []
         boundary: bytes | None = None
         for src, meta in sources:
-            sorted_run = meta is not None
-            if (point is not None and sorted_run
+            is_run = meta.token != _MEM_TOKEN
+            if (point is not None and is_run
                     and not self._bloom_might_contain(meta, point)):
                 # per-run bloom filter: the key is definitely absent —
-                # skip the run's range-mask/gather entirely (pebble's
+                # skip the run's seek and window entirely (pebble's
                 # table-filter point-read pruning)
                 from ..utils import metric
 
                 metric.BLOOM_SKIPS.inc()
                 continue
-            if limit_rows is not None and sorted_run and sw is not None:
-                # iterator seek: host binary search over the run's cached
-                # key bytes finds the start position, one device
-                # dynamic-slice lands the window — O(window), never
-                # O(run length) (the pebble iterator SeekGE discipline)
-                vkeys, n_live = meta.void_keys, meta.n_live
-                if n_live == 0:
-                    continue
-                sw_raw = _words_to_bytes(sw)
-                pos = int(np.searchsorted(
-                    vkeys[:n_live],
-                    np.frombuffer(sw_raw, dtype=vkeys.dtype)[0],
-                    side="left",
-                ))
-                if pos >= n_live:
-                    continue
-                size = min(_pad(limit_rows, _CAND_ALIGN), src.capacity)
-                cpos = min(pos, max(0, src.capacity - size))
+            lo, hi = _seek(meta.void_keys, meta.n_live, start, end)
+            if hi <= lo:
+                continue  # no row of the span here
+            want = hi - lo if limit_rows is None else min(hi - lo,
+                                                          limit_rows)
+            size = min(_pad(want, _CAND_ALIGN), src.capacity)
+            cpos = min(lo, max(0, src.capacity - size))
+            if size == src.capacity:
+                win = src  # the whole source: nothing to slice or copy
+            elif is_run and size <= _CACHE_MAX_ROWS:
                 # block cache: runs are immutable, so a (token, pos,
                 # size) window's contents never change — consult the
                 # node cache before dispatching the device slice
@@ -1170,30 +1210,18 @@ class Engine:
                 if win is None:
                     win = _slice_window(src, cpos, size)
                     cache.put(meta.token, cpos, size, win)
-                end_pos = cpos + size
-                if end_pos < n_live:
-                    cut = bytes(vkeys[end_pos - 1].tobytes())
-                    if ew is None or cut < _words_to_bytes(ew):
-                        if boundary is None or cut < boundary:
-                            boundary = cut
-                if point is not None:
-                    # a point read's window IS its candidate tile (sorted,
-                    # a few hundred rows), as it lies in the cache: not
-                    # masked, counted or compacted, each of which would
-                    # cost a launch; mvcc_scan_filter applies the bounds
-                    parts.append(win)
-                    continue
-                m, cnt = _range_mask(win, sw, ew)
-                cnt = int(np.asarray(cnt))
-                if cnt == 0:
-                    continue
-                parts.append(_gather_rows(win, m, _pad(cnt, _CAND_ALIGN)))
-                continue
-            m, cnt = _range_mask(src, sw, ew)
-            cnt = int(np.asarray(cnt))
-            if cnt == 0:
-                continue
-            parts.append(_gather_rows(src, m, _pad(cnt, _CAND_ALIGN)))
+            else:
+                win = _slice_window(src, cpos, size)
+            if cpos + size < hi:
+                # the window ends inside the span: its last key's versions
+                # may go on past it
+                cut = bytes(meta.void_keys[cpos + size - 1].tobytes())
+                if boundary is None or cut < boundary:
+                    boundary = cut
+            parts.append(win)
+        if note is not None:
+            note["sources"] = len(parts)
+            note["window_rows"] = sum(p.capacity for p in parts)
         if not parts:
             return None, None
         if len(parts) == 1:
@@ -1484,6 +1512,65 @@ class Engine:
         # before its row is visible: safe to follow without the mutex
         return self._resolve_value(value[i], int(vlen[i]))
 
+    def range_read(self, start: bytes, end: bytes, ts: int, decode,
+                   txn: int = 0, limit_rows: int | None = None) -> RangeRead:
+        """[start, end) snapshot read at `ts` that stays on the device: the
+        span's windows are sought and merged (`_bounded_view`, both bounds)
+        and ``decode(view, ts, txn, start_words, end_words) -> (out, any
+        conflict, rows selected)`` runs over the candidate view: the
+        caller's program, which applies `mvcc_scan_filter` and unpacks what
+        it wants of the rows (KVTable's fused filter-and-decode). Nothing of the rows comes to the
+        host: the one sync is the readback of the conflict flag and the
+        row count. A foreign intent in the span raises WriteIntentError.
+
+        ``limit_rows`` pages the read: each source gives at most that many
+        rows of the span, and where one was cut the result's `boundary` is
+        the key the next page starts at (rows at or past it are left out
+        of this one); it grows by itself where a page's first key alone
+        has more versions than the limit.
+
+        The mutex is held for the snapshot only, as in `get`; a span no
+        key of the memtable can fall in leaves the memtable out."""
+        from ..utils import tracing
+
+        sw = K.encode_bound(start, self.key_width)
+        ew = K.encode_bound(end, self.key_width)
+        first = bytes(start).ljust(self.key_width, b"\x00")
+        with tracing.leaf_span("storage/engine.range_read") as span:
+            with self.mu:
+                t0 = time.perf_counter()
+                snap = self._snapshot(span=(start, end))
+                if span is not None:
+                    span.tags["held_ms"] = 1e3 * (time.perf_counter() - t0)
+            note: dict = {}
+            limit = limit_rows
+            while True:
+                view, boundary = self._bounded_view(
+                    snap, sw, ew, limit_rows=limit, note=note)
+                if boundary is None or boundary > first:
+                    break
+                limit *= 4  # the page would end inside its first key
+            if span is not None:
+                span.tags["window_rows"] = note["window_rows"]
+                span.tags["sources"] = note["sources"]
+            if view is None:
+                return RangeRead(None, 0, None, 0, 0)
+            if boundary is not None:
+                ew = K.encode_bound(boundary, self.key_width)
+            out, conflict, rows = decode(
+                view, np.int64(ts), np.int64(txn), sw, ew)
+            conflict, rows = jax.device_get((conflict, rows))
+        if conflict:
+            _sel, hit = mvcc.mvcc_scan_filter(
+                view, np.int64(ts), np.int64(txn), sw, ew)
+            idx = np.nonzero(np.asarray(hit))[0]
+            raise WriteIntentError(
+                K.decode_keys(np.asarray(view.key)[idx]),
+                [int(t) for t in np.asarray(view.txn)[idx]],
+            )
+        return RangeRead(out, int(rows), boundary, note["window_rows"],
+                         note["sources"])
+
     # -- intents ------------------------------------------------------------
 
     @_locked
@@ -1615,15 +1702,11 @@ class Engine:
             with self.mu:
                 snap = self._run_snapshot()
         n = sum(1 for k in snap.memtable.keys if start <= k < end)
+        first, last = (b.ljust(self.key_width, b"\x00")
+                       for b in (start, end))
         for m in snap.metas:
-            if not m.n_live:
-                continue
-            lo, hi = (np.frombuffer(b.ljust(self.key_width, b"\x00"),
-                                    dtype=m.void_keys.dtype)[0]
-                      for b in (start, end))
-            live = m.void_keys[:m.n_live]
-            n += int(np.searchsorted(live, hi, side="left")
-                     - np.searchsorted(live, lo, side="left"))
+            lo, hi = _seek(m.void_keys, m.n_live, first, last)
+            n += hi - lo
         return n
 
     @_locked

@@ -11,13 +11,23 @@ inside the KV server ("direct columnar scan"). Here:
   NUL-free (the engine's zero-padded fixed-width keys cannot contain 0x00;
   the reference instead escapes 0x00 in its variable-length encoding).
 - values: a null bitmap (1 bit per column, set = non-NULL) followed by one
-  8-byte little-endian slot per column (floats as raw IEEE bits).
+  slot per column: 8 little-endian bytes (floats as raw IEEE bits), or for a
+  CHAR(n) column (coldata.types.CHAR: text stored raw, no dictionary) a
+  4-byte little-endian length and n bytes, zero-padded. The device never
+  reads the length (text holds no NUL, so the padding carries it); it is
+  stored because the row format is the deployment's (sbtest1: 1 + 8 + 8 +
+  124 + 64 = 205 B in a 256 B slot, configs/sysbench_oltp.json) and a
+  stored row outlives the rule that text holds no NUL: the host's
+  decode_row cuts by it. A schema without a CHAR(n) column has the layout
+  it always had, byte for byte.
 - decode: the entire value column of a KVBlock ([cap, VW] uint8) unpacks
   into typed device columns with shift-sum lane arithmetic — the direct
   columnar scan as a traced kernel, no per-row host loop.
 """
 
 from __future__ import annotations
+
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -60,44 +70,87 @@ def decode_pk(key: bytes) -> int:
         else (u ^ (1 << 63))
 
 
+CHAR_LEN_BYTES = 4  # a CHAR(n) slot: this length, then n bytes
+
+
+def _is_char(t) -> bool:
+    return t.family is Family.BYTES and t.text
+
+
+@functools.lru_cache(maxsize=256)
+def slot_layout(schema: Schema) -> tuple[tuple[int, ...], int]:
+    """(byte offset of each column's slot in the value, value width); a
+    schema is static plan-side data, so a row's codec looks it up once."""
+    off = (len(schema) + 7) // 8
+    offs = []
+    for t in schema.types:
+        offs.append(off)
+        off += CHAR_LEN_BYTES + t.width if _is_char(t) else 8
+    return tuple(offs), off
+
+
 def value_width(schema: Schema) -> int:
-    nullbytes = (len(schema) + 7) // 8
-    return nullbytes + 8 * len(schema)
+    return slot_layout(schema)[1]
+
+
+def text_bytes(v, width: int) -> bytes:
+    """A CHAR(width) value as it is stored: UTF-8, at most `width` bytes,
+    no NUL (PostgreSQL refuses one in text; here the zero padding of the
+    device's fixed-width representation carries the length)."""
+    b = bytes(v) if isinstance(v, (bytes, bytearray, np.bytes_)) \
+        else str(v).encode("utf-8")
+    if len(b) > width:
+        raise ValueError(
+            f"value of {len(b)} bytes too long for type CHAR({width})")
+    if b"\x00" in b:
+        raise ValueError("a CHAR(n) value cannot hold a NUL byte")
+    return b
 
 
 def encode_row(schema: Schema, row: dict) -> bytes:
     """Pack one row into the fixed-width value payload. NULL = missing key
     or None value."""
-    ncols = len(schema)
-    nullbytes = (ncols + 7) // 8
-    out = bytearray(nullbytes + 8 * ncols)
+    offs, width = slot_layout(schema)
+    out = bytearray(width)
     for i, (name, t) in enumerate(zip(schema.names, schema.types)):
         v = row.get(name)
         if v is None:
             continue
         out[i // 8] |= 1 << (i % 8)  # set = non-NULL
+        if _is_char(t):
+            b = text_bytes(v, t.width)
+            o = offs[i]
+            out[o:o + CHAR_LEN_BYTES] = len(b).to_bytes(CHAR_LEN_BYTES,
+                                                        "little")
+            out[o + CHAR_LEN_BYTES:o + CHAR_LEN_BYTES + len(b)] = b
+            continue
         if t.family is Family.FLOAT:
             bits = np.float64(v).view(np.uint64)
         elif t.family is Family.BOOL:
             bits = np.uint64(1 if v else 0)
         else:
             bits = np.int64(int(v)).view(np.uint64)
-        out[nullbytes + 8 * i: nullbytes + 8 * (i + 1)] = int(bits).to_bytes(
-            8, "little")
+        out[offs[i]: offs[i] + 8] = int(bits).to_bytes(8, "little")
     return bytes(out)
 
 
 def decode_row(schema: Schema, value: bytes) -> dict:
-    """Host-side single-row decode (debugging / point lookups)."""
-    ncols = len(schema)
-    nullbytes = (ncols + 7) // 8
+    """Host-side single-row decode (debugging / point lookups); a CHAR(n)
+    column comes back as str."""
+    offs, _ = slot_layout(schema)
     out = {}
     for i, (name, t) in enumerate(zip(schema.names, schema.types)):
         if not (value[i // 8] >> (i % 8)) & 1:
             out[name] = None
             continue
-        bits = int.from_bytes(value[nullbytes + 8 * i: nullbytes + 8 * (i + 1)],
-                              "little")
+        o = offs[i]
+        if _is_char(t):
+            ln = int.from_bytes(value[o:o + CHAR_LEN_BYTES], "little")
+            out[name] = bytes(
+                value[o + CHAR_LEN_BYTES:o + CHAR_LEN_BYTES + ln]
+            ).decode("utf-8", "replace")
+            continue
+        bits = int.from_bytes(value[o:o + 8], "little")
         if t.family is Family.FLOAT:
             out[name] = float(np.uint64(bits).view(np.float64))
         elif t.family is Family.BOOL:
@@ -130,13 +183,15 @@ def encode_rows(schema: Schema, columns: dict[str, np.ndarray],
     payloads (the colenc analog: the write path's columnar encoder; the
     per-row encode_row remains for single-row DML)."""
     valids = valids or {}
-    ncols = len(schema)
-    nullbytes = (ncols + 7) // 8
+    offs, width = slot_layout(schema)
     n = len(next(iter(columns.values())))
-    out = np.zeros((n, nullbytes + 8 * ncols), dtype=np.uint8)
+    out = np.zeros((n, width), dtype=np.uint8)
     for i, (name, t) in enumerate(zip(schema.names, schema.types)):
         a = np.asarray(columns[name])
         v = valids.get(name)
+        if _is_char(t):
+            _encode_char_column(out, offs[i], i, t.width, a, v)
+            continue
         if t.family is Family.FLOAT:
             bits = a.astype(np.float64).view(np.uint64)
         elif t.family is Family.BOOL:
@@ -144,7 +199,7 @@ def encode_rows(schema: Schema, columns: dict[str, np.ndarray],
         else:
             bits = a.astype(np.int64).view(np.uint64)
         lanes = bits.astype("<u8").view(np.uint8).reshape(n, 8)
-        off = nullbytes + 8 * i
+        off = offs[i]
         if v is None:
             out[:, i // 8] |= np.uint8(1 << (i % 8))
             out[:, off:off + 8] = lanes
@@ -153,6 +208,39 @@ def encode_rows(schema: Schema, columns: dict[str, np.ndarray],
             out[vb, i // 8] |= np.uint8(1 << (i % 8))
             out[vb, off:off + 8] = lanes[vb]
     return out
+
+
+def char_matrix(a: np.ndarray, width: int) -> np.ndarray:
+    """A CHAR(width) column as [N, width] uint8, zero-padded: from a uint8
+    matrix of at most that width (bulk loaders make their text as bytes),
+    or from str / bytes values (encoded one by one)."""
+    if a.dtype == np.uint8 and a.ndim == 2:
+        if a.shape[1] > width:
+            raise ValueError(f"{a.shape[1]}-byte rows too long for type "
+                             f"CHAR({width})")
+        m = np.zeros((len(a), width), dtype=np.uint8)
+        m[:, :a.shape[1]] = a
+        return m
+    enc = [text_bytes(x, width) for x in a]
+    return np.array(enc, dtype=f"S{width}").reshape(len(enc)).view(
+        np.uint8).reshape(len(enc), width)
+
+
+def _encode_char_column(out: np.ndarray, off: int, i: int, width: int,
+                        a: np.ndarray, valid) -> None:
+    """One CHAR(width) column into its slots of `out`: the length is the
+    bytes before the zero padding (a value holds no NUL)."""
+    m = char_matrix(a, width)
+    nz = m != 0
+    lens = nz.sum(axis=1).astype("<u4")
+    # zero bytes only after the last non-zero one
+    if (nz[:, 1:] & ~nz[:, :-1]).any():
+        raise ValueError("a CHAR(n) value cannot hold a NUL byte")
+    rows = slice(None) if valid is None else np.asarray(valid, dtype=bool)
+    out[rows, i // 8] |= np.uint8(1 << (i % 8))
+    out[rows, off:off + CHAR_LEN_BYTES] = lens.view(np.uint8).reshape(
+        -1, CHAR_LEN_BYTES)[rows]
+    out[rows, off + CHAR_LEN_BYTES:off + CHAR_LEN_BYTES + width] = m[rows]
 
 
 # -- device-side columnar decode (read path: the cFetcher kernel) -----------
@@ -175,15 +263,23 @@ def decode_columns(
 
     The direct-columnar-scan kernel (col_mvcc.go role): every requested
     column unpacks with lane-parallel shift sums; NULL bits gate `valid`."""
-    ncols = len(schema)
-    nullbytes = (ncols + 7) // 8
-    idxs = col_idxs if col_idxs is not None else tuple(range(ncols))
+    offs, _ = slot_layout(schema)
+    idxs = col_idxs if col_idxs is not None else tuple(range(len(schema)))
     cols = []
     for i in idxs:
         t = schema.types[i]
         nb = value[:, i // 8]
         valid = ((nb >> np.uint8(i % 8)) & np.uint8(1)).astype(jnp.bool_)
-        raw = _le_words(value[:, nullbytes + 8 * i: nullbytes + 8 * (i + 1)])
+        if _is_char(t):
+            # the bytes as they are stored: coldata's BYTES(n), zero-padded
+            lo = offs[i] + CHAR_LEN_BYTES
+            live = (valid & sel)[:, None]
+            cols.append(Column(
+                data=jnp.where(live, value[:, lo:lo + t.width],
+                               jnp.uint8(0)),
+                valid=valid & sel))
+            continue
+        raw = _le_words(value[:, offs[i]: offs[i] + 8])
         if t.family is Family.FLOAT:
             # uint64 -> (lo32, hi32) -> f64 by the u32-pair route, which
             # utils/backend.float_bitcast_ok checks on the backend in use

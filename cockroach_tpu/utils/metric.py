@@ -238,6 +238,18 @@ ENGINE_SNAPSHOT_BUILDS = DEFAULT.counter(
     "storage_engine_snapshot_builds",
     "read snapshots of the run set rebuilt because the run set changed "
     "(flush, ingest, compaction, a resolution that rewrote a run)")
+KV_RANGE_READS = DEFAULT.counter(
+    "sql_kv_range_reads",
+    "pages read by the primary-key range plan route "
+    "(KVTable.range_batches: one Engine.range_read each)")
+KV_RANGE_ROWS = DEFAULT.counter(
+    "sql_kv_range_rows",
+    "rows the primary-key range route returned (newest visible version a "
+    "key, inside the bounds)")
+KV_RANGE_WINDOW_ROWS = DEFAULT.counter(
+    "sql_kv_range_window_rows",
+    "rows of run and memtable windows the primary-key range route sliced "
+    "or took from the block cache: its read amplification")
 KV_TABLE_DECODES = DEFAULT.counter(
     "sql_kv_table_decodes",
     "whole-table columnar decodes of a KV-backed table "

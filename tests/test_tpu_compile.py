@@ -290,8 +290,81 @@ def test_host_planned_run_order_at_node_store_shapes_has_no_sort(one_chip):
         assert not re.search(r"\bsort\(", compiled.as_text())
 
 
+def test_range_read_programs_at_sysbench_shapes_compile(one_chip):
+    """What a sysbench range statement launches (PR 45), at the cell's
+    shapes: a 128-row window of a store with 64-byte keys and 256-byte
+    values through the fused MVCC filter and row decode (`pkrange_decode`:
+    byte slices of the value slot for CHAR(120), shift sums for the
+    integers), and the ORDER BY over the decoded CHAR(120) column (fifteen
+    big-endian words and a null bit packed into sixteen sort operands; at
+    128 rows the sort compiles in seconds, where PR 22 met minutes at
+    65,536). Neither holds a loop or a program over the table's rows."""
+    from cockroach_tpu.coldata import types as T
+    from cockroach_tpu.coldata.batch import Batch, Column
+    from cockroach_tpu.kv.table import KVTable
+    from cockroach_tpu.ops import sort as sort_ops
+
+    n = 128
+    schema = T.Schema.of(id=T.INT64, k=T.INT64, c=T.CHAR(120),
+                         pad=T.CHAR(60))
+    table = KVTable.__new__(KVTable)  # the program reads these alone
+    table.schema, table.pk_idx, table._range_programs = schema, 0, {}
+    view = _kvblock_shape(n, one_chip, key_width=64, val_width=256)
+    scalar = jax.ShapeDtypeStruct((), jnp.int64, sharding=one_chip)
+    words = jax.ShapeDtypeStruct((8,), jnp.uint64, sharding=one_chip)
+    for idxs in ((2,), (1,), (0, 1, 2, 3)):
+        text = table._range_program(idxs)._jitted.lower(
+            view, scalar, scalar, words, words).compile().as_text()
+        assert not re.search(r"\bwhile\(", text)
+
+    def s(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    batch = Batch(cols=(Column(data=s((n, 120), jnp.uint8),
+                               valid=s((n,), jnp.bool_)),),
+                  mask=s((n,), jnp.bool_))
+    out = schema.select((2,))
+    # crlint: allow-raw-jit(AOT compile for a described chip: nothing is dispatched)
+    text = jax.jit(lambda b: sort_ops.sort_batch(
+        b, out, (sort_ops.SortKey(0),))).lower(batch).compile().as_text()
+    assert re.search(r"\bsort\(", text)
+
+
 def test_described_devices_are_not_attached(topo):
     """The process still runs on the CPU mesh: describing a chip must not
     change what jax.devices() reports to the rest of the suite."""
     assert jax.devices()[0].platform == "cpu"
     assert np.all([d.platform == "tpu" for d in topo.devices])
+
+
+def test_char_predicates_at_sysbench_widths_compile(one_chip):
+    """A filter over a raw CHAR(120) column (PR 45): the literal of `c =
+    'x'` rides as one zero-padded row (a Param of the cached plan), `<`
+    decides by the first of fifteen big-endian words that differs, LIKE
+    walks the 120 byte columns once with one state a pattern position (a
+    loop over W, never over the rows)."""
+    from cockroach_tpu.coldata import types as T
+    from cockroach_tpu.coldata.batch import Column
+    from cockroach_tpu.ops import expr as ex
+
+    n = 128
+    schema = T.Schema.of(c=T.CHAR(120))
+
+    def s(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    col = Column(data=s((n, 120), jnp.uint8), valid=s((n,), jnp.bool_))
+    lit = s((120,), jnp.uint8)
+
+    def pred(c, v):
+        with ex.param_scope((v,)):
+            p = ex.and_(
+                ex.Cmp("lt", ex.ColRef(0), ex.Param(0, T.CHAR(120))),
+                ex.Cmp("ne", ex.ColRef(0), ex.Const(b"abc", T.CHAR(120))),
+                ex.BytesLike(ex.ColRef(0), "1%-_9%".encode(), True),
+                ex.Cmp("gt", ex.BytesLen(ex.ColRef(0)), ex.lit(3)))
+            return ex.eval_expr(p, (c,), schema)
+
+    # crlint: allow-raw-jit(AOT compile for a described chip: nothing is dispatched)
+    text = jax.jit(pred).lower(col, lit).compile().as_text()
+    assert text.count("while(") <= 2  # LIKE's pass over the byte columns
