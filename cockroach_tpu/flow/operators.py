@@ -1727,7 +1727,7 @@ class HashJoinOp(OneInputOperator):
         eremaps = self.build_code_remaps or None
         # two programs, chosen once from the plan: a producer that proves
         # its tiles live-prefix has them placed at a running offset, any
-        # other compacted through a nonzero index a tile
+        # other compacted through a `live_index` a tile
         self._places_build = self.build.emits_live_prefix
         into_one = concat_prefix if self._places_build else concat
 

@@ -147,7 +147,7 @@ class Streamer:
         """-> Batch of the requested columns for rows whose pk is in
         `pks`, at the table's read context. Output capacity = padded
         len(pks) (missing pks leave masked-off rows)."""
-        from ..coldata.batch import Batch, Column, empty_batch
+        from ..coldata.batch import Batch, Column, empty_batch, live_index
         from ..storage import keys as K
         from ..storage import mvcc
         from ..storage.lsm import WriteIntentError
@@ -188,7 +188,9 @@ class Streamer:
         posc = jnp.clip(pos, 0, len(spks) - 1)
         sel = sel & (dpks[posc] == vpk)
         # compacting gather: hits land in [0, cap_out)
-        dest = jnp.nonzero(sel, size=cap_out, fill_value=view.key.shape[0])[0]
+        dest, _ = live_index(sel, cap_out)
+        dest = jnp.pad(dest, (0, cap_out - dest.shape[0]),
+                       constant_values=sel.shape[0])
         batch = rowcodec.decode_columns(view.value, sel, tbl.schema, idxs)
 
         def take(col):

@@ -271,6 +271,28 @@ def test_placed_join_build_at_q21_shapes_has_one_scatter(one_chip):
     assert len(re.findall(r"\bdynamic-update-slice\(", text)) >= 7
 
 
+def test_compaction_index_at_a_tile_and_q3s_cap_is_one_plain_sort(one_chip):
+    """`live_index` at the shapes every join cell's emit runs it (a
+    1,048,576-row mask, q3's 65,536 cap; PR 46): the chip's compiler keeps
+    ONE sort of ONE s32 operand and no scatter (the library's sized
+    `nonzero` is a scatter-add of 1,048,576 updates). The gotcha it met:
+    asked for a STABLE sort, XLA:TPU adds an iota operand as the tie-break
+    (`sort(x, iota), is_stable=true`) and compiles in 21 s where this
+    compiles in 3 (this sandbox, PR 46); the live positions are distinct
+    and the dead rows all carry the fill, so stability buys nothing."""
+    from cockroach_tpu.coldata.batch import live_index
+
+    mask = jax.ShapeDtypeStruct((1 << 20,), jnp.bool_, sharding=one_chip)
+    # crlint: allow-raw-jit(AOT compile for a described chip: nothing is dispatched)
+    text = jax.jit(live_index, static_argnames="capacity").lower(
+        mask, capacity=1 << 16).compile().as_text()
+    sorts = re.findall(r"= (\S+) sort\(([^)]*)\)", text)
+    assert len(sorts) == 1
+    shape, operands = sorts[0]
+    assert shape.startswith("s32[1048576]") and "," not in operands
+    assert not re.search(r"\bscatter\(", text)
+
+
 def test_host_planned_run_order_at_node_store_shapes_has_no_sort(one_chip):
     """The engine's write path at the node store's widths (64-byte keys,
     128-byte values): a compaction's output is one gather by a permutation
