@@ -798,6 +798,24 @@ class LimitOp(OneInputOperator):
 # Aggregation
 
 
+def _stored_keys_below(op: Operator, cols) -> bool:
+    """Whether every row `op` hands on, dead or live, still holds in
+    ``cols`` what its table stored there: the chain down to a ScanOp is
+    Filters (which clear mask bits) and Projects that pass ``cols`` through
+    as bare column references."""
+    cols = list(cols)
+    while True:
+        if isinstance(op, FilterOp):
+            op = op.child
+        elif isinstance(op, ProjectOp):
+            if not all(isinstance(op.exprs[c], ex.ColRef) for c in cols):
+                return False
+            cols = [op.exprs[c].idx for c in cols]
+            op = op.child
+        else:
+            return isinstance(op, ScanOp)
+
+
 class AggregateOp(OneInputOperator):
     """GROUP BY aggregation (hashAggregator analog). mode:
     - complete: input rows -> final results
@@ -835,6 +853,16 @@ class AggregateOp(OneInputOperator):
         # the dead-row compaction sort too.
         self.ordered = ordered
         self.prefix_live = prefix_live
+        if ordered and not prefix_live and not _stored_keys_below(
+                child, group_cols):
+            # ops/aggregation.py `_ordered_groupby` groups a tile where its
+            # rows lie: a dead row inside a group must still carry the
+            # group's key, which only a chain that never rewrites the
+            # stored key can promise (plan/builder.py `_clustered_input`)
+            raise ValueError(
+                "an ordered aggregate over dead rows needs its group keys "
+                "as the table stored them: Scan -> Filter -> "
+                "Project(ColRef) chains only")
         # string_agg runs OUTSIDE the device state pipeline: per-row
         # (group key, string code) pairs are collected host-side during
         # the spool and concatenated at finalize (the reference's concat

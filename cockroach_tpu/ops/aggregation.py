@@ -6,11 +6,12 @@ aggregation detects group boundaries in sorted input. The TPU redesign uses two
 strategies, both static-shape:
 
 1. ``sort_groupby`` — the general path. Sort the tile by the group key columns
-   (XLA sort), detect segment boundaries, reduce with segmented associative
-   scans (ops/segscan.py — log-depth fused passes; jax.ops.segment_* lowers
-   to scatter, which serializes on the TPU vector unit at ~100ms/op/1M rows).
-   Replaces pointer-chasing hash tables, which TPUs cannot do, with sorts and
-   scans, which they do well.
+   (XLA sort), detect segment boundaries, reduce with segmented scans
+   (ops/segscan.py; jax.ops.segment_* lowers to scatter, which serializes on
+   the TPU vector unit). Replaces pointer-chasing hash tables, which TPUs
+   cannot do, with sorts and scans, which they do well. Over keys already
+   adjacent (``presorted``) nothing is sorted by key and no row moves before
+   the reduction: ``_ordered_groupby``.
 
 2. ``smallgroup_partial_states`` — the MXU/VPU path for planner-known small group
    cardinality G (e.g. TPC-H Q1's returnflag x linestatus = 6): a one-hot
@@ -33,7 +34,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..coldata.batch import Batch, Column
+from ..coldata.batch import Batch, Column, pad_rows
 from ..coldata.types import FLOAT64, INT64, Family, Schema, SQLType
 from . import segscan
 
@@ -146,12 +147,13 @@ def _segment_agg(spec: AggSpec, col: Column | None, live, seg, cap,
 def _scan_agg_entries(spec: AggSpec, col: Column | None, live,
                       t: SQLType | None):
     """Plan one aggregate as segmented-scan work: returns (entries, finish)
-    where entries is a list of (op, row_vals) to scan and finish(*at_slots)
-    maps the scans' per-segment totals (gathered at segment ends) to
-    (data, valid).
+    where entries is a list of (op, row_vals) to scan and finish(*scanned)
+    maps the scans' running totals, elementwise, to (data, valid): what it
+    gives at a segment's last row is the segment's.
 
-    The scans replace jax.ops.segment_* (scatter-lowered on TPU, ~100ms per
-    op per 1M-row tile) with log-depth fused passes (segscan.py)."""
+    The scans replace jax.ops.segment_* (scatter-lowered on TPU: 71 ms an
+    int64 op over a 1,048,576-row tile, PR 47) with log-depth shifted
+    passes (segscan.py)."""
     add = jnp.add
 
     if spec.func == "count_rows":
@@ -206,15 +208,19 @@ def _scan_agg_entries(spec: AggSpec, col: Column | None, live,
 
 
 def _packed_group_keys(batch: Batch, schema: Schema,
-                       group_cols: tuple[int, ...], col_stats: dict) -> list:
+                       group_cols: tuple[int, ...], col_stats: dict,
+                       dead_bit: bool = True) -> list:
     """Every row's (dead?, group keys) bit-packed into uint64 words: live
     rows sort first, then by group keys, and word equality IS group-key
     equality (nulls are their own group; NULL rows' garbage data is zeroed
     inside key_segments so the NULL group is contiguous even with later
-    key columns in play)."""
+    key columns in play). ``dead_bit=False`` leaves the liveness bit out:
+    the words of the keys alone, for a caller that compares a dead row's
+    key with its neighbours' (`_ordered_groupby`)."""
     from . import keys as key_ops
 
-    segs: list = [key_ops.BitSeg(1, (~batch.mask).astype(jnp.uint64))]
+    segs: list = ([key_ops.BitSeg(1, (~batch.mask).astype(jnp.uint64))]
+                  if dead_bit else [])
     for gi in group_cols:
         c = batch.cols[gi]
         segs.extend(key_ops.key_segments(
@@ -249,15 +255,27 @@ def sort_groupby(
     presorted=True asserts equal group keys are already ADJACENT in the
     input (clustered storage, Table.ordering) and skips the key sort —
     the colexec orderedAggregator specialization (ordered sort-free
-    grouping). compact=True still runs a single-operand stable sort that
-    pushes dead rows last (needed when filters interleave dead rows);
-    compact=False additionally asserts live rows form a prefix (pure
-    scan tiles), making the whole grouping sort-free."""
+    grouping). On an accelerator the tile is then grouped where its rows
+    lie, dead rows included (`_ordered_groupby`, which says what it needs
+    of a dead row's key; an unsorted tile is one after its key sort), and
+    ``compact`` is not read. On the CPU compact=True still runs a stable
+    sort that pushes dead rows last (needed when filters interleave dead
+    rows); compact=False additionally asserts live rows form a prefix
+    (pure scan tiles), making the whole grouping sort-free."""
     cap = batch.capacity
     cap_out = out_capacity or cap
     live = batch.mask
     col_stats = col_stats or {}
 
+    if segscan.use_scans():
+        if not presorted:
+            batch = _sorted_by_keys(batch, schema, group_cols, aggs,
+                                    col_stats)
+        return _ordered_groupby(batch, schema, group_cols, aggs, cap_out,
+                                col_stats)
+
+    # the CPU from here on (XLA:CPU scatters are a cheap serial loop; 20
+    # log-depth scan passes are not — segscan.use_scans)
     operands = _packed_group_keys(batch, schema, group_cols, col_stats)
     perm = jnp.arange(cap, dtype=jnp.int32)
     if not presorted:
@@ -297,49 +315,18 @@ def sort_groupby(
     out_cols: list[Column] = []
     out_mask = jnp.arange(cap_out, dtype=jnp.int32) < num_groups
 
-    if not segscan.use_scans():
-        # CPU: scatter the boundary row's key into its segment slot and
-        # reduce with jax.ops.segment_* (XLA:CPU scatters are a cheap serial
-        # loop; 20 log-depth scan passes are not — segscan.use_scans).
-        seg = jnp.maximum(jnp.cumsum(boundary.astype(jnp.int32)) - 1, 0)
-        dest = jnp.where(boundary, seg, cap_out)
-        for kd, kv in keys_s:
-            data = jnp.zeros(
-                (cap_out,) + kd.shape[1:], kd.dtype
-            ).at[dest].set(kd, mode="drop")
-            valid = jnp.zeros((cap_out,), jnp.bool_).at[dest].set(
-                kv, mode="drop"
-            )
-            out_cols.append(Column(data=data, valid=valid))
-        for spec in aggs:
-            col = None
-            t = None
-            if spec.col is not None:
-                t = schema.types[spec.col]
-                col = Column(
-                    data=batch.cols[spec.col].data[perm],
-                    valid=batch.cols[spec.col].valid[perm],
-                )
-            data, valid = _segment_agg(spec, col, live_s, seg, cap_out, t)
-            out_cols.append(Column(data=data, valid=valid & out_mask))
-        return Batch(cols=tuple(out_cols), mask=out_mask), num_groups
-
-    # TPU: segment j's total lives at its END row after an inclusive
-    # segmented scan; compacting the end rows to the front (one stable sort)
-    # puts segment j's end at position j — scatter-free slot assignment.
-    ends = segscan.seg_ends(boundary, live_s)
-    slot_idx = segscan.compact_to_slots(ends, cap_out)
-
-    # Group key columns: gather the end row's keys (same segment, same key).
+    # scatter the boundary row's key into its segment slot and reduce with
+    # jax.ops.segment_*
+    seg = jnp.maximum(jnp.cumsum(boundary.astype(jnp.int32)) - 1, 0)
+    dest = jnp.where(boundary, seg, cap_out)
     for kd, kv in keys_s:
-        g = kd[slot_idx]
-        m = out_mask if g.ndim == 1 else out_mask[:, None]  # BYTES: [cap, W]
-        data = jnp.where(m, g, jnp.zeros_like(g))
-        out_cols.append(Column(data=data, valid=kv[slot_idx] & out_mask))
-
-    # One fused multi-scan covers every aggregate's per-segment reduction.
-    entries: list = []
-    finishers: list = []
+        data = jnp.zeros(
+            (cap_out,) + kd.shape[1:], kd.dtype
+        ).at[dest].set(kd, mode="drop")
+        valid = jnp.zeros((cap_out,), jnp.bool_).at[dest].set(
+            kv, mode="drop"
+        )
+        out_cols.append(Column(data=data, valid=valid))
     for spec in aggs:
         col = None
         t = None
@@ -349,19 +336,101 @@ def sort_groupby(
                 data=batch.cols[spec.col].data[perm],
                 valid=batch.cols[spec.col].valid[perm],
             )
-        es, finish = _scan_agg_entries(spec, col, live_s, t)
+        data, valid = _segment_agg(spec, col, live_s, seg, cap_out, t)
+        out_cols.append(Column(data=data, valid=valid & out_mask))
+    return Batch(cols=tuple(out_cols), mask=out_mask), num_groups
+
+
+def _sorted_by_keys(batch: Batch, schema: Schema,
+                    group_cols: tuple[int, ...], aggs: tuple[AggSpec, ...],
+                    col_stats: dict) -> Batch:
+    """``batch`` in the order of its packed group keys, dead rows last: a
+    presorted tile. Only the mask and the columns the grouping reads follow
+    the sort's permutation (the others stay as they were), and the mask
+    and every valid bitmap follow in shared words (`segscan.pack_bits`):
+    one 32-bit gather where each was a `pred` one."""
+    operands = _packed_group_keys(batch, schema, group_cols, col_stats)
+    perm = jax.lax.sort(
+        operands + [jnp.arange(batch.capacity, dtype=jnp.int32)],
+        num_keys=len(operands) + 1)[-1]
+    used = sorted(set(group_cols) | {
+        spec.col for spec in aggs if spec.col is not None})
+    words = [w[perm] for w in segscan.pack_bits(
+        [batch.mask] + [batch.cols[i].valid for i in used])]
+    mask, *valids = segscan.unpack_bits(words, 1 + len(used))
+    moved = dict(zip(used, valids))
+    return Batch(cols=tuple(
+        Column(data=c.data[perm], valid=moved[i]) if i in moved else c
+        for i, c in enumerate(batch.cols)), mask=mask)
+
+
+def _ordered_groupby(batch: Batch, schema: Schema,
+                     group_cols: tuple[int, ...], aggs: tuple[AggSpec, ...],
+                     cap_out: int, col_stats: dict):
+    """`sort_groupby` on an accelerator, over a tile whose equal keys are
+    adjacent (stored so, or just sorted so): it is grouped where its rows
+    lie. No row moves before the reduction (nothing pushes dead rows last)
+    and the groups leave as a live prefix, in arrival order, by
+    `segscan.rows_to_front`: no sort, gather or scatter of a tile's size.
+    The parent's kernel gathered each key word, state word and valid bitmap
+    through a stable sort's permutation, 120-208 ms a 1,048,576-row tile of
+    q18 and q21 (9-28 ms a gather in its trace); this one reads 1.05 ms
+    (PR 47, q18 traced).
+
+    What it relies on, under a Filter as without one: a dead row between
+    two live rows of one group carries that group's key. `plan/builder.py`
+    `_clustered_input` proves it for every chain it calls ordered (Scan ->
+    Filter -> Project(ColRef): a Filter clears mask bits and nothing
+    rewrites a stored key; `AggregateOp` refuses any other chain), and the
+    key sort puts dead rows last. A dead row elsewhere may hold anything (a
+    tile's padded tail): it adds each lane's identity, a run of keys with
+    no live row ends nowhere, and one that continues a live group only
+    moves that group's end.
+
+    Boundaries compare EVERY row's key words with its neighbour's; the lanes
+    (`_scan_agg_entries`, dead rows at their identities) and one `or` lane
+    of liveness reduce in one `segscan.seg_scan_multi`; a segment's last row
+    holds its totals and is wanted when the segment has a live row; the
+    finishers run elementwise over the tile, so a count read only as
+    `c > 0` leaves as one bit of the shared word of valid bits."""
+    cap = batch.capacity
+    live = batch.mask
+    idx = jnp.arange(cap, dtype=jnp.int32)
+    boundary = idx == 0
+    for w in _packed_group_keys(batch, schema, group_cols, col_stats,
+                                dead_bit=False):
+        boundary = boundary | (w != jnp.roll(w, 1, axis=0))
+
+    entries: list = [(jnp.logical_or, live)]
+    finishers: list = []
+    for spec in aggs:
+        col = batch.cols[spec.col] if spec.col is not None else None
+        t = schema.types[spec.col] if spec.col is not None else None
+        es, finish = _scan_agg_entries(spec, col, live, t)
         finishers.append((len(entries), len(es), finish))
         entries.extend(es)
-    if entries:
-        scanned = segscan.seg_scan_multi(
-            [op for op, _ in entries], [v for _, v in entries], boundary
-        )
-        at_slots = [s[slot_idx] for s in scanned]
-    for start, n, finish in finishers:
-        data, valid = finish(*at_slots[start:start + n])
-        data = jnp.where(out_mask, data, jnp.zeros_like(data[:1]))
-        out_cols.append(Column(data=data, valid=valid & out_mask))
+    scanned = segscan.seg_scan_multi(
+        [op for op, _ in entries], [v for _, v in entries], boundary)
 
+    last = jnp.concatenate([boundary[1:], jnp.ones((1,), jnp.bool_)])
+    ends = last & scanned[0]
+    num_groups = jnp.sum(ends, dtype=jnp.int32)
+
+    arrays: list = []
+    for gi in group_cols:
+        arrays += [batch.cols[gi].data, batch.cols[gi].valid]
+    for start, n, finish in finishers:
+        arrays += list(finish(*scanned[start:start + n]))
+    front = segscan.rows_to_front(ends, arrays)
+
+    out_mask = jnp.arange(cap_out, dtype=jnp.int32) < num_groups
+    out_cols: list[Column] = []
+    for data, valid in zip(front[::2], front[1::2]):
+        data, valid = (pad_rows(data[:cap_out], cap_out),
+                       pad_rows(valid[:cap_out], cap_out))
+        m = out_mask.reshape((cap_out,) + (1,) * (data.ndim - 1))
+        out_cols.append(Column(data=jnp.where(m, data, jnp.zeros_like(data)),
+                               valid=valid & out_mask))
     return Batch(cols=tuple(out_cols), mask=out_mask), num_groups
 
 

@@ -144,17 +144,31 @@ def test_packed_key_sort_compiles(one_chip):
     assert compiled.as_text()
 
 
+@pytest.mark.parametrize("filtered", [False, True],
+                         ids=["prefix_live", "under_a_filter"])
 def test_streaming_ordered_aggregate_tile_kernel_compiles(one_chip,
-                                                         monkeypatch):
+                                                         monkeypatch,
+                                                         filtered):
     """AggregateOp's per-tile kernel in streaming mode (presorted partial,
     the carried group met with it, finalize) and its tail, on the branch an
-    accelerator traces (segmented scans; `use_scans` asks the default
-    backend, which is the CPU here, so the test steers it). Kept small: the
-    kernel took 115.8 s to compile at 1 << 20 rows in this sandbox against
-    the partial's 110.5 s (a scratch script, PR 33), 2.3 s at 4,096."""
+    accelerator traces (`use_scans` asks the default backend, which is the
+    CPU here, so the test steers it), for a `sum` + `avg` plan with and
+    without a Filter below. Kept small: the parent's kernel took 194 s to
+    compile at 1 << 20 rows in this sandbox (a scratch script, PR 47; 115.8 s
+    in PR 33) and 76-78 s on the chip's host, this one 7 s there; 2-4 s at
+    4,096 here.
+
+    What keeps the found cost out (PR 47; the parent paid 120-208 ms a
+    1,048,576-row tile, all but 8 of them in gathers of 9-28 ms each): the
+    kernel moves nothing of a tile's size by index. No gather (the parent
+    had one a key word, a state word and a valid bitmap), no sort (neither
+    the parent's stable one, nor a second under a Filter: no dead-row
+    compaction), no scatter, no loop; the groups reach the front by
+    `segscan.rows_to_front`'s conditional shifts."""
     from cockroach_tpu.catalog import Catalog, Table
     from cockroach_tpu.coldata import DECIMAL, INT64, Schema
     from cockroach_tpu.coldata.batch import empty_batch
+    from cockroach_tpu.ops import expr as ex
     from cockroach_tpu.ops import segscan
     from cockroach_tpu.plan import builder
     from cockroach_tpu.sql.rel import Rel
@@ -166,10 +180,12 @@ def test_streaming_ordered_aggregate_tile_kernel_compiles(one_chip,
         "li", Schema.of(k=INT64, q=DECIMAL(12, 2)),
         {"k": np.arange(n, dtype=np.int64) // 4 * 7_000_003,
          "q": np.arange(n, dtype=np.int64)}, ordering=("k",)))
+    rel = Rel.scan(cat, "li")
+    if filtered:
+        rel = rel.filter(ex.Cmp("gt", rel.c("q"), ex.lit(5)))
     op = builder.build(
-        Rel.scan(cat, "li").groupby(["k"], [("s", "sum", "q"),
-                                            ("a", "avg", "q")]).plan, cat)
-    assert op.streaming
+        rel.groupby(["k"], [("s", "sum", "q"), ("a", "avg", "q")]).plan, cat)
+    assert op.streaming and op.prefix_live != filtered
     op.init()
 
     def described(batch, rows):
@@ -182,12 +198,42 @@ def test_streaming_ordered_aggregate_tile_kernel_compiles(one_chip,
     out, carried = jax.eval_shape(op._stream_fn._jitted, tile, carry)
     assert out.capacity == 4096 and carried.capacity == 1
     text = op._stream_fn._jitted.lower(tile, carry).compile().as_text()
-    # the stitch is elementwise, as the scans are: no scatter instruction
-    # (the opcode, not the word: the module's table of traced frames names
-    # whatever function first traced a cached scan, `dense_scatter_states`
+    # opcodes, not words: the module's table of traced frames names
+    # whatever function first traced a cached scan (`dense_scatter_states`
     # when a test of the dense aggregate ran before in this process)
-    assert not re.search(r"\bscatter\(", text)
+    for opcode in ("scatter", "gather", "sort", "while"):
+        assert not re.search(rf"\b{opcode}\(", text), opcode
     assert op._stream_tail_fn._jitted.lower(carry).compile().as_text()
+
+
+def test_unsorted_partial_gathers_no_bitmap(one_chip, monkeypatch):
+    """`sort_groupby` over a tile in no key order, on the accelerator's
+    branch: the key sort is its ONE sort (the parent ran a second, stable
+    one to take its slots), every gather reads through that sort's
+    permutation, and none of them is a `pred` one: the mask and the valid
+    bitmaps follow in one shared uint32 word (`_sorted_by_keys`). No scatter."""
+    from cockroach_tpu.coldata import INT64, Schema
+    from cockroach_tpu.coldata.batch import empty_batch
+    from cockroach_tpu.ops import aggregation as agg_ops
+    from cockroach_tpu.ops import segscan
+
+    monkeypatch.setattr(segscan, "use_scans", lambda: True)
+    schema = Schema.of(a=INT64, b=INT64, x=INT64, unread=INT64)
+    specs = (agg_ops.AggSpec("sum", 2, "s"), agg_ops.AggSpec("min", 2, "m"))
+    tile = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct((4096,) + x.shape[1:], x.dtype,
+                                       sharding=one_chip),
+        empty_batch(schema, 8))
+    # crlint: allow-raw-jit(AOT compile for a described chip: nothing is dispatched)
+    text = jax.jit(lambda b: agg_ops.sort_groupby(
+        b, schema, (0, 1), specs, out_capacity=4096)).lower(
+            tile).compile().as_text()
+    assert len(re.findall(r"\bsort\(", text)) == 1
+    assert not re.search(r"\bscatter\(", text)
+    gathers = [ln for ln in text.splitlines() if re.search(r"\bgather\(", ln)]
+    # a, b, x: two 32-bit words each; the flags' word; `unread` stays put
+    assert len(gathers) == 7, gathers
+    assert not any("pred[" in ln.split(" gather(")[0] for ln in gathers)
 
 
 def test_general_join_emit_at_q13_shapes_has_no_loop(one_chip):
