@@ -21,11 +21,13 @@ run keeps the 1,000 acknowledged SQL writes and cuts YCSB-E's keyspace
 through the Pallas scan filter on the chip, but nothing that small is
 merged, so the merge gate is reached only by `--full`, at 1M keys: 1,351 s
 on an empty cache when PR 22 ran it, 1,278 s of it compile). The
-four-chip shuffle runs q3 at SF0.05 for the same reason, four times over (a
-four-chip call is charged fourfold): its one SPMD program holds 18 sorts
-and did not finish compiling for a described v5e 2x2 at SF1 in 263
-CPU-minutes, against 243 s on 8 cores at SF0.05. `--full --chips 4` asks
-for SF1.
+four-chip shuffle sends the served q3 text through a Session of a node
+spanning the four chips, at SF0.05 by default because a four-chip call is
+charged fourfold; `--full --chips 4` asks for SF1, which the benchmark's
+cell `tpch_sf1_x4.q3_shuffle` runs in every check (since PR 48 the SF1
+program compiles in minutes: 288 s + 72 s for a described v5e 2x2 on 8
+cores, where the 13-sort, 140-scatter program before it was unfinished
+after 263 CPU-minutes).
 
 There is no CPU mode. Without a TPU the script prints why and exits
 non-zero before any phase runs; tests/test_chip_smoke.py rehearses the phase
@@ -214,74 +216,86 @@ def _pandas_q3(cat):
 
 def phase_shuffle(sf: float = 1.0, seed: int = 19920101, n_devices: int = 4
                   ) -> dict:
-    """q3 through Rel.run_distributed on a mesh over `n_devices` devices,
-    against the same plan on one device and the pandas oracle."""
+    """The served q3 text through a Session of a node that spans
+    `n_devices` devices (Session -> plan cache -> sql/distsql.py -> one SPMD
+    program), against the same Session on one device and the pandas
+    oracle."""
     import jax
 
-    # the hand-built plan: no Session reaches the mesh yet (ROADMAP D5)
-    from cockroach_tpu.bench import queries as Q
     from cockroach_tpu.bench import tpch
-    from cockroach_tpu.parallel import mesh as mesh_mod
-    from cockroach_tpu.parallel.planner import DistributedQuery
+    from cockroach_tpu.bench.tpch_sql import TPCH_SQL
+    from cockroach_tpu.catalog import Catalog
+    from cockroach_tpu.flow import dispatch
+    from cockroach_tpu.server.node import Node
+    from cockroach_tpu.sql import Session, explain
+    from cockroach_tpu.utils import metric, tracing
 
-    devices = jax.devices()[:n_devices]
-    check(len(devices) == n_devices, (
+    check(len(jax.devices()) >= n_devices, (
         f"need {n_devices} devices, jax reports {len(jax.devices())}"))
-    mesh = mesh_mod.make_mesh(n_devices)
     t0 = time.time()
     cat = tpch.gen_tpch(sf=sf, seed=seed)
-    rel = Q.QUERIES["q3"](cat)
+    text = " ".join(TPCH_SQL["q3"].split())
     nrows = cat.get("lineitem").num_rows
     emit(phase="shuffle", step="load", sf=sf, lineitem_rows=nrows,
          seconds=round(time.time() - t0, 2))
 
-    # the program run_distributed builds, held here for inspection
-    dq = DistributedQuery(rel.plan, cat, mesh)
-    check(not dq._local_fallback, "q3 fell back to local execution")
-    shard_rows = {}
-    for (tname, _names, _cap), batch in dq._scan_cache.items():
-        shard_rows[tname] = {
-            sh.device.id: int(np.asarray(sh.data).sum())
-            for sh in batch.mask.addressable_shards}
-    li = shard_rows["lineitem"]
-    check(len(li) == n_devices, f"lineitem shards sit on {sorted(li)}")
-    check(sum(li.values()) == nrows, (li, nrows))
-    # row-sharded in order, the per-device capacity padded to 1024 rows:
-    # every device holds 1/n of the rows, the last one short by the padding
-    check(max(li.values()) - min(li.values()) < n_devices * 1024, li)
-    t0 = time.time()
-    compiled = dq._fn._jitted.lower(*dq._scan_batches).compile()
-    hlo = compiled.as_text()
-    check("all-to-all" in hlo, "no all-to-all in the distributed program")
-    emit(phase="shuffle", step="program", local_fallback=False,
-         lineitem_rows_per_device=li, all_to_all=hlo.count("all-to-all"),
-         compile_seconds=round(time.time() - t0, 2),
-         memory=str(compiled.memory_analysis()))
-
-    t0 = time.time()
-    got = rel.run_distributed(mesh)
-    dist_s = time.time() - t0
-    np.testing.assert_allclose(
-        np.asarray(got["revenue"], dtype=np.float64),
-        _pandas_q3(cat).revenue.to_numpy(), rtol=1e-9)
-    emit(phase="shuffle", step="distributed", rows=len(got["revenue"]),
-         equals_pandas=True, seconds=round(dist_s, 2))
-    # the same plan on one device, last: on an empty cache it compiles
-    # for longer than everything above
-    t0 = time.time()
-    want = rel.run()
-    for k in want:
-        a, w = np.asarray(got[k]), np.asarray(want[k])
-        check(len(a) == len(w), (k, len(a), len(w)))
-        if w.dtype.kind == "f":
-            np.testing.assert_allclose(a, w, rtol=1e-9, err_msg=k)
-        else:
-            check((a == w).all(), k)
-    emit(phase="shuffle", step="single_device",
-         distributed_equals_single=True,
-         seconds=round(time.time() - t0, 2))
-    return {"lineitem_rows_per_device": li,
-            "all_to_all": hlo.count("all-to-all")}
+    node = Node(devices=n_devices).start(pg_port=0)
+    try:
+        served, one = node._sql_catalog, Catalog()
+        for name, table in cat.tables.items():
+            served.tables[name] = one.tables[name] = table
+        plan = explain(served, "EXPLAIN (DISTSQL) " + text)
+        check("exchange (all-to-all)" in plan, plan)
+        sess = Session(served)
+        runs0 = metric.PLAN_CACHE_MESH_RUNS.value
+        # the first run compiles the program at its first-guess caps and
+        # learns them; the second compiles the fitted program and is what a
+        # statement runs from then on
+        got, seconds, compiles = None, [], []
+        for _ in range(3):
+            t0, c0 = time.time(), dispatch.compiles()
+            got = sess.execute(text)
+            seconds.append(round(time.time() - t0, 2))
+            compiles.append(dispatch.compiles() - c0)
+        check(metric.PLAN_CACHE_MESH_RUNS.value - runs0 >= 3,
+              "q3 fell back to local execution")
+        check(compiles[-1] == 0, compiles)
+        li = cat.get("lineitem").mesh_shard_rows()
+        check(li is not None and len(li) == n_devices,
+              f"lineitem shards sit on {li}")
+        check(sum(li.values()) == nrows, (li, nrows))
+        # row-sharded in order, the per-device capacity padded to 1024
+        # rows: every device holds 1/n of the rows, the last one short
+        check(max(li.values()) - min(li.values()) < n_devices * 1024, li)
+        pull = tracing.totals().get("flow/pull", {"tags": {}})["tags"]
+        stages = int(pull.get("exchange_stages", 0)) // 3
+        check(stages >= 1, pull)
+        emit(phase="shuffle", step="program", local_fallback=False,
+             lineitem_rows_per_device=li, all_to_all=stages,
+             run_seconds=seconds, compiles=compiles,
+             compile_seconds=tracing.compile_seconds())
+        np.testing.assert_allclose(
+            np.asarray(got["revenue"], dtype=np.float64),
+            _pandas_q3(cat).revenue.to_numpy(), rtol=1e-9)
+        emit(phase="shuffle", step="distributed", rows=len(got["revenue"]),
+             equals_pandas=True, seconds=seconds[-1])
+        # the same text on one device, last: on an empty cache it compiles
+        # for longer than everything above
+        t0 = time.time()
+        want = Session(one).execute(text)
+        for k in want:
+            a, w = np.asarray(got[k]), np.asarray(want[k])
+            check(len(a) == len(w), (k, len(a), len(w)))
+            if w.dtype.kind == "f":
+                np.testing.assert_allclose(a, w, rtol=1e-9, err_msg=k)
+            else:
+                check((a == w).all(), k)
+        emit(phase="shuffle", step="single_device",
+             distributed_equals_single=True,
+             seconds=round(time.time() - t0, 2))
+    finally:
+        node.stop()
+    return {"lineitem_rows_per_device": li, "all_to_all": stages}
 
 
 # ---------------------------------------------------------------------------

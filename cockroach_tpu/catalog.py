@@ -48,6 +48,19 @@ def _stable_bounds(lo: int, hi: int) -> tuple[int, int]:
             (1 << max(hi, 0).bit_length()) - 1)
 
 
+def mesh_shard_shape(n: int, D: int) -> tuple[int, int]:
+    """(rows, capacity) of one device's share of an ``n``-row table spread
+    over ``D`` devices: ceil(n / D) rows rounded up to 1,024, so that every
+    device holds a quarter within D x 1,024 rows (the last one is short by
+    the rounding), in a tile whose capacity is rounded up again, to 65,536
+    rows above that size: TPC-H's `lineitem` moves with the seed by
+    thousands of rows (1 to 7 lines an order), and a capacity that followed
+    it would key the mesh program's compile cache by the data."""
+    rows = max(TILE_ALIGN, -(-n // (D * TILE_ALIGN)) * TILE_ALIGN)
+    g = TILE_ALIGN if rows < (1 << 16) else (1 << 16)
+    return rows, -(-rows // g) * g
+
+
 def _bucket_cap(n: int) -> int:
     for b in SHAPE_BUCKETS:
         if n <= b:
@@ -86,6 +99,9 @@ class Table:
     dictionaries: dict[str, Dictionary] = field(default_factory=dict)
     _device: dict | None = None
     _stats: dict | None = None
+    # the table's placement on a multi-device node: (mesh, per-column
+    # row-sharded arrays), filled by mesh_batch as statements read columns
+    _mesh_device: tuple | None = None
     # physical clustering: host rows are stored grouped (equal values
     # adjacent) by this column prefix — e.g. TPC-H lineitem by l_orderkey,
     # KV tables by primary key. Enables the sort-free ordered aggregation
@@ -283,6 +299,66 @@ class Table:
             cols.append(dev[cname])
         return Batch(cols=tuple(cols), mask=dev["__mask__"])
 
+    def mesh_batch(self, mesh, names: tuple[str, ...] | None = None
+                   ) -> Batch:
+        """The table as a multi-device node holds it: row-sharded over the
+        mesh axis in contiguous primary-key (storage) order, device i the
+        rows [i x share, (i + 1) x share) as a live prefix of its tile
+        (`mesh_shard_shape`). A column goes from the host to its D devices
+        ONCE, when a statement first reads it (device_batch's rule, PR 38),
+        and stays with the table, not with a plan. Host sources are
+        snapshotted as device_batch snapshots them."""
+        import jax
+
+        from .parallel.mesh import AXIS, row_sharding
+
+        names = names or self.schema.names
+        held = self._mesh_device
+        if held is None or held[0] != mesh:
+            held = self._mesh_device = (mesh, {})
+        dev = held[1]
+        host = dev.setdefault("__host__", self.columns)
+        valids = dev.setdefault("__valids__", self.valids)
+        n = len(next(iter(host.values()))) if host else 0
+        D = mesh.shape[AXIS]
+        share, local_cap = mesh_shard_shape(n, D)
+        sharding = row_sharding(mesh)
+
+        def put(a, dtype, width=()):
+            buf = np.zeros((D, local_cap) + width, dtype=dtype)
+            for i in range(D):
+                part = a[i * share:(i + 1) * share] if np.ndim(a) else a
+                buf[i, :max(0, min(share, n - i * share))] = part
+            return jax.device_put(
+                buf.reshape((D * local_cap,) + width), sharding)
+
+        if "__mask__" not in dev:
+            dev["__mask__"] = put(True, np.bool_)
+        cols = []
+        for cname in names:
+            if cname not in dev:
+                t = self.schema.type_of(cname)
+                a = np.asarray(host[cname])
+                data = (put(a, np.uint8, (t.width,))
+                        if t.family is Family.BYTES
+                        else put(a.astype(t.dtype), t.dtype))
+                from .coldata.batch import Column
+
+                dev[cname] = Column(
+                    data=data,
+                    valid=(put(np.asarray(valids[cname]), np.bool_)
+                           if cname in valids else dev["__mask__"]))
+            cols.append(dev[cname])
+        return Batch(cols=tuple(cols), mask=dev["__mask__"])
+
+    def mesh_shard_rows(self) -> dict[int, int] | None:
+        """{device id: live rows resident there} of the placement above,
+        None where no statement has placed the table on a mesh yet."""
+        if self._mesh_device is None or "__mask__" not in self._mesh_device[1]:
+            return None
+        return {sh.device.id: int(np.asarray(sh.data).sum())
+                for sh in self._mesh_device[1]["__mask__"].addressable_shards}
+
     @staticmethod
     def from_strings(
         name: str,
@@ -324,6 +400,9 @@ class Catalog:
     def __init__(self):
         self.tables: dict[str, Table] = {}
         self.version = 0
+        # the devices this catalog's node spans (server/node.py
+        # Node(devices=n)); None: one device, every plan runs there
+        self.mesh = None
 
     def bump_version(self) -> int:
         self.version += 1

@@ -190,6 +190,10 @@ def main(argv=None) -> int:
                     help="pgwire listen port for --start (0 = ephemeral)")
     ap.add_argument("--http-port", type=int, default=8080,
                     help="HTTP admin port for --start (0 = ephemeral)")
+    ap.add_argument("--devices", type=int, default=None, metavar="N",
+                    help="for --start: span the node over the first N "
+                         "devices (tables row-sharded over them, statements "
+                         "run across them under distsql=auto); default: one")
     args = ap.parse_args(argv)
 
     if args.cpu:
@@ -202,7 +206,8 @@ def main(argv=None) -> int:
 
         from .server.node import Node
 
-        node = Node().start(pg_port=args.pg_port, http_port=args.http_port)
+        node = Node(devices=args.devices).start(
+            pg_port=args.pg_port, http_port=args.http_port)
         print(f"node {node.node_id} serving: "
               f"pgwire 127.0.0.1:{node.pg.addr[1]} "
               f"http 127.0.0.1:{node.admin.port}", flush=True)

@@ -233,10 +233,12 @@ def run_operator(root) -> dict[str, np.ndarray]:
     }
 
 
-def run_plan_with_stats(plan: PlanNode, catalog: Catalog):
+def run_plan_with_stats(plan: PlanNode, catalog: Catalog, root=None):
     """Run with ComponentStats collection; returns (results, root operator).
-    The stats land on the active tracing span."""
-    root = plan_builder.build(plan, catalog)
+    The stats land on the active tracing span. ``root``: the tree to run
+    where the caller placed the plan itself (sql/distsql.py)."""
+    if root is None:
+        root = plan_builder.build(plan, catalog)
     root.collect_stats(True)
     with tracing.span("query") as sp:
         res = run_operator(root)

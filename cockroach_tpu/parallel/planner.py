@@ -11,17 +11,23 @@ aggregation states -> psum/pmin/pmax). XLA schedules the collectives and
 overlaps them with local compute; there is no flow registry and no
 serialization.
 
-Capacity contract: every stage has a static output capacity derived from its
-inputs (scaled by a host-controlled `factor`). Stages that can overflow —
-Exchange send buckets and general (duplicate-key) join outputs — report
-overflow counts; `DistributedQuery.run()` retries with a doubled factor
-until clean (the host-side retry loop the shuffle contract promises,
-parallel/shuffle.py:12-16).
+Capacity contract: every stage has a static output capacity. A stage that
+can overflow (an exchange's send buckets, a unique join's compact emission,
+a general join's output) writes what it counted into ONE int32 vector the
+program returns beside its result, read back once a statement. The host
+learns each stage's cap from those counts as a join learns its emission cap
+(flow/operators.py): the first run sizes an exchange at 1.25 x the fair
+share of its input and emits joins probe-aligned; its counts then fit every
+cap to what the data holds (`_fit_cap`: an eighth of room, three
+significant bits), and from there caps only grow. A count past its cap
+re-sizes and re-runs the statement (`MeshOp.post_run_update`, counted as
+`mesh_overflow_reruns`); nothing is ever truncated silently.
 """
 
 from __future__ import annotations
 
-from dataclasses import dataclass
+import time
+from dataclasses import dataclass, field
 from typing import Callable
 
 import jax
@@ -31,17 +37,19 @@ from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from ..catalog import Catalog
-from ..coldata.batch import Batch, Column, Dictionary, from_host, to_host
+from ..coldata.batch import Batch, Column, Dictionary, to_host
 from ..coldata.types import FLOAT64, Family, Schema
 from ..flow import dispatch
+from ..flow.operator import SourceOperator
 from ..ops import aggregation as agg_ops
 from ..ops import expr as ex
 from ..ops import join as join_ops
 from ..ops import sort as sort_ops
 from ..plan import spec as S
 from ..plan.distribute import distribute
+from ..utils import metric, settings, tracing
 from .mesh import AXIS
-from .shuffle import _local_shuffle
+from .shuffle import exchange, route
 
 
 def _pow2(n: int) -> int:
@@ -49,6 +57,37 @@ def _pow2(n: int) -> int:
     while p < n:
         p *= 2
     return p
+
+
+def _fit_cap(n: int) -> int:
+    """A learned cap for a stage that counted ``n`` rows at its fullest:
+    an eighth of room (a run's other DATE moves a count by a percent or
+    two), rounded up to three significant bits so that seeds and
+    parameters land on few program shapes, 128 rows at the least."""
+    n = max(128, n + n // 8 + 1)
+    g = max(128, (1 << (n - 1).bit_length()) // 8)
+    return -(-n // g) * g
+
+
+def _row_bytes(schema: Schema) -> int:
+    """Bytes of one row on the wire: every column's data and its valid
+    byte (benchmarks/mesh_bytes.py reads the same widths)."""
+    return sum((t.width if t.family is Family.BYTES
+                else int(np.dtype(t.dtype).itemsize)) + 1
+               for t in schema.types)
+
+
+@dataclass
+class _Stage:
+    """One stage of the program that counts rows against a cap: what the
+    host reads back for it and learns from."""
+
+    kind: str  # exchange | emit | general
+    cap: int | None  # None: an emission not yet learned (probe-aligned)
+    width: int = 0  # exchange: counts a device; others: 1
+    row_bytes: int = 0
+    keys: tuple = ()
+    node: int = 0  # id() of the plan's Exchange node (EXPLAIN ANALYZE)
 
 
 @dataclass
@@ -61,18 +100,25 @@ class _LNode:
     dicts: dict[int, Dictionary]
     replicated: bool
     cap: int  # per-device output capacity (static)
+    stats: dict = field(default_factory=dict)  # column -> (lo, hi)
 
 
 class _Lowering:
-    def __init__(self, catalog: Catalog, D: int, factor: int):
+    def __init__(self, catalog: Catalog, D: int, caps: dict[int, int]):
         self.catalog = catalog
         self.D = D
-        self.factor = factor
+        self.caps = caps  # stage index -> learned cap
+        self.stages: list[_Stage] = []  # in lowering order
         self.scan_specs: list[tuple[str, tuple[str, ...], int]] = []
-        self.overflows: list[jax.Array] = []  # collected during tracing
+        self.counted: list = []  # (stage index, int32 [width]) this trace
         self.emit_cache: dict = {}  # per-trace shared-subtree results
 
     # -- helpers ------------------------------------------------------------
+
+    def _stage(self, kind: str, default: int | None, **kw) -> int:
+        sid = len(self.stages)
+        self.stages.append(_Stage(kind, self.caps.get(sid, default), **kw))
+        return sid
 
     def _all_gather(self, ln: _LNode) -> _LNode:
         """Replicate a sharded batch on every device (Gather/Broadcast)."""
@@ -86,9 +132,11 @@ class _Lowering:
                 lambda x: jax.lax.all_gather(x, AXIS, axis=0, tiled=True), b
             )
 
-        return _LNode(emit, ln.schema, ln.dicts, True, ln.cap * self.D)
+        return _LNode(emit, ln.schema, ln.dicts, True, ln.cap * self.D,
+                      ln.stats)
 
-    def _exchange(self, ln: _LNode, keys: tuple[int, ...]) -> _LNode:
+    def _exchange(self, ln: _LNode, keys: tuple[int, ...],
+                  node: int = 0) -> _LNode:
         types = [ln.schema.types[i] for i in keys]
         hash_tables = {
             pos: ln.dicts[i].hashes
@@ -96,22 +144,25 @@ class _Lowering:
         } or None
         # key positions are passed positionally to hash_columns via the
         # extracted column list, so hash tables index by position
-        out_cap = _pow2(ln.cap * 2 * self.factor)
-        send_cap = max(
-            128, (ln.cap * 2 * self.factor // self.D) // 128 * 128
-        )
         D = self.D
+        # before anything is learned: 1.25 x the fair share of the input
+        # tile, which a uniform hash cannot pass whatever the filters keep
+        fair = -(-ln.cap * 5 // (4 * D * 128)) * 128
+        sid = self._stage("exchange", min(ln.cap, max(128, fair)), width=D,
+                          row_bytes=_row_bytes(ln.schema), keys=tuple(keys),
+                          node=node)
+        send_cap = self.stages[sid].cap
         inner = ln.emit
 
         def emit(env):
             b = inner(env)
-            out, ovf = _local_shuffle(
-                b, keys, types, hash_tables, D, send_cap, out_cap
-            )
-            self.overflows.append(ovf[0])
+            _h, bucket = route(b, keys, types, hash_tables, D)
+            out, counts = exchange(b, bucket, D, send_cap)
+            self.counted.append((sid, counts))
             return out
 
-        return _LNode(emit, ln.schema, ln.dicts, False, out_cap)
+        return _LNode(emit, ln.schema, ln.dicts, False, D * send_cap,
+                      ln.stats)
 
     # -- node dispatch ------------------------------------------------------
 
@@ -142,7 +193,8 @@ class _Lowering:
                 lowering.emit_cache[_key] = r
             return r
 
-        ln = _LNode(cached_emit, ln.schema, ln.dicts, ln.replicated, ln.cap)
+        ln = _LNode(cached_emit, ln.schema, ln.dicts, ln.replicated, ln.cap,
+                    ln.stats)
         memo[id(plan)] = ln
         return ln
 
@@ -159,12 +211,16 @@ class _Lowering:
         # live rows — sizing from num_rows would drop the tail at compact
         snap_fn = getattr(table, "snapshot_live_rows", None)
         rows = snap_fn() if callable(snap_fn) else table.num_rows
-        local_cap = max(
-            1024, -(-rows // (self.D * 1024)) * 1024
-        )
+        from ..catalog import mesh_shard_shape
+
+        _share, local_cap = mesh_shard_shape(rows, self.D)
         slot = len(self.scan_specs)
         self.scan_specs.append((plan.table, tuple(names), local_cap))
-        return _LNode(lambda env: env[slot], schema, dicts, False, local_cap)
+        stats_fn = getattr(table, "col_stats", None)
+        by_name = stats_fn() if callable(stats_fn) else {}
+        stats = {i: by_name[n] for i, n in enumerate(names) if n in by_name}
+        return _LNode(lambda env: env[slot], schema, dicts, False, local_cap,
+                      stats)
 
     def _lower_filter(self, plan: S.Filter) -> _LNode:
         ln = self.lower(plan.input)
@@ -174,7 +230,8 @@ class _Lowering:
             b = inner(env)
             return b.with_mask(ex.filter_mask(b, schema, pred))
 
-        return _LNode(emit, schema, ln.dicts, ln.replicated, ln.cap)
+        return _LNode(emit, schema, ln.dicts, ln.replicated, ln.cap,
+                      ln.stats)
 
     def _lower_project(self, plan: S.Project) -> _LNode:
         ln = self.lower(plan.input)
@@ -198,10 +255,15 @@ class _Lowering:
                 cols.append(Column(data=d, valid=v))
             return Batch(cols=tuple(cols), mask=b.mask)
 
-        return _LNode(emit, out_schema, dicts, ln.replicated, ln.cap)
+        stats = {}
+        for i, e in enumerate(plan.exprs):
+            b = ex.expr_bounds(e, schema, ln.stats)
+            if b is not None:
+                stats[i] = b
+        return _LNode(emit, out_schema, dicts, ln.replicated, ln.cap, stats)
 
     def _lower_exchange(self, plan: S.Exchange) -> _LNode:
-        return self._exchange(self.lower(plan.input), plan.keys)
+        return self._exchange(self.lower(plan.input), plan.keys, id(plan))
 
     def _lower_broadcast(self, plan: S.Broadcast) -> _LNode:
         return self._all_gather(self.lower(plan.input))
@@ -233,10 +295,13 @@ class _Lowering:
                 plan.input, plan.group_cols, self.catalog
             )
 
+            in_stats = ln.stats
+
             def emit(env):
                 b = inner(env)
                 part, _ = agg_ops.sort_groupby(
                     b, base, gcols, pspecs, out_capacity=cap,
+                    col_stats=in_stats,
                     presorted=ordered, compact=not prefix_live,
                 )  # num_groups <= live rows <= cap: no overflow possible
                 return part
@@ -245,7 +310,8 @@ class _Lowering:
                 plan.group_cols.index(gi): d
                 for gi, d in ln.dicts.items() if gi in plan.group_cols
             }
-            return _LNode(emit, state_schema, dicts, ln.replicated, cap)
+            return _LNode(emit, state_schema, dicts, ln.replicated, cap,
+                          _group_stats(ln.stats, plan.group_cols))
 
         if plan.mode == "final":
             base = plan.base_schema
@@ -258,17 +324,19 @@ class _Lowering:
                 base, plan.group_cols, plan.aggs, state_schema, "final"
             )
             cap, inner = ln.cap, ln.emit
+            key_stats = {i: b for i, b in ln.stats.items() if i < k}
 
             def emit(env):
                 b = inner(env)
                 merged, _ = agg_ops.sort_groupby(
                     b, state_schema, tuple(range(k)), merge_specs,
-                    out_capacity=cap,
+                    out_capacity=cap, col_stats=key_stats,
                 )
                 return agg_ops.finalize_states(merged, final_map, k)
 
             dicts = {i: d for i, d in ln.dicts.items() if i < k}
-            return _LNode(emit, out_schema, dicts, ln.replicated, cap)
+            return _LNode(emit, out_schema, dicts, ln.replicated, cap,
+                          key_stats)
 
         # complete (replicated input): partial + finalize in one pass
         base = ln.schema
@@ -280,11 +348,12 @@ class _Lowering:
             base, plan.group_cols, plan.aggs, state_schema, "complete"
         )
         gcols, cap, inner = plan.group_cols, ln.cap, ln.emit
+        in_stats = ln.stats
 
         def emit(env):
             b = inner(env)
             part, _ = agg_ops.sort_groupby(
-                b, base, gcols, pspecs, out_capacity=cap
+                b, base, gcols, pspecs, out_capacity=cap, col_stats=in_stats
             )
             return agg_ops.finalize_states(part, final_map, k)
 
@@ -292,7 +361,8 @@ class _Lowering:
             plan.group_cols.index(gi): d
             for gi, d in ln.dicts.items() if gi in plan.group_cols
         }
-        return _LNode(emit, out_schema, dicts, ln.replicated, cap)
+        return _LNode(emit, out_schema, dicts, ln.replicated, cap,
+                      _group_stats(ln.stats, plan.group_cols))
 
     def _lower_dense_agg(self, plan: S.Aggregate, ln: _LNode) -> _LNode:
         """Dense-code aggregation: [G] states merge across the mesh with
@@ -405,18 +475,56 @@ class _Lowering:
         pschema, bschema = pl.schema, bl.schema
         pkeys, bkeys, spec = plan.probe_keys, plan.build_keys, plan.spec
         replicated = pl.replicated and bl.replicated
+        stats = dict(pl.stats)
+        if spec.join_type not in ("semi", "anti"):
+            for i, st in bl.stats.items():
+                stats[len(pschema) + i] = st
+        # every bounded key (catalog statistics, dictionary sizes) packs
+        # EXACTLY into one word, as the served flow's HashJoinOp plans it:
+        # the probe is then one gather of a dense table (few bits) or an
+        # unrolled binary search, with no hash, no collision loop
+        layout = join_ops.plan_exact_key(
+            pschema, pkeys, bschema, bkeys, pl.stats, bl.stats,
+            {pk: len(pl.dicts[pk]) for pk in pkeys if pk in pl.dicts},
+            have_remaps=True,
+        )
 
         if spec.build_unique:
+            lut = (layout is not None and layout.total_bits
+                   <= settings.get("sql.distsql.dense_lut_bits"))
+            # an inner or left join cuts its output to a learned cap and
+            # gathers its build columns late, at that cap (`emit_unique_
+            # compact`); until a run has counted the rows it emits
+            # probe-aligned and cannot overflow
+            sid = (self._stage("emit", None, width=1)
+                   if spec.join_type in ("inner", "left") else None)
+            cap = None if sid is None else self.stages[sid].cap
+
             def emit(env):
                 p, b = pemit(env), bemit(env)
-                return join_ops.hash_join_unique(
-                    p, pschema, pkeys, b, bschema, bkeys, spec,
-                    pht, bht, remaps,
-                )
+                if lut:
+                    found_idx, found = join_ops.dense_lut_probe(
+                        p, pkeys, layout,
+                        join_ops.build_dense_lut(b, bkeys, layout, remaps))
+                else:
+                    found_idx, found = join_ops.probe_unique(
+                        p, pschema, pkeys, b, bschema, bkeys, pht, bht,
+                        remaps, exact_layout=layout, exact_remaps=remaps)
+                if cap is None:
+                    out = join_ops.emit_unique(p, b, spec, found_idx, found)
+                    n = jnp.sum(out.mask, dtype=jnp.int32)
+                else:
+                    out, n = join_ops.emit_unique_compact(
+                        p, b, spec, found_idx, found, cap)
+                if sid is not None:
+                    self.counted.append((sid, n.astype(jnp.int32)[None]))
+                return out
 
-            return _LNode(emit, out_schema, dicts, replicated, pl.cap)
+            return _LNode(emit, out_schema, dicts, replicated,
+                          pl.cap if cap is None else min(cap, pl.cap), stats)
 
-        out_cap = _pow2(pl.cap * 2 * self.factor)
+        sid = self._stage("general", _pow2(pl.cap * 2), width=1)
+        out_cap = self.stages[sid].cap
 
         def emit(env):
             p, b = pemit(env), bemit(env)
@@ -424,12 +532,10 @@ class _Lowering:
                 p, pschema, pkeys, b, bschema, bkeys, spec, out_cap,
                 pht, bht, remaps,
             )
-            self.overflows.append(
-                jnp.maximum(total - out_cap, 0).astype(jnp.int32)
-            )
+            self.counted.append((sid, total.astype(jnp.int32)[None]))
             return out
 
-        return _LNode(emit, out_schema, dicts, replicated, out_cap)
+        return _LNode(emit, out_schema, dicts, replicated, out_cap, stats)
 
     def _lower_mergejoin(self, plan: S.MergeJoin) -> _LNode:
         from ..ops import merge_join as mj_ops
@@ -444,7 +550,8 @@ class _Lowering:
         probe_rank, build_rank = mj_ops.rank_tables_for(
             pl.schema, plan.probe_key, pl.dicts, plan.build_key, bl.dicts,
         )
-        out_cap = _pow2(pl.cap * 2 * self.factor)
+        sid = self._stage("general", _pow2(pl.cap * 2), width=1)
+        out_cap = self.stages[sid].cap
         pemit, bemit = pl.emit, bl.emit
         pschema, bschema = pl.schema, bl.schema
         pk, bk, spec = plan.probe_key, plan.build_key, plan.spec
@@ -455,9 +562,7 @@ class _Lowering:
                 p, pschema, pk, b, bschema, bk, spec, out_cap,
                 probe_rank, build_rank,
             )
-            self.overflows.append(
-                jnp.maximum(total - out_cap, 0).astype(jnp.int32)
-            )
+            self.counted.append((sid, total.astype(jnp.int32)[None]))
             return out
 
         return _LNode(emit, out_schema, dicts,
@@ -471,12 +576,33 @@ class _Lowering:
             k.col: ln.dicts[k.col].ranks
             for k in plan.keys if k.col in ln.dicts
         }
-        schema, keys, inner = ln.schema, plan.keys, ln.emit
+        schema, keys, inner, stats = ln.schema, plan.keys, ln.emit, ln.stats
 
         def emit(env):
-            return sort_ops.sort_batch(inner(env), schema, keys, rank_tables)
+            return sort_ops.sort_batch(inner(env), schema, keys, rank_tables,
+                                       stats)
 
-        return _LNode(emit, schema, ln.dicts, ln.replicated, ln.cap)
+        return _LNode(emit, schema, ln.dicts, ln.replicated, ln.cap, stats)
+
+    def _lower_topk(self, plan: S.TopK) -> _LNode:
+        """ORDER BY ... LIMIT k as `ops/sort.topk_batch`'s k-selection: a
+        shard keeps its first k rows of the order at pow2(k) slots, so the
+        Gather above moves D x pow2(k) rows and the merge selects again
+        (sorttopk.go + OrderedSynchronizer; plan/distribute.py stages it)."""
+        ln = self.lower(plan.input)
+        rank_tables = {
+            key.col: ln.dicts[key.col].ranks
+            for key in plan.keys if key.col in ln.dicts
+        }
+        schema, keys, k, inner, stats = (ln.schema, plan.keys, plan.k,
+                                         ln.emit, ln.stats)
+        out_cap = min(ln.cap, _pow2(k))
+
+        def emit(env):
+            return sort_ops.topk_batch(inner(env), schema, keys, k, out_cap,
+                                       rank_tables, stats)
+
+        return _LNode(emit, schema, ln.dicts, ln.replicated, out_cap, stats)
 
     def _lower_limit(self, plan: S.Limit) -> _LNode:
         from ..coldata.batch import compact
@@ -493,7 +619,8 @@ class _Lowering:
                 b = compact(b, capacity=out_cap)  # order-preserving
             return b
 
-        return _LNode(emit, ln.schema, ln.dicts, ln.replicated, out_cap)
+        return _LNode(emit, ln.schema, ln.dicts, ln.replicated, out_cap,
+                      ln.stats)
 
     def _lower_union(self, plan: S.Union) -> _LNode:
         from ..coldata.batch import concat
@@ -540,6 +667,11 @@ class _Lowering:
         return _LNode(emit, out_schema, dicts, ln.replicated, ln.cap)
 
 
+def _group_stats(stats: dict, group_cols) -> dict:
+    return {pos: stats[gi] for pos, gi in enumerate(group_cols)
+            if gi in stats}
+
+
 def _needs_local(plan) -> bool:
     """True when the plan contains a construct the SPMD lowering cannot
     express (today: string_agg's host-side concatenation)."""
@@ -560,125 +692,171 @@ def _needs_local(plan) -> bool:
 
 
 class DistributedQuery:
-    """One distributed query: plan rewrite + SPMD lowering + retry loop.
+    """One distributed query: plan rewrite + SPMD lowering + learned caps.
 
     The reference analog of DistSQLPlanner.PlanAndRunAll + the flow runtime
-    (distsql_running.go:1751,:710), collapsed into build-jit-run."""
+    (distsql_running.go:1751,:710), collapsed into build-jit-run. ``params``
+    is the plan-cache entry's ParamStore: its slots ride into the program
+    as replicated device arguments, so another literal binds the same
+    compiled program."""
 
     def __init__(self, plan: S.PlanNode, catalog: Catalog, mesh,
                  broadcast_rows: int | None = None,
-                 already_distributed: bool = False):
+                 already_distributed: bool = False, params=None):
         self.catalog = catalog
         self.mesh = mesh
         self.D = mesh.shape[AXIS]
-        # unsupported-for-distribution constructs fall back to local
-        # operator execution — the reference's checkSupportForPlanNode
-        # discipline (distsql_physical_planner.go:541): distribute what we
-        # can, never fail a query for being non-distributable
-        self._local_fallback = _needs_local(plan)
-        if self._local_fallback:
-            self.plan = plan
-            self.dplan = plan  # explain() shows the (local) plan
-            return
+        self.params = params
+        # a plan the SPMD lowering cannot express never gets here:
+        # sql/distsql.py `decide` keeps it local (the reference's
+        # checkSupportForPlanNode discipline,
+        # distsql_physical_planner.go:541)
+        if _needs_local(plan):
+            raise TypeError("plan not distributable (string_agg)")
         self.dplan = plan if already_distributed else distribute(
             plan, catalog, broadcast_rows
         )
-        self._build(factor=1)
+        self.caps: dict[int, int] = {}  # stage index -> learned cap
+        self.learned = False  # a run's counts have fitted every cap
+        self.reruns = 0  # statements sent round again by an overflow
+        self._build()
 
-    def _build(self, factor: int):
-        self.factor = factor
-        low = _Lowering(self.catalog, self.D, factor)
+    def _build(self):
+        low = _Lowering(self.catalog, self.D, self.caps)
         root = low.lower(self.dplan)
         self.root = root
-        nscans = len(low.scan_specs)
+        self.stages = low.stages
+        self.scan_specs = low.scan_specs
+        # where each stage's counts lie in the vector the program returns
+        self._slices, at = [], 0
+        for st in low.stages:
+            self._slices.append((at, at + st.width))
+            at += st.width
 
-        def local_fn(*scan_batches):
-            low.overflows = []
+        def local_fn(params, *scan_batches):
+            low.counted = []
             low.emit_cache = {}
-            out = root.emit(list(scan_batches))
+            with ex.param_scope(params):
+                out = root.emit(list(scan_batches))
             low.emit_cache = {}
-            if low.overflows:
-                ovf = sum(jnp.asarray(o, jnp.int32) for o in low.overflows)
-            else:
-                ovf = jnp.int32(0)
-            return out, ovf[None]
+            by_stage = dict(low.counted)
+            counts = (jnp.concatenate([by_stage[i].astype(jnp.int32)
+                                       for i in range(len(low.stages))])
+                      if low.stages else jnp.zeros((0,), jnp.int32))
+            return out, counts[None]
 
-        in_specs = tuple(P(AXIS) for _ in range(nscans))
+        in_specs = (P(),) + tuple(P(AXIS) for _ in low.scan_specs)
         out_specs = (P() if root.replicated else P(AXIS), P(AXIS))
         # dispatch.jit so the whole-pipeline SPMD program counts into
-        # sql_kernel_dispatches (one dispatch per run_batch attempt)
+        # sql_kernel_dispatches (one dispatch a run) and compiles into
+        # dispatch.compiles() like any flow kernel
         self._fn = dispatch.jit(shard_map(
             local_fn, mesh=self.mesh, in_specs=in_specs,
             out_specs=out_specs, check_vma=False,
         ), name="dist_query")
-        # global sharded scan inputs (partitioned-scan placement), cached:
-        # scan shapes don't depend on `factor`, so overflow retries reuse
-        # the already-uploaded shards instead of re-sharding every table
+
+    def _scan_inputs(self) -> list[Batch]:
+        """Every scanned table's row-sharded columns. A host table keeps
+        them itself (`Table.mesh_batch`: uploaded once, when first read, by
+        whichever plan reads them); a KV-engine-backed table is snapshotted
+        anew every launch (`Rel.run_distributed` reaches here; `distsql=auto`
+        keeps such a plan local) through its direct columnar scan and the
+        snapshot row-sharded (the
+        range/leaseholder placement model would instead read per-device
+        spans; one-snapshot-then-shard keeps the same SPMD program shape
+        meanwhile)."""
         from .dist import shard_batch
 
-        if not hasattr(self, "_scan_cache"):
-            self._scan_cache = {}
-        self._scan_batches = []
-        for spec in low.scan_specs:
-            if spec not in self._scan_cache:
-                tname, names, local_cap = spec
-                t = self.catalog.get(tname)
-                if hasattr(t, "columns"):
-                    sub = t.schema.select(
-                        tuple(t.schema.index(n) for n in names))
-                    arrays = {n: np.asarray(t.columns[n]) for n in names}
-                    valids = {n: t.valids[n]
-                              for n in names if n in t.valids}
-                    gb = from_host(sub, arrays, valids=valids,
-                                   capacity=local_cap * self.D)
-                else:
-                    # KV-engine-backed table: snapshot the newest-visible
-                    # rows through the direct columnar scan, then row-shard
-                    # the snapshot like any other input (the
-                    # range/leaseholder placement model would instead read
-                    # per-device spans; one-snapshot-then-shard keeps the
-                    # same SPMD program shape meanwhile)
-                    from ..coldata.batch import compact
+        out = []
+        for spec in self.scan_specs:
+            tname, names, local_cap = spec
+            t = self.catalog.get(tname)
+            if hasattr(t, "mesh_batch"):
+                gb = t.mesh_batch(self.mesh, names)
+                if gb.capacity != local_cap * self.D:
+                    raise RuntimeError(
+                        f"{tname} holds {gb.capacity} padded rows but the "
+                        f"plan sized {local_cap * self.D}; re-plan after "
+                        "the table changed")
+                out.append(gb)
+                continue
+            from ..coldata.batch import compact
 
-                    gb = t.device_batch(tuple(names))
-                    # backstop for the snapshot/now() divergence (sizing
-                    # uses snapshot_live_rows): compacting more live rows
-                    # than planned would silently DROP the tail — fail
-                    # loudly instead (one live-count sync at scan setup)
-                    live = int(np.asarray(
-                        jnp.sum(gb.mask, dtype=jnp.int32)))
-                    if live > local_cap * self.D:
-                        raise RuntimeError(
-                            f"snapshot of {tname} holds {live} live rows "
-                            f"but the plan sized {local_cap * self.D}; "
-                            "re-plan after the snapshot moved"
-                        )
-                    gb = compact(gb, capacity=local_cap * self.D)
-                self._scan_cache[spec] = shard_batch(gb, self.mesh)
-            self._scan_batches.append(self._scan_cache[spec])
+            gb = t.device_batch(tuple(names))  # this launch's snapshot
+            # backstop for the snapshot/now() divergence (sizing uses
+            # snapshot_live_rows): compacting more live rows than planned
+            # would silently DROP the tail — fail loudly instead (one
+            # live-count sync a launch)
+            live = int(np.asarray(jnp.sum(gb.mask, dtype=jnp.int32)))
+            if live > local_cap * self.D:
+                raise RuntimeError(
+                    f"snapshot of {tname} holds {live} live rows "
+                    f"but the plan sized {local_cap * self.D}; "
+                    "re-plan after the snapshot moved"
+                )
+            out.append(shard_batch(compact(gb, capacity=local_cap * self.D),
+                                   self.mesh))
+        return out
 
-    def run_batch(self, max_retries: int = 4) -> tuple[Batch, Schema, dict]:
-        """Execute with the overflow-retry loop; returns the global output
+    def launch(self):
+        """One run of the program -> (global output batch, [D, n] counts,
+        both still on the device)."""
+        args = tuple(self.params.args()) if self.params is not None else ()
+        return self._fn(args, *self._scan_inputs())
+
+    def absorb(self, counts: np.ndarray) -> tuple[bool, dict, list]:
+        """Read one run's counts ([D, n], on the host): -> (an overflow cut
+        this run's output short, the exchanges' totals, and the same a
+        stage for EXPLAIN ANALYZE). Caps are fitted to the first clean
+        run's counts and only grow afterwards; any change re-lowers the
+        program, which then compiles at its next launch."""
+        fullest = [int(counts[:, a:b].max()) if counts.size else 0
+                   for a, b in self._slices]
+        over = [st.cap is not None and m > st.cap
+                for st, m in zip(self.stages, fullest)]
+        overflow, changed = any(over), False
+        for sid, (st, m) in enumerate(zip(self.stages, fullest)):
+            if over[sid] or not (overflow or self.learned):
+                fit = _fit_cap(m)
+                if st.kind == "general":
+                    fit = _pow2(fit)
+                changed = changed or fit != st.cap
+                self.caps[sid] = fit
+        by_stage = []
+        for st, (a, b) in zip(self.stages, self._slices):
+            if st.kind != "exchange":
+                continue
+            c = np.minimum(counts[:, a:b], st.cap).astype(np.int64)
+            off = int(c.sum() - np.trace(c))
+            by_stage.append({
+                "node": st.node, "keys": st.keys, "send_cap": st.cap,
+                "rows": int(c.sum()),
+                "offchip_rows": off, "offchip_bytes": off * st.row_bytes,
+                "send_slots": self.D * self.D * st.cap})
+        seen = {"exchange_stages": len(by_stage)}
+        for k in ("rows", "offchip_rows", "offchip_bytes", "send_slots"):
+            seen["exchange_" + k] = sum(s[k] for s in by_stage)
+        if not overflow:
+            self.learned = True
+        if changed:
+            self._build()
+        return overflow, seen, by_stage
+
+    def run_batch(self, max_retries: int = 6) -> tuple[Batch, Schema, dict]:
+        """Execute with the overflow re-run loop; returns the global output
         batch (+ schema and dictionaries for host decode)."""
         for _ in range(max_retries):
-            out, ovf = self._fn(*self._scan_batches)
-            if int(np.asarray(ovf).sum()) == 0:
+            out, counts = self.launch()
+            overflow = self.absorb(np.asarray(counts))[0]
+            if not overflow:
                 return out, self.root.schema, self.root.dicts
-            # a shuffle bucket or join output overflowed its static
-            # capacity: double every stage capacity and re-lower
-            self._build(factor=self.factor * 2)
+            self.reruns += 1
         raise RuntimeError(
-            f"distributed query still overflows at factor {self.factor}"
+            f"distributed query still overflows after {max_retries} runs"
         )
 
     def run(self) -> dict[str, np.ndarray]:
         from ..utils.errors import query_boundary
-
-        if self._local_fallback:
-            from ..flow.runtime import run_operator
-            from ..plan import builder as plan_builder
-
-            return run_operator(plan_builder.build(self.plan, self.catalog))
 
         @query_boundary("distributed flow")
         def _go():
@@ -690,8 +868,69 @@ class DistributedQuery:
     def explain(self) -> str:
         from ..plan.explain import explain_plan
 
-        if self._local_fallback:
-            # checkSupportForPlanNode said no: the plan runs locally
-            return ("distribution: local (plan not distributable)\n"
-                    + explain_plan(self.dplan))
         return explain_plan(self.dplan)
+
+
+class MeshOp(SourceOperator):
+    """A plan-cache entry's mesh executor as the root of an operator tree:
+    one tile, the whole program's output, so that `runtime.run_operator`
+    pulls, reads back, annotates and re-runs a mesh statement as it does any
+    other. The counts the program returns are read once a statement, at the
+    end of the stream and so inside the `flow/pull` span: the wait for the
+    program lands in the span's ``readback_ms``, what the exchanges
+    delivered in its tags, and an overflow re-runs the statement through
+    `post_run_update` like a join's emission cap."""
+
+    KERNEL = "mesh"
+
+    def __init__(self, query: DistributedQuery):
+        super().__init__()
+        self.query = query
+        self.output_schema = query.root.schema
+        self.dictionaries = dict(query.root.dicts)
+        self.col_stats = dict(query.root.stats)
+        self.what = f"{query.D} devices"
+        self.exchange_stages: list[dict] = []  # the last run's, a stage
+        self._counts = None
+        self._drained = False
+        self._overflow = False
+        self._rerun = False
+
+    def init(self) -> None:
+        super().init()
+        self._counts = None
+        self._drained = False
+        self._overflow = False
+
+    def _next(self):
+        sp = tracing.current()
+        if self._counts is None:
+            if self._drained:
+                return None
+            if self._rerun and sp is not None:
+                sp.add_tag("mesh_overflow_reruns", 1)
+            self._rerun = False
+            out, self._counts = self.query.launch()
+            metric.PLAN_CACHE_MESH_RUNS.inc()
+            return out
+        # the end of the stream: the statement's one wait for the program
+        t0 = time.perf_counter()
+        with tracing.annotation("flow.readback"):
+            # crlint: allow-host-sync(the mesh program's counts: ONE readback a statement)
+            counts = np.asarray(self._counts)
+        self._counts, self._drained = None, True
+        self._overflow, seen, self.exchange_stages = self.query.absorb(counts)
+        if sp is not None:
+            sp.inc_tag("readback_ms",
+                       round((time.perf_counter() - t0) * 1e3, 3))
+            if not self._overflow:
+                for tag, v in seen.items():
+                    sp.inc_tag(tag, v)
+        return None
+
+    def post_run_update(self, truncated: bool = False) -> bool:
+        overflow, self._overflow = self._overflow, False
+        if overflow:
+            self.query.reruns += 1
+            self._rerun = True
+        return overflow

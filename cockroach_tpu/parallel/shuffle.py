@@ -9,11 +9,10 @@ single ``lax.all_to_all`` over the ICI mesh axis delivers every bucket to its
 owner. No serialization, no streams, no flow registry — the interconnect is
 the router.
 
-Static-shape contract: send buffers are [D, send_cap]; rows that overflow
-their destination bucket are counted and reported so the host can retry with
-a larger factor (same capacity-bucketing pattern as the join/groupby kernels).
-With a balanced 64-bit hash, overflow at send_cap = 2x fair share is
-vanishingly rare at real tile sizes.
+Static-shape contract: send buffers are [D, send_cap]; every device reports
+the rows it had for each destination, so a bucket past ``send_cap`` is seen
+by the host, which re-sizes and re-runs (parallel/planner.py learns its caps
+from the same counts, as a join learns its emission cap).
 """
 
 from __future__ import annotations
@@ -26,20 +25,73 @@ import numpy as np
 from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
-from ..coldata.batch import Batch, Column
+from ..coldata.batch import Batch, Column, live_index, pad_rows, take_rows
 from ..coldata.types import Schema
 from ..flow import dispatch
+from ..ops import segscan
 from ..ops.hashing import hash_columns
 from .mesh import AXIS
 
 
+def route(batch: Batch, keys, types, hash_tables, D: int):
+    """Every row's destination device: the 64-bit key hash every one-chip
+    join computes (`ops/hashing.hash_columns`), modulo D; D for a dead row.
+    The same function on every chip, so equal keys meet on one."""
+    h = hash_columns([batch.cols[i] for i in keys], types, hash_tables)
+    return h, jnp.where(batch.mask, (h % np.uint64(D)).astype(jnp.int32), D)
+
+
+def exchange(batch: Batch, bucket, D: int, send_cap: int):  # crlint: allow-mem-accounting(shard_map kernel: send/recv buffers are [D, send_cap] statics the planner budgets)
+    """Per-device half of the all-to-all (runs inside shard_map): rows whose
+    ``bucket`` is d go to device d. -> (received, counts).
+
+    A row's rank in its destination bucket is its place among the bucket's
+    rows in tile order, and for the D <= 8 devices a host has the send
+    buffer of each destination is the tile with that bucket's rows brought
+    to the front (`segscan.rows_to_front`: log2(rows) rounds of selects),
+    cut at ``send_cap``: no sort of the tile, no gather and no scatter (the
+    first design ranked by a stable two-operand `lax.sort` and scattered
+    every column twice; PR 46 read 68-74 ms a 1,048,576-update scatter on
+    this chip). One `lax.all_to_all` a lane delivers the [D, send_cap]
+    buffers. ``received`` is [D * send_cap]: source s's rows are a live
+    prefix of segment s, in s's tile order, so a learned ``send_cap`` leaves
+    little padding and nothing compacts it. ``counts`` [D] int32 are the
+    rows this device had for each destination, BEFORE the cut: a count past
+    ``send_cap`` is an overflow the host sees (with the learned caps it
+    reads the same vector for), re-sizes and re-runs; it never truncates
+    silently."""
+    arrays = []
+    for c in batch.cols:
+        arrays += [c.data, c.valid]
+    counts, sends = [], []
+    for d in range(D):
+        wanted = bucket == d
+        counts.append(jnp.sum(wanted, dtype=jnp.int32))
+        front = segscan.rows_to_front(wanted, arrays)
+        sends.append([pad_rows(a[:send_cap], send_cap) for a in front])
+    counts = jnp.stack(counts)
+    live = (jnp.arange(send_cap, dtype=jnp.int32)[None, :]
+            < jnp.minimum(counts, send_cap)[:, None])  # [D, send_cap]
+    recv = []
+    for lane in zip(*sends):
+        x = jnp.stack(lane)  # [D, send_cap, ...]
+        x = jax.lax.all_to_all(x, AXIS, split_axis=0, concat_axis=0)
+        recv.append(x.reshape((D * send_cap,) + x.shape[2:]))
+    # what lies past a bucket's count is garbage of the compaction: the
+    # mask that travels is the prefix mask, not the garbage's
+    rmask = jax.lax.all_to_all(live, AXIS, split_axis=0, concat_axis=0
+                               ).reshape((D * send_cap,))
+    cols = tuple(Column(data=d, valid=v & rmask)
+                 for d, v in zip(recv[0::2], recv[1::2]))
+    return Batch(cols=cols, mask=rmask), counts
+
+
 def _local_shuffle(batch: Batch, keys, types, hash_tables, D, send_cap,  # crlint: allow-mem-accounting(shard_map kernel: send/recv buffers are [D, send_cap] statics from make_shuffle capacities the planner budgets)
                    out_cap, hot=None):
-    """Per-device half of the shuffle (runs inside shard_map)."""
-    cap = batch.capacity
-    cols = [batch.cols[i] for i in keys]
-    h = hash_columns(cols, types, hash_tables)
-    bucket = (h % np.uint64(D)).astype(jnp.int32)
+    """`exchange` with its output compacted to ``out_cap`` rows (the
+    standalone shuffles below; the planner's stages keep the received
+    layout) -> (out, [1] rows lost to a full bucket or a full output)."""
+    h, bucket = route(batch, keys, types, hash_tables, D)
     keep = None
     if hot is not None:
         # heavy-hitter keys keep their rows LOCAL instead of funneling the
@@ -52,74 +104,19 @@ def _local_shuffle(batch: Batch, keys, types, hash_tables, D, send_cap,  # crlin
         pos = jnp.clip(jnp.searchsorted(hot, h), 0, hot.shape[0] - 1)
         keep = batch.mask & (hot[pos] == h)
         bucket = jnp.where(keep, D, bucket)
-    bucket = jnp.where(batch.mask, bucket, D)  # dead rows sort last
-
-    # slot within destination bucket, via sort (stable rank-in-bucket)
-    iota = jnp.arange(cap, dtype=jnp.int32)
-    sb, si = jax.lax.sort([bucket, iota], num_keys=1, is_stable=True)
-    first = jnp.searchsorted(sb, sb, side="left").astype(jnp.int32)
-    pos_sorted = iota - first
-    slot = jnp.zeros((cap,), jnp.int32).at[si].set(pos_sorted)
-
-    send_live = batch.mask if keep is None else (batch.mask & ~keep)
-    live = send_live & (slot < send_cap)
-    overflow = jnp.sum(send_live & (slot >= send_cap), dtype=jnp.int32)
-    dest = jnp.where(live, bucket * send_cap + slot, D * send_cap)
-
-    def scatter_col(c: Column) -> Column:
-        if c.data.ndim == 2:
-            data = jnp.zeros((D * send_cap, c.data.shape[1]), c.data.dtype)
-        else:
-            data = jnp.zeros((D * send_cap,), c.data.dtype)
-        data = data.at[dest].set(c.data, mode="drop")
-        valid = jnp.zeros((D * send_cap,), jnp.bool_).at[dest].set(
-            c.valid, mode="drop"
-        )
-        return Column(data=data, valid=valid)
-
-    send_mask = jnp.zeros((D * send_cap,), jnp.bool_).at[dest].set(
-        batch.mask, mode="drop"
-    )
-    send = Batch(
-        cols=tuple(scatter_col(c) for c in batch.cols), mask=send_mask
-    )
-    # [D*send_cap] -> [D, send_cap] -> all_to_all -> received from each peer
-    send = jax.tree_util.tree_map(
-        lambda x: x.reshape((D, send_cap) + x.shape[1:]), send
-    )
-    recv = jax.tree_util.tree_map(
-        lambda x: jax.lax.all_to_all(x, AXIS, split_axis=0, concat_axis=0),
-        send,
-    )
-    flat = jax.tree_util.tree_map(
-        lambda x: x.reshape((D * send_cap,) + x.shape[2:]), recv
-    )
-    # compact received rows (plus locally-kept hot rows) into the output
-    if keep is None:
-        m = flat.mask
-        srcs = flat.cols
-    else:
-        m = jnp.concatenate([flat.mask, keep])
-        srcs = tuple(
-            Column(data=jnp.concatenate([fc.data, bc.data]),
-                   valid=jnp.concatenate([fc.valid, bc.valid]))
-            for fc, bc in zip(flat.cols, batch.cols)
-        )
-    rdest = jnp.cumsum(m.astype(jnp.int32)) - 1
-    rdest = jnp.where(m, rdest, out_cap)
-    received = jnp.sum(m, dtype=jnp.int32)
-
-    def compact_col(c: Column) -> Column:
-        if c.data.ndim == 2:
-            data = jnp.zeros((out_cap, c.data.shape[1]), c.data.dtype)
-        else:
-            data = jnp.zeros((out_cap,), c.data.dtype)
-        data = data.at[rdest].set(c.data, mode="drop")
-        valid = jnp.zeros((out_cap,), jnp.bool_).at[rdest].set(c.valid, mode="drop")
-        return Column(data=data, valid=valid)
-
-    out_mask = jnp.arange(out_cap, dtype=jnp.int32) < jnp.minimum(received, out_cap)
-    out = Batch(cols=tuple(compact_col(c) for c in srcs), mask=out_mask)
+    recv, counts = exchange(batch, bucket, D, send_cap)
+    overflow = jnp.sum(jnp.maximum(counts - send_cap, 0), dtype=jnp.int32)
+    if keep is not None:
+        recv = Batch(
+            cols=tuple(
+                Column(data=jnp.concatenate([fc.data, bc.data]),
+                       valid=jnp.concatenate([fc.valid, bc.valid]))
+                for fc, bc in zip(recv.cols, batch.cols)),
+            mask=jnp.concatenate([recv.mask, keep]))
+    idx, received = live_index(recv.mask, out_cap)
+    out_mask = jnp.arange(out_cap, dtype=jnp.int32) < received
+    out = Batch(cols=tuple(take_rows(c, idx, out_cap) for c in recv.cols),
+                mask=out_mask)
     dropped = jnp.maximum(received - out_cap, 0)
     return out, (overflow + dropped)[None]  # [1] per device -> [D] global
 

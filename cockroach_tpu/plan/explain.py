@@ -154,6 +154,43 @@ def explain_plan(plan: S.PlanNode, catalog=None) -> str:
     return "\n".join(lines)
 
 
+def explain_analyze_mesh(root_op) -> str:
+    """EXPLAIN ANALYZE of a statement that ran as one program across the
+    node's devices (parallel/planner.py MeshOp, run with
+    collect_stats(True)): the distributed plan, each all-to-all stage with
+    what the program's counts said of it (live rows delivered, those that
+    left their chip, the send cap a bucket and the slots moved), then the
+    statement's rows, time and dispatches. The stages between exchanges are
+    traced compute of the one program and have no clock of their own."""
+    q = root_op.query
+    by_node = {s["node"]: s for s in root_op.exchange_stages}
+    st = root_op.stats
+    lines = [f"distribution: mesh ({q.D} devices), one program "
+             f"[rows={st.rows} time={st.time_s*1e3:.1f}ms "
+             f"overflow re-runs={q.reruns}]"]
+
+    def walk(n: S.PlanNode, depth: int):
+        s = by_node.get(id(n))
+        ran = ("" if s is None else
+               f"  [rows={s['rows']} offchip_rows={s['offchip_rows']} "
+               f"send_cap={s['send_cap']} send_slots={s['send_slots']}]")
+        lines.append("  " * depth + "-> " + _node_label(n, None) + ran)
+        for c in _children(n):
+            walk(c, depth + 1)
+
+    walk(q.dplan, 0)
+    tsp = getattr(root_op, "_trace_span", None)
+    if tsp is not None:
+        lines.append("trace:")
+        lines.append(tsp.tree(indent=1))
+    kd = getattr(st, "kernel_dispatches", 0)
+    if kd:
+        lines.append(f"kernel dispatches: {kd}")
+        kc = getattr(st, "kernel_compiles", 0)
+        lines.append(f"kernel compiles: {kc} (cached: {kd - kc})")
+    return "\n".join(lines)
+
+
 def _fmt_bytes(n: int) -> str:
     """Human byte figure for EXPLAIN ANALYZE memory lines (KiB below one
     MiB, else MiB — mirroring the reference's humanizeutil sizes)."""

@@ -47,8 +47,25 @@ class Node:
         adopt_interval_s: float = 0.5,
         gossip_peers: list | None = None,
         lease_ranges: list[int] | None = None,
+        devices: int | None = None,
     ):
         self.node_id = int(node_id)
+        # the devices this node's SQL stores live on: None is ONE device,
+        # jax's first, whatever else the machine shows; n > 1 spans the
+        # first n (parallel/mesh.make_mesh), base tables row-sharded over
+        # them (catalog.Table.mesh_batch) and statements placed by
+        # sql/distsql.py. A deployment fact like --store, not a setting.
+        self.mesh = None
+        if devices is not None and int(devices) > 1:
+            import jax
+
+            from ..parallel.mesh import make_mesh
+
+            if len(jax.devices()) < int(devices):
+                raise ValueError(
+                    f"Node(devices={devices}): jax shows "
+                    f"{len(jax.devices())} device(s)")
+            self.mesh = make_mesh(int(devices))
         self.db = db if db is not None else DB(
             # key budget: tsdb keys are "\x01ts<metric>|<13-digit ms>" —
             # metric names run ~30 bytes, so the node store uses wide keys
@@ -160,6 +177,7 @@ class Node:
             from ..catalog import Catalog
 
             self._sql_catalog = Catalog()
+            self._sql_catalog.mesh = self.mesh
             self.pg = PgServer(catalog=self._sql_catalog, db=self.db,
                                port=pg_port).serve_background()
 

@@ -34,7 +34,12 @@ def explain(catalog, text: str) -> str:
     note = matview.explain_note(catalog, rel)
     prefix = (note + "\n") if note else ""
     if distsql:
-        return prefix + rel.explain_distributed()
+        # on a node that spans devices: what a session's default mode
+        # would run; on any other catalog: the plan every device jax shows
+        # would run (sql/distsql.py decides both)
+        served = getattr(catalog, "mesh", None) is not None
+        return prefix + rel.explain_distributed(
+            mode="auto" if served else "on")
     if analyze:
         import time as _time
         from types import SimpleNamespace

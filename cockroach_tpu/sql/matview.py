@@ -301,6 +301,7 @@ class Registry:
             tbl.columns = new_cols
             tbl.valids = new_valids
             tbl._device = None
+            tbl._mesh_device = None
             tbl._stats = None
             for proof in ("_dense_keys", "_unique_keys"):
                 if hasattr(tbl, proof):

@@ -56,7 +56,6 @@ import numpy as np
 from ..coldata.batch import Dictionary
 from ..coldata.types import Family
 from ..ops import expr as ex
-from ..plan import builder as plan_builder
 from ..plan import spec as S
 from ..utils import metric, settings, tracing
 
@@ -316,13 +315,16 @@ class _Entry:
     settings signature is part of an entry's key, so the slots it was
     created under are the slots it lives under."""
 
-    __slots__ = ("pplan", "types", "catalog", "version", "fingerprint",
-                 "hits", "first", "cap", "_free", "_trees", "_cond")
+    __slots__ = ("pplan", "types", "catalog", "distsql", "version",
+                 "fingerprint", "hits", "first", "cap", "_free", "_trees",
+                 "_cond")
 
-    def __init__(self, pplan, types, catalog, fingerprint, tree):
+    def __init__(self, pplan, types, catalog, fingerprint, tree,
+                 distsql: str = "auto"):
         self.pplan = pplan
         self.types = types
         self.catalog = catalog
+        self.distsql = distsql
         self.version = catalog.version
         self.fingerprint = fingerprint
         self.hits = 0
@@ -347,7 +349,8 @@ class _Entry:
                 return self._free.pop()
             self._trees += 1
         try:
-            tree = _build_tree(self.pplan, self.types, self.catalog)
+            tree = _build_tree(self.pplan, self.types, self.catalog,
+                               self.distsql)
         except BaseException:
             self.give_back(None)
             raise
@@ -365,9 +368,19 @@ class _Entry:
             self._cond.notify()
 
 
-def _build_tree(pplan, types, catalog):
+def _build_tree(pplan, types, catalog, distsql: str = "auto"):
+    """What an entry runs: the local operator tree, or on a node that
+    spans devices the mesh program as a one-operator tree, as
+    sql/distsql.py `place` decides (once an entry; the mode keys it)."""
+    from . import distsql as distsql_mod
+
     store = ParamStore(types)
-    return plan_builder.build(pplan, catalog, params=store), store
+    return distsql_mod.place(pplan, catalog, distsql, params=store), store
+
+
+def _placement_key(catalog, distsql: str):
+    """The session's `distsql` where it can change where a plan runs."""
+    return distsql if getattr(catalog, "mesh", None) is not None else None
 
 
 class PlanCache:
@@ -487,7 +500,7 @@ def run_cached(rel, text: str | None = None):
     return res, status
 
 
-def run_cached_ex(rel, text: str | None = None):
+def run_cached_ex(rel, text: str | None = None, distsql: str = "auto"):
     """Execute a bound Rel through the plan cache.
 
     Returns ``(results, status, fingerprint)`` with status one of ``hit``
@@ -510,7 +523,8 @@ def run_cached_ex(rel, text: str | None = None):
         with tracing.leaf_span("sql.plancache.lookup"):
             pplan, values, types = parameterize(plan)
             key = (plan_key(pplan), rel.catalog.version, _settings_sig(),
-                   _dict_gen(rel.catalog, pplan))
+                   _dict_gen(rel.catalog, pplan),
+                   _placement_key(rel.catalog, distsql))
             entry = cache.lookup(key)
     except _Unkeyable:
         return runtime.run_plan(plan, rel.catalog), "uncacheable", ""
@@ -518,7 +532,8 @@ def run_cached_ex(rel, text: str | None = None):
     if entry is None:
         status = "miss"
         entry = _Entry(pplan, types, rel.catalog, _fingerprint(text),
-                       _build_tree(pplan, types, rel.catalog))
+                       _build_tree(pplan, types, rel.catalog, distsql),
+                       distsql)
         # run BEFORE publishing: a plan whose first execution fails never
         # enters the cache (concurrent first executions may both build;
         # insert keeps whichever published first)
@@ -567,11 +582,12 @@ def run_memoized(catalog, text: str):
     return None if m is None else m[0]
 
 
-def run_memoized_ex(catalog, text: str):
+def run_memoized_ex(catalog, text: str, distsql: str = "auto"):
     """Exact-text fast path: if this verbatim statement ran before and
-    its entry is still live (same catalog version + settings), execute it
-    without parsing or binding. Returns (results, entry fingerprint) or
-    None (fall through to the normal path)."""
+    its entry is still live (same catalog version + settings, placed under
+    the same `distsql`), execute it without parsing or binding. Returns
+    (results, entry fingerprint) or None (fall through to the normal
+    path)."""
     if not _cacheable():
         return None
     cache = cache_for(catalog)
@@ -583,7 +599,8 @@ def run_memoized_ex(catalog, text: str):
     # — the entry itself may still live under the old key, so a stale
     # dictionary generation has to be rejected here, not left to lookup
     if (key[1] != catalog.version or key[2] != _settings_sig()
-            or key[3] != _dict_gen_for(catalog, tables)):
+            or key[3] != _dict_gen_for(catalog, tables)
+            or key[4] != _placement_key(catalog, distsql)):
         return None
     entry = cache.lookup(key)
     if entry is None:
@@ -602,7 +619,8 @@ def probe(rel) -> str:
     try:
         pplan, _, _ = parameterize(rel.optimized_plan())
         key = (plan_key(pplan), rel.catalog.version, _settings_sig(),
-               _dict_gen(rel.catalog, pplan))
+               _dict_gen(rel.catalog, pplan),
+               _placement_key(rel.catalog, "auto"))
     except _Unkeyable:
         return "uncacheable"
     hit = cache_for(rel.catalog).peek(key) is not None

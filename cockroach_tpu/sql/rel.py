@@ -417,34 +417,38 @@ class Rel:
     def run_distributed(self, mesh=None,
                         broadcast_rows: int | None = None
                         ) -> dict[str, np.ndarray]:
-        """Execute distributed over the device mesh: the plan is rewritten
-        with Exchange/Broadcast/Gather stages (plan/distribute.py) and
-        lowered into one SPMD program (parallel/planner.py)."""
+        """Execute distributed over the device mesh: the served plan
+        (`optimized_plan()`) is rewritten with Exchange/Broadcast/Gather
+        stages (plan/distribute.py) and lowered into one SPMD program
+        (parallel/planner.py), by the one site that places a plan
+        (sql/distsql.py; mode `on`: a plan that cannot be distributed runs
+        locally). ``mesh`` defaults to the catalog's node's, else to every
+        device jax shows."""
+        from ..flow.runtime import run_operator
+        from . import distsql
+
+        return run_operator(distsql.place(
+            self.optimized_plan(), self.catalog, "on",
+            mesh=self._mesh(mesh), broadcast_rows=broadcast_rows))
+
+    def _mesh(self, mesh=None):
         from ..parallel import mesh as mesh_mod
-        from ..parallel.planner import DistributedQuery
 
         if mesh is None:
-            mesh = mesh_mod.make_mesh()
-        return DistributedQuery(
-            self.plan, self.catalog, mesh, broadcast_rows=broadcast_rows
-        ).run()
+            mesh = getattr(self.catalog, "mesh", None)
+        return mesh if mesh is not None else mesh_mod.make_mesh()
 
-    def explain_distributed(self, broadcast_rows: int | None = None) -> str:
+    def explain_distributed(self, broadcast_rows: int | None = None,
+                            mode: str = "on", mesh=None) -> str:
         """EXPLAIN of the distributed plan (Exchange/Broadcast/Gather
-        stages visible). Pass the same broadcast_rows as run_distributed
-        to see the plan that would actually execute."""
-        from ..parallel.planner import _needs_local
-        from ..plan.distribute import distribute
-        from ..plan.explain import explain_plan
+        stages visible), or of the local one under `distribution: local
+        (<why>)` where `run_distributed` would run that. Pass the same
+        broadcast_rows as run_distributed to see the plan that would
+        actually execute."""
+        from . import distsql
 
-        if _needs_local(self.plan):
-            # run_distributed falls back to local execution for this plan
-            # (checkSupportForPlanNode discipline) — show that truth
-            return ("distribution: local (plan not distributable)\n"
-                    + explain_plan(self.plan))
-        return explain_plan(
-            distribute(self.plan, self.catalog, broadcast_rows)
-        )
+        return distsql.explain(self.optimized_plan(), self.catalog, mode,
+                               self._mesh(mesh), broadcast_rows)
 
     def explain(self) -> str:
         from ..plan.explain import explain_plan
@@ -457,6 +461,14 @@ class Rel:
         from ..flow.runtime import run_plan_with_stats
         from ..plan.explain import explain_analyze
 
+        from ..plan.explain import explain_analyze_mesh
+        from . import distsql
+
         plan = self.optimized_plan()
-        res, root = run_plan_with_stats(plan, self.catalog)
+        # placed as a session's default mode places it: on a node that
+        # spans devices the mesh program runs, and is what is rendered
+        placed = distsql.place(plan, self.catalog, "auto")
+        res, root = run_plan_with_stats(plan, self.catalog, root=placed)
+        if hasattr(root, "exchange_stages"):
+            return explain_analyze_mesh(root), res
         return explain_analyze(plan, root), res

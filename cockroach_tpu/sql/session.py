@@ -317,7 +317,8 @@ class Session:
             from . import plancache
 
             self._set_phase("executing")
-            m = plancache.run_memoized_ex(self.catalog, text)
+            m = plancache.run_memoized_ex(self.catalog, text,
+                                          self._distsql())
             if m is not None:
                 res, fp = m
                 self._last_fp = fp or None
@@ -366,6 +367,12 @@ class Session:
         "distsql": "auto",
     }
 
+    def _distsql(self) -> str:
+        """The session's `distsql` (sql/distsql.py reads it to place a
+        plan on a node that spans devices; it keys the plan-cache entry)."""
+        return getattr(self, "_session_vars", {}).get(
+            "distsql", self._SESSION_VAR_DEFAULTS["distsql"]).lower()
+
     def _maybe_session_var_stmt(self, text: str):
         import re as _re
 
@@ -380,6 +387,13 @@ class Session:
             raw = m.group(2).strip().strip("'\"")
             if not hasattr(self, "_session_vars"):
                 self._session_vars = {}
+            if name == "distsql":
+                from . import distsql as distsql_mod
+
+                if raw.lower() not in distsql_mod.MODES:
+                    raise BindError(
+                        f"invalid value for parameter \"distsql\": {raw!r} "
+                        f"(one of {', '.join(distsql_mod.MODES)})")
             self._session_vars[name] = raw
             if name == "application_name":
                 from . import activity
@@ -506,7 +520,8 @@ class Session:
 
             rel, _mv = matview.maybe_rewrite(self.catalog, rel)
             self._set_phase("executing")
-            res, _, fp = plancache.run_cached_ex(rel, text=text)
+            res, _, fp = plancache.run_cached_ex(rel, text=text,
+                                                 distsql=self._distsql())
             self._last_fp = fp or None
             return res
         # in-txn SELECT: scans read at the txn snapshot, and every scanned

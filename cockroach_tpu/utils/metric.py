@@ -396,6 +396,11 @@ PLAN_CACHE_POOL_RUNS = DEFAULT.counter(
     "sql_plan_cache_pool_runs",
     "statements that ran on a tree other than their plan-cache entry's "
     "first")
+PLAN_CACHE_MESH_RUNS = DEFAULT.counter(
+    "sql_plan_cache_mesh_runs",
+    "runs of a plan-cache entry's mesh program (parallel/planner.py "
+    "MeshOp): a statement of a multi-device node that ran across its "
+    "devices; an overflow's re-run counts again")
 SQL_MEM_CURRENT = DEFAULT.gauge(
     "sql_mem_current",
     "logical SQL bytes currently reserved against the node's root memory "

@@ -129,6 +129,52 @@ def test_hash_shuffle_compiles_on_four_chips(topo):
     print("shuffle memory per device:", compiled.memory_analysis())
 
 
+def test_exchange_at_sf1s_first_stage_moves_no_row_by_index(topo):
+    """parallel/shuffle.exchange at the shapes of the four-chip Q3's first
+    stage (a 1,507,328-row `lineitem` shard of three int64 columns, send
+    buckets at the first-guess cap): four `rows_to_front` compactions and
+    the all-to-all, with no sort, no scatter and no gather of the tile (the
+    first design: a stable two-operand sort and two tile-sized scatters a
+    column)."""
+    from cockroach_tpu import coldata as cd
+    from cockroach_tpu.coldata.batch import Batch, Column
+    from cockroach_tpu.parallel import mesh as mesh_mod
+    from cockroach_tpu.parallel.shuffle import exchange, route
+    from jax import shard_map
+
+    mesh = mesh_mod.make_mesh(devices=list(topo.devices))
+    rows = NamedSharding(mesh, P(mesh_mod.AXIS))
+    schema = cd.Schema.of(k=cd.INT64, p=cd.DECIMAL(12, 2),
+                          d=cd.DECIMAL(12, 2))
+    local_cap, send_cap = 1_507_328, 471_040
+    n = 4 * local_cap
+
+    def col():
+        return Column(data=jax.ShapeDtypeStruct((n,), jnp.int64,
+                                                sharding=rows),
+                      valid=jax.ShapeDtypeStruct((n,), jnp.bool_,
+                                                 sharding=rows))
+
+    batch = Batch(cols=(col(), col(), col()),
+                  mask=jax.ShapeDtypeStruct((n,), jnp.bool_, sharding=rows))
+
+    def local_fn(b):
+        _h, bucket = route(b, (0,), [schema.types[0]], None, 4)
+        out, counts = exchange(b, bucket, 4, send_cap)
+        return out, counts[None]
+
+    # crlint: allow-raw-jit(AOT compile for a described chip: nothing is dispatched)
+    compiled = jax.jit(shard_map(
+        local_fn, mesh=mesh, in_specs=(P(mesh_mod.AXIS),),
+        out_specs=(P(mesh_mod.AXIS), P(mesh_mod.AXIS)),
+        check_vma=False)).lower(batch).compile()
+    hlo = compiled.as_text()
+    assert "all-to-all" in hlo
+    for op in (" sort(", " scatter(", " gather("):
+        assert op not in hlo, op
+    print("exchange memory per device:", compiled.memory_analysis())
+
+
 def test_packed_key_sort_compiles(one_chip):
     """The engine's canonical sort: one packed u64 key word plus the
     permutation operand (ops/keys.py). Kept small: the same compile took
